@@ -21,7 +21,6 @@ Layers (bottom-up):
 * ``utils/`` — metrics and logging.
 """
 
-from . import _compat  # noqa: F401  — jax API aliases for older runtimes
 from .binding import (DDStoreError, NativeStore, fault_configure,
                       owner_of)
 from .elastic import recover as elastic_recover

@@ -1,14 +1,15 @@
 """On-demand native build for the ddstore_tpu C++ core.
 
 Compiles ddstore_tpu/native/*.cc into a shared library with g++ the first
-time the binding is imported (or whenever a source file is newer than the
-cached .so). This replaces the reference's `CC=mpicc CXX=mpicxx pip install .`
+time the binding is imported (or whenever the cached .so was built from
+other source contents). This replaces the reference's `CC=mpicc CXX=mpicxx pip install .`
 requirement (/root/reference/README.md:20-32) — no MPI toolchain exists on
 TPU-VM hosts, and the library must be usable from a plain checkout.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -52,14 +53,38 @@ def _lib_path(mode: str) -> str:
     return os.path.join(_LIB_DIR, f"libddstore_tpu{suffix}.so")
 
 
-def _stale(lib_path: str) -> bool:
+def _flags(mode: str) -> list:
+    flags = ["-O2", "-std=c++17", "-fPIC", "-shared", "-pthread", "-Wall"]
+    if mode:
+        # -O1 + frame pointers give usable sanitizer reports.
+        flags += [_SANITIZERS[mode], "-O1", "-fno-omit-frame-pointer", "-g"]
+    return flags
+
+
+def _source_digest(mode: str) -> str:
+    """Hash of everything the library is built from: the contents of
+    _SOURCES + _HEADERS and the compile flags. Staleness is decided from
+    this, not from mtimes — a tree copy (rsync, git archive, the chip
+    tool) does not preserve mtimes, and a ``_lib/`` that travelled from
+    another tree must be rebuilt, not loaded."""
+    h = hashlib.sha256(" ".join(_flags(mode)).encode())
+    for f in _SOURCES + _HEADERS:
+        h.update(f.encode() + b"\0")
+        with open(os.path.join(_NATIVE_DIR, f), "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
+
+
+def _stale(lib_path: str, digest: str) -> bool:
+    """True unless ``lib_path`` exists and its sidecar records that it was
+    built from exactly ``digest``."""
     if not os.path.exists(lib_path):
         return True
-    lib_mtime = os.path.getmtime(lib_path)
-    for f in _SOURCES + _HEADERS:
-        if os.path.getmtime(os.path.join(_NATIVE_DIR, f)) > lib_mtime:
-            return True
-    return False
+    try:
+        with open(lib_path + ".srchash") as f:
+            return f.read().strip() != digest
+    except OSError:
+        return True
 
 
 def _sweep_strays(max_age_s: float = 600.0) -> None:
@@ -86,22 +111,17 @@ def build(force: bool = False) -> str:
     with _lock:
         if os.path.isdir(_LIB_DIR) and os.access(_LIB_DIR, os.W_OK):
             _sweep_strays()
-        if not force and not _stale(lib_path):
-            return lib_path
         # Installed wheels bundle the library (setup.py build_native); the
         # site-packages tree may be read-only, so fall back to the bundled
         # lib rather than insisting on a rebuild.
         if os.path.exists(lib_path) and not os.access(_LIB_DIR, os.W_OK):
             return lib_path
+        digest = _source_digest(mode)
+        if not force and not _stale(lib_path, digest):
+            return lib_path
         os.makedirs(_LIB_DIR, exist_ok=True)
         cxx = os.environ.get("DDSTORE_CXX", "g++")
-        cmd = [
-            cxx, "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-            "-Wall",
-        ]
-        if mode:
-            # -O1 + frame pointers give usable sanitizer reports.
-            cmd += [_SANITIZERS[mode], "-O1", "-fno-omit-frame-pointer", "-g"]
+        cmd = [cxx] + _flags(mode)
         cmd += [os.path.join(_NATIVE_DIR, s) for s in _SOURCES]
         # Build to a temp path then rename: concurrent test processes may
         # race on the build, and dlopen of a half-written .so is fatal.
@@ -113,6 +133,11 @@ def build(force: bool = False) -> str:
             subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True,
                            text=True)
             os.replace(tmp, lib_path)
+            # Library first, sidecar second: a crash in between leaves a
+            # lib with no (or an old) digest, which reads as stale.
+            with open(tmp, "w") as f:
+                f.write(digest + "\n")
+            os.replace(tmp, lib_path + ".srchash")
         except subprocess.CalledProcessError as e:  # pragma: no cover
             raise RuntimeError(
                 f"native build failed:\n{e.stderr}") from e
@@ -124,8 +149,8 @@ def build(force: bool = False) -> str:
 
 def main(argv=None) -> None:
     """``python -m ddstore_tpu._build`` (or ``make native``): the
-    reproducible rebuild entry — compiles iff a native source is newer
-    than the cached library and prints the library path either way."""
+    reproducible rebuild entry — compiles iff the cached library was built
+    from other source contents and prints the library path either way."""
     import argparse
 
     ap = argparse.ArgumentParser(

@@ -68,7 +68,10 @@ class Block(nn.Module):
         if use_sp:
             out, _ = ring_attention(q, k, v, mesh=self.mesh,
                                     axis=self.sp_axis, causal=True)
-        elif jax.default_backend() == "tpu" and s % 8 == 0:
+        elif jax.default_backend() == "tpu":
+            # On the chip the kernel is the only path: a length it cannot
+            # tile raises in flash_attention (callers pad) rather than
+            # sliding to the S×S reference.
             out, _ = flash_attention(q, k, v, causal=True)
         else:
             out, _ = mha_reference(q, k, v, causal=True)

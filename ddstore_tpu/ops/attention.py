@@ -27,12 +27,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU/interpret-only; keep the module importable anywhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
@@ -160,7 +156,7 @@ _SEMS = ("parallel", "parallel", "arbitrary")
 
 
 def _tpu_params(interpret):
-    if interpret or not _HAS_PALLAS:
+    if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=_SEMS)}
@@ -448,9 +444,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     exceed VMEM). The backward defaults follow block_q/block_k unless
     overridden.
     """
-    if not _HAS_PALLAS:  # pragma: no cover
-        return mha_reference(q, k, v, causal=causal, q_offset=q_offset,
-                             kv_offset=kv_offset, scale=scale)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     if block_q is None:

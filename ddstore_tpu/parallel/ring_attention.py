@@ -147,8 +147,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     any cross-communication). Callable inside jit: shard_map composes.
 
     impl: "flash" (Pallas kernel per ring step — O(block) memory),
-    "xla" (mha_reference), or "auto" (flash on TPU when chunk shapes
-    allow, xla otherwise).
+    "xla" (mha_reference), or "auto" (flash on TPU, xla elsewhere). The
+    flash kernel needs equal chunks that are multiples of 8 and raises
+    otherwise, on "auto" as well.
     """
     n = mesh.shape[axis]
     if batch_axis is None and "dp" in mesh.shape:
@@ -181,17 +182,19 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 hspec = None
     spec = P(bspec, hspec, axis, None)
     sq, sk = q.shape[2] // n, k.shape[2] // n
-    if impl == "auto":
-        use_flash = (jax.default_backend() == "tpu"
-                     and sq == sk and sq % 8 == 0)
-    elif impl in ("flash", "xla"):
-        # The static three-case causal split needs aligned equal chunks.
-        use_flash = impl == "flash"
-        if use_flash and (sq != sk or sq % 8):
-            raise ValueError(f"impl='flash' needs equal tile-aligned "
-                             f"chunks, got ({sq},{sk})")
-    else:
+    if impl not in ("auto", "flash", "xla"):
         raise ValueError(f"unknown impl: {impl!r}")
+    # "auto" is the flash kernel on TPU and the XLA path elsewhere (the
+    # CPU test path). On the chip a chunk shape the kernel cannot take
+    # raises like an explicit "flash" does — it never slides to the
+    # (S/n)×(S/n) reference.
+    use_flash = impl == "flash" or (impl == "auto"
+                                    and jax.default_backend() == "tpu")
+    # The static three-case causal split needs aligned equal chunks.
+    if use_flash and (sq != sk or sq % 8):
+        raise ValueError(f"ring attention's flash kernel needs equal "
+                         f"tile-aligned chunks, got ({sq},{sk}); pad the "
+                         f"sequence or pass impl='xla'")
     if n == 1:
         if use_flash:
             return flash_attention(q, k, v, causal=causal)

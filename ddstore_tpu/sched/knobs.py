@@ -71,8 +71,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     _k("DDSTORE_BARRIER_TIMEOUT_S", "config"),
     _k("DDSTORE_BENCH_DEADLINE_S", "config"),
     _k("DDSTORE_BENCH_PHASE_TIMEOUT_S", "config"),
-    _k("DDSTORE_BENCH_PROBE_TIMEOUT_S", "config"),
-    _k("DDSTORE_BENCH_SKIP_PROBE", "config"),
     _k("DDSTORE_CHAOS_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_CMA", "config", desc="0 disables the CMA fast path "
        "entirely (a capability switch, not a per-class preference)"),
@@ -92,7 +90,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     _k("DDSTORE_CXX", "config",
        desc="C++ compiler for the on-demand native build (default g++)"),
     _k("DDSTORE_DEBUG", "config"),
-    _k("DDSTORE_DRYRUN_TIMEOUT_S", "config"),
     _k("DDSTORE_FAILOVER_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_FAULT_RANKS", "config"),
     _k("DDSTORE_FAULT_SEED", "config"),
@@ -153,7 +150,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
        desc="explicit pod size for pod_bootstrap (with "
             "DDSTORE_COORDINATOR/DDSTORE_PROCESS_ID)"),
     _k("DDSTORE_OP_DEADLINE_S", "config"),
-    _k("DDSTORE_PEAK_FLOPS", "config"),
     _k("DDSTORE_POD_AUTODETECT", "config"),
     _k("DDSTORE_POOL_THREADS", "config"),
     _k("DDSTORE_PPSCHED_PHASE_TIMEOUT_S", "config"),

@@ -5,8 +5,10 @@ stderr and the whole MPI job dies (/root/reference/src/common.cxx:100-111).
 This example shows the ddstore_tpu alternative end to end:
 
 * 4 worker processes build a TCP store, checkpoint their shards
-  (``save_shard``) and train a store-fed VAE (CPU jax — the point here is
-  the store fabric, not the chip).
+  (``save_shard``) and train a store-fed VAE. Every worker pins JAX to
+  the CPU, by design and on any machine: four ranks train side by side
+  and one of them is SIGKILLed, while a chip belongs to one process at a
+  time — the point here is the store fabric, not the chip.
 * The supervisor (this script) SIGKILLs one worker mid-training.
 * Survivors hit a bounded-timeout ``DDStoreError``, call
   ``elastic_recover`` and block at the recovery rendezvous.

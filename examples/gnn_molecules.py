@@ -54,10 +54,6 @@ def main():
     args = p.parse_args()
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     import numpy as np
 
     from ddstore_tpu import DDStore, auto_group
@@ -65,7 +61,9 @@ def main():
                                   GraphShardedDataset, synthetic_graphs)
     from ddstore_tpu.models import gnn
     from ddstore_tpu.parallel import make_mesh
+    from ddstore_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     group = auto_group()
     store = DDStore(group, width=args.width)
     if args.data_dir is not None:

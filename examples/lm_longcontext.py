@@ -87,10 +87,6 @@ def main():
     args = p.parse_args()
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     import jax.numpy as jnp
     import numpy as np
 
@@ -99,7 +95,9 @@ def main():
                                   ShardedDataset)
     from ddstore_tpu.models import transformer
     from ddstore_tpu.parallel import make_mesh
+    from ddstore_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     n_dev = len(jax.local_devices())
     dp = min(args.dp, n_dev)
     pp, tp = args.pp, args.tp

@@ -66,17 +66,14 @@ def main():
 
     import jax
 
-    # Honor an explicit JAX_PLATFORMS even on images whose site hooks
-    # register a different default backend after env parsing.
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from ddstore_tpu import DDStore, auto_group
     from ddstore_tpu.data import (DeviceLoader, DistributedSampler,
                                   ShardedDataset, synthetic_mnist)
     from ddstore_tpu.models import vae
     from ddstore_tpu.parallel import make_mesh
+    from ddstore_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     group = auto_group()
     store = DDStore(group, width=args.width)
     if args.data_dir is not None:

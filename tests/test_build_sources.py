@@ -87,3 +87,30 @@ def test_headers_listed_for_cache_keying():
         f"native/*.h vs _build.py _HEADERS drift: only on disk: "
         f"{sorted(on_disk - headers)}; only in _HEADERS: "
         f"{sorted(headers - on_disk)}")
+
+
+def test_staleness_is_decided_by_content_not_mtime(tmp_path, monkeypatch):
+    """A cached library is fresh only while its sidecar names the digest
+    of the current sources + flags: touching a file changes nothing, a
+    one-byte edit (or a ``_lib/`` that travelled from another tree, whose
+    sidecar names other contents) forces a rebuild."""
+    from ddstore_tpu import _build
+
+    native = tmp_path / "native"
+    native.mkdir()
+    for f in _build._SOURCES + _build._HEADERS:
+        (native / f).write_text(f"// {f}\n")
+    monkeypatch.setattr(_build, "_NATIVE_DIR", str(native))
+    digest = _build._source_digest("")
+    lib = tmp_path / "libddstore_tpu.so"
+    assert _build._stale(str(lib), digest)            # no library
+    lib.write_bytes(b"\x7fELF")
+    assert _build._stale(str(lib), digest)            # no sidecar
+    (tmp_path / "libddstore_tpu.so.srchash").write_text(digest + "\n")
+    assert not _build._stale(str(lib), digest)
+    os.utime(native / "store.cc", (1, 1))             # mtime is ignored
+    assert _build._source_digest("") == digest
+    (native / "wire.h").write_text("// wire.h edited\n")
+    edited = _build._source_digest("")
+    assert edited != digest and _build._stale(str(lib), edited)
+    assert _build._source_digest("address") != edited  # flags are hashed

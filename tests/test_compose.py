@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ddstore_tpu import _compat
 from ddstore_tpu.models import transformer
 from ddstore_tpu.models.transformer import lm_from_stages, lm_to_stages
 from ddstore_tpu.parallel import make_mesh
@@ -117,12 +116,6 @@ def _assert_pp_grads_match(mesh, n_stages, n_micro, schedule="gpipe",
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.xfail(_compat.SHIMMED_SHARD_MAP,
-                   reason="pre-AbstractMesh jax (0.4.x): the _compat "
-                          "shim refuses partial-manual shard_map (auto "
-                          "tp inside manual pp) — known pre-existing "
-                          "failure on that runtime, must pass on "
-                          "jax >= 0.5", strict=False)
 def test_pp_tp_losses_match_sequential():
     mesh = make_mesh({"pp": 2, "tp": 2})
     got = _pp_losses(mesh, n_stages=2, n_micro=4)
