@@ -263,7 +263,7 @@ def _tcp_worker(rank, world, rdv, outfile, num, dim):
                     state, loss = step(state, xb, sub)
                 jax.block_until_ready(loss)
                 res["tcp_vae_eff"] = \
-                    loader.metrics.summary()["input_pipeline_efficiency"]
+                    1.0 - loader.metrics.summary()["loader_wait_share"]
             s.barrier()
         if rank == 0:
             with open(outfile, "w") as f:
@@ -3252,7 +3252,7 @@ def vae_pipeline_bench(samples=8192, batch=512, warm_epochs=2, epochs=5):
                 # Steady-state capability: best epoch for each metric
                 # (single epochs see scheduler noise on shared hosts).
                 best_sps = max(best_sps, sps)
-                eff = max(eff, m["input_pipeline_efficiency"])
+                eff = max(eff, 1.0 - m["loader_wait_share"])
         # Device-step-only rate on the last staged batch: the pipeline
         # number minus this is the host->device link (the VAE pipeline's
         # actual bottleneck, and the part that varies with the transfer
@@ -3428,7 +3428,7 @@ def gnn_pipeline_bench(graphs=4096, graphs_per_slot=8, warm_epochs=1,
             if epoch >= warm_epochs:
                 m = loader.metrics.summary()
                 best_gps = max(best_gps, nb * batch / dt)
-                eff = max(eff, m["input_pipeline_efficiency"])
+                eff = max(eff, 1.0 - m["loader_wait_share"])
         # Device-step-only rate on the last staged batch (same
         # attribution as the vae phase).
         def one_step():

@@ -1,0 +1,8 @@
+"""Roofline share of the flash dkv kernel (``ddstore_flash_dkv``): 8 d FLOPs
+per live pair and head; see ``flash_fwd_roofline``."""
+
+from ddbench import scopes
+
+
+def read(ctx):
+    return scopes.flash_roofline(ctx, "ddstore_flash_dkv")

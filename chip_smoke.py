@@ -256,8 +256,8 @@ def store_leg(store, sets, mesh, mesh1, cfg, record):
             f"{faults['retry_attempts']}")
         m = loader.metrics.summary()
         say(f"    loader (host clock): fetch p50 "
-            f"{m['host_fetch']['p50_s'] * 1e3:.2f} ms, stage p50 "
-            f"{m['device_put']['p50_s'] * 1e3:.2f} ms, consumer wait p50 "
+            f"{m['host_fetch']['p50_s'] * 1e3:.2f} ms, stage enqueue p50 "
+            f"{m['stage_enqueue']['p50_s'] * 1e3:.2f} ms, consumer wait p50 "
             f"{m['device_wait']['p50_s'] * 1e3:.2f} ms")
         say("    python -m ddstore_tpu.diag:")
         diag.main([])

@@ -12,8 +12,14 @@ called out in §7 as the anti-pattern to fix). Here the loader:
   slice directly),
 * runs fetch+stage on a background thread, `prefetch` batches deep, so
   host I/O overlaps device compute (double buffering by default),
-* records the BASELINE.json metrics (device-wait, fetch and stage
-  latencies, input-pipeline efficiency).
+* records, on the host clock, the consumer's wait and the fetch and
+  stage-enqueue latencies (``PipelineMetrics``), and writes the same three
+  as spans into a profiler trace when one is being taken:
+  ``ddstore:wait_batch`` (the consumer blocked on the next batch),
+  ``ddstore:fetch`` (plan, remote reads and copy of one batch) and
+  ``ddstore:stage`` (the ENQUEUE of its host-to-device transfers; no
+  worker blocks on a transfer, so the span does not hold the transfer's
+  end). The spans of one batch share ``batch``, its number in the epoch.
 """
 
 from __future__ import annotations
@@ -386,7 +392,8 @@ class DeviceLoader:
         store = self.dataset.store
         data_var = self.dataset.data_var
         d = int(self.mesh.shape[self.axis])
-        with self.metrics.fetch.timed(), annotate("ddstore:device_fetch"):
+        with self.metrics.fetch.timed(), \
+                annotate("ddstore:device_fetch", batch=seq, rows=len(idx)):
             plan = plan_device_fetch(store.row_starts(data_var), idx, d)
             # Consume the window delivery only once the plan is viable —
             # a ValueError above falls back to the host path, which will
@@ -406,7 +413,7 @@ class DeviceLoader:
 
         def finalize():
             with self.metrics.stage.timed(), \
-                    annotate("ddstore:device_exchange"):
+                    annotate("ddstore:device_exchange", batch=seq):
                 out = [exchange_staged(sf, self.mesh, self.axis)
                        for sf in staged]
             return out[0] if len(out) == 1 else tuple(out)
@@ -499,7 +506,8 @@ class DeviceLoader:
                         # it either — it would re-raise the same error.
                         self._degrade_readahead(e)
                         ra = None
-        with self.metrics.fetch.timed(), annotate("ddstore:fetch"):
+        with self.metrics.fetch.timed(), \
+                annotate("ddstore:fetch", batch=seq, rows=len(idx)):
             batch = None
             if ra is not None:
                 try:
@@ -539,7 +547,12 @@ class DeviceLoader:
                 batch = self.transform(batch)
         if self._sharding is None:
             return batch
-        with self.metrics.stage.timed(), annotate("ddstore:stage"):
+        with self.metrics.stage.timed(), \
+                annotate("ddstore:stage", batch=seq, rows=len(idx)) as span:
+            if span.is_enabled():  # counted only while somebody traces
+                span.set_metadata(bytes=sum(
+                    np.asarray(x).nbytes
+                    for x in jax.tree_util.tree_leaves(batch)))
             put = lambda x: jax.make_array_from_process_local_data(
                 self._sharding, np.ascontiguousarray(x))
             # tree_map preserves container types (tuples, NamedTuple
@@ -608,15 +621,21 @@ class DeviceLoader:
             it = enumerate(self._index_batches())
             for seq, idx in itertools.islice(it, self.prefetch):
                 futs.append(ex.submit(self._fetch, idx, seq, ra))
+            # Futures complete in submission order, so the batch the
+            # consumer waits for is the count of those it has taken.
+            taken = 0
             while futs:
                 t0 = time.perf_counter()
-                item = futs.popleft().result()
-                if isinstance(item, _PendingExchange):
-                    # Collective dispatch happens HERE, on the consumer
-                    # thread — the only thread launching collective
-                    # programs (the train step is its other client).
-                    item = item.finalize()
+                with annotate("ddstore:wait_batch", batch=taken):
+                    item = futs.popleft().result()
+                    if isinstance(item, _PendingExchange):
+                        # Collective dispatch happens HERE, on the
+                        # consumer thread — the only thread launching
+                        # collective programs (the train step is its
+                        # other client).
+                        item = item.finalize()
                 self.metrics.wait.record(time.perf_counter() - t0)
+                taken += 1
                 nxt = next(it, None)
                 if nxt is not None:
                     futs.append(ex.submit(self._fetch, nxt[1], nxt[0],

@@ -33,6 +33,7 @@ from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
 from ..parallel.ring_attention import ring_attention
 from ..parallel.tp import (expert_rules, megatron_rules, shard_pytree,
                            shardings_of)
+from ..utils.profile import phase
 
 
 class Block(nn.Module):
@@ -54,50 +55,52 @@ class Block(nn.Module):
         dt = self.compute_dtype
         hd = self.dim // self.heads
 
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dt)
-        qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=dt,
-                       name="qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(b, s, self.heads, hd).transpose(
-            0, 2, 1, 3)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
-        if self.sow_kv:
-            self.sow("intermediates", "kv", (k, v))
-        use_sp = (self.mesh is not None
-                  and self.mesh.shape.get(self.sp_axis, 1) > 1)
-        if use_sp:
-            out, _ = ring_attention(q, k, v, mesh=self.mesh,
-                                    axis=self.sp_axis, causal=True)
-        elif jax.default_backend() == "tpu":
-            # On the chip the kernel is the only path: a length it cannot
-            # tile raises in flash_attention (callers pad) rather than
-            # sliding to the S×S reference.
-            out, _ = flash_attention(q, k, v, causal=True)
-        else:
-            out, _ = mha_reference(q, k, v, causal=True)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, self.dim).astype(dt)
-        x = x + nn.Dense(self.dim, use_bias=False, dtype=dt,
-                         name="proj")(out)
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dt)
+            qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=dt,
+                           name="qkv")(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            to_heads = lambda t: t.reshape(b, s, self.heads, hd).transpose(
+                0, 2, 1, 3)
+            q, k, v = to_heads(q), to_heads(k), to_heads(v)
+            if self.sow_kv:
+                self.sow("intermediates", "kv", (k, v))
+            use_sp = (self.mesh is not None
+                      and self.mesh.shape.get(self.sp_axis, 1) > 1)
+            if use_sp:
+                out, _ = ring_attention(q, k, v, mesh=self.mesh,
+                                        axis=self.sp_axis, causal=True)
+            elif jax.default_backend() == "tpu":
+                # On the chip the kernel is the only path: a length it cannot
+                # tile raises in flash_attention (callers pad) rather than
+                # sliding to the S×S reference.
+                out, _ = flash_attention(q, k, v, causal=True)
+            else:
+                out, _ = mha_reference(q, k, v, causal=True)
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, self.dim).astype(dt)
+            x = x + nn.Dense(self.dim, use_bias=False, dtype=dt,
+                             name="proj")(out)
 
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(dt)
-        if self.n_experts > 0:
-            from .moe import MoeMlp
-            # token_mask (B, S) excludes padded positions from expert
-            # dispatch: they take no capacity and can't evict real
-            # tokens (one-pass MoE prefill over padded prompts).
-            vmask = None if token_mask is None else \
-                token_mask.reshape(b * s)
-            y, aux = MoeMlp(self.n_experts, self.mlp_ratio * self.dim,
-                            top_k=self.moe_top_k,
-                            capacity=self.moe_capacity,
-                            compute_dtype=dt, name="moe")(
-                h.reshape(b * s, self.dim), vmask)
-            self.sow("intermediates", "moe_aux", aux)
-            x = x + y.reshape(b, s, self.dim).astype(dt)
-        else:
-            h = nn.Dense(self.mlp_ratio * self.dim, dtype=dt, name="up")(h)
-            h = nn.gelu(h)
-            x = x + nn.Dense(self.dim, dtype=dt, name="down")(h)
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(dt)
+            if self.n_experts > 0:
+                from .moe import MoeMlp
+                # token_mask (B, S) excludes padded positions from expert
+                # dispatch: they take no capacity and can't evict real
+                # tokens (one-pass MoE prefill over padded prompts).
+                vmask = None if token_mask is None else \
+                    token_mask.reshape(b * s)
+                y, aux = MoeMlp(self.n_experts, self.mlp_ratio * self.dim,
+                                top_k=self.moe_top_k,
+                                capacity=self.moe_capacity,
+                                compute_dtype=dt, name="moe")(
+                    h.reshape(b * s, self.dim), vmask)
+                self.sow("intermediates", "moe_aux", aux)
+                x = x + y.reshape(b, s, self.dim).astype(dt)
+            else:
+                h = nn.Dense(self.mlp_ratio * self.dim, dtype=dt, name="up")(h)
+                h = nn.gelu(h)
+                x = x + nn.Dense(self.dim, dtype=dt, name="down")(h)
         return x
 
 
@@ -176,8 +179,9 @@ class TransformerLM(nn.Module):
         fused-xent path applies the head kernel itself). ``token_mask``
         (B, S) bool marks real vs padded positions — only MoE routing
         consumes it (padded tokens take no expert capacity)."""
-        x = EmbedPE(self.vocab, self.dim, self.compute_dtype,
-                    name="embed")(tokens, positions)
+        with jax.named_scope("embed"):
+            x = EmbedPE(self.vocab, self.dim, self.compute_dtype,
+                        name="embed")(tokens, positions)
         if self.remat:
             policy = None
             if self.remat_policy:
@@ -200,7 +204,8 @@ class TransformerLM(nn.Module):
                           moe_capacity=self.moe_capacity,
                           sow_kv=self.sow_kv,
                           name=f"block{i}")(x, token_mask)
-        return LMHead(self.vocab, name="lmhead")(x, return_features)
+        with jax.named_scope("head"):
+            return LMHead(self.vocab, name="lmhead")(x, return_features)
 
 
 # Switch-MoE load-balancing aux weight — THE single source for the
@@ -284,16 +289,19 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
     else:
         out = model.apply(params, tokens, positions, fused_xent)
         aux = 0.0
-    if not fused_xent:
-        return loss_fn(out, targets) + aux
+    # The same scope as the final norm and LMHead inside the model: the
+    # whole head, loss included, is one name in a device trace.
+    with jax.named_scope("head"):
+        if not fused_xent:
+            return loss_fn(out, targets) + aux
 
-    from ..ops.xent import fused_linear_xent
+        from ..ops.xent import fused_linear_xent
 
-    w = params["params"]["lmhead"]["head"]["kernel"]
-    nll = fused_linear_xent(
-        out.reshape(-1, out.shape[-1]).astype(model.compute_dtype),
-        w, targets.reshape(-1), xent_block, model.compute_dtype)
-    return nll.mean() + aux
+        w = params["params"]["lmhead"]["head"]["kernel"]
+        nll = fused_linear_xent(
+            out.reshape(-1, out.shape[-1]).astype(model.compute_dtype),
+            w, targets.reshape(-1), xent_block, model.compute_dtype)
+        return nll.mean() + aux
 
 
 # One-shot flag for the fused-xent auto-enable notice (ADVICE r3 #3).
@@ -306,6 +314,7 @@ class TrainState(NamedTuple):
     step: jax.Array
 
 
+@phase("ddstore:state_init")
 def create_train_state(rng: jax.Array, model: TransformerLM,
                        lr: float = 3e-4, mesh: Optional[Mesh] = None
                        ) -> Tuple[TrainState, optax.GradientTransformation]:
@@ -373,7 +382,8 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
         return lm_loss(model, params, tok, tgt, pos,
                        fused_xent=fused_xent, mesh=mesh)
 
-    def step(state: TrainState, tokens, targets, positions):
+    def ddstore_lm_train_step(state: TrainState, tokens, targets,
+                              positions):
         if accum_steps == 1:
             loss, grads = jax.value_and_grad(lossf)(
                 state.params, tokens, targets, positions)
@@ -400,12 +410,17 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
                 lambda g, p: (g / accum_steps).astype(p.dtype),
                 gsum, state.params)
             loss = lsum / accum_steps
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
+    # The function's name is the program's: ``jit_ddstore_lm_train_step`` in
+    # a trace and the ``fun_name`` of its compile durations.
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0,) if donate else ())
+        return jax.jit(ddstore_lm_train_step,
+                       donate_argnums=(0,) if donate else ())
     repl = NamedSharding(mesh, P())
     if state is None and any(mesh.shape.get(a, 1) > 1
                              for a in ("tp", "ep", "fsdp")):
@@ -420,7 +435,8 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
                        if mesh.shape.get(a, 1) > 1) or None
     sp = model.sp_axis if mesh.shape.get(model.sp_axis, 1) > 1 else None
     seq = NamedSharding(mesh, P(batch_axes, sp))
-    return jax.jit(step, in_shardings=(state_sh, seq, seq, seq),
+    return jax.jit(ddstore_lm_train_step,
+                   in_shardings=(state_sh, seq, seq, seq),
                    out_shardings=(state_sh, repl),
                    donate_argnums=(0,) if donate else ())
 

@@ -15,7 +15,10 @@ Design notes (TPU):
 * causal masking takes global ``q_offset``/``kv_offset`` so the same
   kernel serves ring-attention steps, where the kv chunk's global
   position rotates per step;
-* on CPU (tests) the identical kernel runs in interpreter mode.
+* on CPU (tests) the identical kernel runs in interpreter mode;
+* the three kernels carry stable names (``ddstore_flash_fwd``,
+  ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
+  custom call after them, on one chip and inside the ring's branches.
 """
 
 from __future__ import annotations
@@ -177,6 +180,7 @@ def _fwd_impl(q, k, v, causal, q_offset, kv_offset, scale, block_q, block_k,
         kv_offset=kv_offset, block_q=block_q, block_k=block_k)
     out_f, lse_f = pl.pallas_call(
         kernel,
+        name="ddstore_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -359,6 +363,7 @@ def _flash_bwd(causal, q_offset, kv_offset, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq_dq, block_k=bk_dq,
                           **common),
+        name="ddstore_flash_dq",
         grid=(bhs, sq // bq_dq, sk // bk_dq),
         in_specs=[
             pl.BlockSpec((1, bq_dq, d), lambda bh, i, j: (bh, i, 0)),
@@ -377,6 +382,7 @@ def _flash_bwd(causal, q_offset, kv_offset, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq_dkv, block_k=bk_dkv,
                           **common),
+        name="ddstore_flash_dkv",
         grid=(bhs, sk // bk_dkv, sq // bq_dkv),
         in_specs=[
             pl.BlockSpec((1, bq_dkv, d), lambda bh, j, i: (bh, i, 0)),

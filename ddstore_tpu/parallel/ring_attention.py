@@ -74,44 +74,47 @@ def _ring_body(q, k, v, idx_chunk, *, axis: str, n: int, causal: bool,
         src = (idx - step) % n
         kv_off = src * sk
 
-        if use_flash:
-            # The kernel's offsets are static; the traced ring position
-            # reduces to three static mask shapes (module docstring).
-            def attend_past(args):
-                qq, kk, vv = args
-                return flash_attention(qq, kk, vv, causal=False)
+        # One scope a ring step (attend, combine, rotate), so a device
+        # trace sets the ring's own work apart from the block around it.
+        with jax.named_scope("ring_step"):
+            if use_flash:
+                # The kernel's offsets are static; the traced ring position
+                # reduces to three static mask shapes (module docstring).
+                def attend_past(args):
+                    qq, kk, vv = args
+                    return flash_attention(qq, kk, vv, causal=False)
 
-            def attend_diag(args):
-                qq, kk, vv = args
-                return flash_attention(qq, kk, vv, causal=True)
+                def attend_diag(args):
+                    qq, kk, vv = args
+                    return flash_attention(qq, kk, vv, causal=True)
 
-            if causal:
-                out_i, lse_i = jax.lax.cond(
-                    src == idx, attend_diag,
-                    lambda args: jax.lax.cond(src < idx, attend_past,
-                                              masked, args),
-                    (q, k, v))
+                if causal:
+                    out_i, lse_i = jax.lax.cond(
+                        src == idx, attend_diag,
+                        lambda args: jax.lax.cond(src < idx, attend_past,
+                                                  masked, args),
+                        (q, k, v))
+                else:
+                    out_i, lse_i = attend_past((q, k, v))
             else:
-                out_i, lse_i = attend_past((q, k, v))
-        else:
-            def attend(args):
-                qq, kk, vv = args
-                return mha_reference(qq, kk, vv, causal=causal,
-                                     q_offset=q_off, kv_offset=kv_off)
+                def attend(args):
+                    qq, kk, vv = args
+                    return mha_reference(qq, kk, vv, causal=causal,
+                                         q_offset=q_off, kv_offset=kv_off)
 
-            if causal:
-                # A kv chunk entirely in this q chunk's future is fully
-                # masked: skip its O(S²/n²) compute on devices where that
-                # holds (half of all (device, step) pairs — the ring-level
-                # twin of the flash kernel's per-block `live` predicate).
-                out_i, lse_i = jax.lax.cond(src <= idx, attend, masked,
-                                            (q, k, v))
-            else:
-                out_i, lse_i = attend((q, k, v))
-        acc_out, acc_lse = _combine(acc_out, acc_lse, out_i, lse_i)
-        if step < n - 1:
-            k = jax.lax.ppermute(k, axis, perm)
-            v = jax.lax.ppermute(v, axis, perm)
+                if causal:
+                    # A kv chunk entirely in this q chunk's future is fully
+                    # masked: skip its O(S²/n²) compute on devices where that
+                    # holds (half of all (device, step) pairs — the ring-level
+                    # twin of the flash kernel's per-block `live` predicate).
+                    out_i, lse_i = jax.lax.cond(src <= idx, attend, masked,
+                                                (q, k, v))
+                else:
+                    out_i, lse_i = attend((q, k, v))
+            acc_out, acc_lse = _combine(acc_out, acc_lse, out_i, lse_i)
+            if step < n - 1:
+                k = jax.lax.ppermute(k, axis, perm)
+                v = jax.lax.ppermute(v, axis, perm)
     return acc_out.astype(q.dtype), acc_lse
 
 

@@ -29,6 +29,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from .utils.profile import phase
+
 
 class ProcessGroup:
     """Abstract control-plane group."""
@@ -157,6 +159,7 @@ class FileGroup(ProcessGroup):
     rosters only hellos carrying its own id.
     """
 
+    @phase("ddstore:rendezvous")  # ends when every rank is present
     def __init__(self, root: str, rank: int, size: int,
                  timeout: float = 120.0,
                  launch_id: Optional[str] = None):

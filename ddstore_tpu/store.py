@@ -23,6 +23,7 @@ from .binding import (ERR_ADMISSION, ERR_CORRUPT, ERR_PEER_LOST,
                       DDStoreError, NativeStore)
 from .rendezvous import (ProcessGroup, SingleGroup, ThreadGroup,
                          auto_group)
+from .utils.profile import phase
 
 __all__ = ["AsyncBatchRead", "DDStore", "DDStoreError"]
 
@@ -323,27 +324,34 @@ class DDStore:
         nrows = arr.shape[0]
         sample_shape = tuple(arr.shape[1:])
         disp = _row_disp(sample_shape)
-        metas = self.group.allgather(
-            (nrows, arr.dtype.str, sample_shape))
-        shapes = {(d, s) for _, d, s in metas}
-        if len(shapes) != 1:
-            raise DDStoreError(-9, f"add({name}): ranks disagree on "
-                                   f"dtype/sample shape: {sorted(shapes)}")
-        all_nrows = [m[0] for m in metas]
-        self._native.add(self._wname(name), arr, all_nrows, copy=copy)
-        # A borrowed buffer the caller can't write (e.g. a frombuffer
-        # view over an immutable bytes object) must refuse update() with
-        # a DDStoreError, not let the native memcpy SIGSEGV on the
-        # unwritable pages.
-        if not copy and not arr.flags.writeable:
-            readonly = True
-        self._meta[name] = _VarMeta(arr.dtype, sample_shape, disp, all_nrows,
-                                    pinned=None if copy else arr,
-                                    readonly=readonly)
-        # `add` is collective in the reference (MPI_Win_create,
-        # ddstore.hpp:56-62); completing it with a barrier gives the same
-        # guarantee: once any rank returns, every shard is readable.
-        self._finish_collective_add(name)
+        # One set-up phase a registration (add_file, add_mmap and
+        # add_ragged all come through here): the collective part, from the
+        # shape exchange to the last barrier.
+        with phase("ddstore:register", rows=nrows, bytes=arr.nbytes):
+            metas = self.group.allgather(
+                (nrows, arr.dtype.str, sample_shape))
+            shapes = {(d, s) for _, d, s in metas}
+            if len(shapes) != 1:
+                raise DDStoreError(
+                    -9, f"add({name}): ranks disagree on dtype/sample "
+                        f"shape: {sorted(shapes)}")
+            all_nrows = [m[0] for m in metas]
+            self._native.add(self._wname(name), arr, all_nrows, copy=copy)
+            # A borrowed buffer the caller can't write (e.g. a frombuffer
+            # view over an immutable bytes object) must refuse update()
+            # with a DDStoreError, not let the native memcpy SIGSEGV on the
+            # unwritable pages.
+            if not copy and not arr.flags.writeable:
+                readonly = True
+            self._meta[name] = _VarMeta(arr.dtype, sample_shape, disp,
+                                        all_nrows,
+                                        pinned=None if copy else arr,
+                                        readonly=readonly)
+            # `add` is collective in the reference (MPI_Win_create,
+            # ddstore.hpp:56-62); completing it with a barrier gives the
+            # same guarantee: once any rank returns, every shard is
+            # readable.
+            self._finish_collective_add(name)
 
     def init(self, name: str, nrows: int, sample_shape: Tuple[int, ...],
              dtype) -> None:

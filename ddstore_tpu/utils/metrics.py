@@ -1,7 +1,7 @@
 """Observability the reference lacks entirely (SURVEY §5: its only tracing
-is commented-out printf): per-get latency histograms and the
-input-pipeline-efficiency metric that is the BASELINE.json north star
-(≥95% efficiency == near-zero device stall)."""
+is commented-out printf): per-get latency histograms and the loader's own
+wait share on the host clock. (How idle the DEVICE is, BASELINE.json's
+north star, is read from a profiler trace: ``utils/profile.py``.)"""
 
 from __future__ import annotations
 
@@ -98,10 +98,12 @@ def plan_stats_delta(begin: Dict, end: Dict) -> Dict:
 
 
 class PipelineMetrics:
-    """Input-pipeline efficiency: fraction of wall-clock the device did NOT
-    wait on data. The loader records how long each ``__next__`` blocked
-    (`wait`); the training loop's total span is everything else (compute +
-    dispatch). efficiency = 1 - wait/total.
+    """The loader's host-clock accounting. The loader records how long
+    each ``__next__`` blocked the consumer (`wait`); the training loop's
+    total span is everything else (compute + dispatch).
+    ``loader_wait_share`` = wait/total: the share of the epoch the trainer
+    sat blocked on the loader. It is NOT the device's idle share (a chip
+    fed late by a fast loop reads near 0 here): that comes from a trace.
 
     With a plan source attached (``set_plan_source`` — the loader wires
     its dataset's ``DDStore.plan_stats`` automatically), the summary also
@@ -137,7 +139,9 @@ class PipelineMetrics:
     def __init__(self, plan_source: Optional[Callable[[], Dict]] = None):
         self.wait = LatencyHistogram("device_wait")
         self.fetch = LatencyHistogram("host_fetch")
-        self.stage = LatencyHistogram("device_put")
+        # Closes when a batch's host-to-device transfers are ENQUEUED, not
+        # when they end (the loader never blocks on a transfer).
+        self.stage = LatencyHistogram("stage_enqueue")
         # Readahead window accounting: how long the consumer stalled on
         # an unfinished window fetch vs how long staged windows sat
         # ready ahead of need (the overlap headroom), plus the fetch
@@ -817,19 +821,19 @@ class PipelineMetrics:
         return end - self._t_start
 
     @property
-    def efficiency(self) -> float:
+    def loader_wait_share(self) -> float:
         total = self.total_s
         if total <= 0:
-            return 1.0
-        return max(0.0, 1.0 - self.wait.total / total)
+            return 0.0
+        return min(1.0, self.wait.total / total)
 
     def summary(self) -> Dict:
         out = {
-            "input_pipeline_efficiency": self.efficiency,
+            "loader_wait_share": self.loader_wait_share,
             "total_s": self.total_s,
             "device_wait": self.wait.summary(),
             "host_fetch": self.fetch.summary(),
-            "device_put": self.stage.summary(),
+            "stage_enqueue": self.stage.summary(),
         }
         if self._plan_begin is not None:
             # Mid-epoch summary: diff against the live counters.

@@ -1,22 +1,92 @@
-"""JAX profiler integration (SURVEY §5: the reference's only tracing is
-commented-out printf; the rebuild pairs the host-side latency histograms
-in :mod:`.metrics` with device-side traces).
+"""The one tracing mechanism: names and spans on the JAX profiler's clock.
 
-``trace(logdir)`` captures a TensorBoard/XProf trace of everything inside
-the block — XLA device ops, host callbacks, and any :func:`annotate`d
-host-side phases — viewable with ``tensorboard --logdir`` or xprof.
-``annotate(name)`` marks host-side spans (store fetches, staging) so they
-line up against device activity on the trace timeline; it is a cheap
-no-op when no trace is active, so the data layer can annotate
-unconditionally.
+Everything the program says about where its time goes is read from one
+place, a profiler trace (``.xplane.pb``), where host spans and device
+operations share a timeline. No second recorder, no exporter, no switch:
+the profiler being on is the switch, and an annotation outside a trace is
+inert. The vocabulary (``ddstore`` prefix, or a scope of the step
+``ddstore_lm_train_step``):
+
+``ddstore_flash_fwd``, ``ddstore_flash_dq``, ``ddstore_flash_dkv``
+    ``ops/attention.py``, ``pallas_call(name=)``. Device: the three flash
+    kernels, named alike on one chip and in the ring's branches. On the
+    v5e the name is the HLO instruction's, which is what a trace calls the
+    operation (``%ddstore_flash_fwd.8 = ... custom-call(...)``).
+``embed``, ``attn``, ``mlp``, ``head``, ``optimizer``; ``ring_step``
+    ``models/transformer.py``; ``parallel/ring_attention.py``,
+    ``jax.named_scope``. Device: in every operation's ``op_name``, forward
+    and transposed (the compiled module's metadata; a trace does not
+    repeat it, so a reader joins trace and module by instruction name).
+``ddstore_lm_train_step``
+    ``make_train_step``: the jitted step (``jit_ddstore_lm_train_step`` on
+    a trace's ``XLA Modules`` line, ``fun_name`` in :func:`counters`).
+``ddstore:wait_batch``
+    ``data/loader.py``. Host: the consumer blocked on the next batch.
+``ddstore:fetch``
+    ``data/loader.py``. Host: plan, remote reads and copy of one batch.
+``ddstore:stage``
+    ``data/loader.py``. Host: the ENQUEUE of one batch's host-to-device
+    transfers, not their end (the runtime's own
+    ``TransferToDevice=>IssueEvent=>Done`` events are the end).
+``ddstore:rendezvous``, ``ddstore:register``, ``ddstore:state_init``
+    ``rendezvous.py``, ``store.py``, ``models/transformer.py``. Set-up
+    phases: ``FileGroup.__init__`` until every rank is present; one
+    collective ``DDStore.add`` (``rows``, ``bytes``); ``create_train_state``.
+
+The loader's three spans of one batch share ``batch`` (its number in the
+epoch); ``fetch`` carries ``rows``, ``stage`` ``rows`` and ``bytes``
+(annotation arguments: event stats in the trace).
+
+Set-up happens before anybody starts a profiler, so :func:`phase` also
+appends to a small process-wide list that :func:`phases` reads back in
+epoch nanoseconds; ``profile_start_time`` of the trace's ``Task
+Environment`` plane plus an event's ``start_ns`` is the same clock (within
+40 us on the v5e host, PERF.md), so a phase, or a ddtrace event (same
+``CLOCK_MONOTONIC``), can be laid beside a trace. The log keeps the newest
+1024 phases (a process makes a few, and one a ``DDStore.add``).
+:func:`counters` holds the seconds ``jax.monitoring`` reports for tracing
+each jitted function and lowering it to a module: the part of a start that a
+warm compile cache does not take away (``utils.enable_compile_cache``
+registers the listener; the benchmark's ``step_trace_lower_s`` reads the
+step's).
+
+To look at a training run: wrap a few steady steps in :func:`trace` (or
+``python3 benchmarks/run.py --workload W --trace 1 --keep-trace FILE`` on
+the chip), open the directory with xprof / TensorBoard, or read the
+``.xplane.pb`` with ``jax.profiler.ProfileData``: device operations are on
+the ``XLA Ops`` line of each ``/device:TPU:n`` plane, the spans on the host
+planes' thread lines. ``benchmarks/ddbench/tracered.py`` and ``scopes.py``
+are the reductions the benchmark's metrics use (``benchmarks/tests`` checks
+them against recorded v5e slices).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
-from typing import Iterator
+import sys
+import threading
+import time
+from typing import Deque, Dict, Iterator, List
 
-__all__ = ["trace", "annotate", "step_annotate"]
+__all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
+           "watch_compiles", "counters"]
+
+# One reading of both clocks, taken together: perf_counter_ns (what a phase
+# records; CLOCK_MONOTONIC, as ddtrace) and the epoch clock a trace is
+# anchored to.
+_CLOCK_PAIR = (time.perf_counter_ns(), time.time_ns())
+
+_lock = threading.Lock()
+_phases: Deque[dict] = collections.deque(maxlen=1024)
+
+# jax.monitoring names -> the short keys of counters().
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+}
+_compile_s: Dict[str, Dict[str, float]] = {}
+_watching = False
 
 
 @contextlib.contextmanager
@@ -36,7 +106,8 @@ def trace(logdir: str, *, create_perfetto_link: bool = False
 
 def annotate(name: str, **kwargs):
     """Named host-side span on the profiler timeline (zero-cost when no
-    trace is active). Usable as context manager or decorator."""
+    trace is active). Keyword arguments become the event's stats. Usable
+    as context manager or decorator."""
     import jax
 
     return jax.profiler.TraceAnnotation(name, **kwargs)
@@ -48,3 +119,74 @@ def step_annotate(step: int, name: str = "train_step"):
     import jax
 
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+@contextlib.contextmanager
+def phase(name: str, **counts) -> Iterator[None]:
+    """A set-up phase: an :func:`annotate` span (when JAX is loaded; a
+    data-only process never imports it for this) and one entry of the
+    process-wide phase log, closed even when the block raises. Usable as
+    context manager or decorator, from any thread; phases may nest (the
+    log says so by their times alone)."""
+    entry = {"name": name, "start": time.perf_counter_ns(), "end": None,
+             "counts": dict(counts)}
+    with _lock:
+        _phases.append(entry)
+    span = annotate(name, **counts) if "jax" in sys.modules \
+        else contextlib.nullcontext()
+    try:
+        with span:
+            yield
+    finally:
+        entry["end"] = time.perf_counter_ns()
+
+
+def phases() -> List[dict]:
+    """The closed phases of this process (the newest 1024), in the order
+    they began: ``name``, ``start_ns`` and ``end_ns`` in epoch nanoseconds
+    (a trace's ``profile_start_time`` + an event's ``start_ns`` is the same
+    clock) and ``counts``."""
+    mono0, epoch0 = _CLOCK_PAIR
+    with _lock:
+        snap = list(_phases)
+    return [{"name": e["name"],
+             "start_ns": epoch0 + e["start"] - mono0,
+             "end_ns": epoch0 + e["end"] - mono0,
+             "counts": dict(e["counts"])}
+            for e in snap if e["end"] is not None]
+
+
+def _on_duration(event: str, seconds: float, **kwargs) -> None:
+    """Sums trace and lowering seconds per ``fun_name``. JAX reports
+    tracing under ``f`` and lowering under ``jit(f)``: one key."""
+    key = _DURATIONS.get(event)
+    if key is None:
+        return
+    fun = str(kwargs.get("fun_name", ""))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    with _lock:
+        per_fun = _compile_s.setdefault(fun, {})
+        per_fun[key] = per_fun.get(key, 0.0) + float(seconds)
+
+
+def watch_compiles() -> None:
+    """Registers the ``jax.monitoring`` listener behind :func:`counters`,
+    once a process (``enable_compile_cache()`` calls this)."""
+    import jax
+
+    global _watching
+    with _lock:
+        if _watching:
+            return
+        _watching = True
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def counters() -> dict:
+    """What JAX reported in this process since ``enable_compile_cache()``:
+    ``compile_s[fun_name]`` with ``trace_s`` and ``lower_s``, the seconds it
+    spent tracing that function and lowering it to a module, summed over
+    every time it did. A compile cache shortens neither."""
+    with _lock:
+        return {"compile_s": {f: dict(d) for f, d in _compile_s.items()}}

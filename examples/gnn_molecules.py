@@ -114,7 +114,7 @@ def main():
             gps = nb * per_proc_batch * max(1, jax.process_count()) / dt
             print(f"epoch {epoch}: loss={total / max(1, nb):.4f} "
                   f"graphs/s={gps:.0f} "
-                  f"pipeline_eff={m['input_pipeline_efficiency']:.3f} "
+                  f"loader_wait_share={m['loader_wait_share']:.4f} "
                   f"fetch_p50={m['host_fetch']['p50_s'] * 1e3:.2f}ms",
                   flush=True)
     store.close()

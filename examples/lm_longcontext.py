@@ -206,7 +206,7 @@ def main():
             tps = nb * batch * args.seq / dt
             print(f"epoch {epoch}: loss={tot / max(1, nb):.4f} "
                   f"tokens/s={tps:.0f} "
-                  f"pipeline_eff={m['input_pipeline_efficiency']:.3f}",
+                  f"loader_wait_share={m['loader_wait_share']:.4f}",
                   flush=True)
     if args.generate > 0 and store.rank == 0:
         # KV-cached greedy continuation of the first window's prefix —

@@ -141,7 +141,7 @@ def main():
             print(f"epoch {epoch}: loss/sample="
                   f"{total / max(1, nb) / per_proc_batch:.3f} "
                   f"samples/s={sps:.0f} "
-                  f"pipeline_eff={m['input_pipeline_efficiency']:.3f} "
+                  f"loader_wait_share={m['loader_wait_share']:.4f} "
                   f"fetch_p50={m['host_fetch']['p50_s'] * 1e3:.2f}ms"
                   + (" bytes_moved=" + str(m["bytes_moved"])
                      if "bytes_moved" in m else ""),

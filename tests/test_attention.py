@@ -227,3 +227,27 @@ def test_ring_single_axis_mesh_fallback():
     out, lse = ring_attention(q, k, v, mesh=mesh, causal=True)
     out_r, lse_r = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_r), atol=1e-6)
+
+
+def test_the_three_kernels_carry_their_names():
+    """A device trace names a Mosaic call after ``pallas_call(name=)``: the
+    gradient of flash attention holds exactly the three named kernels."""
+    q, k, v = _qkv(7, s=64)
+
+    def loss(q, k, v):
+        out, _ = flash_attention(q, k, v, causal=True, block_q=32,
+                                 block_k=32)
+        return out.sum()
+
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert sorted(names) == ["ddstore_flash_dkv", "ddstore_flash_dq",
+                             "ddstore_flash_fwd"]

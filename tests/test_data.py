@@ -198,7 +198,9 @@ class TestDeviceLoaderHost:
                 pass
             s = loader.metrics.summary()
             assert s["host_fetch"]["count"] == 4
-            assert 0.0 <= s["input_pipeline_efficiency"] <= 1.0
+            assert 0.0 <= s["loader_wait_share"] <= 1.0
+            assert "input_pipeline_efficiency" not in s
+            assert "stage_enqueue" in s and "device_put" not in s
 
 
 class TestDeviceLoaderJax:

@@ -2,16 +2,22 @@
 the annotated data-layer spans must not perturb results (annotations are
 no-ops without an active trace)."""
 
+import collections
 import glob
 import os
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.profiler import ProfileData
 
 from ddstore_tpu import DDStore, SingleGroup
 from ddstore_tpu.data import DeviceLoader, DistributedSampler, ShardedDataset
-from ddstore_tpu.utils import annotate, step_annotate, trace
+from ddstore_tpu.utils import (PipelineMetrics, annotate, profile,
+                               step_annotate, trace)
 
 
 def test_trace_produces_artifact(tmp_path):
@@ -39,3 +45,174 @@ def test_annotated_loader_runs_without_trace():
         assert len(batches) == 4
         total = np.concatenate(batches)
         np.testing.assert_array_equal(np.sort(total, axis=0), data)
+
+
+# -- the program's own names and spans (ISSUE 25) ---------------------------
+
+
+def _events(logdir, prefix):
+    """``{name: [stats, ...]}`` of the host events under ``logdir`` whose
+    name starts with ``prefix``, and the trace's epoch anchor."""
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    found, anchor = {}, None
+    for plane in ProfileData.from_file(path).planes:
+        stats = dict(plane.stats)
+        anchor = stats.get("profile_start_time", anchor)
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    found.setdefault(ev.name, []).append(
+                        dict(ev.stats, start_ns=ev.start_ns))
+    return found, anchor
+
+
+def test_loader_spans_share_a_batch_number(tmp_path):
+    """Three batches in a trace: each has its wait, fetch and stage span,
+    joined by ``batch``, with the counts taken at the boundary."""
+    logdir = str(tmp_path / "prof")
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
+    with DDStore(SingleGroup(), backend="local") as store:
+        data = np.arange(48 * 4, dtype=np.float32).reshape(48, 4)
+        ds = ShardedDataset(store, data)
+        loader = DeviceLoader(ds, DistributedSampler(48, 1, 0),
+                              batch_size=16, mesh=mesh)
+        with trace(logdir):
+            assert len(list(loader)) == 3
+    found, _ = _events(logdir, "ddstore:")
+    for name in ("ddstore:wait_batch", "ddstore:fetch", "ddstore:stage"):
+        assert sorted(s["batch"] for s in found[name]) == [0, 1, 2], name
+    assert {s["rows"] for s in found["ddstore:fetch"]} == {16}
+    assert {(s["rows"], s["bytes"]) for s in found["ddstore:stage"]} \
+        == {(16, 16 * 4 * 4)}
+
+
+def test_phase_nests_counts_and_survives_an_exception():
+    before = len(profile.phases())
+    with profile.phase("ddstore:test_outer", bytes=7):
+        with pytest.raises(ValueError):
+            with profile.phase("ddstore:test_inner", rows=3):
+                raise ValueError("inside")
+        with profile.phase("ddstore:test_second"):
+            pass
+    outer, inner, second = profile.phases()[before:]
+    assert [p["name"] for p in (outer, inner, second)] == [
+        "ddstore:test_outer", "ddstore:test_inner", "ddstore:test_second"]
+    assert outer["counts"] == {"bytes": 7}
+    assert inner["counts"] == {"rows": 3} and second["counts"] == {}
+    # nesting is in the times: both inner phases lie inside the outer one
+    assert outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"] \
+        <= second["start_ns"] <= second["end_ns"] <= outer["end_ns"]
+
+
+def test_phase_decorates_and_logs_from_any_thread():
+    """The decorator form makes one entry a call, on whichever thread."""
+    @profile.phase("ddstore:test_decorated", rows=1)
+    def work():
+        return 5
+
+    before = len(profile.phases())
+    with profile.phase("ddstore:test_main"):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert work() == 5
+    main, other, mine = profile.phases()[before:]
+    assert main["name"] == "ddstore:test_main"
+    assert other["name"] == mine["name"] == "ddstore:test_decorated"
+    assert other["counts"] == mine["counts"] == {"rows": 1}
+    assert other["end_ns"] <= mine["start_ns"]
+
+
+def test_phase_log_keeps_the_newest(monkeypatch):
+    """Always on, so bounded: a process that registers variables for ever
+    keeps the newest entries and loses the oldest."""
+    assert profile._phases.maxlen == 1024
+    monkeypatch.setattr(profile, "_phases", collections.deque(maxlen=3))
+    for i in range(5):
+        with profile.phase("ddstore:test_many", i=i):
+            pass
+    assert [p["counts"]["i"] for p in profile.phases()] == [2, 3, 4]
+
+
+def test_phases_are_on_the_epoch_clock_and_the_trace_s(tmp_path):
+    """``phases()`` gives epoch nanoseconds, within a millisecond of
+    ``time.time_ns()``; a phase inside a trace starts where the trace
+    (its anchor + the event's start) says it does."""
+    logdir = str(tmp_path / "prof")
+    with trace(logdir):
+        t0 = time.time_ns()
+        with profile.phase("ddstore:test_clock"):
+            pass
+        t1 = time.time_ns()
+    mine = [p for p in profile.phases() if p["name"] == "ddstore:test_clock"]
+    assert t0 - 1_000_000 <= mine[-1]["start_ns"] <= mine[-1]["end_ns"] \
+        <= t1 + 1_000_000
+    found, anchor = _events(logdir, "ddstore:test_clock")
+    in_trace = anchor + found["ddstore:test_clock"][-1]["start_ns"]
+    assert abs(in_trace - mine[-1]["start_ns"]) < 1_000_000
+
+
+def test_store_and_state_set_up_are_phases(tmp_path):
+    from ddstore_tpu import FileGroup
+    from ddstore_tpu.models import transformer
+
+    before = len(profile.phases())
+    group = FileGroup(str(tmp_path / "rdv"), 0, 1)
+    with DDStore(group, backend="local") as store:
+        store.add("x", np.zeros((8, 4), np.float32))
+        store.add_ragged("r", [np.zeros((3, 2), np.float32)])
+    model = transformer.TransformerLM(vocab=64, dim=32, heads=4, layers=1)
+    transformer.create_train_state(jax.random.key(0), model)
+    mine = profile.phases()[before:]
+    names = [p["name"] for p in mine]
+    assert names[0] == "ddstore:rendezvous"
+    # add_ragged registers two variables through add(), one after the
+    # other, so a sum over ddstore:register counts every byte once.
+    reg = [p for p in mine if p["name"] == "ddstore:register"]
+    assert [p["counts"]["bytes"] for p in reg] == [8 * 4 * 4, 3 * 2 * 4, 16]
+    assert all(a["end_ns"] <= b["start_ns"] for a, b in zip(reg, reg[1:]))
+    assert names[-1] == "ddstore:state_init"
+
+
+def test_compile_counters_are_kept_per_function(monkeypatch, tmp_path):
+    from ddstore_tpu.utils import enable_compile_cache
+
+    # With the variable set the call touches no JAX config: it only starts
+    # the counters, and does so once however often it is called.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == enable_compile_cache() == str(tmp_path)
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 1.0,
+        fun_name="ddstore_test_once")
+    assert profile.counters()["compile_s"]["ddstore_test_once"] \
+        == {"trace_s": 1.0}
+
+    def ddstore_test_counted(x):
+        return x * 3 + 1
+
+    jax.jit(ddstore_test_counted).lower(jnp.ones(5)).compile()
+    mine = profile.counters()["compile_s"]["ddstore_test_counted"]
+    # one key a function, whatever prefix JAX reports each stage under
+    assert set(mine) == {"trace_s", "lower_s"}
+    assert all(v > 0 for v in mine.values())
+    jax.jit(ddstore_test_counted).lower(jnp.ones(6)).compile()
+    again = profile.counters()["compile_s"]["ddstore_test_counted"]
+    assert all(again[k] > mine[k] for k in mine)
+    assert set(profile.counters()) == {"compile_s"}
+
+
+def test_summary_names_what_it_measures():
+    m = PipelineMetrics()
+    m.epoch_start()
+    m.wait.record(0.25)
+    time.sleep(0.01)
+    m.epoch_end()
+    s = m.summary()
+    assert "input_pipeline_efficiency" not in s and "device_put" not in s
+    assert not hasattr(m, "efficiency")
+    assert s["loader_wait_share"] == pytest.approx(
+        min(1.0, 0.25 / s["total_s"]))
+    assert s["stage_enqueue"]["count"] == 0
+    assert PipelineMetrics().loader_wait_share == 0.0

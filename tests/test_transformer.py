@@ -85,3 +85,26 @@ def test_store_fed_lm_training_loss_decreases():
                 losses.append(float(loss))
         assert losses[-1] < losses[0] * 0.6, (losses[0], losses[-1])
         assert losses[-1] < np.log(vocab)
+
+
+def test_the_step_carries_its_scope_names():
+    """The names a device trace is read by: the step's function name and
+    one scope a module boundary, forward and transposed, in the lowered
+    text's locations (they become each operation's ``op_name``)."""
+    mesh = make_mesh({"dp": 2, "sp": 2}, jax.devices()[:4])
+    model = transformer.TransformerLM(vocab=64, dim=32, heads=4, layers=2,
+                                      compute_dtype=jnp.float32, mesh=mesh)
+    state, tx = transformer.create_train_state(jax.random.key(0), model,
+                                               mesh=mesh)
+    step = transformer.make_train_step(model, tx, mesh=mesh, state=state)
+    tok = jnp.zeros((2, 16), jnp.int32)
+    pos = jnp.tile(jnp.arange(16, dtype=jnp.int32), (2, 1))
+    text = step.lower(state, tok, tok, pos).as_text(debug_info=True)
+    assert "jit_ddstore_lm_train_step" in text
+    for scope in ("embed", "attn", "mlp", "head", "optimizer"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    # a shard_map's body begins a name stack of its own; the compiled
+    # module joins it to the caller's (``.../attn/shard_map/ring_step/...``)
+    assert '"ring_step/' in text
+    assert "transpose(jvp(TransformerLM))/block1/attn/" in text
+    assert "jvp(TransformerLM)/block0/mlp/" in text

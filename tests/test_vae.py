@@ -105,5 +105,5 @@ def test_store_fed_training_loss_decreases():
         # per-epoch improvement, not a specific ratio.
         assert losses[2] < losses[1] < losses[0], losses
         assert losses[-1] < losses[0] * 0.99, losses
-        eff = loader.metrics.summary()["input_pipeline_efficiency"]
-        assert 0.0 <= eff <= 1.0
+        wait = loader.metrics.summary()["loader_wait_share"]
+        assert 0.0 <= wait <= 1.0
