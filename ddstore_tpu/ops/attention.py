@@ -15,6 +15,14 @@ Design notes (TPU):
 * causal masking takes global ``q_offset``/``kv_offset`` so the same
   kernel serves ring-attention steps, where the kv chunk's global
   position rotates per step;
+* a causal call spends nothing on pairs the mask kills: its grid is the
+  enumeration of its live blocks (scalar-prefetched step tables: a dead
+  block is no step, so it costs no DMA and no step), and a block that
+  straddles the diagonal is computed in static strips that end at the
+  diagonal, while the fetched block stays large. ``causal_geometry``
+  counts what a call computes and fetches from the same functions the
+  kernels' set-up runs on, and ``utils.profile.counters()`` keeps it per
+  compiled shape. ``causal=False`` lowers as it always did;
 * on CPU (tests) the identical kernel runs in interpreter mode;
 * the three kernels carry stable names (``ddstore_flash_fwd``,
   ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
@@ -25,39 +33,206 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import profile
+
 NEG_INF = float("-inf")
 
 
-def _causal_liveness(iq, ik, block_q, block_k, q_offset, kv_offset):
-    """(live, diag) for a causal (q-block, k-block) pair: ``live`` = the
-    block has any unmasked entry; ``diag`` = it straddles the diagonal
-    and needs the iota mask (blocks entirely in the past are mask-free —
-    the mask's compare/select is pure VPU cost). THE single classification
-    shared by the forward and both backward kernels."""
-    q_lo = q_offset + iq * block_q
-    k_lo = kv_offset + ik * block_k
-    live = k_lo <= q_lo + block_q - 1
-    diag = live & (k_lo + block_k - 1 > q_lo)
-    return live, diag
+# Rows (forward, dq) or columns (dkv) of one compute strip inside a block
+# that straddles the causal diagonal, per kernel, and the grain its other
+# side is cut to (the lane width). The FETCHED block stays large; what is
+# computed of it ends at the diagonal. Chosen on the chip (PERF.md section
+# 6, PR 26): dq gains a tenth from the narrower strip, the forward and dkv
+# nothing that pays for twice the bodies to trace and lower at every start.
+_STRIP = {"ddstore_flash_fwd": 512, "ddstore_flash_dq": 256,
+          "ddstore_flash_dkv": 512}
+_LANES = 128
+# A diagonal block's position against the diagonal (first row - first
+# column) is one of a few static values, each a kernel body of its own;
+# beyond this many bodies the kernel masks whole blocks by a traced shift.
+_MAX_DIAG_BODIES = 32
+
+# Codes of a grid step (``_steps``): what to run, and whether the step
+# opens or closes its row of the accumulation.
+_NOTHING, _INTERIOR, _DIAGONAL = 0, 1, 2
+_FIRST, _LAST = 1 << 16, 1 << 17
 
 
-def _masked_dispatch(causal, live, diag, update):
-    """Run ``update(masked)`` under the liveness predicates: the diagonal
-    body with masking, interior live blocks without, dead blocks not at
-    all (non-causal: one unmasked body, unconditionally)."""
-    if causal:
-        pl.when(diag)(lambda: update(True))
-        pl.when(live & ~diag)(lambda: update(False))
+def _tiles(num, den, n):
+    """``min(max(num, 0) // den, n)``: a count of whole tiles."""
+    return np.minimum(np.maximum(num, 0) // den, n)
+
+
+def _live_cols(row_lo, rows, col_lo, cols, n):
+    """Causal classification of ``n`` column tiles of width ``cols`` from
+    position ``col_lo`` against the rows ``[row_lo, row_lo + rows)``:
+    ``(n_past, n_live)``. Tiles ``[0, n_past)`` lie wholly in the past (no
+    mask), ``[n_past, n_live)`` straddle the diagonal, the rest are dead.
+    THE classification of the forward and dq kernels (k/v stream) at both
+    sizes, blocks over the grid and lane-wide tiles inside a block, and of
+    the counter."""
+    return (_tiles(row_lo - col_lo + 1, cols, n),
+            _tiles(row_lo + rows - 1 - col_lo + cols, cols, n))
+
+
+def _live_rows(col_lo, cols, row_lo, rows, n):
+    """The same classification seen from the columns ``[col_lo, col_lo +
+    cols)`` over ``n`` row tiles of height ``rows`` from ``row_lo`` (the
+    dkv kernel's q stream): ``(first_live, first_past)``. Tiles ``[0,
+    first_live)`` are dead, ``[first_live, first_past)`` straddle the
+    diagonal, ``[first_past, n)`` lie wholly in the past."""
+    return (_tiles(col_lo - row_lo, rows, n),
+            _tiles(col_lo + cols - 1 - row_lo + rows - 1, rows, n))
+
+
+class FlashGeometry(NamedTuple):
+    """How one kernel tiles one call: what its set-up builds the grid, the
+    index maps and the strips from, and what that costs per batch*head
+    (``utils.profile.counters()`` keeps it per compiled shape)."""
+    sq: int
+    sk: int
+    block_q: int
+    block_k: int
+    sub_q: int                 # rows of a compute tile in a diagonal block
+    sub_k: int                 # and its columns
+    q_offset: int
+    kv_offset: int
+    stream: str                # innermost grid axis: "k" (fwd, dq), "q" (dkv)
+    pairs_needed: int          # (query, key) pairs with key <= query
+    pairs_computed: int        # pairs in the tiles a body runs over
+    grid_steps: int
+    steps_fetching_dead: int   # DMAs of a streamed block no live step uses
+
+
+def _steps(geo):
+    """``_enumerate`` for the call ``geo`` describes."""
+    return _enumerate(geo.sq, geo.sk, geo.block_q, geo.block_k,
+                      geo.q_offset, geo.kv_offset, geo.stream)
+
+
+@functools.lru_cache(maxsize=256)
+def _enumerate(sq, sk, bq, bk, q_offset, kv_offset, stream):
+    """The grid of one causal kernel, enumerated: only the steps whose
+    block is live, in the kernel's order (a row that is dead throughout
+    keeps one step, to write its zeros). Returns ``(outer, inner, code,
+    shifts)``: per step the two block indices and ``_INTERIOR`` or
+    ``_DIAGONAL + v`` (``v`` indexes ``shifts``, the static values of first
+    row - first column over the diagonal blocks) with ``_FIRST`` /
+    ``_LAST`` set on a row's ends."""
+    nq, nk = sq // bq, sk // bk
+    if stream == "k":
+        outer, inner = np.indices((nq, nk))
+        iq, ik = outer, inner
+        n_past, n_live = _live_cols(q_offset + iq * bq, bq, kv_offset, bk,
+                                    nk)
+        live, past = ik < n_live, ik < n_past
+        keep = live | ((n_live == 0) & (ik == 0))
     else:
-        update(False)
+        outer, inner = np.indices((nk, nq))
+        ik, iq = outer, inner
+        first_live, first_past = _live_rows(kv_offset + ik * bk, bk,
+                                            q_offset, bq, nq)
+        live, past = iq >= first_live, iq >= first_past
+        keep = live | ((first_live == nq) & (iq == nq - 1))
+    shift = q_offset + iq * bq - kv_offset - ik * bk
+    shifts, variant = np.unique(shift[live & ~past], return_inverse=True)
+    code = np.full(live.shape, _NOTHING)
+    code[live & past] = _INTERIOR
+    code[live & ~past] = _DIAGONAL + variant.ravel()
+    kept = np.cumsum(keep, axis=1)
+    code |= np.where(keep & (kept == 1), _FIRST, 0)
+    code |= np.where(keep & (kept == kept[:, -1:]), _LAST, 0)
+    return tuple(np.asarray(a[keep], np.int32) for a in (outer, inner, code)
+                 ) + (tuple(int(x) for x in shifts),)
+
+
+def _strips(geo, shift):
+    """The compute strips of a diagonal block whose first row lies
+    ``shift`` past its first column: ``(rows, cols, strip_shift)`` as
+    static slices of the block. A forward or dq strip is ``sub_q`` rows by
+    every ``sub_k``-wide tile up to the last live one; a dkv strip is
+    ``sub_k`` columns by every ``sub_q``-high tile from the first live one.
+    One matmul chain each, masked by its own shift."""
+    bq, bk, tq, tk = geo.block_q, geo.block_k, geo.sub_q, geo.sub_k
+    if geo.stream == "k":
+        for g in range(bq // tq):
+            live = int(_live_cols(shift + g * tq, tq, 0, tk, bk // tk)[1])
+            if live:
+                yield (slice(g * tq, (g + 1) * tq), slice(0, live * tk),
+                       shift + g * tq)
+    else:
+        for c in range(bk // tk):
+            first = int(_live_rows(c * tk, tk, shift, tq, bq // tq)[0])
+            if first < bq // tq:
+                yield (slice(first * tq, bq), slice(c * tk, (c + 1) * tk),
+                       shift + first * tq - c * tk)
+
+
+def _static_diagonal(geo, shifts):
+    """Whether the diagonal blocks' strips are few enough to be static
+    bodies (else: whole blocks under a traced shift)."""
+    strips = geo.block_q // geo.sub_q if geo.stream == "k" \
+        else geo.block_k // geo.sub_k
+    return len(shifts) * strips <= _MAX_DIAG_BODIES
+
+
+@functools.lru_cache(maxsize=256)
+def causal_geometry(sq: int, sk: int, blocks: Tuple[int, int],
+                    sub: Tuple[int, int], q_offset: int = 0,
+                    kv_offset: int = 0, stream: str = "k") -> FlashGeometry:
+    """Geometry of one causal kernel call, counted per batch*head from
+    what the kernel's set-up itself runs on (``_steps``, ``_strips``):
+    ``pairs_needed`` (what ``benchmarks/ddbench/flops.py`` counts: s(s+1)/2
+    at zero offsets), ``pairs_computed`` (interior blocks whole, diagonal
+    blocks by their strips, dead blocks nothing), ``grid_steps`` and
+    ``steps_fetching_dead``."""
+    (bq, bk), (tq, tk) = blocks, sub
+    geo = FlashGeometry(sq, sk, bq, bk, tq, tk, q_offset, kv_offset, stream,
+                        0, 0, 0, 0)
+    outer, inner, code, shifts = _steps(geo)
+    what = code & (_FIRST - 1)
+    if _static_diagonal(geo, shifts):
+        in_strips = [sum((r.stop - r.start) * (c.stop - c.start)
+                         for r, c, _ in _strips(geo, shift))
+                     for shift in shifts]
+    else:
+        in_strips = [bq * bk] * len(shifts)
+    computed = (what == _INTERIOR).sum() * bq * bk + sum(
+        in_strips[v - _DIAGONAL] for v in what[what >= _DIAGONAL])
+    needed = np.clip(q_offset + np.arange(sq) - kv_offset + 1, 0, sk).sum()
+    # The pipeline issues a DMA when the streamed block's index changes;
+    # the block then serves every step until the next change. A DMA is
+    # spent on the dead when none of the steps it serves is live.
+    dma = np.cumsum(np.concatenate([[True], inner[1:] != inner[:-1]]))
+    serves_live = np.bincount(dma, weights=what != _NOTHING) > 0
+    return geo._replace(pairs_needed=int(needed),
+                        pairs_computed=int(computed),
+                        grid_steps=len(code),
+                        steps_fetching_dead=int((~serves_live[1:]).sum()))
+
+
+def _dense_geometry(sq, sk, bq, bk, stream):
+    """Non-causal: every block live, one unmasked body a step."""
+    return FlashGeometry(sq, sk, bq, bk, bq, bk, 0, 0, stream, sq * sk,
+                         sq * sk, (sq // bq) * (sk // bk), 0)
+
+
+def _sub_tile(block: int, want: int) -> int:
+    """Largest multiple of the lane width that divides ``block`` and is <=
+    ``want``; the block itself when there is none."""
+    for t in range(min(want, block) // _LANES * _LANES, 0, -_LANES):
+        if block % t == 0:
+            return t
+    return block
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -88,43 +263,98 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype), lse
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale, causal, q_offset, kv_offset, block_q, block_k):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _causal_mask(s, shift):
+    """Keep ``s[r, c]`` where global key position <= query position:
+    ``shift`` = first row's position - first column's."""
+    col_minus_row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+    return jnp.where(col_minus_row <= shift, s, NEG_INF)
 
-    @pl.when(ik == 0)
+
+class _Step(NamedTuple):
+    """One grid step as a kernel body sees it: whether it opens / closes
+    its row of the accumulation, and ``run(update)``, which calls
+    ``update(rows, cols, shift)`` over what is live of the step's block
+    (``shift`` None: no mask)."""
+    first: jax.Array
+    last: jax.Array
+    run: Callable
+
+
+_WHOLE = slice(None)
+
+
+def _grid_kernel(kernel, causal, geo):
+    """Adapts ``kernel(step, *refs)`` to the grid ``_call`` builds.
+
+    Non-causal: the full ``(bh, outer, inner)`` grid, one unmasked body a
+    step. Causal: the enumerated steps of ``_steps``, their block indices
+    and codes read from the scalar-prefetched tables; an interior block
+    runs that same single body, a block that straddles the diagonal runs
+    the static strips of its position (``_strips``)."""
+    if not causal:
+        def dense(*refs):
+            inner, n = pl.program_id(2), pl.num_programs(2)
+            kernel(_Step(inner == 0, inner == n - 1,
+                         lambda update: update(_WHOLE, _WHOLE, None)), *refs)
+        return dense
+
+    _, _, codes, shifts = _steps(geo)
+    # No interior block, no interior body: a whole call fetched as one
+    # diagonal block never lowers the block-wide scores.
+    has_interior = bool(((codes & (_FIRST - 1)) == _INTERIOR).any())
+    static = _static_diagonal(geo, shifts)
+
+    def enumerated(outer_ref, inner_ref, code_ref, *refs):
+        t = pl.program_id(1)
+        code = code_ref[t]
+        what = code & (_FIRST - 1)
+
+        def run(update):
+            def strips(shift):
+                for strip in _strips(geo, shift):
+                    update(*strip)
+
+            if has_interior:
+                pl.when(what == _INTERIOR)(
+                    lambda: update(_WHOLE, _WHOLE, None))
+            if static:
+                for v, shift in enumerate(shifts):
+                    pl.when(what == _DIAGONAL + v)(
+                        functools.partial(strips, shift))
+            else:
+                iq, ik = outer_ref[t], inner_ref[t]
+                if geo.stream == "q":
+                    iq, ik = ik, iq
+                shift = (geo.q_offset + iq * geo.block_q
+                         - geo.kv_offset - ik * geo.block_k)
+                pl.when(what >= _DIAGONAL)(
+                    lambda: update(_WHOLE, _WHOLE, shift))
+
+        kernel(_Step((code & _FIRST) != 0, (code & _LAST) != 0, run), *refs)
+    return enumerated
+
+
+def _flash_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                  acc_scr, *, scale):
+    @pl.when(step.first)
     def _():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # For causal, a K/V block entirely in the future contributes nothing —
-    # predicate the whole accumulation away (≈halves causal FLOPs). Blocks
-    # entirely in the PAST need no mask either: the iota/compare/select on
-    # a (block_q, block_k) tile is pure VPU work and the kernel is
-    # VPU-bound, so interior blocks take a mask-free body and only the
-    # O(S/block) diagonal-straddling blocks pay for masking.
-    if causal:
-        live, diag = _causal_liveness(iq, ik, block_q, block_k, q_offset,
-                                      kv_offset)
-    else:
-        live, diag = True, False
-
-    def update(masked):
-        q = q_ref[0]  # (block_q, D)
-        k = k_ref[0]  # (block_k, D)
+    # A K/V tile entirely in the future contributes nothing and is never
+    # computed; one entirely in the PAST needs no mask (the iota, compare
+    # and select on a tile are pure VPU work and the kernel is VPU-bound).
+    # ``step.run`` decides which tiles run and how.
+    def update(rows, cols, shift):
+        q = q_ref[0, rows, :]                                # (tq, D)
+        k = k_ref[0, cols, :]                                # (tk, D)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if shift is not None:
+            s = _causal_mask(s, shift)
 
-        if masked:
-            qpos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kv_offset + ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]                               # (block_q, 1)
+        m_prev = m_scr[rows, :1]                             # (tq, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         # Rows with everything masked so far keep m=-inf; safe_m keeps the
@@ -133,212 +363,188 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         p = jnp.exp(s - safe_m)
         corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0],
+        l_new = l_scr[rows, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[rows, :] = acc_scr[rows, :] * corr + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0, cols, :],
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[rows, :] = jnp.broadcast_to(m_new, (m_new.shape[0], 128))
+        l_scr[rows, :] = jnp.broadcast_to(l_new, (l_new.shape[0], 128))
 
-    _masked_dispatch(causal, live, diag, update)
+    step.run(update)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step.last)
     def _():
         l = l_scr[:, :1]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         m = m_scr[:, :1]
         lse = jnp.where(jnp.isfinite(m),
                         m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
-        lse_ref[0] = jnp.broadcast_to(lse, (block_q, 128))
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
-# Grid-step overhead on TPU is ~0.3us and steps run sequentially per core,
-# so blocks must be big enough that the MXU work dominates: 512x2048 blocks
-# measured 100.8 TF/s vs 12.8 TF/s at 128x128 on v5e (7.0x over XLA's 14.4).
-_SEMS = ("parallel", "parallel", "arbitrary")
+def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
+          interpret):
+    """One flash ``pallas_call`` over ``(bh, S, .)`` operands. ``resident``
+    says per operand whether its block rides the outer grid axis (it
+    stays in VMEM across a row; the outputs all do) or the inner one (it
+    is streamed).
+
+    Grid steps run one after another on the core at ~0.35 us each before
+    any work, and a step whose streamed block is new pays its DMA, so the
+    fetched block is large and, causal, only live blocks are steps at all
+    (PERF.md section 6, PR 26, has what the sizes were chosen from)."""
+    bhs = operands[0].shape[0]
+    q_side = geo.stream == "k"     # which side the outer axis walks
+
+    def block(width, on_outer):
+        rows = geo.block_q if on_outer == q_side else geo.block_k
+        if causal:
+            def index(bh, t, outer, inner, code):
+                return bh, (outer if on_outer else inner)[t], 0
+        else:
+            def index(bh, o, i):
+                return bh, o if on_outer else i, 0
+        return pl.BlockSpec((1, rows, width), index)
+
+    in_specs = [block(x.shape[-1], on_outer)
+                for x, on_outer in zip(operands, resident)]
+    out_specs = [block(o.shape[-1], True) for o in out_shape]
+    if causal:
+        tables = _steps(geo)[:3]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bhs, len(tables[0])),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
+        sems = ("parallel", "arbitrary")
+        operands = tuple(jnp.asarray(t) for t in tables) + tuple(operands)
+    else:
+        n_q, n_k = geo.sq // geo.block_q, geo.sk // geo.block_k
+        grid_spec = pl.GridSpec(
+            grid=(bhs,) + ((n_q, n_k) if q_side else (n_k, n_q)),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
+        sems = ("parallel", "parallel", "arbitrary")
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(dimension_semantics=sems)}
+    return pl.pallas_call(
+        _grid_kernel(kernel, causal, geo), name=name, grid_spec=grid_spec,
+        out_shape=out_shape, interpret=interpret, **params)(*operands)
 
 
-def _tpu_params(interpret):
-    if interpret:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=_SEMS)}
-
-
-def _fwd_impl(q, k, v, causal, q_offset, kv_offset, scale, block_q, block_k,
-              interpret):
-    """Runs the forward kernel; returns (out, lse, lse128-residual)."""
+def _fwd_impl(q, k, v, causal, scale, geo, interpret):
+    """Runs the forward kernel; returns (out, lse)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
-    grid = (b * h, sq // block_q, sk // block_k)
-
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, q_offset=q_offset,
-        kv_offset=kv_offset, block_q=block_q, block_k=block_k)
-    out_f, lse_f = pl.pallas_call(
-        kernel,
-        name="ddstore_flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            # lse carries a broadcast 128-lane dim purely so its block is
-            # (block_q, 128)-tile-aligned for the TPU lowering; lane 0 is
-            # the value. The full tensor doubles as the backward residual.
-            pl.BlockSpec((1, block_q, 128), lambda bh, i, j: (bh, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),    # running numerator
-        ],
-        interpret=interpret,
-        **_tpu_params(interpret),
-    )(qf, kf, vf)
-    return (out_f.reshape(b, h, sq, d), lse_f[..., 0].reshape(b, h, sq),
-            lse_f)
+    bhs = b * h
+    out_f, lse_f = _call(
+        functools.partial(_flash_kernel, scale=scale), "ddstore_flash_fwd",
+        causal, geo,
+        (q.reshape(bhs, sq, d), k.reshape(bhs, sk, d), v.reshape(bhs, sk, d)),
+        (True, False, False),
+        [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype),
+         # lse carries a broadcast 128-lane dim purely so its block is
+         # (block_q, 128)-tile-aligned for the TPU lowering; lane 0 is
+         # the value.
+         jax.ShapeDtypeStruct((bhs, sq, 128), jnp.float32)],
+        [pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running max
+         pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running denom
+         pltpu.VMEM((geo.block_q, d), jnp.float32)],    # running numerator
+        interpret)
+    return out_f.reshape(b, h, sq, d), lse_f[..., 0].reshape(b, h, sq)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, dta_ref, dq_ref,
-                   dq_acc, *, scale, causal, q_offset, kv_offset, block_q,
-                   block_k):
+def _recompute_p(q, k, dta_ref, rows, shift, scale):
+    """The backward kernels' recomputed probabilities of one tile."""
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    if shift is not None:
+        s = _causal_mask(s, shift)
+    lse = dta_ref[0, rows, 1:2]                              # (tq, 1)
+    # Fully-masked rows have lse = -inf; exp(s - safe_lse) is then
+    # exp(-inf - big) = 0 for every column — no full-block select.
+    safe_lse = jnp.where(jnp.isfinite(lse), lse, 1e30)
+    return jnp.exp(s - safe_lse)
+
+
+def _bwd_dq_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dq_ref,
+                   dq_acc, *, scale):
     """dq for one q block, streaming k/v blocks (recompute-p flash bwd).
 
     ``dta`` packs the per-row residual scalars into one 128-lane tensor
     (lane 0 = c = delta - dlse with delta = rowsum(do*o); lane 1 = lse):
     one streamed side input instead of two."""
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
+    @pl.when(step.first)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    if causal:
-        live, diag = _causal_liveness(iq, ik, block_q, block_k, q_offset,
-                                      kv_offset)
-    else:
-        live, diag = True, False
-
-    def update(masked):
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if masked:
-            qpos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kv_offset + ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        lse = dta_ref[0][:, 1:2]                             # (block_q, 1)
-        # Fully-masked rows have lse = -inf; exp(s - safe_lse) is then
-        # exp(-inf - big) = 0 for every column — no full-block select.
-        safe_lse = jnp.where(jnp.isfinite(lse), lse, 1e30)
-        p = jnp.exp(s - safe_lse)
-        do = do_ref[0]
-        dp = jnp.dot(do, v_ref[0].T, preferred_element_type=jnp.float32)
+    def update(rows, cols, shift):
+        k = k_ref[0, cols, :]
+        p = _recompute_p(q_ref[0, rows, :], k, dta_ref, rows, shift, scale)
+        dp = jnp.dot(do_ref[0, rows, :], v_ref[0, cols, :].T,
+                     preferred_element_type=jnp.float32)
         # ds = p * (dp - c) with c = delta - dlse packed in lane 0.
-        t = p * (dp - dta_ref[0][:, :1])
-        dq_acc[:] = dq_acc[:] + jnp.dot(
+        t = p * (dp - dta_ref[0, rows, :1])
+        dq_acc[rows, :] = dq_acc[rows, :] + jnp.dot(
             t.astype(k.dtype), k, preferred_element_type=jnp.float32) * scale
 
-    _masked_dispatch(causal, live, diag, update)
+    step.run(update)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step.last)
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, scale, causal, q_offset,
-                    kv_offset, block_q, block_k):
+def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, scale):
     """dk/dv for one k/v block, streaming q blocks.
 
     The q-side streams (q, do, dta) re-fetch every grid step here (their
     block index rides the innermost loop), so the packed single ``dta``
     side input (c = delta - dlse in lane 0, lse in lane 1) halves the
     f32 side-stream HBM traffic vs separate lse + dta tensors."""
-    ik, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(iq == 0)
+    @pl.when(step.first)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        live, diag = _causal_liveness(iq, ik, block_q, block_k, q_offset,
-                                      kv_offset)
-    else:
-        live, diag = True, False
-
-    def update(masked):
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if masked:
-            qpos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kv_offset + ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        lse = dta_ref[0][:, 1:2]
-        safe_lse = jnp.where(jnp.isfinite(lse), lse, 1e30)
-        p = jnp.exp(s - safe_lse)
-        do = do_ref[0]
-        dv_acc[:] = dv_acc[:] + jnp.dot(
+    def update(rows, cols, shift):
+        q = q_ref[0, rows, :]
+        p = _recompute_p(q, k_ref[0, cols, :], dta_ref, rows, shift, scale)
+        do = do_ref[0, rows, :]
+        dv_acc[cols, :] = dv_acc[cols, :] + jnp.dot(
             p.astype(do.dtype).T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_ref[0].T, preferred_element_type=jnp.float32)
-        t = p * (dp - dta_ref[0][:, :1])
-        dk_acc[:] = dk_acc[:] + jnp.dot(
+        dp = jnp.dot(do, v_ref[0, cols, :].T,
+                     preferred_element_type=jnp.float32)
+        t = p * (dp - dta_ref[0, rows, :1])
+        dk_acc[cols, :] = dk_acc[cols, :] + jnp.dot(
             t.astype(q.dtype).T, q, preferred_element_type=jnp.float32) \
             * scale
 
-    _masked_dispatch(causal, live, diag, update)
+    step.run(update)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step.last)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
-def _flash(q, k, v, causal, q_offset, kv_offset, scale, block_q, block_k,
-           bwd_blocks, interpret):
-    out, lse, _ = _fwd_impl(q, k, v, causal, q_offset, kv_offset, scale,
-                            block_q, block_k, interpret)
-    return out, lse
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, geos, interpret):
+    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret)
 
 
-def _flash_fwd(q, k, v, causal, q_offset, kv_offset, scale, block_q,
-               block_k, bwd_blocks, interpret):
-    out, lse, _ = _fwd_impl(q, k, v, causal, q_offset, kv_offset,
-                            scale, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, scale, geos, interpret):
+    out, lse = _fwd_impl(q, k, v, causal, scale, geos[0], interpret)
     # Residual is the THIN (B, H, S) lse — the kernel's 128-lane output
     # is tile-alignment scaffolding and holding it across fwd→bwd would
     # cost 128x the activation memory (~1 GiB at the S=8192 LM config).
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, q_offset, kv_offset, scale, block_q, block_k,
-               bwd_blocks, interpret, res, g):
+def _flash_bwd(causal, scale, geos, interpret, res, g):
     q, k, v, out, lse = res
     do, dlse = g
     # The backward kernels stream different data patterns than the
     # forward (dq: k/v innermost; dkv: the whole q side innermost), so
     # they take their own block shapes.
-    bq_dq, bk_dq, bq_dkv, bk_dkv = bwd_blocks
+    _, g_dq, g_dkv = geos
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bhs = b * h
@@ -357,55 +563,20 @@ def _flash_bwd(causal, q_offset, kv_offset, scale, block_q, block_k,
     c = delta - dlse.reshape(bhs, sq).astype(jnp.float32)
     dta = jnp.pad(jnp.stack([c, lse.reshape(bhs, sq)], axis=-1),
                   ((0, 0), (0, 0), (0, 126)))
+    operands = (qf, kf, vf, dof, dta)
 
-    common = dict(scale=scale, causal=causal, q_offset=q_offset,
-                  kv_offset=kv_offset)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=bq_dq, block_k=bk_dq,
-                          **common),
-        name="ddstore_flash_dq",
-        grid=(bhs, sq // bq_dq, sk // bk_dq),
-        in_specs=[
-            pl.BlockSpec((1, bq_dq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk_dq, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk_dq, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bq_dq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq_dq, 128), lambda bh, i, j: (bh, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq_dq, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bhs, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq_dq, d), jnp.float32)],
-        interpret=interpret,
-        **_tpu_params(interpret),
-    )(qf, kf, vf, dof, dta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=bq_dkv, block_k=bk_dkv,
-                          **common),
-        name="ddstore_flash_dkv",
-        grid=(bhs, sk // bk_dkv, sq // bq_dkv),
-        in_specs=[
-            pl.BlockSpec((1, bq_dkv, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bq_dkv, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, bq_dkv, 128), lambda bh, j, i: (bh, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, j, i: (bh, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bhs, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bhs, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk_dkv, d), jnp.float32),
-            pltpu.VMEM((bk_dkv, d), jnp.float32),
-        ],
-        interpret=interpret,
-        **_tpu_params(interpret),
-    )(qf, kf, vf, dof, dta)
+    (dq,) = _call(
+        functools.partial(_bwd_dq_kernel, scale=scale), "ddstore_flash_dq",
+        causal, g_dq, operands, (True, False, False, True, True),
+        [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype)],
+        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret)
+    dk, dv = _call(
+        functools.partial(_bwd_dkv_kernel, scale=scale), "ddstore_flash_dkv",
+        causal, g_dkv, operands, (False, True, True, False, False),
+        [jax.ShapeDtypeStruct((bhs, sk, d), k.dtype),
+         jax.ShapeDtypeStruct((bhs, sk, d), v.dtype)],
+        [pltpu.VMEM((g_dkv.block_k, d), jnp.float32),
+         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -421,6 +592,30 @@ def _fit_block(block: int, s: int) -> int:
         if s % b == 0:
             return b
     return 0
+
+
+def _default_blocks(causal, sq, sk, d, q_offset, kv_offset):
+    """``((block_q, block_k) forward, (block_q, block_k) dq and dkv)``
+    from what a call can see; PERF.md section 6 (PR 26) has the v5e times
+    they were chosen from. A causal call of at most 2048 x 2048, heads no
+    wider than 128, none of whose blocks would lie wholly in the past, is
+    fetched whole by the backward kernels and in up to 1024 rows by the
+    forward (the whole 2048 rows put the forward within 1 % of the 16 MiB
+    of VMEM a kernel may take): nothing runs as an interior body, whose
+    block-wide scores would not fit VMEM at these sizes, and almost every
+    grid step is work. Otherwise 512 x 2048 below S=8192; at it and beyond
+    the backward kernels and the non-causal forward take 1024 x 1024
+    (2048-wide q blocks exceed VMEM there), the causal forward stays at
+    512 x 2048: its diagonal strips want the width."""
+    if causal and max(sq, sk) <= 2048 and d <= 128:
+        short = (min(sq, 1024), sk), (sq, sk)
+        if not any(_INTERIOR in _enumerate(sq, sk, *blocks, q_offset,
+                                           kv_offset, "k")[2] & (_FIRST - 1)
+                   for blocks in short):
+            return short
+    if sq < 8192:
+        return (512, 2048), (512, 2048)
+    return ((512, 2048) if causal else (1024, 1024)), (1024, 1024)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -443,44 +638,60 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q/block_k (forward) and ``bwd_blocks`` = (block_q_dq,
     block_k_dq, block_q_dkv, block_k_dkv) are upper bounds, fitted per
     call to the largest divisor of the sequence length that is a multiple
-    of 8. The defaults are length-adaptive, tuned on v5e with FULL
-    fwd+dq+dkv gradients: 512x2048 below S=8192 (measured ~101 TF/s
-    useful vs ~13 TF/s at 128x128 — grid-step overhead, not FLOPs,
-    dominates small blocks) and 1024x1024 at S>=8192 (2048-wide q blocks
-    exceed VMEM). The backward defaults follow block_q/block_k unless
-    overridden.
+    of 8. The defaults come from the call's own shape
+    (``_default_blocks``); the backward's follow an explicit
+    block_q/block_k unless overridden. What a causal call then computes
+    and fetches is ``causal_geometry``'s to say, and is recorded per
+    kernel under ``utils.profile.counters()["flash_geometry"]``.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if block_q is None:
-        block_q = 1024 if sq >= 8192 else 512
-    if block_k is None:
-        block_k = 1024 if sq >= 8192 else 2048
+    fwd, bwd = _default_blocks(causal, sq, sk, d, q_offset, kv_offset)
+    # An explicit block_q / block_k bounds all three kernels, as ever.
+    fwd = (block_q or fwd[0], block_k or fwd[1])
+    if bwd_blocks is None:
+        bwd_blocks = (block_q or bwd[0], block_k or bwd[1]) * 2
+    elif any(bl < 8 for bl in bwd_blocks):
+        raise ValueError(f"bwd_blocks entries must be >= 8 (TPU sublane "
+                         f"tile), got {bwd_blocks}")
     # Block sizes are upper bounds: fit each to the largest multiple of 8
     # (Mosaic sublane tile) that divides the sequence. Any seq length
     # divisible by 8 therefore works with the big TPU-tuned defaults
     # (e.g. sq=640 fits block_q=320); a misaligned length fails with the
     # same error on every backend, not just at TPU lowering time.
-    block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, sk)
-    if not block_q or not block_k:
+    block_q, block_k = _fit_block(fwd[0], sq), _fit_block(fwd[1], sk)
+    bwd_blocks = tuple(_fit_block(bl, s_) for bl, s_
+                       in zip(bwd_blocks, (sq, sk, sq, sk)))
+    if not (block_q and block_k and all(bwd_blocks)):
         raise ValueError(f"seq lens ({sq},{sk}) must be multiples of 8 "
                          f"(TPU tile alignment)")
-    if bwd_blocks is None:
-        bwd_blocks = (block_q, block_k, block_q, block_k)
-    else:
-        if any(bl < 8 for bl in bwd_blocks):
-            raise ValueError(f"bwd_blocks entries must be >= 8 (TPU "
-                             f"sublane tile), got {bwd_blocks}")
-        bq_dq, bk_dq, bq_dkv, bk_dkv = bwd_blocks
-        bwd_blocks = (_fit_block(bq_dq, sq), _fit_block(bk_dq, sk),
-                      _fit_block(bq_dkv, sq), _fit_block(bk_dkv, sk))
-        if not all(bwd_blocks):
-            raise ValueError(f"seq lens ({sq},{sk}) must be multiples of "
-                             f"8 (TPU tile alignment)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _flash(q, k, v, causal, q_offset, kv_offset, scale, block_q,
-                  block_k, bwd_blocks, interpret)
+    # One geometry a kernel, from what this call can see; the kernels'
+    # set-up runs on it and the process's counters keep it per shape.
+    geos = []
+    for name, stream, bq, bk in (
+            ("ddstore_flash_fwd", "k", block_q, block_k),
+            ("ddstore_flash_dq", "k") + bwd_blocks[:2],
+            ("ddstore_flash_dkv", "q") + bwd_blocks[2:]):
+        if causal:
+            # A strip is _STRIP of the side its accumulator lives on; the
+            # other side is cut at the lane width, up to the diagonal.
+            sub = (_sub_tile(bq, _STRIP[name]), _sub_tile(bk, _LANES)) \
+                if stream == "k" else (
+                _sub_tile(bq, _LANES), _sub_tile(bk, _STRIP[name]))
+            geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
+                                  kv_offset, stream)
+        else:
+            geo = _dense_geometry(sq, sk, bq, bk, stream)
+        profile.count_geometry(
+            name, f"{'causal' if causal else 'full'} bh{b * h} "
+            f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "
+            f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k}",
+            {f: getattr(geo, f) for f in (
+                "pairs_needed", "pairs_computed", "grid_steps",
+                "steps_fetching_dead")})
+        geos.append(geo)
+    return _flash(q, k, v, causal, scale, tuple(geos), interpret)

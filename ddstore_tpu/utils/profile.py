@@ -70,7 +70,7 @@ import time
 from typing import Deque, Dict, Iterator, List
 
 __all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
-           "watch_compiles", "counters"]
+           "watch_compiles", "count_geometry", "counters"]
 
 # One reading of both clocks, taken together: perf_counter_ns (what a phase
 # records; CLOCK_MONOTONIC, as ddtrace) and the epoch clock a trace is
@@ -87,6 +87,8 @@ _DURATIONS = {
 }
 _compile_s: Dict[str, Dict[str, float]] = {}
 _watching = False
+# Kernel name -> call shape -> what ops/attention.py's geometry counts.
+_geometry: Dict[str, Dict[str, Dict[str, int]]] = {}
 
 
 @contextlib.contextmanager
@@ -183,10 +185,22 @@ def watch_compiles() -> None:
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
-def counters() -> dict:
-    """What JAX reported in this process since ``enable_compile_cache()``:
-    ``compile_s[fun_name]`` with ``trace_s`` and ``lower_s``, the seconds it
-    spent tracing that function and lowering it to a module, summed over
-    every time it did. A compile cache shortens neither."""
+def count_geometry(kernel: str, call: str, counts: Dict[str, int]) -> None:
+    """``ops/attention.py``, while a flash call is traced: what ``kernel``
+    will do for a call of this shape, per batch*head (``pairs_needed``,
+    ``pairs_computed``, ``grid_steps``, ``steps_fetching_dead``)."""
     with _lock:
-        return {"compile_s": {f: dict(d) for f, d in _compile_s.items()}}
+        _geometry.setdefault(kernel, {})[call] = dict(counts)
+
+
+def counters() -> dict:
+    """What this process counted. ``compile_s[fun_name]`` with ``trace_s``
+    and ``lower_s``: the seconds JAX reported, since
+    ``enable_compile_cache()``, for tracing that function and lowering it
+    to a module, summed over every time it did (a compile cache shortens
+    neither). ``flash_geometry[kernel][call]``: the causal geometry of
+    every flash call traced so far (:func:`count_geometry`)."""
+    with _lock:
+        return {"compile_s": {f: dict(d) for f, d in _compile_s.items()},
+                "flash_geometry": {k: {c: dict(n) for c, n in d.items()}
+                                   for k, d in _geometry.items()}}

@@ -251,3 +251,207 @@ def test_the_three_kernels_carry_their_names():
     walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
     assert sorted(names) == ["ddstore_flash_dkv", "ddstore_flash_dq",
                              "ddstore_flash_fwd"]
+
+
+# ---------------------------------------------------------------------------
+# The causal geometry (PR 26): dead grid steps fetch nothing, a block that
+# straddles the diagonal is computed by sub-tiles.
+# ---------------------------------------------------------------------------
+
+
+def _pallas_calls(fn, *args):
+    """(name, grid, primitives of the kernel body, primitives of the index
+    maps) of every pallas_call in ``fn``'s jaxpr."""
+    found = []
+
+    def prims(jaxpr, into):
+        for e in jaxpr.eqns:
+            into.add(e.primitive.name)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                prims(sub, into)
+        return into
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                maps = set()
+                for bm in gm.block_mappings:
+                    prims(bm.index_map_jaxpr.jaxpr, maps)
+                found.append((eqn.params["name"], tuple(gm.grid),
+                              prims(eqn.params["jaxpr"], set()), maps))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+# sq, sk, q_offset, kv_offset, (block_q, block_k), bwd_blocks. dq cuts a
+# 384-row block into three 128-row strips; the forward and dkv strip at 512,
+# which the 1536-row blocks of the last case give three of.
+_GEOMETRY_CASES = [
+    pytest.param(768, 768, 0, 0, (384, 384), None, id="square"),
+    pytest.param(768, 1536, 0, 0, (384, 768), None, id="sq<sk"),
+    pytest.param(1536, 768, 0, 0, (768, 384), None, id="sq>sk"),
+    pytest.param(768, 768, 128, 0, (384, 384), None, id="q_offset"),
+    pytest.param(768, 768, 0, 384, (384, 384), None, id="partly-masked-q"),
+    pytest.param(768, 768, 0, 1024, (384, 384), None, id="fully-masked-q"),
+    pytest.param(768, 768, 64, 200, (384, 768), (384, 384, 768, 384),
+                 id="unaligned-offsets"),
+    pytest.param(768, 768, 0, 0, (384, 384), (64, 128, 32, 256),
+                 id="bwd_blocks"),
+    pytest.param(3072, 3072, 0, 0, (1536, 1536), None, id="three-strips"),
+]
+
+
+@pytest.mark.parametrize("sq,sk,q_off,kv_off,blocks,bwd", _GEOMETRY_CASES)
+def test_causal_geometry_matches_reference(sq, sk, q_off, kv_off, blocks,
+                                           bwd):
+    """Causal forward and gradients against the reference where the new
+    geometry is entered: at least two blocks of at least three sub-tiles
+    each, rectangular calls, offsets that mask a q range wholly or partly
+    (out = 0, lse = -inf, no NaN, forward and backward)."""
+    from ddstore_tpu.ops.attention import _STRIP, _sub_tile
+    bq, bk = blocks
+    # Two q blocks at least, of three strips at least in dq (in all three
+    # kernels in the case with 1536-row blocks).
+    strips = {name[14:]: (bk if name.endswith("dkv") else bq) // _sub_tile(
+        bk if name.endswith("dkv") else bq, want)
+        for name, want in _STRIP.items()}
+    assert sq // bq >= 2 and strips["dq"] >= 3, strips
+    assert bq < 1536 or min(strips.values()) >= 3, strips
+    kq, kk, kv, kt = jax.random.split(jax.random.key(sq + sk + q_off), 4)
+    q = jax.random.normal(kq, (1, 2, sq, 32))
+    k = jax.random.normal(kk, (1, 2, sk, 32))
+    v = jax.random.normal(kv, (1, 2, sk, 32))
+    tgt = jax.random.normal(kt, q.shape)
+    kw = dict(causal=True, q_offset=q_off, kv_offset=kv_off)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=bq, block_k=bk,
+                               bwd_blocks=bwd, **kw)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, **kw)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum((out - tgt) ** 2) + 0.1 * jnp.sum(
+                jnp.where(jnp.isfinite(lse), lse, 0.0))
+        return f
+
+    out_f, lse_f = flash(q, k, v)
+    out_r, lse_r = ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_r),
+                               atol=2e-5, rtol=2e-5)
+    seen = np.isfinite(np.asarray(lse_r))
+    np.testing.assert_array_equal(np.isfinite(np.asarray(lse_f)), seen)
+    np.testing.assert_allclose(np.asarray(lse_f)[seen],
+                               np.asarray(lse_r)[seen], atol=2e-5, rtol=2e-5)
+    assert (np.asarray(out_f)[~seen] == 0).all()
+    assert (np.asarray(lse_f)[~seen] == -np.inf).all()
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3,
+                                   rtol=2e-3)
+
+
+def test_many_diagonal_positions_fall_back_to_a_traced_shift():
+    """8-row blocks against 512-wide ones meet the diagonal at 64 different
+    positions: more static bodies than the kernels keep, so they mask whole
+    blocks by a traced shift, and the counter says what that computes."""
+    from ddstore_tpu.ops.attention import causal_geometry
+    q, k, v = _qkv(13, b=1, h=1, s=512, d=16)
+    geo = causal_geometry(512, 512, (8, 512), (8, 128))
+    assert geo.pairs_computed == 512 * 512 and geo.grid_steps == 64
+
+    def loss(fn, **kw):
+        return lambda q, k, v: (fn(q, k, v, causal=True, **kw)[0] ** 2).sum()
+
+    gf = jax.grad(loss(flash_attention, block_q=8, block_k=512),
+                  argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3,
+                                   rtol=2e-3)
+
+
+def test_noncausal_kernels_keep_their_structure():
+    """``causal=False`` (the ring's ``attend_past``) lowers to what it
+    always was: the full (bh, outer, inner) grid, identity index maps, one
+    body and no loop. The causal call of the same shape enumerates its
+    three live steps and reads its block indices from the step tables."""
+    q = jnp.zeros((1, 2, 768, 32))
+
+    def grad_of(causal):
+        def f(q, k, v):
+            out, _ = flash_attention(q, k, v, causal=causal, block_q=384,
+                                     block_k=384)
+            return out.sum()
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    names = ["ddstore_flash_dkv", "ddstore_flash_dq", "ddstore_flash_fwd"]
+    full = _pallas_calls(grad_of(False), q, q, q)
+    assert sorted(c[0] for c in full) == names
+    for name, grid, body, maps in full:
+        assert grid == (2, 2, 2), (name, grid)
+        assert not body & {"while", "scan"}, (name, body)
+        assert not maps, (name, maps)
+    causal = _pallas_calls(grad_of(True), q, q, q)
+    assert sorted(c[0] for c in causal) == names
+    for name, grid, body, maps in causal:
+        assert grid == (2, 3), (name, grid)
+        assert not body & {"while", "scan"}, (name, body)
+        assert maps == {"get"}, (name, maps)
+
+
+@pytest.mark.parametrize("s,bound", [(2048, 1.35), (8192, 1.10),
+                                     (16384, 1.10)])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_causal_geometry_counts(s, bound, kernel):
+    """The counter as arithmetic, at the geometry ``flash_attention``
+    derives for a (1, 1, s, 64) causal call with default blocks: what is
+    computed beyond the needed pairs stays inside the bound (2.0 at 2048
+    and 1.125 at 8192 before sub-tiles), no DMA serves only dead steps,
+    and the needed pairs are the s(s+1)/2 the benchmark's rooflines
+    count (``benchmarks/ddbench/flops.py``)."""
+    from ddstore_tpu.utils import profile
+    x = jax.ShapeDtypeStruct((1, 1, s, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), x)
+    calls = profile.counters()["flash_geometry"]["ddstore_flash_" + kernel]
+    (geo,) = [g for call, g in calls.items()
+              if call.startswith(f"causal bh1 q{s}+0 k{s}+0 d64 ")]
+    assert geo["pairs_needed"] == s * (s + 1) // 2
+    assert geo["pairs_computed"] / geo["pairs_needed"] <= bound
+    assert geo["steps_fetching_dead"] == 0
+    assert geo["grid_steps"] >= 1
+
+
+def test_causal_geometry_is_what_the_old_geometry_was_not():
+    """``causal_geometry`` with a sub-tile equal to the block is the
+    block-wise kernel: 2.0 of the needed pairs at S=2048 under 512x2048
+    blocks, 1.125 at S=8192 under 1024x1024. Offsets count only visible
+    pairs; a wholly masked call needs and computes nothing; the ``q``
+    stream (dkv) covers the same tiles as the ``k`` stream."""
+    from ddstore_tpu.ops.attention import causal_geometry
+    old = causal_geometry(2048, 2048, (512, 2048), (512, 2048))
+    assert old.pairs_computed == 4 * 512 * 2048
+    assert old.pairs_computed / old.pairs_needed > 1.99
+    new = causal_geometry(2048, 2048, (512, 2048), (256, 256))
+    assert new.pairs_computed == 36 * 256 * 256 and new.grid_steps == 4
+    old8 = causal_geometry(8192, 8192, (1024, 1024), (1024, 1024))
+    assert old8.pairs_computed == 36 * 1024 * 1024
+    assert (old8.grid_steps, old8.steps_fetching_dead) == (36, 0)
+    for stream in "kq":
+        ring = causal_geometry(128, 128, (64, 64), (64, 64), 128, 0, stream)
+        assert ring.pairs_needed == ring.pairs_computed == 128 * 128
+        dead = causal_geometry(128, 128, (64, 64), (64, 64), 0, 128, stream)
+        assert dead.pairs_needed == dead.pairs_computed == 0
+        half = causal_geometry(256, 256, (128, 128), (128, 128), 0, 128,
+                               stream)
+        assert half.pairs_needed == 128 * 129 // 2
+        assert half.pairs_computed == 128 * 128
