@@ -17,6 +17,10 @@ create_train_state / make_train_step):
                 same store, the lowered step checked for the Mosaic
                 kernels, then flash against the XLA reference (outputs and
                 gradients) on this backend;
+* experts leg — one chip only: the latent-attention expert model at its
+                published widths (dense layer + one expert layer + MTP,
+                S=2048), loss and every gradient leaf against the plain
+                float32 reference, and the tokens the two route differently;
 * ragged leg  — one short epoch of the examples/gnn_molecules.py path;
 * two timings the next issues need, labelled as smoke output.
 
@@ -49,11 +53,17 @@ REAL = dict(dry_run=False, vae_rows=16384, vae_batch=512,
             lm=dict(vocab=32768, dim=1024, heads=16, layers=8),
             lm_runs=((2048, 8, 3), (8192, 2, 2)),
             attn_s=2048, attn_s_misaligned=1032,
+            # (batch, seq); (loss, gradient leaf) tolerances of bf16 against
+            # float32; share of tokens a near-tie may route differently
+            experts_shape=(1, 2048), experts_tol=(1e-3, 0.3),
+            experts_flips=0.05,
             graphs=256, chain_steps=5, stage_reps=20)
 DRY = dict(dry_run=True, vae_rows=2048, vae_batch=64,
            lm=dict(vocab=512, dim=64, heads=4, layers=2),
            lm_runs=((128, 4, 3), (256, 2, 2)),
            attn_s=128, attn_s_misaligned=136,
+           experts_shape=(1, 64), experts_tol=(1e-5, 1e-3),
+           experts_flips=0.0,
            graphs=64, chain_steps=3, stage_reps=5)
 # Steps of the one-device VAE run a multi-device run is compared against.
 VAE_REF_STEPS = 5
@@ -364,6 +374,91 @@ def kernel_leg_multichip(store, sets, mesh, mesh1, cfg, record):
                      1e-5 if cfg["dry_run"] else 2e-2)
 
 
+def experts_leg(cfg, record):
+    """The latent-attention expert model at its published widths (the
+    benchmark's glm47-flash-ep8 configuration cut to its dense layer, one
+    expert layer and the MTP module): loss and every gradient leaf of the
+    bf16 program against the plain float32 reference, and how many tokens
+    the two route differently (near-ties of the sigmoid scores)."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddstore_tpu.models import transformer
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm47-flash-ep8.json")) as f:
+        desc = json.load(f)
+    desc["num_hidden_layers"] = 2
+    if cfg["dry_run"]:
+        desc.update(desc["dry_run"])
+    spec = importlib.util.spec_from_file_location(
+        "smoke_ref_mla_moe_lm", os.path.join(
+            root, "benchmarks", "reference", "mla_moe_lm.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    batch, seq = cfg["experts_shape"]
+    with leg("experts", record):
+        dtype = jnp.float32 if cfg["dry_run"] else jnp.bfloat16
+        model = transformer.lm_from_description(desc, compute_dtype=dtype)
+        state, _ = transformer.create_train_state(jax.random.key(SEED),
+                                                  model)
+        params = state.params
+        rng = np.random.default_rng((SEED, 27))
+        tok, tgt = rng.integers(0, model.vocab, (2, batch, seq),
+                                dtype=np.int32)
+        pos = np.tile(np.arange(seq, dtype=np.int32), (batch, 1))
+        (loss, loads), grads = jax.jit(jax.value_and_grad(
+            lambda p: transformer.lm_loss(model, p, tok, tgt, pos),
+            has_aux=True))(params)
+        arch = dict(model.arch._asdict(), heads=model.heads)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, tok, tgt, pos, arch=arch,
+                               token_block=1024)))(params)
+        loss_err = abs(float(loss) - float(want)) / abs(float(want))
+        norm = lambda t: float(jnp.linalg.norm(t.astype(jnp.float32)))
+        rel = {}
+        for (path, g), w in zip(
+                jax.tree_util.tree_flatten_with_path(grads)[0],
+                jax.tree_util.tree_leaves(want_grads)):
+            if norm(w) > 0:
+                rel[jax.tree_util.keystr(path)] = norm(g - w) / norm(w)
+            elif norm(g) > 0:
+                raise AssertionError(f"{path}: gradient where the "
+                                     f"reference has none")
+        worst = max(rel, key=rel.get)
+        # Who is routed differently: the program's choice (sown by the
+        # expert layers) against the reference's, expert sets a token.
+        _, inter = model.clone(remat=False).apply(
+            params, tok, pos, True, next_tokens=tgt,
+            mutable=["intermediates"])
+        sown = inter["intermediates"]
+        mine = [sown["block1"]["moe"]["chosen"][0],
+                sown["mtp"]["block"]["moe"]["chosen"][0]]
+        theirs = jax.jit(lambda p: ref.forward(p, tok, tgt, pos, arch,
+                                               token_block=1024)[1])(params)
+        differ = [int((np.sort(np.asarray(a), -1)
+                       != np.sort(np.asarray(b), -1)).any(-1).sum())
+                  for a, b in zip(mine, theirs)]
+        say(f"    experts b={batch} S={seq}: loss {float(loss):.6f}, "
+            f"reference {float(want):.6f} (relative {loss_err:.2e}); "
+            f"gradient leaves {len(rel)}, relative norm of the difference "
+            f"median {sorted(rel.values())[len(rel) // 2]:.2e}, worst "
+            f"{rel[worst]:.2e} at {worst}; tokens routed differently "
+            f"{differ} of {batch * seq} a layer; held experts' load "
+            f"{np.asarray(loads)[:, :int(desc['n_routed_experts'])].tolist()}")
+        rtol, gtol = cfg["experts_tol"]
+        if loss_err > rtol or rel[worst] > gtol \
+                or max(differ) > cfg["experts_flips"] * batch * seq:
+            raise AssertionError(
+                f"experts leg: loss {loss_err:.2e} (allowed {rtol}), worst "
+                f"gradient leaf {rel[worst]:.2e} at {worst} (allowed "
+                f"{gtol}), routed differently {differ}")
+
+
 def ragged_leg(store, sets, mesh, cfg, record):
     import jax
     import numpy as np
@@ -516,6 +611,8 @@ def rank0_main(rdv, cfg, owners):
                              make_mesh({"dp": 2, "sp": 2}, devs), mesh1,
                              cfg, record)
     check_owners(owners)
+    if n == 1:
+        experts_leg(cfg, record)
     ragged_leg(store, sets, mesh, cfg, record)
     if n == 1:
         with leg("staging fact", record):

@@ -31,6 +31,13 @@ Routing details:
 
 (EP is absent in the reference — SURVEY §2.2; with this module the
 framework covers the full dp/tp/pp/sp/ep set.)
+
+:class:`SharedRoutedMoe` is the other expert layer, the one a published
+width can instantiate: shared + routed SwiGLU experts under ``noaux_tc``
+sigmoid routing with no dropped token, as one expert-parallel chip's
+share (told which experts it holds, it routes over all of them and
+computes its own part). ``MoeMlp`` stays what ``decode.py``,
+``parallel/pipeline.py`` and ``parallel/tp.py`` build.
 """
 
 from __future__ import annotations
@@ -40,6 +47,13 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..utils import profile
+
+# The router's correction bias is a seeded, non-zero leaf: its trained
+# values and update speed are not in a published config, and a zero bias
+# would leave the selection by ``scores + bias`` untested.
+ROUTER_BIAS_STD = 0.1
 
 
 def default_capacity(tokens: int, n_experts: int, top_k: int,
@@ -129,3 +143,160 @@ class MoeMlp(nn.Module):
         frac = oh.sum(axis=(0, 1)) / (nvalid * k)
         aux = e * jnp.sum(frac * mean_prob)
         return y.astype(x.dtype), aux
+
+
+def route_noaux_tc(scores: jax.Array, bias: jax.Array, top_k: int,
+                   scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """``noaux_tc`` routing without a group limit (DeepSeek-V3, GLM-4 MoE):
+    the ``top_k`` experts of ``scores + bias`` are chosen, the weights come
+    from the scores alone, normalised over the chosen and scaled. The bias
+    only steers the choice, so no gradient reaches it. ``scores`` (T, E)
+    float32 sigmoid outputs; returns ``(chosen (T, k) int32, weights (T, k)
+    float32)``."""
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+
+
+@jax.custom_vjp
+def _to_experts(x, order, rank, local):
+    """Rows of ``x`` (T, d) laid out for the grouped product: row r is the
+    token of pair ``order[r]`` (pairs are (token, choice), k a token).
+    ``rank`` (T, k) is the inverse (the row of each pair), ``local`` (T, k)
+    says which pairs' experts are held here. The transpose is written out
+    as a gather through ``rank``, not left to autodiff's scatter-add, and
+    reads only the held pairs' rows: the others' cotangents are not the
+    grouped product's to define."""
+    return x[order // rank.shape[1]]
+
+
+def _to_experts_fwd(x, order, rank, local):
+    return _to_experts(x, order, rank, local), (rank, local)
+
+
+def _to_experts_bwd(res, dxs):
+    rank, local = res
+    dx = jnp.where(local[..., None], dxs[rank], 0).astype(jnp.float32)
+    return dx.sum(axis=1).astype(dxs.dtype), None, None, None
+
+
+_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
+
+
+@jax.custom_vjp
+def _from_experts(ys, w, order, rank, local):
+    """``y[t] = sum_j w[t, j] ys[rank[t, j]]`` over the held pairs, float32:
+    the weighted way back from the grouped product's rows (same layout as
+    :func:`_to_experts`). Rows of absent experts are selected away, never
+    multiplied by zero. Gathers both ways."""
+    picked = ys[rank].astype(jnp.float32) * w[..., None]
+    return jnp.where(local[..., None], picked, 0).sum(axis=1)
+
+
+def _from_experts_fwd(ys, w, order, rank, local):
+    return _from_experts(ys, w, order, rank, local), (ys, w, order, rank,
+                                                      local)
+
+
+def _from_experts_bwd(res, dy):
+    ys, w, order, rank, local = res
+    k = rank.shape[1]
+    held = local.reshape(-1)[order][:, None]
+    dys = jnp.where(held, dy[order // k] * w.reshape(-1)[order][:, None], 0)
+    dw = (ys[rank].astype(jnp.float32) * dy[:, None, :]).sum(axis=-1)
+    return (dys.astype(ys.dtype), jnp.where(local, dw, 0).astype(w.dtype),
+            None, None, None)
+
+
+_from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
+
+
+class SharedRoutedMoe(nn.Module):
+    """Shared + routed SwiGLU experts with no dropped token, as one
+    expert-parallel chip's share: ``(T, d) -> ((T, d), load (n_routed,))``.
+
+    ``share = (which, of)``: this chip is number ``which`` of ``of`` that
+    divide the layer's ``n_routed`` experts between them, and holds the
+    consecutive ``n_routed // of`` from ``which * n_routed // of``. It
+    routes over all ``n_routed`` (sigmoid scores, :func:`route_noaux_tc`),
+    computes every (token, chosen expert) pair whose expert it holds, and
+    adds the shared expert, which every chip computes alike. What the other
+    chips' experts would add is not here and nothing stands in for it: on
+    one chip the layer runs without its exchange. ``load`` counts the
+    tokens routed to each of the ``n_routed`` experts (int32).
+
+    Every (token, chosen expert) pair is sorted by held expert, the pairs
+    of absent experts behind them, and the held rows are multiplied by
+    ``jax.lax.ragged_dot`` (on the TPU XLA's own grouped-matmul kernel,
+    which does the work of the rows each expert got and no more: PERF.md
+    section 6, PR 27). Shapes are static, T x k rows, so no pair is ever
+    dropped, whatever the routing; what even routing leaves unused of them
+    costs element-wise passes, not matmuls.
+    """
+
+    n_routed: int
+    top_k: int
+    hidden: int
+    share: Tuple[int, int] = (0, 1)
+    scaling: float = 1.0
+    n_shared: int = 1
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
+        t, d = x.shape
+        e, k, dt = self.n_routed, self.top_k, self.compute_dtype
+        which, of = self.share
+        if e % of or not 0 <= which < of:
+            raise ValueError(f"share {self.share} does not divide "
+                             f"{e} routed experts")
+        held = e // of
+        first = which * held
+        profile.count_moe_layout(
+            "/".join(self.path), held=held, of=e, first=first, top_k=k,
+            tokens=t)
+
+        experts = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,))
+        w_gate = self.param("w_gate", experts, (held, d, self.hidden))
+        w_up = self.param("w_up", experts, (held, d, self.hidden))
+        w_down = self.param("w_down", experts, (held, self.hidden, d))
+
+        with jax.named_scope("moe_dispatch"):
+            # Router and its statistics in float32.
+            scores = jax.nn.sigmoid(nn.Dense(
+                e, use_bias=False, dtype=jnp.float32, name="router")(
+                    x.astype(jnp.float32)))
+            bias = self.param("router_bias",
+                              nn.initializers.normal(ROUTER_BIAS_STD), (e,))
+            chosen, weights = route_noaux_tc(scores, bias, k, self.scaling)
+            # For whoever asks with mutable=["intermediates"] (chip_smoke's
+            # count of near-tie tokens); nothing otherwise.
+            self.sow("intermediates", "chosen", chosen)
+            flat = chosen.reshape(t * k)
+            load = (flat[:, None] == jnp.arange(e)).sum(0, dtype=jnp.int32)
+            # Pairs sorted by held expert; the pairs of absent experts
+            # sort behind them and the grouped product never reads them.
+            local = (flat >= first) & (flat < first + held)
+            order = jnp.argsort(jnp.where(local, flat - first, held),
+                                stable=True).astype(jnp.int32)
+            rank = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+            local = local.reshape(t, k)
+            sizes = jax.lax.dynamic_slice_in_dim(load, first, held)
+            xs = _to_experts(x.astype(dt), order, rank, local)
+        with jax.named_scope("moe_experts"):
+            h = nn.silu(jax.lax.ragged_dot(xs, w_gate.astype(dt), sizes)) \
+                * jax.lax.ragged_dot(xs, w_up.astype(dt), sizes)
+            ys = jax.lax.ragged_dot(h, w_down.astype(dt), sizes)
+        with jax.named_scope("moe_dispatch"):
+            y = _from_experts(ys, weights, order, rank, local)
+
+        # The shared expert: the block's dense MLP work, on every chip.
+        wide = self.n_shared * self.hidden
+        hs = nn.silu(nn.Dense(wide, use_bias=False, dtype=dt,
+                              name="shared_gate")(x)) \
+            * nn.Dense(wide, use_bias=False, dtype=dt, name="shared_up")(x)
+        y = y + nn.Dense(d, use_bias=False, dtype=dt,
+                         name="shared_down")(hs).astype(jnp.float32)
+        return y.astype(x.dtype), load

@@ -16,7 +16,7 @@ sequence axis.
 from __future__ import annotations
 
 import math
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, Mapping, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -104,6 +104,225 @@ class Block(nn.Module):
         return x
 
 
+class MlaMoeArch(NamedTuple):
+    """A latent-attention (MLA), shared + routed expert, multi-token-
+    prediction decoder, by the keys of its published ``config.json`` (the
+    ``glm4_moe_lite`` / DeepSeek-V3 family). ``TransformerLM(arch=...)``
+    builds :class:`MlaBlock` layers from it; ``vocab``, ``dim``, ``heads``
+    and ``layers`` stay the model's own fields. ``n_routed_experts`` is the
+    router's width (the published count); ``expert_share = (which, of)`` is
+    this chip's share of them (:class:`~.moe.SharedRoutedMoe`)."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 1
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
+    expert_share: Tuple[int, int] = (0, 1)
+    bias_update_speed: float = 0.0   # noaux_tc: what a step moves each
+    #                               expert's correction bias toward balance
+    #                               (update_router_bias); 0 leaves it alone
+
+
+def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
+    """A :class:`TransformerLM` from one description of the architecture:
+    either the dense block's own keys (``vocab``, ``dim``, ``heads``,
+    ``layers``, ``mlp_ratio``; ``experts`` / ``moe_top_k`` for the
+    capacity-bound ``MoeMlp``), or the keys of a published
+    latent-attention expert model's ``config.json`` (``q_lora_rank``
+    present), where ``n_routed_experts`` counts the experts held here, of
+    ``expert_parallel = {"chips": n, "chip": i}`` chips that share each
+    layer, and the router is ``chips`` times as wide. ``kw`` are further
+    ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
+    if "q_lora_rank" not in desc:
+        return TransformerLM(
+            vocab=int(desc["vocab"]), dim=int(desc["dim"]),
+            heads=int(desc["heads"]), layers=int(desc["layers"]),
+            mlp_ratio=int(desc.get("mlp_ratio", 4)),
+            n_experts=int(desc.get("experts", 0)),
+            moe_top_k=int(desc.get("moe_top_k", 1)), **kw)
+    for key, want in (("hidden_act", "silu"), ("topk_method", "noaux_tc"),
+                      ("n_group", 1), ("topk_group", 1),
+                      ("norm_topk_prob", True), ("attention_bias", False),
+                      ("tie_word_embeddings", False), ("rope_scaling", None),
+                      ("partial_rotary_factor", 1)):
+        if desc.get(key, want) != want:
+            raise ValueError(f"{key}={desc[key]!r} is not built here "
+                             f"(only {want!r})")
+    if desc.get("num_key_value_heads", desc["num_attention_heads"]) \
+            != desc["num_attention_heads"]:
+        raise ValueError("latent attention has one key/value head a "
+                         "query head")
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    fields = {k: desc[k] for k in MlaMoeArch._fields if k in desc}
+    fields["n_routed_experts"] = int(desc["n_routed_experts"]) \
+        * int(ep["chips"])
+    fields["expert_share"] = (int(ep["chip"]), int(ep["chips"]))
+    if "remat_policy" in desc and "remat_policy" not in kw:
+        kw = dict(kw, remat=True, remat_policy=desc["remat_policy"])
+    return TransformerLM(
+        vocab=int(desc["vocab_size"]), dim=int(desc["hidden_size"]),
+        heads=int(desc["num_attention_heads"]),
+        layers=int(desc["num_hidden_layers"]),
+        arch=MlaMoeArch(**fields), **kw)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale`` in float32."""
+
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        x = x.astype(jnp.float32)
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * scale
+
+
+def rope(x, positions, theta: float):
+    """Rotary positions on all of ``x``'s last dimension, ``x`` (B, S, H,
+    D), ``positions`` (B, S) global indices. Pairs are the two halves
+    (dimension i with i + D/2, the Hugging Face ``rotate_half`` layout); a
+    checkpoint that pairs neighbours differs by a fixed permutation of the
+    projection's columns."""
+    half = x.shape[-1] // 2
+    inv = jnp.exp(-math.log(theta) * jnp.arange(half) / half)
+    ang = positions[..., None, None].astype(jnp.float32) * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class MlaBlock(nn.Module):
+    """Pre-RMSNorm decoder block of :class:`MlaMoeArch`: multi-head latent
+    attention computed uncompressed (training: per head q = [q_nope |
+    q_rope], k = [k_nope | k_rope], the rotary key one vector a position
+    shared by all heads), then a SwiGLU MLP (``dense``) or the shared +
+    routed experts. No biases. Returns ``x``, and the expert layer's load
+    vector beside it."""
+
+    dim: int
+    heads: int
+    arch: MlaMoeArch
+    dense: bool
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, x, positions):
+        b, s, _ = x.shape
+        a, dt, nh = self.arch, self.compute_dtype, self.heads
+        lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
+                                       name=name)
+        norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
+        nope, rot, vd = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
+
+        with jax.named_scope("attn"):
+            h = norm("ln1")(x).astype(dt)
+            cq = norm("q_norm")(lin(a.q_lora_rank, "q_a")(h)).astype(dt)
+            q = lin(nh * (nope + rot), "q_b")(cq).reshape(
+                b, s, nh, nope + rot)
+            kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
+            ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
+            kv = lin(nh * (nope + vd), "kv_b")(ckv).reshape(
+                b, s, nh, nope + vd)
+            k_rope = rope(kva[..., None, a.kv_lora_rank:], positions,
+                          a.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], positions,
+                                     a.rope_theta)], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rot))],
+                axis=-1)
+            q, k, v = (t.transpose(0, 2, 1, 3)
+                       for t in (q, k, kv[..., nope:]))
+            if jax.default_backend() == "tpu":
+                # As in Block: on the chip the kernel is the only path.
+                out, _ = flash_attention(q, k, v, causal=True)
+            else:
+                out, _ = mha_reference(q, k, v, causal=True)
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * vd).astype(dt)
+            x = x + lin(self.dim, "proj")(out)
+
+        with jax.named_scope("mlp"):
+            h = norm("ln2")(x).astype(dt)
+            if self.dense:
+                h = nn.silu(lin(a.intermediate_size, "gate")(h)) \
+                    * lin(a.intermediate_size, "up")(h)
+                return x + lin(self.dim, "down")(h)
+            from .moe import SharedRoutedMoe
+            y, load = SharedRoutedMoe(
+                a.n_routed_experts, a.num_experts_per_tok,
+                a.moe_intermediate_size, share=a.expert_share,
+                scaling=a.routed_scaling_factor,
+                n_shared=a.n_shared_experts, compute_dtype=dt,
+                name="moe")(h.reshape(b * s, self.dim))
+            return x + y.reshape(b, s, self.dim), load
+
+
+def _remat_policy(name: Optional[str]):
+    """A ``jax.checkpoint_policies`` entry by name, or ``names:a,b`` for
+    ``save_only_these_names(a, b)`` (``flash_out`` and ``flash_lse`` are
+    the flash forward's output and statistics: saved, the forward kernel
+    does not run again in the backward pass)."""
+    if not name:
+        return None
+    if name.startswith("names:"):
+        return jax.checkpoint_policies.save_only_these_names(
+            *name[len("names:"):].split(","))
+    policy = getattr(jax.checkpoint_policies, name, None)
+    if policy is None:
+        valid = sorted(n for n in dir(jax.checkpoint_policies)
+                       if not n.startswith("_"))
+        raise ValueError(
+            f"remat_policy {name!r} is not a jax.checkpoint_policies "
+            f"entry; valid: {valid}, or names:<a>,<b>")
+    return policy
+
+
+class MtpModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2; layer ``num_hidden_layers`` of a ``glm4_moe`` checkpoint):
+    ``eh_proj [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)]``, one expert block,
+    RMSNorm. The embedding and the head are the main model's, applied by
+    the caller. Returns the features for the head and the block's load."""
+
+    dim: int
+    heads: int
+    arch: MlaMoeArch
+    compute_dtype: Any
+    remat: bool
+    remat_policy: Optional[str]
+
+    @nn.compact
+    def __call__(self, hidden, next_embedded, positions):
+        eps, dt = self.arch.rms_norm_eps, self.compute_dtype
+        with jax.named_scope("embed"):
+            both = jnp.concatenate(
+                [RMSNorm(eps, name="enorm")(next_embedded),
+                 RMSNorm(eps, name="hnorm")(hidden)], axis=-1).astype(dt)
+            x = nn.Dense(self.dim, use_bias=False, dtype=dt,
+                         name="eh_proj")(both)
+        cls = nn.remat(MlaBlock, policy=_remat_policy(self.remat_policy)) \
+            if self.remat else MlaBlock
+        x, load = cls(self.dim, self.heads, self.arch, False, dt,
+                      name="block")(x, positions)
+        with jax.named_scope("head"):
+            return RMSNorm(eps, name="norm")(x), load
+
+
 class EmbedPE(nn.Module):
     """Token embedding + fixed sinusoidal positions. Stateless PE works at
     any context length and is exact under sequence sharding (depends only
@@ -113,11 +332,20 @@ class EmbedPE(nn.Module):
     vocab: int
     dim: int
     compute_dtype: Any
+    sinusoid: bool = True   # False: the embedding alone (rotary models)
+    init_std: Optional[float] = None   # set: drawn normal(0, init_std)
+    #                         instead of flax's 1/sqrt(dim), so that a
+    #                         token's identity is not lost under the first
+    #                         blocks' outputs
 
     @nn.compact
     def __call__(self, tokens, positions):
+        init = {} if self.init_std is None else {
+            "embedding_init": nn.initializers.normal(self.init_std)}
         x = nn.Embed(self.vocab, self.dim, dtype=self.compute_dtype,
-                     name="tok")(tokens)
+                     name="tok", **init)(tokens)
+        if not self.sinusoid:
+            return x
         half = self.dim // 2
         freqs = jnp.exp(-math.log(10000.0) * jnp.arange(half) / half)
         ang = positions[..., None].astype(jnp.float32) * freqs
@@ -135,10 +363,14 @@ class LMHead(nn.Module):
     the ``(tokens, vocab)`` logits tensor never materializes."""
 
     vocab: int
+    rms_eps: Optional[float] = None   # set: the final norm is an RMSNorm
 
     @nn.compact
     def __call__(self, x, features_only: bool = False):
-        x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
+        if self.rms_eps is None:
+            x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
+        else:
+            x = RMSNorm(self.rms_eps, name="lnf")(x)
         if features_only:
             return x
         return nn.Dense(self.vocab, use_bias=False, dtype=jnp.float32,
@@ -168,32 +400,38 @@ class TransformerLM(nn.Module):
     #                               saveable" keeps matmul outputs and only
     #                               recomputes the cheap elementwise work —
     #                               most of full remat's memory win at a
-    #                               fraction of its recompute cost)
+    #                               fraction of its recompute cost), or
+    #                               "names:flash_out,flash_lse" (_remat_policy)
+    arch: Optional[MlaMoeArch] = None   # set: MlaBlock layers (RMSNorm,
+    #                               rotary latent attention, SwiGLU, shared
+    #                               + routed experts, MTP) instead of Block
 
     @nn.compact
     def __call__(self, tokens, positions, return_features: bool = False,
-                 *, token_mask: Optional[jax.Array] = None):
+                 *, token_mask: Optional[jax.Array] = None,
+                 next_tokens: Optional[jax.Array] = None):
         """tokens/positions: (B, S) int32; positions are GLOBAL indices so
         sequence-sharded chunks embed correctly. ``return_features=True``
         returns the post-final-LayerNorm features instead of logits (the
         fused-xent path applies the head kernel itself). ``token_mask``
         (B, S) bool marks real vs padded positions — only MoE routing
-        consumes it (padded tokens take no expert capacity)."""
+        consumes it (padded tokens take no expert capacity).
+
+        With ``arch`` the result is ``(out, mtp_features, loads)``:
+        ``loads`` (expert layers, n_routed_experts) int32 counts the tokens
+        routed to each expert, main layers first; ``mtp_features`` (None
+        without ``next_tokens`` or MTP modules) are the MTP module's
+        features for the main head, position i predicting token i + 2
+        (``next_tokens`` is the window's targets row)."""
+        if self.arch is not None:
+            return self._mla_moe(tokens, positions, return_features,
+                                 next_tokens)
         with jax.named_scope("embed"):
             x = EmbedPE(self.vocab, self.dim, self.compute_dtype,
                         name="embed")(tokens, positions)
         if self.remat:
-            policy = None
-            if self.remat_policy:
-                policy = getattr(jax.checkpoint_policies,
-                                 self.remat_policy, None)
-                if policy is None:
-                    valid = sorted(n for n in dir(jax.checkpoint_policies)
-                                   if not n.startswith("_"))
-                    raise ValueError(
-                        f"remat_policy {self.remat_policy!r} is not a "
-                        f"jax.checkpoint_policies entry; valid: {valid}")
-            block_cls = nn.remat(Block, policy=policy)
+            block_cls = nn.remat(Block,
+                                 policy=_remat_policy(self.remat_policy))
         else:
             block_cls = Block
         for i in range(self.layers):
@@ -206,6 +444,47 @@ class TransformerLM(nn.Module):
                           name=f"block{i}")(x, token_mask)
         with jax.named_scope("head"):
             return LMHead(self.vocab, name="lmhead")(x, return_features)
+
+    @nn.nowrap
+    def _mla_moe(self, tokens, positions, return_features, next_tokens):
+        a, dt = self.arch, self.compute_dtype
+        if self.mesh is not None and self.mesh.shape.get(self.sp_axis,
+                                                         1) > 1:
+            raise NotImplementedError(
+                "latent attention is not wired to ring attention: a "
+                "sequence-parallel mesh needs the rotary key chunked with "
+                "the K/V it is part of")
+        embed = EmbedPE(self.vocab, self.dim, dt, sinusoid=False,
+                        init_std=1.0, name="embed")
+        with jax.named_scope("embed"):
+            x = embed(tokens, positions)
+        block_cls = nn.remat(MlaBlock,
+                             policy=_remat_policy(self.remat_policy)) \
+            if self.remat else MlaBlock
+        loads = []
+        for i in range(self.layers):
+            dense = i < a.first_k_dense_replace
+            x = block_cls(self.dim, self.heads, a, dense, dt,
+                          name=f"block{i}")(x, positions)
+            if not dense:
+                x, load = x
+                loads.append(load)
+        with jax.named_scope("head"):
+            out = LMHead(self.vocab, rms_eps=a.rms_norm_eps,
+                         name="lmhead")(x, return_features)
+        mtp = None
+        if a.num_nextn_predict_layers and next_tokens is not None:
+            if a.num_nextn_predict_layers != 1:
+                raise NotImplementedError("one MTP module is built")
+            with jax.named_scope("mtp"):
+                with jax.named_scope("embed"):
+                    nxt = embed(next_tokens, positions)
+                mtp, load = MtpModule(
+                    self.dim, self.heads, a, dt, self.remat,
+                    self.remat_policy, name="mtp")(x, nxt, positions)
+                loads.append(load)
+        return out, mtp, jnp.stack(loads) if loads else jnp.zeros(
+            (0, a.n_routed_experts), jnp.int32)
 
 
 # Switch-MoE load-balancing aux weight — THE single source for the
@@ -280,6 +559,9 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
                     "accumulation); pass fused_xent=False for the f32 "
                     "Dense head", model.vocab, 2 * xent_block,
                     jnp.dtype(model.compute_dtype).name)
+    if model.arch is not None:
+        return _mla_moe_loss(model, params, tokens, targets, positions,
+                             fused_xent, xent_block)
     mutable = ("intermediates",) if model.n_experts > 0 else False
 
     if mutable:
@@ -304,6 +586,140 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
         return nll.mean() + aux
 
 
+def _balanced_block(vocab: int, block: int) -> int:
+    """The fused head's vocabulary block: ``block`` where it divides the
+    vocabulary, else the blocks made equal (to the lane width), so that a
+    vocabulary slice such as 19,360 pads 224 columns and not 5,216."""
+    if vocab % block == 0 or vocab <= block:
+        return block
+    n = -(-vocab // block)
+    return -(-vocab // (n * 128)) * 128
+
+
+def mtp_targets(targets):
+    """``(targets shifted by one, mask)`` for the MTP module: position i,
+    which holds token i and is given token i + 1 (``targets[i]``), predicts
+    token i + 2 = ``targets[i + 1]``. The last position has none inside the
+    window and is masked, so the store's rows stay what they are."""
+    shifted = jnp.concatenate(
+        [targets[:, 1:], jnp.zeros_like(targets[:, :1])], axis=1)
+    mask = jnp.arange(targets.shape[1]) < targets.shape[1] - 1
+    return shifted, jnp.broadcast_to(mask, targets.shape)
+
+
+def _mla_moe_loss(model, params, tokens, targets, positions, fused_xent,
+                  xent_block):
+    """``(CE_main + mtp_loss_weight * CE_mtp, loads)`` of an ``arch``
+    model: both heads are the main head, fused or not alike."""
+    out, mtp, loads = model.apply(params, tokens, positions, fused_xent,
+                                  next_tokens=targets)
+    w = params["params"]["lmhead"]["head"]["kernel"]
+    dt = model.compute_dtype
+
+    def nll(feats, tgt):
+        from ..ops.xent import fused_linear_xent
+
+        return fused_linear_xent(
+            feats.reshape(-1, feats.shape[-1]).astype(dt), w,
+            tgt.reshape(-1), _balanced_block(model.vocab, xent_block), dt)
+
+    with jax.named_scope("head"):
+        loss = nll(out, targets).mean() if fused_xent \
+            else loss_fn(out, targets)
+    if mtp is not None:
+        with jax.named_scope("mtp"), jax.named_scope("head"):
+            tgt, mask = mtp_targets(targets)
+            if fused_xent:
+                per = nll(mtp, tgt)
+            else:
+                logp = jax.nn.log_softmax(mtp @ w.astype(jnp.float32))
+                per = -jnp.take_along_axis(logp, tgt[..., None], -1)
+            per = per.reshape(tgt.shape) * mask
+            loss = loss + model.arch.mtp_loss_weight * per.sum() / mask.sum()
+    return loss, loads
+
+
+def _expert_layers(model: "TransformerLM"):
+    """Paths of the expert layers' parameters, in the order of ``loads``."""
+    a = model.arch
+    paths = [(f"block{i}", "moe")
+             for i in range(a.first_k_dense_replace, model.layers)]
+    if a.num_nextn_predict_layers:
+        paths.append(("mtp", "block", "moe"))
+    return paths
+
+
+def _router_biases(model: "TransformerLM", params) -> jax.Array:
+    """(expert layers, n_routed_experts): the correction biases, stacked."""
+    out = []
+    for path in _expert_layers(model):
+        node = params["params"]
+        for key in path:
+            node = node[key]
+        out.append(node["router_bias"])
+    return jnp.stack(out)
+
+
+def _with_router_biases(model: "TransformerLM", params, biases):
+    """``params`` with the stacked ``biases`` in their leaves' place."""
+    def put(node, path, bias):
+        if not path:
+            return dict(node, router_bias=bias)
+        return dict(node, **{path[0]: put(node[path[0]], path[1:], bias)})
+
+    p = params["params"]
+    for path, bias in zip(_expert_layers(model), biases):
+        p = put(p, path, bias)
+    return dict(params, params=p)
+
+
+def _toward_balance(loads) -> jax.Array:
+    """+1 where an expert's load is under its layer's mean, -1 over it."""
+    mean = loads.sum(-1, keepdims=True) / loads.shape[-1]
+    return jnp.sign(mean - loads).astype(jnp.float32)
+
+
+def update_router_bias(model: "TransformerLM", params, loads, speed):
+    """The ``noaux_tc`` rule (DeepSeek-V3, arXiv:2412.19437 section 2.1.2):
+    after a step, each expert's correction bias moves by ``speed`` toward
+    balance, up where its load was under the layer's mean and down where it
+    was over. No gradient is involved. ``loads`` as the model returns them;
+    returns the parameters with the new biases."""
+    return _with_router_biases(
+        model, params,
+        _router_biases(model, params) + speed * _toward_balance(loads))
+
+
+def balance_router_bias(model: "TransformerLM", state: "TrainState", tokens,
+                        targets, positions, *, iters: int = 48,
+                        speed: float = 0.05, decay: float = 0.92
+                        ) -> "TrainState":
+    """Set-up for a seeded expert model. A router with seeded weights sends
+    most tokens to a few experts (every hidden state shares a large common
+    part), as no model in training does under this rule, and how many land
+    on the experts held here then swings with the seed several-fold. This
+    runs :func:`update_router_bias` alone, forward passes only, at a speed
+    that falls from ``speed`` by ``decay`` a pass, over the batches
+    ``tokens`` / ``targets`` (n, B, S) in turn: about where the rule would
+    have brought the biases for these weights. Nothing else of the state
+    moves."""
+    @jax.jit
+    def run(params, tokens, targets):
+        def body(i, biases):
+            k = i % tokens.shape[0]
+            loads = model.apply(
+                _with_router_biases(model, params, biases), tokens[k],
+                positions, True, next_tokens=targets[k])[2]
+            return biases + speed * decay ** i * _toward_balance(loads)
+
+        return jax.lax.fori_loop(0, iters, body,
+                                 _router_biases(model, params))
+
+    biases = run(state.params, tokens, targets)
+    return state._replace(
+        params=_with_router_biases(model, state.params, biases))
+
+
 # One-shot flag for the fused-xent auto-enable notice (ADVICE r3 #3).
 _FUSED_AUTO_LOGGED = False
 
@@ -316,14 +732,24 @@ class TrainState(NamedTuple):
 
 @phase("ddstore:state_init")
 def create_train_state(rng: jax.Array, model: TransformerLM,
-                       lr: float = 3e-4, mesh: Optional[Mesh] = None
+                       lr=3e-4, mesh: Optional[Mesh] = None
                        ) -> Tuple[TrainState, optax.GradientTransformation]:
+    # ``lr``: Adam's rate, a float or an optax schedule of the step.
     # Init through a mesh-free clone: the param structure is identical and
     # tracing ring attention would demand init shapes divisible by the
     # mesh axes.
     tok = jnp.zeros((1, 8), jnp.int32)
     init_model = model.clone(mesh=None)
-    params = init_model.init(rng, tok, jnp.tile(jnp.arange(8), (1, 1)))
+    # An MTP module's parameters exist only when it is given next tokens.
+    mtp = {"next_tokens": tok} if model.arch is not None else {}
+    init = lambda key: init_model.init(
+        key, tok, jnp.tile(jnp.arange(8), (1, 1)), **mtp)
+    if model.arch is not None:
+        # One program for every leaf: at 706 M parameters the eager
+        # leaf-by-leaf init is dozens of small programs, each compiled on
+        # a cold start. The dense path's init stays eager (a set-up PR's).
+        init = jax.jit(init)
+    params = init(rng)
     tx = optax.adam(lr)
     if mesh is None:
         return TrainState(params, tx.init(params),
@@ -382,10 +808,16 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
         return lm_loss(model, params, tok, tgt, pos,
                        fused_xent=fused_xent, mesh=mesh)
 
+    # An ``arch`` model's loss comes with its expert layers' load vectors;
+    # the step then returns ``(loss, loads)`` where the others return the
+    # loss (loads summed over the micro-steps of an accumulated step).
+    has_loads = model.arch is not None
+    value_and_grad = jax.value_and_grad(lossf, has_aux=has_loads)
+
     def ddstore_lm_train_step(state: TrainState, tokens, targets,
                               positions):
         if accum_steps == 1:
-            loss, grads = jax.value_and_grad(lossf)(
+            loss, grads = value_and_grad(
                 state.params, tokens, targets, positions)
         else:
             if tokens.shape[0] % accum_steps:
@@ -397,23 +829,32 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
 
             def body(carry, chunk):
                 gsum, lsum = carry
-                l, g = jax.value_and_grad(lossf)(state.params, *chunk)
+                l, g = value_and_grad(state.params, *chunk)
                 gsum = jax.tree_util.tree_map(jnp.add, gsum, g)
-                return (gsum, lsum + l), None
+                return (gsum, jax.tree_util.tree_map(jnp.add, lsum, l)), None
 
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+            lzero = jnp.zeros((), jnp.float32)
+            if has_loads:
+                lzero = (lzero, jnp.zeros(
+                    (len(_expert_layers(model)),
+                     model.arch.n_routed_experts), jnp.int32))
             (gsum, lsum), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32)),
+                body, (zeros, lzero),
                 (split(tokens), split(targets), split(positions)))
             grads = jax.tree_util.tree_map(
                 lambda g, p: (g / accum_steps).astype(p.dtype),
                 gsum, state.params)
-            loss = lsum / accum_steps
+            loss = (lsum[0] / accum_steps, lsum[1]) if has_loads \
+                else lsum / accum_steps
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, state.opt_state,
                                            state.params)
             params = optax.apply_updates(state.params, updates)
+            if has_loads and model.arch.bias_update_speed:
+                params = update_router_bias(model, params, loss[1],
+                                            model.arch.bias_update_speed)
         return TrainState(params, opt_state, state.step + 1), loss
 
     # The function's name is the program's: ``jit_ddstore_lm_train_step`` in

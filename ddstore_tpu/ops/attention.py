@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,6 +56,11 @@ NEG_INF = float("-inf")
 # nothing that pays for twice the bodies to trace and lower at every start.
 _STRIP = {"ddstore_flash_fwd": 512, "ddstore_flash_dq": 256,
           "ddstore_flash_dkv": 512}
+# Heads wider than 128 (PR 27, width 256): dkv gains 5 % from the narrower
+# strip at S=2048 (4.54 against 4.78 ms a call of 160 heads) and 1.5 % at
+# S=8192, the forward nothing; its blocks are square, so one diagonal
+# position and two more bodies.
+_STRIP_WIDE = dict(_STRIP, ddstore_flash_dkv=256)
 _LANES = 128
 # A diagonal block's position against the diagonal (first row - first
 # column) is one of a few static values, each a kernel body of its own;
@@ -532,6 +538,12 @@ def _flash(q, k, v, causal, scale, geos, interpret):
 
 def _flash_fwd(q, k, v, causal, scale, geos, interpret):
     out, lse = _fwd_impl(q, k, v, causal, scale, geos[0], interpret)
+    # Named for a rematerialised caller: under
+    # ``save_only_these_names("flash_out", "flash_lse")`` the backward
+    # pass finds both saved and this kernel does not run a second time
+    # (q, k and v are the caller's to recompute). Identity otherwise.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     # Residual is the THIN (B, H, S) lse — the kernel's 128-lane output
     # is tile-alignment scaffolding and holding it across fwd→bwd would
     # cost 128x the activation memory (~1 GiB at the S=8192 LM config).
@@ -596,9 +608,10 @@ def _fit_block(block: int, s: int) -> int:
 
 def _default_blocks(causal, sq, sk, d, q_offset, kv_offset):
     """``((block_q, block_k) forward, (block_q, block_k) dq and dkv)``
-    from what a call can see; PERF.md section 6 (PR 26) has the v5e times
-    they were chosen from. A causal call of at most 2048 x 2048, heads no
-    wider than 128, none of whose blocks would lie wholly in the past, is
+    from what a call can see; PERF.md section 6 (PR 26, and PR 27 for
+    heads wider than 128) has the v5e times they were chosen from. Heads
+    wider than 128 take 1024 x 1024 throughout. Otherwise a causal call of
+    at most 2048 x 2048, none of whose blocks would lie wholly in the past, is
     fetched whole by the backward kernels and in up to 1024 rows by the
     forward (the whole 2048 rows put the forward within 1 % of the 16 MiB
     of VMEM a kernel may take): nothing runs as an interior body, whose
@@ -607,7 +620,13 @@ def _default_blocks(causal, sq, sk, d, q_offset, kv_offset):
     the backward kernels and the non-causal forward take 1024 x 1024
     (2048-wide q blocks exceed VMEM there), the causal forward stays at
     512 x 2048: its diagonal strips want the width."""
-    if causal and max(sq, sk) <= 2048 and d <= 128:
+    if d > 128:
+        # Chosen on the chip at width 256 (PERF.md section 6, PR 27): every
+        # q/k/v/do/acc tile is twice as deep, 512 x 2048 and wider do not
+        # fit VMEM at S=8192, and of what fits 1024 x 1024 is the fastest
+        # for all three kernels at S=2048 and S=8192 alike.
+        return (1024, 1024), (1024, 1024)
+    if causal and max(sq, sk) <= 2048:
         short = (min(sq, 1024), sk), (sq, sk)
         if not any(_INTERIOR in _enumerate(sq, sk, *blocks, q_offset,
                                            kv_offset, "k")[2] & (_FIRST - 1)
@@ -679,9 +698,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if causal:
             # A strip is _STRIP of the side its accumulator lives on; the
             # other side is cut at the lane width, up to the diagonal.
-            sub = (_sub_tile(bq, _STRIP[name]), _sub_tile(bk, _LANES)) \
+            strip = (_STRIP_WIDE if d > 128 else _STRIP)[name]
+            sub = (_sub_tile(bq, strip), _sub_tile(bk, _LANES)) \
                 if stream == "k" else (
-                _sub_tile(bq, _LANES), _sub_tile(bk, _STRIP[name]))
+                _sub_tile(bq, _LANES), _sub_tile(bk, strip))
             geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
                                   kv_offset, stream)
         else:

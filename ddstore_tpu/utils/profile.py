@@ -70,7 +70,8 @@ import time
 from typing import Deque, Dict, Iterator, List
 
 __all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
-           "watch_compiles", "count_geometry", "counters"]
+           "watch_compiles", "count_geometry", "count_moe_layout",
+           "counters"]
 
 # One reading of both clocks, taken together: perf_counter_ns (what a phase
 # records; CLOCK_MONOTONIC, as ddtrace) and the epoch clock a trace is
@@ -89,6 +90,8 @@ _compile_s: Dict[str, Dict[str, float]] = {}
 _watching = False
 # Kernel name -> call shape -> what ops/attention.py's geometry counts.
 _geometry: Dict[str, Dict[str, Dict[str, int]]] = {}
+# Expert layer (its module path) -> what models/moe.py holds and routes.
+_moe_layout: Dict[str, Dict[str, int]] = {}
 
 
 @contextlib.contextmanager
@@ -193,14 +196,25 @@ def count_geometry(kernel: str, call: str, counts: Dict[str, int]) -> None:
         _geometry.setdefault(kernel, {})[call] = dict(counts)
 
 
+def count_moe_layout(layer: str, **counts: int) -> None:
+    """``models/moe.py``, while an expert layer is traced: the experts this
+    chip holds (``held`` of ``of``, from ``first``), the experts a token
+    takes (``top_k``) and the ``tokens`` of the call."""
+    with _lock:
+        _moe_layout[layer] = dict(counts)
+
+
 def counters() -> dict:
     """What this process counted. ``compile_s[fun_name]`` with ``trace_s``
     and ``lower_s``: the seconds JAX reported, since
     ``enable_compile_cache()``, for tracing that function and lowering it
     to a module, summed over every time it did (a compile cache shortens
     neither). ``flash_geometry[kernel][call]``: the causal geometry of
-    every flash call traced so far (:func:`count_geometry`)."""
+    every flash call traced so far (:func:`count_geometry`).
+    ``moe_layout[layer]``: the share of every expert layer traced so far
+    (:func:`count_moe_layout`)."""
     with _lock:
         return {"compile_s": {f: dict(d) for f, d in _compile_s.items()},
                 "flash_geometry": {k: {c: dict(n) for c, n in d.items()}
-                                   for k, d in _geometry.items()}}
+                                   for k, d in _geometry.items()},
+                "moe_layout": {k: dict(d) for k, d in _moe_layout.items()}}

@@ -82,6 +82,16 @@ def main():
                         "capacity left after dp*pp*tp)")
     p.add_argument("--moe-top-k", type=int, default=1,
                    help="experts per token (1=Switch, 2=GShard)")
+    p.add_argument("--config", type=str, default=None, metavar="JSON",
+                   help="build the model from a description of its "
+                        "architecture instead of --vocab/--dim/--layers/"
+                        "--experts: the keys of a published config.json "
+                        "(latent attention, shared + routed experts, MTP; "
+                        "e.g. benchmarks/configs/glm47-flash-ep8.json; "
+                        "with --dry-sizes its toy sizes)")
+    p.add_argument("--dry-sizes", action="store_true",
+                   help="with --config: overlay the file's dry_run block "
+                        "(toy widths for the CPU)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--steps", type=int, default=None)
     args = p.parse_args()
@@ -108,6 +118,8 @@ def main():
     # 4 devices, matching the pre-pp behavior); leftover capacity after
     # dp*pp*tp becomes the sequence axis.
     sp = n_dev // (dp * pp * tp)
+    if args.config:
+        sp = 1  # latent attention is not wired to ring attention
     axes = {"dp": dp}
     if pp > 1:
         axes["pp"] = pp
@@ -125,6 +137,20 @@ def main():
     for v in axes.values():
         n_used *= v
     mesh = make_mesh(axes, jax.local_devices()[:n_used])
+
+    # One description builds either architecture: the dense block (or its
+    # capacity-bound MoeMlp) from the flags, or a published latent-attention
+    # expert model from its config's keys.
+    desc = dict(vocab=args.vocab, dim=args.dim, heads=args.dim // 32,
+                layers=args.layers, experts=args.experts,
+                moe_top_k=args.moe_top_k)
+    if args.config:
+        import json
+        with open(args.config) as f:
+            desc = json.load(f)
+        if args.dry_sizes:
+            desc.update(desc.get("dry_run", {}))
+        args.vocab = int(desc["vocab_size"])
 
     group = auto_group()
     store = DDStore(group)
@@ -145,12 +171,11 @@ def main():
     # Smoke runs on virtual CPU devices therefore compute in f32.
     dtype = jnp.bfloat16 if jax.default_backend() == "tpu" \
         else jnp.float32
-    model = transformer.TransformerLM(
-        vocab=args.vocab, dim=args.dim, heads=args.dim // 32,
-        layers=args.layers, compute_dtype=dtype,
-        n_experts=args.experts, moe_top_k=args.moe_top_k,
-        mesh=mesh, remat=args.remat or args.remat_policy is not None,
-        remat_policy=args.remat_policy)
+    remat = {}
+    if args.remat or args.remat_policy is not None:
+        remat = dict(remat=True, remat_policy=args.remat_policy)
+    model = transformer.lm_from_description(desc, compute_dtype=dtype,
+                                            mesh=mesh, **remat)
     if pp > 1:
         # Pipelined step: stages over pp (megatron-sharded over tp when
         # set, ring attention over sp inside each stage).
@@ -168,12 +193,28 @@ def main():
             n_virtual=nv)
         batch = args.microbatches * 2 * dp
     else:
+        lr = args.lr
+        if desc.get("lr_warmup_steps"):
+            # a description may say how its rate warms up (an expert
+            # model's router does not survive the full rate at step 0)
+            import optax
+            lr = optax.linear_schedule(0.0, float(desc.get("lr", lr)),
+                                       int(desc["lr_warmup_steps"]))
         state, tx = transformer.create_train_state(
-            jax.random.key(args.seed), model, lr=args.lr, mesh=mesh)
+            jax.random.key(args.seed), model, lr=lr, mesh=mesh)
         step = transformer.make_train_step(model, tx, mesh=mesh,
                                            state=state,
                                            accum_steps=args.accum_steps)
         batch = 2 * dp
+        if model.arch is not None:
+            # A seeded router is far from the balance a model in training
+            # keeps: bring the correction biases there on the first windows.
+            n = min(4, len(windows) // batch)
+            cut = lambda a: jnp.asarray(a[:n * batch]).reshape(
+                n, batch, args.seq)
+            state = transformer.balance_router_bias(
+                model, state, cut(windows), cut(nexts),
+                jnp.tile(jnp.arange(args.seq, dtype=jnp.int32), (batch, 1)))
 
     sampler = DistributedSampler(len(ds), store.world_group.size,
                                  store.world_group.rank, seed=args.seed)
@@ -188,13 +229,16 @@ def main():
         tracing = trace(args.profile) if (args.profile and epoch == 0) \
             else contextlib.nullcontext()
         t0 = time.perf_counter()
-        tot, nb = 0.0, 0
+        tot, nb, loads = 0.0, 0, None
         with tracing:
             for i, (tok, tgt) in enumerate(loader):
                 if args.steps is not None and i >= args.steps:
                     break
                 with step_annotate(i):
                     state, loss = step(state, tok, tgt, pos)
+                if model.arch is not None:
+                    # beside the loss: tokens routed to each expert, a layer
+                    loss, loads = loss
                 tot += float(loss)
                 nb += 1
             # Flush the final async step before stop_trace / timing
@@ -206,7 +250,9 @@ def main():
             tps = nb * batch * args.seq / dt
             print(f"epoch {epoch}: loss={tot / max(1, nb):.4f} "
                   f"tokens/s={tps:.0f} "
-                  f"loader_wait_share={m['loader_wait_share']:.4f}",
+                  f"loader_wait_share={m['loader_wait_share']:.4f}"
+                  + ("" if loads is None else " expert load max/mean="
+                     f"{float((loads.max(-1) / loads.mean(-1)).max()):.2f}"),
                   flush=True)
     if args.generate > 0 and store.rank == 0:
         # KV-cached greedy continuation of the first window's prefix —

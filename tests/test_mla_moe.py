@@ -1,0 +1,389 @@
+"""The latent-attention, shared + routed expert, MTP architecture
+(``TransformerLM(arch=...)``) against its plain reference
+(``benchmarks/reference/mla_moe_lm.py``) on seeded weights at a small size,
+and the pieces one by one: MLA, the router, the no-drop dispatch, the
+expert-parallel share, MTP's targets, the bias leaf, the description."""
+
+import importlib.util
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ddstore_tpu.models import moe, transformer as T
+from ddstore_tpu.utils import profile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spec = importlib.util.spec_from_file_location(
+    "ref_mla_moe_lm", os.path.join(ROOT, "benchmarks", "reference",
+                                   "mla_moe_lm.py"))
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+# One dense layer, two expert layers and the MTP module; 2 of 16 routed
+# experts held (chip 1 of 8), 4 a token.
+DESC = dict(
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=12,
+    qk_rope_head_dim=4, v_head_dim=16, intermediate_size=96,
+    moe_intermediate_size=24, n_routed_experts=2, num_experts_per_tok=4,
+    n_shared_experts=1, routed_scaling_factor=1.8, first_k_dense_replace=1,
+    rope_theta=1e6, rms_norm_eps=1e-5, num_nextn_predict_layers=1,
+    vocab_size=128, hidden_size=32, num_attention_heads=4,
+    num_hidden_layers=3, expert_parallel={"chips": 8, "chip": 1})
+B, S = 4, 16
+
+
+def ref_arch(model):
+    a = model.arch
+    return dict(a._asdict(), heads=model.heads)
+
+
+def batch(seed=0, vocab=128):
+    rng = np.random.default_rng(seed)
+    tok = rng.integers(0, vocab, (B, S)).astype(np.int32)
+    tgt = rng.integers(0, vocab, (B, S)).astype(np.int32)
+    return tok, tgt, np.tile(np.arange(S, dtype=np.int32), (B, 1))
+
+
+@pytest.fixture(scope="module")
+def built():
+    model = T.lm_from_description(DESC, compute_dtype=jnp.float32)
+    state, tx = T.create_train_state(jax.random.key(3), model, lr=1e-3)
+    return model, state, tx
+
+
+def test_loss_and_every_gradient_leaf_match_the_reference(built):
+    model, state, _ = built
+    tok, tgt, pos = batch()
+    with jax.default_matmul_precision("highest"):
+        (loss, loads), grads = jax.value_and_grad(
+            lambda p: T.lm_loss(model, p, tok, tgt, pos), has_aux=True)(
+                state.params)
+    want, want_grads = jax.value_and_grad(
+        lambda p: ref.loss(p, tok, tgt, pos, arch=ref_arch(model)))(
+            state.params)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    assert loads.shape == (3, 16) and loads.dtype == jnp.int32
+    assert (np.asarray(loads).sum(1) == B * S * 4).all()
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    wflat = dict(jax.tree_util.tree_flatten_with_path(want_grads)[0])
+    assert len(flat) == len(wflat) > 60
+    for path, g in flat:
+        w = wflat[path]
+        scale = max(float(jnp.abs(w).max()), 1e-6)
+        np.testing.assert_allclose(
+            np.asarray(g) / scale, np.asarray(w) / scale, atol=2e-4,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_fused_head_gives_the_same_loss(built):
+    model, state, _ = built
+    tok, tgt, pos = batch(1)
+    plain, _ = T.lm_loss(model, state.params, tok, tgt, pos,
+                         fused_xent=False)
+    fused, _ = T.lm_loss(model, state.params, tok, tgt, pos,
+                         fused_xent=True, xent_block=48)
+    np.testing.assert_allclose(fused, plain, rtol=1e-5)
+
+
+def test_mla_alone_against_a_written_out_softmax():
+    arch = T.MlaMoeArch(q_lora_rank=12, kv_lora_rank=8, qk_nope_head_dim=6,
+                        qk_rope_head_dim=4, v_head_dim=10,
+                        intermediate_size=16, moe_intermediate_size=8,
+                        n_routed_experts=4, num_experts_per_tok=2,
+                        rope_theta=1e4)
+    blk = T.MlaBlock(16, 2, arch, True, jnp.float32)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(1, 5, 16)), jnp.float32)
+    pos = jnp.asarray([[3, 4, 5, 6, 7]], jnp.int32)
+    params = blk.init(jax.random.key(0), x, pos)
+    p = params["params"]
+    # Undo the MLP: zero its output projection, so the block is x + MLA.
+    p = dict(p, down={"kernel": jnp.zeros_like(p["down"]["kernel"])})
+    got = blk.apply({"params": p}, x, pos) - x
+
+    rms = lambda v, g: v / np.sqrt((v * v).mean(-1, keepdims=True) + 1e-5) * g
+    n = lambda name: np.asarray(p[name]["kernel"], np.float64)
+    xs = np.asarray(x[0], np.float64)
+    h = rms(xs, np.asarray(p["ln1"]["scale"]))
+    q = (rms(h @ n("q_a"), np.asarray(p["q_norm"]["scale"]))
+         @ n("q_b")).reshape(5, 2, 10)
+    kva = h @ n("kv_a")
+    kv = (rms(kva[:, :8], np.asarray(p["kv_norm"]["scale"]))
+          @ n("kv_b")).reshape(5, 2, 16)
+
+    def rot(v, t):
+        out = v.copy()
+        for i in range(2):
+            ang = t * 1e4 ** (-i / 2)
+            a, b = v[..., i], v[..., i + 2]
+            out[..., i] = a * math.cos(ang) - b * math.sin(ang)
+            out[..., i + 2] = b * math.cos(ang) + a * math.sin(ang)
+        return out
+
+    heads = []
+    for hd in range(2):
+        rows = []
+        for i in range(5):
+            qi = np.concatenate([q[i, hd, :6], rot(q[i, hd, 6:], 3 + i)])
+            sc = []
+            for j in range(i + 1):
+                kj = np.concatenate([kv[j, hd, :6], rot(kva[j, 8:], 3 + j)])
+                sc.append(qi @ kj / math.sqrt(10))
+            w = np.exp(np.array(sc) - max(sc))
+            w /= w.sum()
+            rows.append(sum(w[j] * kv[j, hd, 6:] for j in range(i + 1)))
+        heads.append(np.stack(rows))
+    want = np.concatenate(heads, -1) @ n("proj")
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+
+
+def test_router_selects_by_score_plus_bias_and_weighs_by_score():
+    scores = jnp.asarray([[0.9, 0.8, 0.7, 0.1], [0.2, 0.6, 0.5, 0.4]])
+    chosen, w = moe.route_noaux_tc(scores, jnp.zeros(4), 2, 1.8)
+    assert chosen.tolist() == [[0, 1], [1, 2]]
+    np.testing.assert_allclose(w, [[1.8 * 0.9 / 1.7, 1.8 * 0.8 / 1.7],
+                                   [1.8 * 0.6 / 1.1, 1.8 * 0.5 / 1.1]],
+                               rtol=1e-6)
+    # The bias changes who is chosen, and never a weight.
+    chosen, w = moe.route_noaux_tc(scores, jnp.asarray([0., 0., 0., 0.75]),
+                                   2, 1.8)
+    assert chosen.tolist() == [[0, 3], [3, 1]]
+    np.testing.assert_allclose(w, [[1.8 * 0.9 / 1.0, 1.8 * 0.1 / 1.0],
+                                   [1.8 * 0.4 / 1.0, 1.8 * 0.6 / 1.0]],
+                               rtol=1e-6)
+
+
+def _layer(share, n_routed=16, top_k=4):
+    return moe.SharedRoutedMoe(n_routed, top_k, 24, share=share,
+                               scaling=1.8, compute_dtype=jnp.float32)
+
+
+def _arch(share):
+    return dict(num_experts_per_tok=4, routed_scaling_factor=1.8,
+                expert_share=share)
+
+
+def test_no_token_is_dropped_when_every_token_goes_to_the_held_experts():
+    """A bias that sends every token to experts 0-3, all held here: every
+    one of the T x k pairs is computed (both chunks of the sorted rows run,
+    where even routing fills half of the first)."""
+    layer = _layer((0, 4))
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(64, 32)),
+                    jnp.float32)
+    p = layer.init(jax.random.key(1), x)["params"]
+    p = dict(p, router_bias=jnp.where(jnp.arange(16) < 4, 10.0, 0.0))
+    with jax.default_matmul_precision("highest"):
+        y, load = layer.apply({"params": p}, x)
+    assert load.tolist() == [64] * 4 + [0] * 12
+    want, _ = ref.moe(p, x, _arch((0, 4)))
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    # and when none is held here, only the shared expert answers
+    p = dict(p, router_bias=jnp.where(jnp.arange(16) >= 12, 10.0, 0.0))
+    with jax.default_matmul_precision("highest"):
+        y, load = layer.apply({"params": p}, x)
+    assert load.tolist() == [0] * 12 + [64] * 4
+    want, _ = ref.moe(p, x, _arch((0, 4)), shared=True)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """Routed parts of all eight chips' shares + the shared expert once ==
+    the reference's uncut 16-expert layer."""
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(48, 32)),
+                    jnp.float32)
+    whole = _layer((0, 1)).init(jax.random.key(5), x)["params"]
+    want, _ = ref.moe(whole, x, _arch((0, 1)))
+    shared = ref._swiglu(x, whole["shared_gate"]["kernel"],
+                         whole["shared_up"]["kernel"],
+                         whole["shared_down"]["kernel"])
+    total, loads = shared, []
+    for which in range(8):
+        cut = dict(whole, **{k: whole[k][2 * which:2 * which + 2]
+                             for k in ("w_gate", "w_up", "w_down")})
+        with jax.default_matmul_precision("highest"):
+            y, load = _layer((which, 8)).apply({"params": cut}, x)
+        total = total + (y - shared)
+        loads.append(load)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    # every chip routes over all 16 alike
+    assert all((ld == loads[0]).all() for ld in loads)
+    assert int(loads[0].sum()) == 48 * 4
+
+
+def test_moe_layout_counter_says_what_is_held(built):
+    layout = profile.counters()["moe_layout"]
+    assert {k: layout["block1/moe"][k] for k in (
+        "held", "of", "first", "top_k")} == dict(held=2, of=16, first=2,
+                                                 top_k=4)
+    assert set(layout) >= {"block1/moe", "block2/moe", "mtp/block/moe"}
+
+
+def test_mtp_targets_and_mask():
+    tgt = jnp.asarray([[5, 6, 7, 8], [1, 2, 3, 4]])
+    after, mask = T.mtp_targets(tgt)
+    assert after.tolist() == [[6, 7, 8, 0], [2, 3, 4, 0]]
+    assert mask.tolist() == [[True, True, True, False]] * 2
+
+
+def test_mtp_term_is_the_weighted_second_head(built):
+    model, state, _ = built
+    tok, tgt, pos = batch(2)
+    both, _ = T.lm_loss(model, state.params, tok, tgt, pos)
+    no_mtp = model.clone(arch=model.arch._replace(mtp_loss_weight=0.0))
+    main, _ = T.lm_loss(no_mtp, state.params, tok, tgt, pos)
+    arch = ref_arch(model)
+    want = ref.loss(state.params, tok, tgt, pos,
+                    arch=dict(arch, mtp_loss_weight=1.0)) \
+        - ref.loss(state.params, tok, tgt, pos,
+                   arch=dict(arch, mtp_loss_weight=0.0))
+    np.testing.assert_allclose(float(both) - float(main), 0.3 * want,
+                               rtol=1e-4)
+    feats = model.apply(state.params, tok, pos, True, next_tokens=tgt)[1]
+    assert feats.shape == (B, S, 32)
+    assert model.apply(state.params, tok, pos, True)[1] is None
+
+
+def test_the_bias_leaf_takes_no_gradient_and_no_update(built):
+    model, state, tx = built
+    tok, tgt, pos = batch(3)
+    grads = jax.grad(lambda p: T.lm_loss(model, p, tok, tgt, pos)[0])(
+        state.params)
+    for name in ("block1", "block2"):
+        assert not np.asarray(
+            grads["params"][name]["moe"]["router_bias"]).any()
+        assert np.asarray(
+            grads["params"][name]["moe"]["router"]["kernel"]).any()
+    step = T.make_train_step(model, tx, donate=False)
+    new, (loss, loads) = step(state, tok, tgt, pos)
+    for name in ("block1", "block2"):
+        np.testing.assert_array_equal(
+            new.params["params"][name]["moe"]["router_bias"],
+            state.params["params"][name]["moe"]["router_bias"])
+    mb = new.params["params"]["mtp"]["block"]["moe"]
+    np.testing.assert_array_equal(
+        mb["router_bias"],
+        state.params["params"]["mtp"]["block"]["moe"]["router_bias"])
+    assert np.asarray(mb["router_bias"]).any()      # seeded, non-zero
+    assert np.isfinite(float(loss)) and loads.shape == (3, 16)
+
+
+def test_the_noaux_tc_rule_moves_each_bias_toward_balance(built):
+    model, state, tx = built
+    loads = jnp.asarray(np.tile(np.arange(16, dtype=np.int32), (3, 1)))
+    before = T._router_biases(model, state.params)
+    after = T._router_biases(model, T.update_router_bias(
+        model, state.params, loads, 0.01))
+    want = np.where(np.arange(16) < 7.5, 0.01, -0.01)
+    np.testing.assert_allclose(after - before, np.tile(want, (3, 1)),
+                               atol=1e-7)
+    # the step applies it after the optimizer, when the model has a speed
+    tok, tgt, pos = batch(5)
+    fast = model.clone(arch=model.arch._replace(bias_update_speed=0.01))
+    new, (_, loads) = T.make_train_step(fast, tx, donate=False)(
+        state, tok, tgt, pos)
+    np.testing.assert_allclose(
+        T._router_biases(fast, new.params) - before,
+        0.01 * np.sign(B * S * 4 / 16 - np.asarray(loads)), atol=1e-7)
+
+
+def test_balancing_the_biases_in_set_up_evens_the_loads(built):
+    model, state, _ = built
+    rng = np.random.default_rng(6)
+    # a skewed vocabulary, as the benchmark's traffic
+    tok = (rng.integers(0, 128, (3, B, S)) ** 3 // 128 ** 2).astype(np.int32)
+    tgt = np.roll(tok, -1, -1)
+    pos = np.tile(np.arange(S, dtype=np.int32), (B, 1))
+    # largest over mean load, mean over the batches it ran on and the layers
+    uneven = lambda st: float(np.mean([
+        np.asarray(ld.max(-1) / ld.mean(-1)) for ld in (
+            T.lm_loss(model, st.params, tok[i], tgt[i], pos)[1]
+            for i in range(2))]))
+    balanced = T.balance_router_bias(model, state, tok[:2], tgt[:2], pos)
+    assert uneven(balanced) < 0.85 * uneven(state)
+    same = lambda a, b: all(
+        (x == y).all() for (kx, x), (ky, y) in zip(
+            jax.tree_util.tree_flatten_with_path(a.params)[0],
+            jax.tree_util.tree_flatten_with_path(b.params)[0])
+        if "router_bias" not in jax.tree_util.keystr(kx))
+    assert same(balanced, state)
+
+
+@pytest.mark.parametrize("variant", ["remat_names", "remat_full", "accum2"])
+def test_remat_and_accumulation_change_memory_not_the_update(built, variant):
+    model, state, tx = built
+    tok, tgt, pos = batch(4)
+    base, (loss0, loads0) = T.make_train_step(model, tx, donate=False)(
+        state, tok, tgt, pos)
+    kw = {}
+    if variant == "remat_names":
+        model = model.clone(remat=True,
+                            remat_policy="names:flash_out,flash_lse")
+    elif variant == "remat_full":
+        model = model.clone(remat=True)
+    else:
+        kw = dict(accum_steps=2)
+    new, (loss, loads) = T.make_train_step(model, tx, donate=False, **kw)(
+        state, tok, tgt, pos)
+    np.testing.assert_allclose(loss, loss0, rtol=2e-6)
+    np.testing.assert_array_equal(loads, loads0)
+    for a, b in zip(jax.tree_util.tree_leaves(new.params),
+                    jax.tree_util.tree_leaves(base.params)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_one_description_builds_either_architecture():
+    dense = T.lm_from_description(dict(vocab=64, dim=32, heads=2, layers=1))
+    assert dense.arch is None and dense.mlp_ratio == 4
+    old_moe = T.lm_from_description(
+        dict(vocab=64, dim=32, heads=2, layers=1, experts=4, moe_top_k=2))
+    assert old_moe.n_experts == 4 and old_moe.moe_top_k == 2
+    model = T.lm_from_description(dict(DESC, remat_policy="names:flash_out"))
+    assert model.arch.n_routed_experts == 16          # the router's width
+    assert model.arch.expert_share == (1, 8)
+    assert (model.vocab, model.dim, model.heads, model.layers) \
+        == (128, 32, 4, 3)
+    assert model.remat and model.remat_policy == "names:flash_out"
+    with pytest.raises(ValueError, match="n_group"):
+        T.lm_from_description(dict(DESC, n_group=2))
+    with pytest.raises(ValueError, match="remat_policy"):
+        T._remat_policy("no_such_policy")
+
+
+@pytest.mark.parametrize("vocab,block,want", [
+    (32768, 8192, 8192), (19360, 8192, 6528), (512, 8192, 8192),
+    (16384, 8192, 8192), (20000, 4096, 4096)])
+def test_balanced_block(vocab, block, want):
+    got = T._balanced_block(vocab, block)
+    assert got == want
+    assert got % 128 == 0 and -(-vocab // got) == -(-vocab // block)
+
+
+@pytest.mark.parametrize("s", [2048, 3072])
+def test_flash_at_head_width_256_matches_the_reference(s):
+    """Interpret mode, one block row of 1024 and several: forward and the
+    three gradients at the width the MLA block calls the kernels with."""
+    from ddstore_tpu.ops.attention import flash_attention, mha_reference
+
+    ks = jax.random.split(jax.random.key(s), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 2, s, 256), jnp.float32)
+                   for kk in ks)
+
+    def run(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v, causal=True)
+            return (out * do).sum() + lse.sum() * 1e-3
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got, ggrads = run(flash_attention)
+        want, wgrads = run(mha_reference)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(ggrads, wgrads):
+        np.testing.assert_allclose(g, w, atol=5e-4 * float(jnp.abs(w).max()))
+    geo = profile.counters()["flash_geometry"]["ddstore_flash_dkv"]
+    call = next(c for c in geo if f"q{s}+0" in c and "d256" in c)
+    assert "blocks 1024x1024 sub 128x256" in call

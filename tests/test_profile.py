@@ -200,7 +200,8 @@ def test_compile_counters_are_kept_per_function(monkeypatch, tmp_path):
     jax.jit(ddstore_test_counted).lower(jnp.ones(6)).compile()
     again = profile.counters()["compile_s"]["ddstore_test_counted"]
     assert all(again[k] > mine[k] for k in mine)
-    assert set(profile.counters()) == {"compile_s", "flash_geometry"}
+    assert set(profile.counters()) == {"compile_s", "flash_geometry",
+                                       "moe_layout"}
 
 
 def test_summary_names_what_it_measures():
