@@ -227,7 +227,10 @@ def generate(model: TransformerLM, params, prompt: jax.Array,
     **Long prompts**: ``prefill_mesh`` runs the one-pass prefill with
     the model's ring attention over that mesh's ``sp`` axis (sequence
     sharded, K/V rotating over ICI), for prompts a single device's
-    memory can't hold; the decode scan itself stays data-parallel.
+    memory can't hold; the decode scan itself stays data-parallel. The
+    prompt's length is then a multiple of twice the ``sp`` size (the
+    ring's two stripes a position); the blocks hand the cache their K/V
+    in natural order.
     """
     if temperature > 0 and key is None:
         raise ValueError("sampling (temperature > 0) needs `key`")

@@ -11,6 +11,15 @@ Sharding scheme of the train step: tokens/targets (B, S) sharded
 P("dp", "sp"); params replicated; XLA inserts the gradient all-reduce and
 the loss-mean collectives, shard_map inside ring attention handles the
 sequence axis.
+
+What lies on which chip: on a mesh with sp > 1 the losses lay their three
+int32 inputs out in the ring's balanced order first (:func:`ring_order`:
+sp position i holds stripe i and stripe 2·sp-1-i of every sequence, an
+early and a late one, so the causal mask costs every position the same).
+Positions are global indices, every layer but attention is token-wise and
+the loss is a token mean, so no activation is permuted and nothing is
+brought back: the caller, its loader and its P("dp", "sp") spec keep the
+natural order.
 """
 
 from __future__ import annotations
@@ -21,6 +30,7 @@ from typing import Any, Mapping, NamedTuple, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -30,10 +40,29 @@ from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
                                  pipeline_interleaved,
                                  pipeline_interleaved_1f1b,
                                  stack_stage_params)
-from ..parallel.ring_attention import ring_attention
+from ..parallel.ring_attention import balanced_order, ring_attention
 from ..parallel.tp import (expert_rules, megatron_rules, shard_pytree,
                            shardings_of)
 from ..utils.profile import phase
+
+
+def ring_order(mesh: Optional[Mesh], sp_axis: str, *arrays, axis: int = 1,
+               back: bool = False):
+    """``arrays`` (None passes through) with their sequence dimension
+    ``axis`` in the order ring attention wants a causal sequence in on
+    ``mesh`` (:func:`~ddstore_tpu.parallel.ring_attention.balanced_order`);
+    ``back=True`` returns such arrays to natural order. On a mesh without a
+    sequence axis they come back as they are: this permutes exactly where
+    :class:`Block` rings."""
+    n = 1 if mesh is None else mesh.shape.get(sp_axis, 1)
+    if n == 1:
+        return arrays
+    some = next(a for a in arrays if a is not None)
+    order = balanced_order(some.shape[axis], n)
+    if back:
+        order = np.argsort(order)
+    return tuple(None if a is None else jnp.take(a, order, axis=axis)
+                 for a in arrays)
 
 
 class Block(nn.Module):
@@ -51,6 +80,10 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, token_mask: Optional[jax.Array] = None):
+        """``x`` (B, S, dim). On a mesh with a sequence axis the sequence
+        is in the ring's order (:func:`ring_order`); only the stashed K/V
+        of ``sow_kv`` are brought back to natural order (a permutation of
+        two (B, H, S, hd) tensors a layer: the prefill's price)."""
         b, s, _ = x.shape
         dt = self.compute_dtype
         hd = self.dim // self.heads
@@ -64,7 +97,8 @@ class Block(nn.Module):
                 0, 2, 1, 3)
             q, k, v = to_heads(q), to_heads(k), to_heads(v)
             if self.sow_kv:
-                self.sow("intermediates", "kv", (k, v))
+                self.sow("intermediates", "kv", ring_order(
+                    self.mesh, self.sp_axis, k, v, axis=2, back=True))
             use_sp = (self.mesh is not None
                       and self.mesh.shape.get(self.sp_axis, 1) > 1)
             if use_sp:
@@ -409,13 +443,24 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions, return_features: bool = False,
                  *, token_mask: Optional[jax.Array] = None,
-                 next_tokens: Optional[jax.Array] = None):
+                 next_tokens: Optional[jax.Array] = None,
+                 ring_ordered: bool = False):
         """tokens/positions: (B, S) int32; positions are GLOBAL indices so
         sequence-sharded chunks embed correctly. ``return_features=True``
         returns the post-final-LayerNorm features instead of logits (the
         fused-xent path applies the head kernel itself). ``token_mask``
         (B, S) bool marks real vs padded positions — only MoE routing
         consumes it (padded tokens take no expert capacity).
+
+        On a mesh with sp > 1 the sequence runs in the ring's balanced
+        order (:func:`ring_order`). Inputs and outputs are in natural order
+        all the same: the int32 inputs are laid out here and the (B, S,
+        vocab) logits or (B, S, dim) features brought back, which costs an
+        exchange of that whole tensor over the sp axis. A caller that
+        needs no per-position output in natural order (:func:`lm_loss`:
+        a token mean against targets permuted alike) lays its inputs out
+        itself and says so with ``ring_ordered=True``; nothing is then
+        permuted here. A capacity-bound MoE drops by the order it sees.
 
         With ``arch`` the result is ``(out, mtp_features, loads)``:
         ``loads`` (expert layers, n_routed_experts) int32 counts the tokens
@@ -426,6 +471,9 @@ class TransformerLM(nn.Module):
         if self.arch is not None:
             return self._mla_moe(tokens, positions, return_features,
                                  next_tokens)
+        if not ring_ordered:
+            tokens, positions, token_mask = ring_order(
+                self.mesh, self.sp_axis, tokens, positions, token_mask)
         with jax.named_scope("embed"):
             x = EmbedPE(self.vocab, self.dim, self.compute_dtype,
                         name="embed")(tokens, positions)
@@ -443,7 +491,10 @@ class TransformerLM(nn.Module):
                           sow_kv=self.sow_kv,
                           name=f"block{i}")(x, token_mask)
         with jax.named_scope("head"):
-            return LMHead(self.vocab, name="lmhead")(x, return_features)
+            out = LMHead(self.vocab, name="lmhead")(x, return_features)
+        if not ring_ordered:
+            out, = ring_order(self.mesh, self.sp_axis, out, back=True)
+        return out
 
     @nn.nowrap
     def _mla_moe(self, tokens, positions, return_features, next_tokens):
@@ -538,7 +589,14 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
     gather it, so pass ``mesh`` whenever one is in play. The fused head
     matmul runs in ``model.compute_dtype`` with f32 accumulation; the
     unfused path keeps the (possibly vocab-sharded) f32 Dense.
+
+    The three (B, S) inputs come in natural order. Where the model's mesh
+    has sp > 1 they are laid out in the ring's order here, once, before the
+    embedding (:func:`ring_order`); the loss is a token mean, so nothing is
+    brought back.
     """
+    tokens, targets, positions = ring_order(
+        model.mesh, model.sp_axis, tokens, targets, positions)
     if fused_xent is None:
         tp = mesh is not None and mesh.shape.get(tp_axis, 1) > 1
         # >= 2 blocks required: a single-block "fusion" still materializes
@@ -566,10 +624,11 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
 
     if mutable:
         out, inter = model.apply(params, tokens, positions, fused_xent,
-                                 mutable=mutable)
+                                 mutable=mutable, ring_ordered=True)
         aux = MOE_AUX_WEIGHT * moe_aux_sum(inter) / model.layers
     else:
-        out = model.apply(params, tokens, positions, fused_xent)
+        out = model.apply(params, tokens, positions, fused_xent,
+                          ring_ordered=True)
         aux = 0.0
     # The same scope as the final norm and LMHead inside the model: the
     # whole head, loss included, is one name in a device trace.
@@ -790,7 +849,10 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
                     fused_xent: Optional[bool] = None,
                     accum_steps: int = 1):
     """Jitted dp×sp(×tp) train step: (tokens, targets, positions) all
-    (B, S), batch over ``dp``, sequence over ``sp``. Pass ``state`` when
+    (B, S) in natural order, batch over ``dp``, sequence over ``sp`` in
+    contiguous chunks as the loader stages them; :func:`lm_loss` moves the
+    three to the ring's balanced order inside the step (sp position i then
+    works on stripes i and 2·sp-1-i of each sequence). Pass ``state`` when
     its params carry TP shardings — the step pins them in place (and the
     gradient/optimizer math stays sharded the same way). ``fused_xent``
     is forwarded to :func:`lm_loss` (default: auto at vocab >= 8192).
@@ -1004,8 +1066,10 @@ def _make_stage_fn(model: "TransformerLM", n_stages: int,
     """Stage body for the pipeline schedules. With a mesh whose sp axis
     is >1 the blocks ring their attention over it (pp×sp: the schedules
     are manual over pp/dp only, so the ring's nested shard_map over sp
-    composes — VERDICT r3 missing #1); otherwise mesh=None keeps the
-    round-3 behavior (flash/XLA attention on the full local sequence)."""
+    composes — VERDICT r3 missing #1) and take the sequence in the ring's
+    order, which the pipelined losses give it from the same mesh;
+    otherwise mesh=None keeps the round-3 behavior (flash/XLA attention
+    on the full local sequence)."""
     g = _stage_group_size(model.layers, n_stages)
     sp_mesh = mesh if (mesh is not None
                        and mesh.shape.get(model.sp_axis, 1) > 1) else None
@@ -1104,7 +1168,11 @@ def pp_gpipe_value_and_grad(model: TransformerLM, stage_fn, pp_params,
     With ``n_virtual > 1`` the ring runs the interleaved virtual-stage
     schedule instead (``schedule="interleaved"``; the stage stack must
     be device-major, see ``lm_to_stages``) — same autodiff backward,
-    V× smaller bubble."""
+    V× smaller bubble. ``mesh`` is the one ``stage_fn`` was made with:
+    where it has sp > 1 the stages ring, and the natural-order inputs
+    are laid out for them here (:func:`ring_order`)."""
+    tokens, targets, positions = ring_order(
+        mesh, model.sp_axis, tokens, targets, positions)
 
     def lossf(pp_params):
         outer, stages = pp_params
@@ -1148,7 +1216,10 @@ def pp_1f1b_value_and_grad(model: TransformerLM, stage_fn, pp_params,
     ``n_virtual > 1`` the ring runs
     :func:`~ddstore_tpu.parallel.pipeline.pipeline_interleaved_1f1b`
     (``schedule="interleaved_1f1b"``: 2V/(V+1)× smaller bubble AND the
-    M-independent stash; device-major stage stack required)."""
+    M-independent stash; device-major stage stack required). ``mesh`` and
+    the inputs' order: as :func:`pp_gpipe_value_and_grad`."""
+    tokens, targets, positions = ring_order(
+        mesh, model.sp_axis, tokens, targets, positions)
     outer, stages = pp_params
 
     def embed_f(embed_params):

@@ -63,15 +63,16 @@ def _check_case(s, hd, causal, qo, ko):
 def flash_reference_check(s: int, s_misaligned: int = 0) -> int:
     """Assert flash == reference ON THE CURRENT BACKEND — outputs AND
     gradients, head_dim 64 and 128, at sequence length ``s``: plain,
-    causal, and the two ring offset cases; then the ring's
-    ``lax.cond``-of-kernels construct. ``s_misaligned`` (optional) adds
-    one causal case at a length that is a multiple of 8 but not of the
-    bf16 tile's 16 rows. Returns the number of cases; raises on any
-    mismatch — a caller must fail loudly, not time wrong code."""
+    causal, and two offset cases; then the causal ring's first step and
+    its later ones on either side of ``src``. ``s_misaligned``
+    (optional) adds one causal case at a length that is a multiple of 8
+    but not of the bf16 tile's 16 rows. Returns the number of cases;
+    raises on any mismatch — a caller must fail loudly, not time wrong
+    code."""
     ncases = 0
     for hd in (64, 128):
-        # (causal, q_offset, kv_offset): plain, causal/diag, ring "past"
-        # chunk, ring mid-offset diag.
+        # (causal, q_offset, kv_offset): plain, causal, a kv chunk wholly
+        # in the past, a diagonal at a mid offset.
         for causal, qo, ko in [(False, 0, 0), (True, 0, 0), (True, s, 0),
                                (True, s // 2, s // 2)]:
             _check_case(s, hd, causal, qo, ko)
@@ -80,38 +81,38 @@ def flash_reference_check(s: int, s_misaligned: int = 0) -> int:
         _check_case(s_misaligned, 64, True, 0, 0)
         ncases += 1
 
-    # The ring three-case construct: lax.cond selecting between
-    # statically-configured Pallas kernels (parallel/ring_attention.py
-    # _ring_body) — compile and run every branch on this backend.
-    q = jax.random.normal(jax.random.key(7), (1, 2, s, 64), jnp.bfloat16)
+    # The causal ring's cases (parallel/ring_attention.py): the plain
+    # causal call of its first step, then one unmasked kernel over two
+    # stripe pairs, the second selected by the traced side ``src`` is on,
+    # combined into the stripes' accumulators — compile and run both sides
+    # on this backend, the kernel against the reference in the same
+    # construct.
+    from ..parallel.ring_attention import causal_ring_step
 
-    @jax.jit
-    def ring_cases(pred_diag, pred_past, q):
-        def diag(args):
-            return flash_attention(*args, causal=True)
+    kq, kk, kv = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(kq, (1, 2, s, 64), jnp.bfloat16)
+    k = jax.random.normal(kk, (2, 1, 2, s, 64), jnp.bfloat16)
+    v = jax.random.normal(kv, (2, 1, 2, s, 64), jnp.bfloat16)
 
-        def past(args):
-            return flash_attention(*args, causal=False)
+    def ring_cases(attend):
+        @jax.jit
+        def run(src_is_earlier, q, k, v):
+            out, lse = attend(q, k[0], v[0], causal=True)
+            halves = zip(jnp.split(out, 2, 2), jnp.split(lse, 2, 2))
+            early, late = causal_ring_step(attend, src_is_earlier, *halves,
+                                           q, k[1], v[1])
+            return (out, jnp.concatenate([early[0], late[0]], 2),
+                    jnp.concatenate([early[1], late[1]], 2))
+        return run
 
-        def masked(args):
-            return (jnp.zeros(q.shape, q.dtype),
-                    jnp.full(q.shape[:3], -jnp.inf, jnp.float32))
-
-        return jax.lax.cond(
-            pred_diag, diag,
-            lambda a: jax.lax.cond(pred_past, past, masked, a), (q, q, q))
-
-    for pd, pp, ref_kw in [(True, False, dict(causal=True)),
-                           (False, True, dict(causal=False)),
-                           (False, False, None)]:
-        out, lse = ring_cases(pd, pp, q)
-        if ref_kw is None:
-            if np.asarray(out).any() or \
-                    np.isfinite(np.asarray(lse)).any():
-                raise AssertionError(
-                    "ring masked branch produced nonzero output")
-        else:
-            want, _ = jax.jit(lambda q: mha_reference(q, q, q, **ref_kw))(q)
-            _check(f"ring-cond {ref_kw}", out, want)
+    for earlier in (True, False):
+        got = ring_cases(flash_attention)(earlier, q, k, v)
+        want = ring_cases(mha_reference)(earlier, q, k, v)
+        if earlier:
+            _check("ring step 0 (causal)", got[0], want[0])
+            ncases += 1
+        tag = f"ring step, src {'<' if earlier else '>'} idx"
+        _check(f"{tag}: out", got[1], want[1])
+        _check(f"{tag}: lse", got[2], want[2])
         ncases += 1
     return ncases

@@ -13,7 +13,8 @@ from .pipeline import (interleave_order, interleave_stage_params,
                        pipeline_1f1b, pipeline_apply,
                        pipeline_interleaved, pipeline_interleaved_1f1b,
                        stack_stage_params)
-from .ring_attention import ring_attention, ring_self_attention
+from .ring_attention import (balanced_order, ring_attention,
+                             ring_self_attention)
 from .shuffle import (all_to_all_rows, exchange_rows,
                       global_shuffle_epoch, host_global_shuffle,
                       permute_rows, ragged_global_shuffle)
@@ -31,6 +32,7 @@ __all__ = [
     "global_shuffle_epoch",
     "host_global_shuffle",
     "ragged_global_shuffle",
+    "balanced_order",
     "ring_attention",
     "ring_self_attention",
     "fsdp_rules",

@@ -9,7 +9,7 @@ inert. The vocabulary (``ddstore`` prefix, or a scope of the step
 
 ``ddstore_flash_fwd``, ``ddstore_flash_dq``, ``ddstore_flash_dkv``
     ``ops/attention.py``, ``pallas_call(name=)``. Device: the three flash
-    kernels, named alike on one chip and in the ring's branches. On the
+    kernels, named alike on one chip and in the ring's steps. On the
     v5e the name is the HLO instruction's, which is what a trace calls the
     operation (``%ddstore_flash_fwd.8 = ... custom-call(...)``).
 ``embed``, ``attn``, ``mlp``, ``head``, ``optimizer``; ``ring_step``
@@ -71,7 +71,7 @@ from typing import Deque, Dict, Iterator, List
 
 __all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
            "watch_compiles", "count_geometry", "count_moe_layout",
-           "counters"]
+           "count_ring_geometry", "counters"]
 
 # One reading of both clocks, taken together: perf_counter_ns (what a phase
 # records; CLOCK_MONOTONIC, as ddtrace) and the epoch clock a trace is
@@ -92,6 +92,7 @@ _watching = False
 _geometry: Dict[str, Dict[str, Dict[str, int]]] = {}
 # Expert layer (its module path) -> what models/moe.py holds and routes.
 _moe_layout: Dict[str, Dict[str, int]] = {}
+_ring_geometry: Dict[str, dict] = {}
 
 
 @contextlib.contextmanager
@@ -204,6 +205,16 @@ def count_moe_layout(layer: str, **counts: int) -> None:
         _moe_layout[layer] = dict(counts)
 
 
+def count_ring_geometry(call: str, counts: dict) -> None:
+    """``parallel/ring_attention.py``, while a ring is traced: ``n``,
+    ``chunk_rows``, the ``order`` the sequence lies in, and per ring
+    position the ``pairs_needed`` and the ``pairs_computed`` by its calls,
+    with the largest over the mean (``max_over_mean``: 1.0 is a ring on
+    which no position waits for another's kernels)."""
+    with _lock:
+        _ring_geometry[call] = dict(counts)
+
+
 def counters() -> dict:
     """What this process counted. ``compile_s[fun_name]`` with ``trace_s``
     and ``lower_s``: the seconds JAX reported, since
@@ -212,9 +223,13 @@ def counters() -> dict:
     neither). ``flash_geometry[kernel][call]``: the causal geometry of
     every flash call traced so far (:func:`count_geometry`).
     ``moe_layout[layer]``: the share of every expert layer traced so far
-    (:func:`count_moe_layout`)."""
+    (:func:`count_moe_layout`). ``ring_geometry[call]``: what each ring
+    position of every ring traced so far needs and computes
+    (:func:`count_ring_geometry`)."""
     with _lock:
         return {"compile_s": {f: dict(d) for f, d in _compile_s.items()},
                 "flash_geometry": {k: {c: dict(n) for c, n in d.items()}
                                    for k, d in _geometry.items()},
-                "moe_layout": {k: dict(d) for k, d in _moe_layout.items()}}
+                "moe_layout": {k: dict(d) for k, d in _moe_layout.items()},
+                "ring_geometry": {c: dict(d)
+                                  for c, d in _ring_geometry.items()}}

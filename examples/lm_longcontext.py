@@ -7,7 +7,12 @@ mesh axis so per-device activation memory is O(S/n), K/V chunks rotate
 over the interconnect inside ring attention, and ``--remat`` trades
 recompute for the rest of the activation memory. Token windows live in
 the distributed store and stream through the prefetching loader straight
-into the dp×sp sharding the step demands.
+into the dp×sp sharding the step demands: natural order, one contiguous
+chunk of every window an sp position. Inside the step the loss lays the
+token ids, targets and positions out in the ring's balanced order (sp
+position i works on stripes i and 2·sp-1-i of the window, so the causal
+mask costs every position the same); the first epoch prints what each
+position computes (``ring_geometry``).
 
 Run single-process (8 virtual devices, 2×4 dp×sp):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -221,7 +226,7 @@ def main():
     pos = jnp.tile(jnp.arange(args.seq, dtype=jnp.int32), (batch, 1))
     import contextlib
 
-    from ddstore_tpu.utils import step_annotate, trace
+    from ddstore_tpu.utils import profile, step_annotate, trace
     for epoch in range(args.epochs):
         sampler.set_epoch(epoch)
         loader = DeviceLoader(ds, sampler, batch_size=batch, mesh=mesh,
@@ -254,6 +259,16 @@ def main():
                   + ("" if loads is None else " expert load max/mean="
                      f"{float((loads.max(-1) / loads.mean(-1)).max()):.2f}"),
                   flush=True)
+            if epoch == 0:
+                # Counted while the step was traced: the pairs each sp
+                # position's flash calls compute.
+                for call, geo in profile.counters()["ring_geometry"].items():
+                    print(f"mesh {dict(mesh.shape)} ring {call}: "
+                          f"{geo['order']} order, {geo['chunk_rows']} rows "
+                          f"a position, pairs computed "
+                          f"{geo['pairs_computed']} of needed "
+                          f"{geo['pairs_needed']}, largest over mean "
+                          f"{geo['max_over_mean']:.2f}", flush=True)
     if args.generate > 0 and store.rank == 0:
         # KV-cached greedy continuation of the first window's prefix —
         # on a learned repeated-pattern corpus the continuation should

@@ -9,7 +9,21 @@ import numpy as np
 import pytest
 
 from ddstore_tpu.ops.attention import flash_attention, mha_reference
-from ddstore_tpu.parallel import make_mesh, ring_attention
+from ddstore_tpu.parallel import balanced_order, make_mesh, ring_attention
+
+
+def _ring(q, k, v, *, mesh, causal, **kw):
+    """``ring_attention`` for a natural-order caller: a causal sequence
+    goes in in ``balanced_order`` and out and lse come back by its
+    inverse, as the ring's contract says."""
+    if not causal:
+        return ring_attention(q, k, v, mesh=mesh, causal=False, **kw)
+    order = balanced_order(q.shape[2], mesh.shape["sp"])
+    out, lse = ring_attention(*(jnp.take(t, order, axis=2)
+                                for t in (q, k, v)),
+                              mesh=mesh, causal=True, **kw)
+    back = np.argsort(order)
+    return jnp.take(out, back, axis=2), jnp.take(lse, back, axis=2)
 
 
 def _qkv(key, b=2, h=2, s=256, d=64, dtype=jnp.float32):
@@ -61,7 +75,7 @@ def test_ring_matches_full(causal, axes):
 
     @jax.jit
     def run(q, k, v):
-        return ring_attention(q, k, v, mesh=mesh, causal=causal)
+        return _ring(q, k, v, mesh=mesh, causal=causal)
 
     out_ring, lse_ring = run(q, k, v)
     np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_full),
@@ -74,7 +88,7 @@ def test_ring_bf16():
     mesh = make_mesh({"sp": 8})
     q, k, v = _qkv(3, b=1, h=2, s=512, d=32, dtype=jnp.bfloat16)
     out_full, _ = mha_reference(q, k, v, causal=True)
-    out_ring, _ = jax.jit(lambda a, b, c: ring_attention(
+    out_ring, _ = jax.jit(lambda a, b, c: _ring(
         a, b, c, mesh=mesh, causal=True))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out_ring, np.float32), np.asarray(out_full, np.float32),
@@ -141,7 +155,7 @@ def test_ring_flash_impl_matches_full(causal):
     q, k, v = _qkv(6, b=1, h=2, s=128, d=32)
     out_full, lse_full = mha_reference(q, k, v, causal=causal)
 
-    run = jax.jit(lambda q, k, v: ring_attention(
+    run = jax.jit(lambda q, k, v: _ring(
         q, k, v, mesh=mesh, causal=causal, impl="flash"))
     out_ring, lse_ring = run(q, k, v)
     np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_full),
@@ -158,8 +172,8 @@ def test_ring_flash_impl_matches_full(causal):
         return f
 
     g_ring = jax.jit(jax.grad(loss(
-        lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=causal,
-                                       impl="flash")),
+        lambda q, k, v: _ring(q, k, v, mesh=mesh, causal=causal,
+                              impl="flash")),
         argnums=(0, 1, 2)))(q, k, v)
     g_full = jax.grad(loss(
         lambda q, k, v: mha_reference(q, k, v, causal=causal)),
@@ -191,8 +205,8 @@ def test_ring_sp_tp_composition(impl):
 
     @jax.jit
     def run(q, k, v):
-        return ring_attention(q, k, v, mesh=mesh, causal=True,
-                              heads_axis="tp", impl=impl)
+        return _ring(q, k, v, mesh=mesh, causal=True, heads_axis="tp",
+                     impl=impl)
 
     out, lse = run(qs, ks, vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_full),
@@ -381,10 +395,11 @@ def test_many_diagonal_positions_fall_back_to_a_traced_shift():
 
 
 def test_noncausal_kernels_keep_their_structure():
-    """``causal=False`` (the ring's ``attend_past``) lowers to what it
-    always was: the full (bh, outer, inner) grid, identity index maps, one
-    body and no loop. The causal call of the same shape enumerates its
-    three live steps and reads its block indices from the step tables."""
+    """``causal=False`` (the ring's calls at every step but its first)
+    lowers to what it always was: the full (bh, outer, inner) grid,
+    identity index maps, one body and no loop. The causal call of the
+    same shape enumerates its three live steps and reads its block indices
+    from the step tables."""
     q = jnp.zeros((1, 2, 768, 32))
 
     def grad_of(causal):

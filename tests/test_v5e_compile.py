@@ -1,10 +1,11 @@
 """Compiles, for a described TPU v5e and at the sizes the benchmark runs,
 what no interpret-mode test can refuse: the three flash kernels at head
-width 256 (VMEM) and the expert layer's grouped products (XLA's own
-ragged-dot kernels). Nothing runs and no time is read; a compile that
-passes is not a chip run. Every such test lives in this one file, and the
-topology is described inside a fixture: one process at a time may load the
-TPU's library (on-chip-measurement guide, section 2)."""
+width 256 (VMEM) and at the ring's call shapes on four chips, and the
+expert layer's grouped products (XLA's own ragged-dot kernels). Nothing
+runs and no time is read; a compile that passes is not a chip run. Every
+such test lives in this one file, and the topology is described inside a
+fixture: one process at a time may load the TPU's library
+(on-chip-measurement guide, section 2)."""
 
 import os
 
@@ -51,6 +52,30 @@ def test_flash_kernels_at_width_256_fit_the_chip(one_chip, no_compile_cache,
 
     def f(q, k, v):
         out, _ = flash_attention(q, k, v, causal=True, interpret=False)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q) \
+        .compile().as_text()
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
+                   "ddstore_flash_dkv"):
+        assert kernel in text
+
+
+@pytest.mark.parametrize("causal,bh,s", [(True, 16, 16384),
+                                         (False, 32, 8192)],
+                         ids=["step0-causal", "stripe-pairs"])
+def test_the_ring_calls_of_the_four_chip_cell_fit_the_chip(
+        one_chip, no_compile_cache, causal, bh, s):
+    """A chip's two flash calls a layer in ``dense-lm-d1024.s32k.dp2sp2``
+    (PR 28): the local 16,384 rows causally, then two stacked 8,192 x
+    8,192 stripe pairs unmasked, at head width 64 and the blocks the
+    calls' shapes select."""
+    from ddstore_tpu.ops.attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, bh, s, 64), jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        out, _ = flash_attention(q, k, v, causal=causal, interpret=False)
         return (out.astype(jnp.float32) ** 2).sum()
 
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q) \
