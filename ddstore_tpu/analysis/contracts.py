@@ -192,7 +192,7 @@ def check_knob_registry(repo: str) -> List[Finding]:
                     f"{name} referenced in native code but not in "
                     f"sched.knobs.REGISTRY — classify it as a pin of "
                     f"a planned knob or as config"))
-    py_roots = ["ddstore_tpu", "bench.py", "setup.py"]
+    py_roots = ["ddstore_tpu", "setup.py"]
     for root in py_roots:
         path = os.path.join(repo, root)
         files = []

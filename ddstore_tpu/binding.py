@@ -505,7 +505,7 @@ def trace_enabled() -> bool:
 def trace_reset() -> None:
     """Drop every recorded event (rings trimmed, flight buffer
     cleared); the monotone totals in :func:`trace_stats` keep
-    counting. Test/bench isolation hook."""
+    counting. Test isolation hook."""
     _check(_load().dds_trace_reset(), "trace_reset")
 
 
@@ -700,8 +700,8 @@ def uring_probe() -> dict:
     """Process-wide io_uring capability verdict, independent of any
     store (:data:`URING_PROBE_KEYS` plus a human ``reason`` string —
     "ok", or why the kernel refused). Cached after the first call; the
-    diag module and the bench record it so a TCP-fallback run is
-    diagnosable from its artifacts alone."""
+    diag module prints it so a TCP-fallback run is diagnosable from
+    its output alone."""
     lib = _load()
     arr = (ctypes.c_int64 * 10)()
     _check(lib.dds_uring_probe(arr), "uring_probe")
@@ -786,8 +786,7 @@ class NativeStore:
         """Adaptive routing snapshot for both traffic classes (bulk =
         single >=8 MiB reads; scatter = many-small-op batches): per-path
         EWMA bandwidths, decision/probe counts, crossovers, current
-        preference — exported into bench extras so routing regressions
-        are diagnosable from the BENCH json alone."""
+        preference."""
         out = {}
         for cls, label in ((0, "bulk"), (1, "scatter")):
             cma = ctypes.c_double()

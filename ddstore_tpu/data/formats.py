@@ -116,7 +116,7 @@ def synthetic_mnist(n: int, seed: int = 0
     """Deterministic MNIST-shaped data for offline environments: blurry
     class-conditioned blobs as uint8 pixels (the real idx files' dtype),
     same on every rank (like a shared download). One generator shared by
-    the example and the bench so both always train on identical data;
+    the example and ``chip_smoke.py`` so both train on identical data;
     stored raw, dequantized on device (see models/vae._dequantize)."""
     g = np.random.default_rng(seed)
     labels = g.integers(0, 10, size=n).astype(np.int32)

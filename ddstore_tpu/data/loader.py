@@ -231,8 +231,8 @@ class DeviceLoader:
         # measurement substrate, replacing the knobs' independent
         # tuners whenever it has confident samples. Created even when
         # DDSTORE_SCHED=0 (disabled it never pins anything) so
-        # summary()["sched"] always states the enablement — that is the
-        # fact the sched bench A/B reads. User env pins freeze their
+        # summary()["sched"] always states the enablement
+        # (tests/test_sched.py reads it). User env pins freeze their
         # knobs; the planner plans the rest.
         self.sched = None
         if store is not None and hasattr(store, "sched_cells"):

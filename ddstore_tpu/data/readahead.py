@@ -27,8 +27,8 @@ module closes that gap by planning over a *window* of W batches at once:
 
 ``DeviceLoader(readahead_windows=K)`` wires this under both the host
 path and the device-collective path (window staging happens before the
-ICI exchange); the engine is also usable standalone over a raw store —
-that is what the bench's readahead A/B phase drives.
+ICI exchange); the engine is also usable standalone over a raw store
+(``tests/test_readahead.py`` drives it so).
 """
 
 from __future__ import annotations

@@ -4,9 +4,9 @@ One screenful that answers "which data planes can THIS host actually
 run?" before any store exists — the io_uring probe (the uring wire
 backend and O_DIRECT cold serving hang off it), the CMA fast path's
 kernel preconditions, the core budget every tuner scales by, and a
-page-cache-vs-O_DIRECT verdict for the cold-tier directory. The bench
-embeds the same dict in its extras (``capabilities``), so a
-TCP-fallback or mmap-only run is diagnosable from its artifacts alone.
+page-cache-vs-O_DIRECT verdict for the cold-tier directory.
+``chip_smoke.py`` prints it first, so a TCP-fallback or mmap-only run is
+diagnosable from its output alone.
 
 Report keys (``capability_report()``):
   uring          — :func:`ddstore_tpu.binding.uring_probe` verbatim
@@ -137,8 +137,7 @@ def main(argv=None) -> int:
         description="Report this host's data-plane capabilities "
                     "(io_uring, CMA, cores, cold-tier O_DIRECT).")
     ap.add_argument("--json", action="store_true",
-                    help="machine-readable output (the same dict the "
-                         "bench embeds in extras)")
+                    help="machine-readable output")
     args = ap.parse_args(argv)
     rep = capability_report()
     if args.json:

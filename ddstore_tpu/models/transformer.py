@@ -576,7 +576,7 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
             xent_block: int = 8192, mesh: Optional[Mesh] = None,
             tp_axis: str = "tp"):
     """The LM training loss — THE shared path of :func:`make_train_step`
-    and the bench harness (so what's benchmarked is what trains).
+    and the pipelined step (so what the benchmark runs is what trains).
 
     ``fused_xent`` selects :func:`ddstore_tpu.ops.xent.fused_linear_xent`
     for the head: the trunk returns post-LayerNorm features and the

@@ -203,7 +203,7 @@ int dds_integrity_sums(dds_handle* h, const char* name, int64_t row0,
 }
 
 // One synchronous scrub pass over every resident mirror (the
-// deterministic test/bench hook; the DDSTORE_SCRUB_MS thread does the
+// deterministic test hook; the DDSTORE_SCRUB_MS thread does the
 // same one mirror per tick). Returns the number of divergent mirrors
 // found, or a negative ErrorCode.
 int dds_integrity_scrub(dds_handle* h) {

@@ -4,8 +4,8 @@
 // The reference's only failure handling is exit(1)/throw (SURVEY §5), and
 // its libfabric path retries -EAGAIN unboundedly (common.cxx:332-343); our
 // tree bounded every wait, but until this layer there was no way to even
-// PROVOKE the failure paths in tests. The injector lets a test (or a chaos
-// bench phase) script connection resets, truncated responses, delays, and
+// PROVOKE the failure paths in tests. The injector lets a test (or a soak
+// under a schedule) script connection resets, truncated responses, delays, and
 // serve-loop stalls at op granularity, deterministically:
 //
 //   DDSTORE_FAULT_SPEC="reset:0.01,trunc:0.005,delay:0.02:50,stall:0.002"

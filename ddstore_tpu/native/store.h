@@ -643,7 +643,7 @@ class Store {
   bool verify_mode() const {
     return verify_.load(std::memory_order_relaxed);
   }
-  // Runtime toggles (tests/benches script without env plumbing):
+  // Runtime toggles (tests script without env plumbing):
   // verify -1 keeps / 0 off / 1 on (also enables sum computation);
   // scrub_ms -1 keeps / 0 stops the scrubber / >0 (re)starts it at
   // that per-mirror tick interval.
@@ -655,7 +655,7 @@ class Store {
   int RowSums(const std::string& name, int64_t row0, int64_t count,
               uint64_t* out, int64_t* seq_out);
   // One synchronous scrub pass over every resident mirror (the
-  // deterministic test/bench hook; the background thread does the same
+  // deterministic test hook; the background thread does the same
   // one mirror per tick). Returns the number of divergent mirrors
   // found (repairs counted separately), or a negative ErrorCode.
   int ScrubOnce();

@@ -307,8 +307,8 @@ TcpTransport::TcpTransport(int rank, int world, int port)
   // Same-host fast lane: a second listener on the port-derived abstract
   // Unix socket, served by the SAME HandleConnection protocol loop. On
   // the scatter class the stream is CPU-bound on per-byte cost, and the
-  // Unix lane skips the (possibly sentry-emulated) TCP/IP stack — a
-  // measured ~1.6x per-byte saving on the 2-core bench kernel. Failure
+  // Unix lane skips the (possibly sentry-emulated) TCP/IP stack (what
+  // that saves is not measured on the chip's host). Failure
   // to bind (name squatted, AF_UNIX unavailable) just means no fast
   // lane; peers fall back to loopback TCP on their first dial.
   if (UdsEnabled()) {
@@ -1874,7 +1874,7 @@ constexpr int64_t kBulkBytes = 8 << 20;
 constexpr int64_t kScatterMinOps = 64;
 bool TcpTransport::RouteViaTcp(RouteClass& rc) {
   // The pin env ("1" = always CMA, "0" = always TCP) is read per call so
-  // benches/tests can flip it at runtime. The USER pin outranks the
+  // tests can flip it at runtime. The USER pin outranks the
   // planner pin, which outranks the adaptive estimate.
   if (const char* env = ::getenv(rc.pin_env)) {
     if (env[0] == '1') return false;
@@ -1890,7 +1890,7 @@ bool TcpTransport::RouteViaTcp(RouteClass& rc) {
     // discarded) so BOTH cells stay fresh and the next replan judges
     // live numbers — a pin that also stopped probing would re-confirm
     // itself from frozen data forever. Only the USER env pin above is
-    // absolute (forced-path benches rely on exact forcing).
+    // absolute (forced-path tests rely on exact forcing).
     const int phase = static_cast<int>(d & 31);
     if (phase == 30) rc.discard_probe = true;
     const bool probe = phase >= 30;

@@ -129,12 +129,12 @@ class TcpTransport : public Transport {
 
   // Successful dials of the same-host Unix-domain fast lane since
   // construction (observability: distinguishes "loopback peers rode the
-  // UDS lane" from "silently fell back to loopback TCP" in bench JSON).
+  // UDS lane" from "silently fell back to loopback TCP").
   int64_t uds_conns() const { return uds_conns_.load(); }
 
   // Adaptive routing state snapshot for one traffic class (0 = bulk,
-  // 1 = scatter) — observability: exported into bench extras so routing
-  // regressions are diagnosable from the JSON record alone.
+  // 1 = scatter) — observability: routing_state() exports it so routing
+  // regressions are diagnosable from the counters alone.
   void RoutingState(int cls, double* cma_bw, double* tcp_bw,
                     int64_t* decisions, int64_t* crossovers, int* via_tcp,
                     int* calibrated);
@@ -302,8 +302,8 @@ class TcpTransport : public Transport {
     //                 I/O, so deliberately NOT DDS_NO_BLOCKING — the
     //                 control plane instead EXCLUDES it, see Ping)
     // Response payload bytes this lane has carried (per-peer per-lane
-    // observability: lane utilization/balance is diagnosable from the
-    // BENCH json alone). Atomic: LaneBytes snapshots without taking mu.
+    // observability: lane utilization/balance is diagnosable from
+    // lane_bytes() alone). Atomic: LaneBytes snapshots without taking mu.
     std::atomic<int64_t> bytes{0};
   };
   struct Peer {
@@ -391,9 +391,9 @@ class TcpTransport : public Transport {
   // network namespace, so the name cannot collide between instances).
   // Loopback-addressed peers dial it instead of TCP — same framing
   // protocol, same serving loop, but the stream skips the (emulated)
-  // TCP/IP stack entirely: on the sandboxed 2-core bench kernel that is
-  // a measured ~1.6x per-byte saving, which is exactly the scatter
-  // class's bottleneck (it is CPU-bound on copies, not latency-bound).
+  // TCP/IP stack entirely, which is where the scatter class spends
+  // (it is CPU-bound on copies, not latency-bound); what it saves is
+  // not measured on the chip's host.
   int uds_listen_fd_ = -1;
   std::thread uds_accept_thread_;
   std::atomic<int64_t> uds_conns_{0};  // UDS dials that succeeded
@@ -501,8 +501,8 @@ class TcpTransport : public Transport {
     // beat the current one by this factor). The scatter class runs a
     // tighter band than bulk: its per-op-overhead bottleneck makes the
     // paths land closer together, and a 1.25x band left it parked on a
-    // measurably slower path (auto_batch ~18% under the best forced
-    // path in BENCH r6).
+    // measurably slower path (seen on a CPU container only; not
+    // measured on the chip's host).
     double hysteresis = 1.25;
     int cls = 0;  // 0 = bulk, 1 = scatter (pin/snapshot index)
     // Per-path warm-window cells (the shared measurement substrate,
@@ -512,8 +512,8 @@ class TcpTransport : public Transport {
     WarmStat tcp;
     int64_t decisions = 0;
     int64_t crossovers = 0;  // preference flips (observability: a
-    //                          flapping policy shows up as a count,
-    //                          diagnosable from BENCH json alone)
+    //                          flapping policy shows up as a count
+    //                          in routing_state())
     int cold_skips = 0;  // connect-tainted seeds discarded (bounded,
     //                      shared across both cells — measure.h rule 1)
     // Probes run as consecutive PAIRS on the non-preferred path: the
@@ -552,9 +552,9 @@ class TcpTransport : public Transport {
   // One tuner PER TRAFFIC CLASS, like the router: bulk stripes are
   // byte-bound (lanes add parallel streams/serving cores) while
   // scatter deals whole small ops (lanes shrink every frame and
-  // multiply per-frame cost) — measured on the 2-core bench kernel the
-  // classes' optima differ by >3x, so one shared verdict would park
-  // one class on the other's width.
+  // multiply per-frame cost) — the classes' optima need not coincide,
+  // so one shared verdict would park one class on the other's
+  // width.
   // DDSTORE_TCP_LANES_AUTOTUNE=0 pins striping at the full pool size.
   struct LaneTuner {
     const char* name = "bulk";  // log/observability label

@@ -32,7 +32,7 @@
 // relaxed atomic load per instrumentation site (Enabled()); no
 // allocation, no TLS registration, no clock read happens until the
 // first traced event. DDSTORE_TRACE=1 enables at load;
-// dds_trace_configure() flips it at runtime (tests / A-B benches).
+// dds_trace_configure() flips it at runtime (tests).
 // DDSTORE_TRACE_RING sizes each thread ring (events, default 4096);
 // DDSTORE_TRACE_FLIGHT bounds the flight snapshot (events, default
 // 16384).

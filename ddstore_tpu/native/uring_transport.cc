@@ -592,7 +592,7 @@ UringTransport::UringTransport(int rank, int world, int port)
   if (!engaged_) {
     // The LOUD fallback the probe contract demands: the transport keeps
     // working (inherited TCP path), but nobody should discover that
-    // from a bench number — the verdict is printed once and exported
+    // from a slow run — the verdict is printed once and exported
     // through dds_uring_state/dds_uring_reason.
     std::fprintf(stderr,
                  "[ddstore] DDSTORE_TRANSPORT=uring requested but "

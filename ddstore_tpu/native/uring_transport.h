@@ -1,12 +1,12 @@
 // io_uring data plane: zero-syscall-per-frame wire transport + O_DIRECT
 // cold-tier reads behind one submission-ring abstraction.
 //
-// The measured ceiling on the TCP wire path is per-frame syscall/sentry
-// cost, not bytes (BENCH_r06: route_tcp_scatter 1.75 GB/s vs 12.7 GB/s
-// CMA on identical workloads; PERF_NOTES Round 9's 0.33x forced-stripe
-// scatter is the same tax multiplied by lane dealing). This backend is
-// the honest stand-in for DDStore's one-sided libfabric fi_read method
-// (ROADMAP item 3): the requester submits a whole pipelined frame burst
+// The premise: the ceiling on the TCP wire path is per-frame
+// syscall/sentry cost, not bytes (seen on a CPU container only; no chip
+// host has run this backend: it has no io_uring, ROADMAP D3). It is
+// the stand-in for DDStore's one-sided libfabric fi_read method
+// (the reference's second method):
+// the requester submits a whole pipelined frame burst
 // — request writev + every response header+payload recv — as one batch
 // of SQEs and makes ONE io_uring_enter per burst, instead of one
 // sendmsg/recvmsg pair per frame.

@@ -3,9 +3,8 @@
 Everything numeric in ``tests/test_attention.py`` runs the kernels in
 interpret mode on the CPU; Mosaic lowering is exactly where an
 interpret-correct kernel goes wrong. This check compiles and runs the real
-kernels on the current backend and raises on any mismatch, so the entry
-points that need the kernel to be right on the chip (``chip_smoke.py``,
-``bench.py --phase numerics``) share one implementation.
+kernels on the current backend and raises on any mismatch: ``chip_smoke.py``'s
+kernel leg is the entry point that needs the kernel to be right on the chip.
 """
 
 from __future__ import annotations

@@ -69,9 +69,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     # -- configuration (never planned) -----------------------------------
     _k("DDSTORE_BACKEND", "config", desc="local/tcp backend select"),
     _k("DDSTORE_BARRIER_TIMEOUT_S", "config"),
-    _k("DDSTORE_BENCH_DEADLINE_S", "config"),
-    _k("DDSTORE_BENCH_PHASE_TIMEOUT_S", "config"),
-    _k("DDSTORE_CHAOS_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_CMA", "config", desc="0 disables the CMA fast path "
        "entirely (a capability switch, not a per-class preference)"),
     _k("DDSTORE_CONNECT_TIMEOUT_S", "config"),
@@ -90,7 +87,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     _k("DDSTORE_CXX", "config",
        desc="C++ compiler for the on-demand native build (default g++)"),
     _k("DDSTORE_DEBUG", "config"),
-    _k("DDSTORE_FAILOVER_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_FAULT_RANKS", "config"),
     _k("DDSTORE_FAULT_SEED", "config"),
     _k("DDSTORE_FAULT_SPEC", "config"),
@@ -101,8 +97,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
             "ERR_ADMISSION + retry-after), lease reaping, drain; "
             "default 0, pinned byte-, error-code- and seeded-fault-"
             "counter-identical to the ungated tree"),
-    _k("DDSTORE_GATEWAY_PHASE_TIMEOUT_S", "config",
-       desc="bench gateway-phase subprocess cap, default 300"),
     _k("DDSTORE_GW_ADMIT_MARGIN", "config",
        desc="admission margin in percent of each protected tenant's "
             "SLO threshold (default 80): over-share reads defer once "
@@ -136,9 +130,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
             "(default 3)"),
     _k("DDSTORE_HOST", "config"),
     _k("DDSTORE_IFACES", "config"),
-    _k("DDSTORE_INTEGRITY_PHASE_TIMEOUT_S", "config",
-       desc="bench integrity-phase subprocess cap, default 300"),
-    _k("DDSTORE_LANES_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_METHOD", "config"),
     _k("DDSTORE_METRICS", "config",
        desc="0 disables the always-on ddmetrics latency/bytes "
@@ -152,7 +143,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     _k("DDSTORE_OP_DEADLINE_S", "config"),
     _k("DDSTORE_POD_AUTODETECT", "config"),
     _k("DDSTORE_POOL_THREADS", "config"),
-    _k("DDSTORE_PPSCHED_PHASE_TIMEOUT_S", "config"),
     _k("DDSTORE_PROCESS_ID", "config",
        desc="explicit pod process index for pod_bootstrap"),
     _k("DDSTORE_RANK", "config"),
@@ -174,9 +164,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
     _k("DDSTORE_SCHED", "config",
        desc="0 disables the cost-model scheduler (independent tuners "
             "only); default on"),
-    _k("DDSTORE_SCHED_PHASE_TIMEOUT_S", "config"),
-    _k("DDSTORE_SLO_PHASE_TIMEOUT_S", "config",
-       desc="bench slo-phase subprocess cap, default 300"),
     _k("DDSTORE_SLO_WINDOW_MS", "config",
        desc="minimum spacing between SLO evaluations (ms): an "
             "evaluate_slos() call inside the window is a no-op that "
@@ -187,10 +174,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
             "reaper releases a pin whose owner is suspected dead or "
             "whose age passed the TTL, counting snapshot_stats()"
             "['reclaimed_pins'] — works with the gateway off"),
-    _k("DDSTORE_SOAK_BUDGET_S", "config"),
-    _k("DDSTORE_SOAK_PHASE_TIMEOUT_S", "config"),
-    _k("DDSTORE_TENANTS_PHASE_TIMEOUT_S", "config",
-       desc="bench tenants-phase subprocess cap, default 300"),
     _k("DDSTORE_TENANT_QUOTAS", "config",
        desc="per-tenant registration budgets 't=bytes[:vars],...' "
             "(< 0 = unlimited); an over-budget add/init is refused "
@@ -209,8 +192,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
             "'tenant=cold|hot,...' (a bare 'cold' names the default "
             "tenant); default hot — cold requires "
             "DDSTORE_TIER_COLD_DIR"),
-    _k("DDSTORE_TIERED_PHASE_TIMEOUT_S", "config",
-       desc="bench tiered-phase subprocess cap, default 300"),
     _k("DDSTORE_TENANT_SLOS", "config",
        desc="per-tenant latency objectives 't=p99:5ms,...' (a bare "
             "'p99:5ms' names the default tenant; units ns/us/ms/s) "
@@ -229,8 +210,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
             "byte-identical to the untraced tree)"),
     _k("DDSTORE_TRACE_FLIGHT", "config",
        desc="flight-recorder snapshot bound in events (default 16384)"),
-    _k("DDSTORE_TRACE_PHASE_TIMEOUT_S", "config",
-       desc="bench trace-phase subprocess cap, default 300"),
     _k("DDSTORE_TRACE_RING", "config",
        desc="per-thread trace ring capacity in events (default 4096); "
             "overflow overwrites oldest and counts a drop"),
@@ -248,8 +227,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
        desc="SQ entries per lane ring (default 256, clamped to "
             "[64, 4096]); bounds the frames one io_uring_enter can "
             "carry"),
-    _k("DDSTORE_URING_PHASE_TIMEOUT_S", "config",
-       desc="bench uring-phase subprocess cap, default 300"),
     _k("DDSTORE_URING_REGBUF", "config",
        desc="0 disables IORING_REGISTER_BUFFERS/READ_FIXED for the "
             "cold-tier bounce buffer (default 1; refusal falls back "

@@ -54,7 +54,7 @@ an explicitly-set ``DDSTORE_TCP_LANES`` / ``DDSTORE_CMA_*`` /
 knob at the user's value and the planner plans the rest. That is what
 keeps every PR 1-5 contract byte-identical under the scheduler: the
 lanes=1 identity tests, the chaos determinism runs and the forced-path
-benches all pin the knobs they rely on.
+tests all pin the knobs they rely on.
 
 Replanning
 ----------
@@ -227,7 +227,7 @@ class Scheduler:
         self._plan = Plan(pins=pinned_knobs())
         self.replans = 0
         self.reasons: List[str] = []
-        # Same regime rule the lanes bench exports: client stripe legs
+        # The regime rule: client stripe legs
         # + serving threads of a 1-lane fan-out, + consumer + issuer.
         self.no_core_headroom = cores < 2 * peers + 2
         if store is not None and hasattr(store, "add_peer_listener"):

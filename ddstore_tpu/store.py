@@ -1233,7 +1233,7 @@ class DDStore:
 
     def scrub_once(self) -> int:
         """One synchronous scrub pass over every mirror this rank
-        hosts (the deterministic test/bench hook; ``DDSTORE_SCRUB_MS``
+        hosts (the deterministic test hook; ``DDSTORE_SCRUB_MS``
         runs the same check one mirror per tick in the background).
         Returns the number of divergent mirrors found; repairs (the
         row-aligned re-pull) run inline and are counted in
@@ -1333,8 +1333,9 @@ class DDStore:
         backend), ``wire`` ("uring" when the io_uring loop is engaged,
         else "tcp"/"local"), ``uring_engaged`` and ``uring_reason``
         (the capability probe's words when a requested uring backend
-        fell back — never a crash). Bench/diag record this so a
-        TCP-fallback run is diagnosable from its artifacts alone."""
+        fell back — never a crash). ``python -m ddstore_tpu.diag`` (and
+        ``chip_smoke.py`` through it) prints the probe behind it, so a
+        TCP-fallback run is diagnosable from its output alone."""
         facts = {"backend": self.backend, "wire": self.backend,
                  "uring_engaged": False, "uring_reason": ""}
         if self.backend != "tcp":
@@ -1358,19 +1359,17 @@ class DDStore:
 
     def sched_pin_route(self, cls: int, mode: int) -> None:
         """Planner route pin (0 = CMA, 1 = TCP, -1 = release) for one
-        traffic class. No-op on the local backend (no router)."""
-        try:
+        traffic class. No-op on the local backend (no router); an
+        invalid class or mode raises."""
+        if self.backend == "tcp":
             self._native.sched_pin_route(cls, mode)
-        except DDStoreError:
-            pass  # non-TCP backend: nothing to pin
 
     def sched_pin_lanes(self, cls: int, lanes: int) -> None:
-        """Planner lane-width pin (>= 1, or -1 to release) for one
-        traffic class. No-op on the local backend (no lanes)."""
-        try:
+        """Planner lane-width pin (1..64, clamped to the pool; -1 to
+        release) for one traffic class. No-op on the local backend (no
+        lanes); an invalid class or width raises."""
+        if self.backend == "tcp":
             self._native.sched_pin_lanes(cls, lanes)
-        except DDStoreError:
-            pass
 
     def set_async_width(self, n: int) -> None:
         """Async admission width override (<= 0 restores the

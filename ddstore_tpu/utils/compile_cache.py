@@ -3,7 +3,7 @@
 A cold LM step compiles for tens of seconds; JAX's persistent compilation
 cache turns that into a file read, but only when every process and every
 run names the same directory. So the entry points (``chip_smoke.py``, the
-training examples, ``bench.py --phase``) call :func:`enable_compile_cache`
+training examples, ``benchmarks/run.py``) call :func:`enable_compile_cache`
 once, before their first jit, and nothing else in the tree sets a cache
 directory. The same call starts the program's count of what a cache does
 not save, tracing and lowering

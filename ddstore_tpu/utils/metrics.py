@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 
 class LatencyHistogram:
@@ -186,13 +186,9 @@ class PipelineMetrics:
         self._failover_source: Optional[Callable[[], Dict]] = None
         self._failover_begin: Optional[Dict] = None
         self._failover_end: Optional[Dict] = None
-        # (bytes, fetch_s) per window, for the honest per-window best
-        # bandwidth (bounded: one entry per window, windows are O(epoch
-        # batches / W)).
-        self._ra_fetch_samples: List[Tuple[int, float]] = []
         # Cost-model scheduler snapshot source (Scheduler.snapshot):
-        # summary()["sched"] is how a bench record explains WHY each
-        # transport knob was set this epoch.
+        # summary()["sched"] says WHY each transport knob was set this
+        # epoch.
         self._sched_source: Optional[Callable[[], Dict]] = None
         # Per-tenant ledger source (DDStore.tenant_stats): snapshotted
         # at epoch boundaries, summary()["tenants"] carries the
@@ -486,7 +482,7 @@ class PipelineMetrics:
         counters (``DDStore.tiering_stats``). Snapshotted at epoch
         boundaries; ``summary()["tiering"]`` reports per-epoch deltas
         (gauges raw) plus the derived ``cache_hit_rate`` — hit bytes
-        over consulted bytes, the number the tiered bench gates on."""
+        over consulted bytes."""
         self._tiering_source = source
 
     def _snap_tiering(self) -> Optional[Dict]:
@@ -715,9 +711,6 @@ class PipelineMetrics:
         self.ra_fetch.record(fetch_s)
         with self._ra_mu:
             self._ra_windows += 1
-            if len(self._ra_fetch_samples) < (1 << 16):
-                self._ra_fetch_samples.append(
-                    (int(counters.get("window_bytes", 0)), fetch_s))
             for k, v in counters.items():
                 if k not in self._ra:
                     raise KeyError(f"unknown window counter {k!r}; "
@@ -732,27 +725,15 @@ class PipelineMetrics:
             n = self._ra_windows
             out: Dict = {"windows": n}
             out.update(self._ra)
-            samples = list(self._ra_fetch_samples)
         out["consumer_wait_ms"] = round(self.ra_wait.total * 1e3, 3)
         out["producer_idle_ms"] = round(self.ra_idle.total * 1e3, 3)
         # Transport-leg bandwidth of the window fetches themselves
-        # (issue -> completion), independent of delivery/gather time.
-        # The mean is the overlapped steady state (fetch competes with
-        # the previous window's delivery for cores/memory bandwidth);
-        # `_best` is the fastest window — typically the first of an
-        # epoch, fetched with nothing else running — the uncontended
-        # transport capability, measured the same way a bulk-stripe
-        # benchmark is.
+        # (issue -> completion), independent of delivery/gather time:
+        # the overlapped steady state (a fetch competes with the
+        # previous window's delivery for cores and memory bandwidth).
         out["window_fetch_gbps"] = round(
             out["window_bytes"] / self.ra_fetch.total / 1e9, 3) \
             if self.ra_fetch.total > 0 else 0.0
-        best = max((b / s for b, s in samples if s > 0 and b > 0),
-                   default=0.0)
-        if best:
-            # Per-window best: each window's OWN bytes over its own
-            # fetch time (mean-bytes / min-time would overstate it
-            # whenever a short trailing window posts the minimum).
-            out["window_fetch_gbps_best"] = round(best / 1e9, 3)
         if n:
             out["runs_per_window"] = round(out["runs"] / n, 2)
             out["runs_per_peer_per_window"] = round(
@@ -792,7 +773,6 @@ class PipelineMetrics:
         with self._ra_mu:
             self._ra = {k: 0 for k in self.WINDOW_KEYS}
             self._ra_windows = 0
-            self._ra_fetch_samples = []
         with self._fault_mu:
             self._fault_events = {k: 0 for k in self.FAULT_EVENT_KEYS}
         self.ra_wait = LatencyHistogram("readahead_consumer_wait")
@@ -906,24 +886,24 @@ class PipelineMetrics:
             out["latency"] = lat
         slo = self.slo_summary()
         # Included while any objective is configured (an all-zero
-        # breach row is the "every tenant met its SLO" result the slo
-        # bench reads) or any monitor activity fired.
+        # breach row is the "every tenant met its SLO" result) or any
+        # monitor activity fired.
         if slo and (slo.get("rules", 0) > 0
                     or slo.get("evaluations", 0)
                     or slo.get("breaches", 0)):
             out["slo"] = slo
         gw = self.gateway_summary()
         # Included while the gateway is on (an all-zero verdict row is
-        # the "nothing was deferred" result the gateway bench reads) or
-        # any session/admission activity fired this epoch.
+        # the "nothing was deferred" result) or any session/admission
+        # activity fired this epoch.
         if gw and (gw.get("enabled", 0)
                    or gw.get("attaches", 0) or gw.get("admitted", 0)
                    or gw.get("deferred", 0) or gw.get("rejected", 0)):
             out["gateway"] = gw
         if self._sched_source is not None:
             # Live (not epoch-frozen): the plan is a current-state view,
-            # and a disabled scheduler's {"enabled": False} is itself
-            # the A/B fact the sched bench reads.
+            # and a disabled scheduler's {"enabled": False} is itself a
+            # fact (tests/test_sched.py reads it).
             try:
                 out["sched"] = dict(self._sched_source())
             except Exception:

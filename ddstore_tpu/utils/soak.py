@@ -1,8 +1,9 @@
 """Shared tiering/index-plane soak harness (BASELINE config-5 scale).
 
-One implementation consumed by BOTH the bench (`bench.py` soak phase)
-and the regression test (`tests/test_tiering.py`) so the two can never
-measure different things: a sparse mmap-backed shard at 10^8-row scale,
+One implementation for the regression tests that soak the store
+(`tests/test_tiering.py`, and under a fault or corruption schedule
+`tests/test_fault.py`, `tests/test_integrity.py`): a sparse mmap-backed
+shard at 10^8-row scale,
 sentinel rows pinning read correctness at far offsets, a Feistel-sampled
 partial epoch of batched gets, and RSS accounting that must track pages
 touched — never the row count (the reference copies every shard into
@@ -36,26 +37,18 @@ def _sentinel(r: int) -> np.ndarray:
 
 def mmap_soak(rows: int = 100_000_000, batch: int = 65536,
               nbatches: int = 64, directory: Optional[str] = None,
-              budget_s: Optional[float] = None,
               fault_spec: Optional[str] = None,
               fault_seed: int = 7) -> dict:
     """Run the soak; returns a dict of measurements:
 
     * ``rows`` / ``rows_sampled`` — shard size and rows actually fetched
     * ``rows_per_s`` — batched-get throughput of the sampled epoch
-    * ``batches_run`` — batches completed (< ``nbatches`` when
-      ``budget_s`` cut the epoch short; throughput stays valid — it is
-      rows-fetched over time-spent either way)
+    * ``batches_run`` — batches completed
     * ``rss_add_delta_mb`` — RSS growth across ``add_mmap`` (must be
       ~0: registration must not copy the shard)
     * ``rss_delta_mb`` — RSS growth across the whole soak (bounded by
       pages touched, at most the file size — not by row count)
     * ``sentinels_ok`` — far-offset reads returned the stamped bytes
-
-    ``budget_s`` bounds the SAMPLED-EPOCH wall time: on a slow box
-    (cold page cache, sandboxed I/O) the fixed iteration count can
-    outlive a caller's harness timeout, and a killed soak reports
-    nothing; a budget-truncated one reports everything it measured.
 
     ``fault_spec`` switches the soak to its CHAOS mode: the shard is
     split across a 2-rank in-process group (a single-rank store never
@@ -81,7 +74,7 @@ def mmap_soak(rows: int = 100_000_000, batch: int = 65536,
     """
     if fault_spec is not None:
         return _mmap_soak_chaos(rows, batch, nbatches, directory,
-                                budget_s, fault_spec, fault_seed)
+                                fault_spec, fault_seed)
     from .. import DDStore
     from ..data import DistributedSampler
 
@@ -112,9 +105,6 @@ def mmap_soak(rows: int = 100_000_000, batch: int = 65536,
                 assert out.shape == (len(b), 2)
                 n += len(b)
                 nb += 1
-                if budget_s is not None \
-                        and time.perf_counter() - t0 > budget_s:
-                    break
             dt = time.perf_counter() - t0
             return {"rows": rows, "rows_sampled": n,
                     "rows_per_s": n / dt,
@@ -133,7 +123,7 @@ def mmap_soak(rows: int = 100_000_000, batch: int = 65536,
 
 
 def _mmap_soak_chaos(rows: int, batch: int, nbatches: int,
-                     directory: Optional[str], budget_s: Optional[float],
+                     directory: Optional[str],
                      fault_spec: str, fault_seed: int) -> dict:
     """Chaos variant of the soak (see ``mmap_soak(fault_spec=...)``):
     2-rank ThreadGroup over two sparse mmap shards, deterministic fault
@@ -229,9 +219,6 @@ def _mmap_soak_chaos(rows: int, batch: int, nbatches: int,
                         (out == expected(np.asarray(b))).all())
                     n += len(b)
                     nb += 1
-                    if budget_s is not None \
-                            and time.perf_counter() - t0 > budget_s:
-                        break
                 dt = time.perf_counter() - t0
                 fs = s.fault_stats()
                 is1 = s.integrity_stats() if corrupt_mode else {}

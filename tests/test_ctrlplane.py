@@ -399,7 +399,16 @@ def test_injector_ctrl_domain_is_separate(monkeypatch):
         _close_all(stores)
 
 
-def test_ctrl_faults_absorbed_by_control_retry(monkeypatch):
+@pytest.mark.parametrize("spec,seed,acquires", [
+    # Seed 7 at p=0.3: hits at draw positions 0/3/7 (early — the
+    # injected>0 assert can't go vacuous) and no long hit runs.
+    ("ctrl-reset:0.3", 7, 1),
+    # Every control arm at once, a dozen acquires: a stall of 800 ms is
+    # latency under the 1000 ms per-attempt deadline, not a lost attempt.
+    ("ctrl-reset:0.3,ctrl-delay:0.2:5,ctrl-stall:0.05:800", 77, 12),
+], ids=["reset", "reset-delay-stall-x12"])
+def test_ctrl_faults_absorbed_by_control_retry(spec, seed, acquires,
+                                               monkeypatch):
     """Control-plane chaos, absorbed: with ctrl-reset firing on ~30% of
     control round trips, collective epoch fences (whose mirror refresh
     rides kOpVarSeq probes) and snapshot acquire/release still succeed
@@ -411,13 +420,12 @@ def test_ctrl_faults_absorbed_by_control_retry(monkeypatch):
     thread interleaving shifts which DRAW POSITION each op lands on, so
     the schedule must be safe at any alignment, not just seed-lucky)."""
     _set_budgets(monkeypatch, replication=2, DDSTORE_CMA="0",
-                 DDSTORE_CONTROL_RETRY_MAX="6")
+                 DDSTORE_CONTROL_RETRY_MAX="6",
+                 DDSTORE_CONTROL_TIMEOUT_MS="1000")
     stores = _build_stores(2, "tcp", rows=4, epoch_collective=True)
     try:
         new = np.full((4, 4), 42.0)
-        # Seed 7 at p=0.3: hits at draw positions 0/3/7 (early — the
-        # injected>0 assert can't go vacuous) and no long hit runs.
-        fault_configure("ctrl-reset:0.3", seed=7)
+        fault_configure(spec, seed=seed)
         stores[1].update("v", new)
         for _ in range(3):
             out = _run_collective(stores, (0, 1),
@@ -426,8 +434,9 @@ def test_ctrl_faults_absorbed_by_control_retry(monkeypatch):
             out = _run_collective(stores, (0, 1),
                                   lambda s: s.epoch_end())
             assert out == {0: "ok", 1: "ok"}, out
-        h = stores[0].attach("eval", snapshot=True)
-        h.detach()
+        for _ in range(acquires):  # every one lands: none may raise
+            h = stores[0].attach("eval", snapshot=True)
+            h.detach()
         fs = stores[0].fault_stats()
         fault_configure("", 0)
         assert fs["ctrl_injected"] > 0, fs

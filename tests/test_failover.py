@@ -199,7 +199,7 @@ def test_cma_leg_gated_on_suspect_oracle(monkeypatch):
     oracle check surfaces kErrPeerLost immediately, and at R=1 the
     classified error reaches the caller instead of stale-but-plausible
     bytes. Pre-gate, this read SUCCEEDED via the mapped shm — exactly
-    the masking the failover bench had to force DDSTORE_CMA=0 for."""
+    the masking the failover tests force DDSTORE_CMA=0 against."""
     _set_budgets(monkeypatch, replication=1)
     monkeypatch.setenv("DDSTORE_CMA", "1")
     stores = _build_stores(2, "tcp", rows=8)
@@ -403,15 +403,24 @@ def test_data_path_verdict_outlives_successful_pings(monkeypatch):
         _close_all(stores)
 
 
-def test_readahead_epoch_survives_mid_epoch_death(monkeypatch):
+@pytest.mark.parametrize("traced", [False, True],
+                         ids=["untraced", "flight-recorder"])
+def test_readahead_epoch_survives_mid_epoch_death(traced, monkeypatch):
     """Tentpole composition: a readahead loader epoch with windows in
     flight keeps delivering byte-identical batches through a peer death
     — the window's native run reads fail over inside the store, the
     degraded ladder never engages, and summary()["failover"] shows the
-    reroutes."""
+    reroutes. With ddtrace on, the death leaves its story: the suspect
+    verdict snapshots the flight recorder by itself, and a snapshot
+    taken after the epoch names the dead peer, the verdict and every
+    replica-rerouted read."""
+    from ddstore_tpu import binding, obs
     from ddstore_tpu.data import DistributedSampler, ShardedDataset
     from ddstore_tpu.data.loader import DeviceLoader
 
+    if traced:
+        binding.trace_configure(1)
+        binding.trace_reset()
     _set_budgets(monkeypatch, replication=2, heartbeat_ms=25,
                  DDSTORE_HEARTBEAT_SUSPECT_N="2", DDSTORE_CMA="0")
     world, num, dim, batch = 3, 384, 4, 16
@@ -454,6 +463,11 @@ def test_readahead_epoch_survives_mid_epoch_death(monkeypatch):
                 result["summary"] = loader.metrics.summary()
                 result["failover"] = s.failover_stats()
                 result["faults"] = s.fault_stats()
+                if traced:
+                    result["auto_flights"] = \
+                        binding.trace_stats()["flight_dumps"]
+                    binding.trace_flight("manual", 0)
+                    result["flight"] = binding.trace_flight_dump()
         except Exception as e:  # noqa: BLE001
             errs.append((rank, repr(e)))
 
@@ -473,7 +487,19 @@ def test_readahead_epoch_survives_mid_epoch_death(monkeypatch):
         # The degraded ladder never fired: windows completed through
         # the death via native failover, not per-batch refetch.
         assert summary.get("faults", {}).get("windows_retried", 0) == 0
+        if traced:
+            assert result["auto_flights"] > 0  # verdict snapshotted
+            fl = result["flight"]
+            tree = obs.span_tree(fl, max_spans=1 << 20)
+            assert "suspect (peer=1" in tree       # verdict named
+            assert "dead_owner=1" in tree          # reroutes named
+            rerouted = int((fl["type"] == binding.TRACE_TYPE_CODES[
+                "failover"]).sum())
+            assert rerouted >= fo["failover_reads"]  # every one of them
     finally:
+        if traced:
+            binding.trace_configure(0)
+            binding.trace_reset()
         _close_all(stores)
 
 

@@ -369,15 +369,22 @@ def test_peer_lost_from_engine_is_fatal():
         assert s.async_pending() == 0
 
 
-def test_chaos_loader_epoch_tcp(monkeypatch):
+@pytest.mark.parametrize("lanes", [None, "4"],
+                         ids=["lanes-default", "lanes-4-pinned"])
+def test_chaos_loader_epoch_tcp(lanes, monkeypatch):
     """Acceptance slice at tier-1 scale: a multi-owner TCP store under
     mixed injected faults completes a full loader epoch (host path AND
     readahead) byte-identical vs the fault-free run, with nonzero retry
-    counters and zero give-ups."""
+    counters and zero give-ups — on the default lanes and with four
+    striped lanes pinned (faults must be absorbed with the multi-lane
+    transport active, not only on the single-connection path)."""
     from ddstore_tpu.data import DistributedSampler, ShardedDataset
     from ddstore_tpu.data.loader import DeviceLoader
 
     monkeypatch.setenv("DDSTORE_CMA", "0")
+    if lanes is not None:
+        monkeypatch.setenv("DDSTORE_TCP_LANES", lanes)
+        monkeypatch.setenv("DDSTORE_TCP_LANES_AUTOTUNE", "0")
     world = 2
     name = uuid.uuid4().hex
     errors = []
@@ -413,6 +420,7 @@ def test_chaos_loader_epoch_tcp(monkeypatch):
                     for a, b in zip(ref, chaos_ra):
                         np.testing.assert_array_equal(a, b)
                     out.update(fs)
+                    out["max_lanes"] = s.lane_state()["max_lanes"]
                 s.barrier()
         except Exception as e:  # noqa: BLE001
             errors.append((rank, e))
@@ -429,6 +437,8 @@ def test_chaos_loader_epoch_tcp(monkeypatch):
                 + out["injected_delay"])
     assert injected > 0, out
     assert out["retry_giveups"] == 0, out
+    if lanes is not None:
+        assert out["max_lanes"] == int(lanes), out
 
 
 def test_soak_chaos_mode():

@@ -94,7 +94,7 @@ def test_load_mnist(tmp_path, gz):
 
 def test_load_mnist_raw_uint8(tmp_path):
     # normalize=False keeps the idx files' raw pixels: what the example
-    # and bench register in the store (4x fewer bytes; the VAE step
+    # registers in the store (4x fewer bytes; the VAE step
     # dequantizes on device).
     images, _labels = _write_mnist_fixture(str(tmp_path), n=16)
     x, y = load_mnist(str(tmp_path), normalize=False)

@@ -502,7 +502,71 @@ def _conndrop_workload(s):
     return np.concatenate(outs), counters
 
 
-def test_ctrl_conndrop_deterministic_and_byte_exact(monkeypatch):
+def _conndrop_concurrent_readers(s, readers=16):
+    """Ephemeral reader threads across BOTH ranks' gateways while
+    control connections are hard-closed under them: a refused attach is
+    retried (a shed control op is not data loss), every read is
+    byte-exact, and no session gives up on admission."""
+    bad, failed, giveups = [], [], []
+    lock = threading.Lock()
+
+    def reader(i):
+        sess = None
+        for _ in range(8):
+            try:
+                sess = s.gateway_session(tenant=f"eph{i % 4}",
+                                         target=i % 2, seed=500 + i)
+                break
+            except DDStoreError:
+                continue
+        if sess is None:
+            with lock:
+                failed.append(i)
+            return
+        try:
+            rng = np.random.default_rng(1000 + i)
+            for _ in range(3):
+                idx = rng.integers(0, 2 * ROWS, 32)
+                want = (idx // ROWS + 1)[:, None] * np.ones((1, DIM))
+                if not np.array_equal(sess.get_batch("v", idx), want):
+                    with lock:
+                        bad.append(i)
+        finally:
+            with lock:
+                giveups.append(sess.stats()["admission_giveups"])
+            sess.close()
+
+    # A lease that outlives the run: under drops every renewal may
+    # fail, and expiry is the reap tests' subject, not this one's.
+    s.gateway_configure(lease_ms=30000)
+    fault_configure("ctrl-conndrop:0.25", seed=37)
+    try:
+        ts = [threading.Thread(target=reader, args=(i,))
+              for i in range(readers)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(120)
+        fs = s.fault_stats()  # before the disarm resets the counters
+    finally:
+        fault_configure("", 0)
+    assert not any(t.is_alive() for t in ts), "reader hung"
+    assert not bad and not failed, (bad, failed)
+    assert len(giveups) == readers and sum(giveups) == 0, giveups
+    assert fs["ctrl_injected"] > 0 and fs["retry_giveups"] == 0, fs
+    assert fs["injected_reset"] == 0 and fs["injected_trunc"] == 0, fs
+    return True
+
+
+@pytest.mark.parametrize("concurrent", [False, True],
+                         ids=["serial-twice", "16-readers-2-gateways"])
+def test_ctrl_conndrop_deterministic_and_byte_exact(concurrent,
+                                                    monkeypatch):
+    if concurrent:
+        assert _run_pair(_conndrop_concurrent_readers,
+                         env={"DDSTORE_GATEWAY": "1"},
+                         monkeypatch=monkeypatch) is True
+        return
     out1, c1 = _run_pair(_conndrop_workload,
                          env={"DDSTORE_GATEWAY": "1"},
                          monkeypatch=monkeypatch)
@@ -563,7 +627,6 @@ def test_gateway_knobs_registered():
     for env in ("DDSTORE_GATEWAY", "DDSTORE_GW_LEASE_MS",
                 "DDSTORE_GW_DEFER_MS", "DDSTORE_GW_QUEUE",
                 "DDSTORE_GW_ADMIT_MARGIN", "DDSTORE_GW_LANE_SHARE",
-                "DDSTORE_GW_RETRY_MAX", "DDSTORE_SNAP_PIN_TTL_MS",
-                "DDSTORE_GATEWAY_PHASE_TIMEOUT_S"):
+                "DDSTORE_GW_RETRY_MAX", "DDSTORE_SNAP_PIN_TTL_MS"):
         assert env in REGISTRY, env
         assert REGISTRY[env].kind == "config"

@@ -47,10 +47,17 @@ def _wire_path_only(monkeypatch):
     monkeypatch.setenv("DDSTORE_RETRY_BASE_MS", "2")
 
 
-def _run_pair(body0, world=2, rows=8, row_elems=1 << 19):
+def _seeded_shard(rank, rows=8, row_elems=1 << 19):
+    """A shard no two rows of which agree: a stripe that lands on the
+    wrong peer OR at the wrong lane offset cannot read as correct."""
+    return np.random.default_rng(3 + rank).standard_normal(
+        (rows, row_elems))
+
+
+def _run_pair(body0, world=2, rows=8, row_elems=1 << 19, seeded=False):
     """Two-rank ThreadGroup TCP store with BIG rows (4 MiB) so remote
-    reads cross the striping threshold; rank r's shard is all (r+1).
-    Rank 0 runs ``body0(store)``."""
+    reads cross the striping threshold; rank r's shard is all (r+1), or
+    ``_seeded_shard(r)``. Rank 0 runs ``body0(store)``."""
     name = uuid.uuid4().hex
     errors = []
     result = {}
@@ -59,7 +66,8 @@ def _run_pair(body0, world=2, rows=8, row_elems=1 << 19):
         try:
             g = ThreadGroup(name, rank, world)
             with DDStore(g, backend="tcp") as s:
-                s.add("v", np.full((rows, row_elems), rank + 1,
+                s.add("v", _seeded_shard(rank, rows, row_elems) if seeded
+                      else np.full((rows, row_elems), rank + 1,
                                    np.float64))
                 if rank == 0:
                     result["out"] = body0(s)
@@ -96,18 +104,40 @@ def test_single_lane_is_the_old_contract(monkeypatch):
     assert len(lb) == 1 and lb[0] == 8 * (1 << 19) * 8
 
 
-def test_forced_lanes_stripe_and_balance(monkeypatch):
+@pytest.mark.parametrize("seeded", [False, True],
+                         ids=["rank-stamp", "seeded-oracle"])
+def test_forced_lanes_stripe_and_balance(seeded, monkeypatch):
     """Pinned 4-lane striping (autotune off): a bulk remote read deals
-    round-robin across all four lanes, bytes balanced, result exact."""
+    round-robin across all four lanes, bytes balanced, result exact —
+    against the rank stamp, and against a per-row seeded oracle, where
+    the striped read, a scattered batch with duplicates and the windowed
+    readahead delivery of the same batches must all return exactly the
+    owner's bytes."""
     monkeypatch.setenv("DDSTORE_TCP_LANES", "4")
     monkeypatch.setenv("DDSTORE_TCP_LANES_AUTOTUNE", "0")
 
     def body(s):
         got = s.get("v", 8, 8)
-        assert (got == 2).all()
-        return s.lane_state(), s.lane_bytes(), s.lane_bytes(1)
+        if not seeded:
+            assert (got == 2).all()
+            return s.lane_state(), s.lane_bytes(), s.lane_bytes(1)
+        np.testing.assert_array_equal(got, _seeded_shard(1))
+        st, lb, lb1 = s.lane_state(), s.lane_bytes(), s.lane_bytes(1)
+        from ddstore_tpu.data.readahead import EpochReadahead
 
-    st, lb, lb1 = _run_pair(body)
+        oracle = np.concatenate([_seeded_shard(r) for r in range(2)])
+        eq = [np.array([9, 3, 9, 14, 3]), np.array([15, 0, 8, 12])]
+        with EpochReadahead(s, "v", iter(eq), window_batches=2,
+                            depth=2) as ra:
+            for i, b in enumerate(eq):
+                np.testing.assert_array_equal(ra.get_batch(i, idx=b),
+                                              oracle[b])
+                np.testing.assert_array_equal(s.get_batch("v", b),
+                                              oracle[b])
+        assert s.async_pending() == 0
+        return st, lb, lb1
+
+    st, lb, lb1 = _run_pair(body, seeded=seeded)
     assert st["max_lanes"] == 4 and st["active_lanes"] == 4
     assert st["autotune"] is False and st["parked"] is True
     total = 8 * (1 << 19) * 8
@@ -161,8 +191,8 @@ def test_autotuner_ramps_and_parks(monkeypatch):
 
 
 def test_scatter_class_has_its_own_tuner(monkeypatch):
-    """Bulk stripes and scatter dealing have different lane optima
-    (measured >3x apart on the 2-core bench kernel), so each class
+    """Bulk stripes and scatter dealing need not share a lane optimum,
+    so each class
     parks independently — scatter-only traffic must never inherit the
     bulk verdict, and vice versa."""
     monkeypatch.setenv("DDSTORE_TCP_LANES", "2")

@@ -19,7 +19,7 @@ import uuid
 import numpy as np
 import pytest
 
-from ddstore_tpu import DDStore, SingleGroup, ThreadGroup
+from ddstore_tpu import DDStore, DDStoreError, SingleGroup, ThreadGroup
 from ddstore_tpu.data import DeviceLoader, DistributedSampler, ShardedDataset
 from ddstore_tpu.sched import (WARM_EWMA_ALPHA, WARM_MAX_COLD_SKIPS,
                                WARM_MIN_SAMPLES, ColdSkipBudget, CostModel,
@@ -496,7 +496,14 @@ class TestNativeSchedPlumbing:
                     if rank == 0:
                         res["cells"] = s.sched_cells()
                         pool = s.lane_state()["max_lanes"]
-                        s.sched_pin_lanes(0, 99)  # clamped to the pool
+                        # A width outside what the API takes is
+                        # refused, not swallowed; 64 is taken and the
+                        # pool clamps it.
+                        with pytest.raises(DDStoreError):
+                            s.sched_pin_lanes(0, 99)
+                        s.sched_pin_lanes(0, 64)
+                        with pytest.raises(DDStoreError):
+                            s.sched_pin_route(1, 2)
                         s.sched_pin_route(1, 0)
                         st = s.lane_state()
                         res["pinned_active"] = st["active_lanes"]
@@ -560,7 +567,7 @@ class TestNativeSchedPlumbing:
                  for c in res["cells"]}
         assert {(0, 0, 0), (0, 0, 1), (0, 1, 0), (0, 1, 1)} <= kinds
         assert any(c["source"] == 1 for c in res["cells"])
-        assert res["pinned_active"] == res["pool"]  # 99 clamped
+        assert res["pinned_active"] == res["pool"]  # 64 clamped
         assert res["pinned_parked"] is True
         ladder = 4 if (os.cpu_count() or 1) >= 8 else \
             (2 if (os.cpu_count() or 1) >= 4 else 1)

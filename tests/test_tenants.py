@@ -721,7 +721,7 @@ def test_planner_emits_tenant_budget_cells():
         plan.tenants["capped"]["lanes"] == 1
     assert st.lane_budgets == {t: b["lanes"]
                                for t, b in plan.tenants.items()}
-    # snapshot() carries the cells for the bench/epoch record.
+    # snapshot() carries the cells for the epoch record.
     snap = sched.snapshot()
     assert snap["plan"]["tenants"] == plan.tenants
 

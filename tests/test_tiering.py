@@ -286,32 +286,58 @@ def test_cache_disabled_inert_under_seeded_faults():
     assert a["faults"] == b["faults"], (a["faults"], b["faults"])
 
 
-def test_readahead_warms_cache_and_evicts_on_consumption():
+@pytest.mark.parametrize("cold", [False, True],
+                         ids=["hot-local", "cold-tcp-over-budget"])
+def test_readahead_warms_cache_and_evicts_on_consumption(
+        cold, tmp_path, monkeypatch):
     """The tentpole integration: EpochReadahead plans ahead, warms the
     cache with upcoming windows' row lists, the window reads hit RAM,
-    and consumption-keyed eviction drains every entry by close()."""
+    and consumption-keyed eviction drains every entry by close(). The
+    cold case is the deployment the cache exists for: file-backed cold
+    shards read over the wire (CMA off), a whole epoch of a dataset
+    TWICE the hot budget, every batch equal to a per-rank seeded oracle
+    and no fill failing on the way."""
     from ddstore_tpu.data.readahead import EpochReadahead
 
-    world, rows = 2, 2048
-    name = "warm-ra"
+    world, rows, dim = 2, 2048, 8
+    name = f"warm-ra-{cold}"
     stats = {}
+    if cold:
+        rows, dim = 8192, 64
+        monkeypatch.setenv("DDSTORE_CMA", "0")
+        monkeypatch.setenv("DDSTORE_HEARTBEAT_MS", "0")
+    budget = world * rows * dim * 4 // 2 if cold else 64 << 20
+
+    def shard(rank):
+        if not cold:
+            return np.full((rows, dim), rank + 1.0, np.float32)
+        return np.random.default_rng(300 + rank).standard_normal(
+            (rows, dim)).astype(np.float32)
 
     def body(rank):
         g = ThreadGroup(name, rank, world)
-        with DDStore(g, backend="local") as s:
-            data = np.full((rows, 8), rank + 1.0, np.float32)
-            s.add("v", data)
-            s.tier_configure(64 << 20)
+        with DDStore(g, backend="tcp" if cold else "local") as s:
+            if cold:
+                path = str(tmp_path / f"shard{rank}.bin")
+                shard(rank).tofile(path)
+                s.add_file("v", path, np.float32, (dim,), tier="cold")
+            else:
+                s.add("v", shard(rank))
+            s.tier_configure(budget)
             s.barrier()
             if rank == 0:
                 rng = np.random.default_rng(4)
-                batches = [rng.integers(0, world * rows, size=128)
-                           for _ in range(24)]
-                full = np.concatenate([
-                    np.full((rows, 8), r + 1.0, np.float32)
-                    for r in range(world)])
+                if cold:  # one whole epoch: every row exactly once
+                    perm = rng.permutation(world * rows)
+                    batches = [perm[i:i + 128]
+                               for i in range(0, len(perm), 128)]
+                else:
+                    batches = [rng.integers(0, world * rows, size=128)
+                               for _ in range(24)]
+                full = np.concatenate([shard(r) for r in range(world)])
                 eng = EpochReadahead(s, "v", list(batches),
-                                     window_batches=4, depth=2)
+                                     window_batches=8 if cold else 4,
+                                     depth=2)
                 for i, b in enumerate(batches):
                     np.testing.assert_array_equal(
                         eng.get_batch(i, b), full[b])
@@ -326,6 +352,10 @@ def test_readahead_warms_cache_and_evicts_on_consumption():
     assert stats["cache_entries"] == 0 and stats["cache_bytes"] == 0, \
         stats
     assert stats["pending"] == 0
+    if cold:
+        assert stats["cold_vars"] == 1, stats
+        assert stats["cache_max_bytes"] == budget, stats
+        assert stats["cache_fill_failures"] == 0, stats
 
 
 def test_cold_placement_for_mirrors_and_kept_copies(tmp_path):
@@ -458,8 +488,8 @@ def test_mmap_soak_1e8_rows(tmp_path):
     the reference's copy-everything-into-RAM behavior
     (ddstore.hpp:43-49). Stamped sentinel rows pin read correctness at
     far offsets; a full scan is deliberately NOT done (bounded time).
-    The harness is SHARED with the bench's soak phase
-    (ddstore_tpu.utils.soak) so both measure the same thing."""
+    The harness (ddstore_tpu.utils.soak) is shared with the fault and
+    corruption soaks of test_fault.py and test_integrity.py."""
     from ddstore_tpu.utils.soak import mmap_soak
 
     m = mmap_soak(rows=100_000_000, batch=65536, nbatches=32,

@@ -46,7 +46,7 @@ def test_dp_step_matches_single_device():
 def test_uint8_batch_matches_normalized_float():
     # The device-side dequantize path (uint8 staged raw, /255 on device)
     # must be numerically identical to feeding float32 pixels/255 — the
-    # uint8 path is what the example/bench stage (4x fewer bytes over
+    # uint8 path is what the example stages (4x fewer bytes over
     # the host->device link, ToTensor numerics on device).
     _, state_a, _ = vae.create_train_state(jax.random.key(0))
     model, state_b, tx = vae.create_train_state(jax.random.key(0))

@@ -3,7 +3,7 @@
 The fused op must match the unfused ``logits = x @ w; log_softmax`` path
 — values AND gradients — across block widths (including non-dividing
 vocab sizes) and through the model-level ``lm_loss`` entry point, because
-the bench and train step route through it at real vocab sizes.
+the benchmark's train step routes through it at real vocab sizes.
 """
 
 import jax
