@@ -21,6 +21,8 @@ create_train_state / make_train_step):
                 published widths (dense layer + one expert layer + MTP,
                 S=2048), loss and every gradient leaf against the plain
                 float32 reference, and the tokens the two route differently;
+                the same again with every pair routed to the held experts
+                (every trip of the routed part's loop, none dropped);
 * ragged leg  — one short epoch of the examples/gnn_molecules.py path;
 * two timings the next issues need, labelled as smoke output.
 
@@ -411,24 +413,34 @@ def experts_leg(cfg, record):
         tok, tgt = rng.integers(0, model.vocab, (2, batch, seq),
                                 dtype=np.int32)
         pos = np.tile(np.arange(seq, dtype=np.int32), (batch, 1))
-        (loss, loads), grads = jax.jit(jax.value_and_grad(
+        mine = jax.jit(jax.value_and_grad(
             lambda p: transformer.lm_loss(model, p, tok, tgt, pos),
-            has_aux=True))(params)
+            has_aux=True))
         arch = dict(model.arch._asdict(), heads=model.heads)
-        want, want_grads = jax.jit(jax.value_and_grad(
+        theirs = jax.jit(jax.value_and_grad(
             lambda p: ref.loss(p, tok, tgt, pos, arch=arch,
-                               token_block=1024)))(params)
-        loss_err = abs(float(loss) - float(want)) / abs(float(want))
+                               token_block=1024)))
         norm = lambda t: float(jnp.linalg.norm(t.astype(jnp.float32)))
-        rel = {}
-        for (path, g), w in zip(
-                jax.tree_util.tree_flatten_with_path(grads)[0],
-                jax.tree_util.tree_leaves(want_grads)):
-            if norm(w) > 0:
-                rel[jax.tree_util.keystr(path)] = norm(g - w) / norm(w)
-            elif norm(g) > 0:
-                raise AssertionError(f"{path}: gradient where the "
-                                     f"reference has none")
+
+        def against_reference(params):
+            """Loss, its relative error, each gradient leaf's relative
+            error and the load vectors, on ``params``."""
+            (loss, loads), grads = mine(params)
+            want, want_grads = theirs(params)
+            rel = {}
+            for (path, g), w in zip(
+                    jax.tree_util.tree_flatten_with_path(grads)[0],
+                    jax.tree_util.tree_leaves(want_grads)):
+                if norm(w) > 0:
+                    rel[jax.tree_util.keystr(path)] = norm(g - w) / norm(w)
+                elif norm(g) > 0:
+                    raise AssertionError(f"{path}: gradient where the "
+                                         f"reference has none")
+            return (float(loss), float(want),
+                    abs(float(loss) - float(want)) / abs(float(want)), rel,
+                    np.asarray(loads))
+
+        loss, want, loss_err, rel, loads = against_reference(params)
         worst = max(rel, key=rel.get)
         # Who is routed differently: the program's choice (sown by the
         # expert layers) against the reference's, expert sets a token.
@@ -436,20 +448,20 @@ def experts_leg(cfg, record):
             params, tok, pos, True, next_tokens=tgt,
             mutable=["intermediates"])
         sown = inter["intermediates"]
-        mine = [sown["block1"]["moe"]["chosen"][0],
-                sown["mtp"]["block"]["moe"]["chosen"][0]]
-        theirs = jax.jit(lambda p: ref.forward(p, tok, tgt, pos, arch,
-                                               token_block=1024)[1])(params)
+        chose = [sown["block1"]["moe"]["chosen"][0],
+                 sown["mtp"]["block"]["moe"]["chosen"][0]]
+        they_chose = jax.jit(lambda p: ref.forward(
+            p, tok, tgt, pos, arch, token_block=1024)[1])(params)
         differ = [int((np.sort(np.asarray(a), -1)
                        != np.sort(np.asarray(b), -1)).any(-1).sum())
-                  for a, b in zip(mine, theirs)]
-        say(f"    experts b={batch} S={seq}: loss {float(loss):.6f}, "
-            f"reference {float(want):.6f} (relative {loss_err:.2e}); "
+                  for a, b in zip(chose, they_chose)]
+        say(f"    experts b={batch} S={seq}: loss {loss:.6f}, "
+            f"reference {want:.6f} (relative {loss_err:.2e}); "
             f"gradient leaves {len(rel)}, relative norm of the difference "
             f"median {sorted(rel.values())[len(rel) // 2]:.2e}, worst "
             f"{rel[worst]:.2e} at {worst}; tokens routed differently "
             f"{differ} of {batch * seq} a layer; held experts' load "
-            f"{np.asarray(loads)[:, :int(desc['n_routed_experts'])].tolist()}")
+            f"{loads[:, :int(desc['n_routed_experts'])].tolist()}")
         rtol, gtol = cfg["experts_tol"]
         if loss_err > rtol or rel[worst] > gtol \
                 or max(differ) > cfg["experts_flips"] * batch * seq:
@@ -457,6 +469,32 @@ def experts_leg(cfg, record):
                 f"experts leg: loss {loss_err:.2e} (allowed {rtol}), worst "
                 f"gradient leaf {rel[worst]:.2e} at {worst} (allowed "
                 f"{gtol}), routed differently {differ}")
+        # Once more with every router's bias sending every pair to the
+        # experts held here: all T x k sorted rows are live, so the routed
+        # part's loop makes every trip (four of 2,048 rows at the published
+        # widths), where balanced routing needs the first alone. (The toy
+        # sizes hold fewer experts than a token takes, and make one trip.)
+        a = model.arch
+        which, of = a.expert_share
+        held = a.n_routed_experts // of
+        pairs = batch * seq * min(a.num_experts_per_tok, held)
+        mine_only = jnp.where(
+            jnp.arange(a.n_routed_experts) // held == which, 10.0, 0.0)
+        crowded = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: mine_only if "router_bias"
+            in jax.tree_util.keystr(path) else leaf, params)
+        loss, want, loss_err, rel, loads = against_reference(crowded)
+        worst = max(rel, key=rel.get)
+        live = loads[:, which * held:(which + 1) * held].sum(-1)
+        say(f"    every pair on the held experts: loss {loss:.6f}, "
+            f"reference {want:.6f} (relative {loss_err:.2e}); worst "
+            f"gradient leaf {rel[worst]:.2e} at {worst}; held pairs a layer "
+            f"{live.tolist()} of {pairs}")
+        if loss_err > rtol or rel[worst] > gtol or (live != pairs).any():
+            raise AssertionError(
+                f"experts leg, every pair held: loss {loss_err:.2e} "
+                f"(allowed {rtol}), worst gradient leaf {rel[worst]:.2e} at "
+                f"{worst} (allowed {gtol}), held pairs {live.tolist()}")
 
 
 def ragged_leg(store, sets, mesh, cfg, record):

@@ -42,6 +42,7 @@ computes its own part). ``MoeMlp`` stays what ``decode.py``,
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -158,57 +159,175 @@ def route_noaux_tc(scores: jax.Array, bias: jax.Array, top_k: int,
     return chosen, w / jnp.sum(w, axis=-1, keepdims=True) * scaling
 
 
+def routed_chunk(tokens: int, top_k: int, held: int, n_routed: int) -> int:
+    """The sorted rows :class:`SharedRoutedMoe` runs its routed experts over
+    at a time: the rows even routing sends to this chip's ``held`` of
+    ``n_routed`` experts and half as many again, in whole 1024s, and at
+    most all ``tokens * top_k``. A layer that holds every expert takes all
+    its rows at once."""
+    pairs = tokens * top_k
+    return min(pairs, -(-3 * pairs * held // (2 * n_routed * 1024)) * 1024)
+
+
+def _pairs_rows(rows, rank, live):
+    """``rows[rank]`` with the pairs that are not among the first ``live``
+    of ``rows`` (C, d) set to zero (selected away, never multiplied), choice
+    first: (k, T, d) from ``rank`` (T, k), which may point before or past
+    ``rows``; those pairs read some row and lose it. One gather of T rows a
+    choice: with k between T and d the chip re-tiles the whole result."""
+    rank = rank.T
+    picked = rows.at[jnp.clip(rank, 0, len(rows) - 1)].get(
+        mode="promise_in_bounds")
+    return jnp.where(((rank >= 0) & (rank < live))[..., None], picked, 0)
+
+
 @jax.custom_vjp
-def _to_experts(x, order, rank, local):
+def _to_experts(x, head, rank, live):
     """Rows of ``x`` (T, d) laid out for the grouped product: row r is the
-    token of pair ``order[r]`` (pairs are (token, choice), k a token).
-    ``rank`` (T, k) is the inverse (the row of each pair), ``local`` (T, k)
-    says which pairs' experts are held here. The transpose is written out
-    as a gather through ``rank``, not left to autodiff's scatter-add, and
-    reads only the held pairs' rows: the others' cotangents are not the
-    grouped product's to define."""
-    return x[order // rank.shape[1]]
+    token of pair ``head[r]`` (pairs are (token, choice), k a token; a
+    ``head`` past the last pair is padding). ``rank`` (T, k) is the inverse
+    (the row of each pair, counted from ``head``'s first); the first
+    ``live`` rows are pairs whose experts are held here. The transpose is
+    written out as a gather through ``rank``, not left to autodiff's
+    scatter-add, and reads only the held pairs' rows: the others'
+    cotangents are not the grouped product's to define."""
+    return x.at[head // rank.shape[1]].get(mode="clip")
 
 
-def _to_experts_fwd(x, order, rank, local):
-    return _to_experts(x, order, rank, local), (rank, local)
+def _to_experts_fwd(x, head, rank, live):
+    return _to_experts(x, head, rank, live), (rank, live)
 
 
 def _to_experts_bwd(res, dxs):
-    rank, local = res
-    dx = jnp.where(local[..., None], dxs[rank], 0).astype(jnp.float32)
-    return dx.sum(axis=1).astype(dxs.dtype), None, None, None
+    rank, live = res
+    dx = _pairs_rows(dxs, rank, live).astype(jnp.float32)
+    return dx.sum(axis=0).astype(dxs.dtype), None, None, None
 
 
 _to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
 
 
 @jax.custom_vjp
-def _from_experts(ys, w, order, rank, local):
-    """``y[t] = sum_j w[t, j] ys[rank[t, j]]`` over the held pairs, float32:
-    the weighted way back from the grouped product's rows (same layout as
-    :func:`_to_experts`). Rows of absent experts are selected away, never
-    multiplied by zero. Gathers both ways."""
-    picked = ys[rank].astype(jnp.float32) * w[..., None]
-    return jnp.where(local[..., None], picked, 0).sum(axis=1)
+def _from_experts(ys, w, head, rank, live):
+    """``y[t] = sum_j w[t, j] ys[rank[t, j]]`` over the held pairs among
+    these rows, float32: the weighted way back from the grouped product's
+    rows (same layout as :func:`_to_experts`). Rows of absent experts are
+    selected away, never multiplied by zero. Gathers of wide rows both
+    ways; the weights' cotangent is taken row by row in the sorted layout,
+    where only ``len(head)`` rows are."""
+    return (_pairs_rows(ys, rank, live).astype(jnp.float32)
+            * w.T[..., None]).sum(axis=0)
 
 
-def _from_experts_fwd(ys, w, order, rank, local):
-    return _from_experts(ys, w, order, rank, local), (ys, w, order, rank,
-                                                      local)
+def _from_experts_fwd(ys, w, head, rank, live):
+    return _from_experts(ys, w, head, rank, live), (ys, w, head, rank, live)
 
 
 def _from_experts_bwd(res, dy):
-    ys, w, order, rank, local = res
-    k = rank.shape[1]
-    held = local.reshape(-1)[order][:, None]
-    dys = jnp.where(held, dy[order // k] * w.reshape(-1)[order][:, None], 0)
-    dw = (ys[rank].astype(jnp.float32) * dy[:, None, :]).sum(axis=-1)
-    return (dys.astype(ys.dtype), jnp.where(local, dw, 0).astype(w.dtype),
-            None, None, None)
+    ys, w, head, rank, live = res
+    held = jnp.arange(len(head)) < live
+    dyr = dy.at[head // rank.shape[1]].get(mode="clip")
+    dys = jnp.where(
+        held[:, None],
+        dyr * w.reshape(-1).at[head].get(mode="clip")[:, None], 0)
+    # a row's weight cotangent to its pair: rows are distinct pairs (the
+    # padding past the last pair is dropped), and a scatter of C scalars
+    # is cheaper than a gather of T k through rank
+    dws = jnp.where(held, (ys.astype(jnp.float32) * dyr).sum(axis=-1), 0)
+    dw = jnp.zeros(w.size, jnp.float32).at[head].set(
+        dws, unique_indices=True, mode="drop")
+    return (dys.astype(ys.dtype), dw.reshape(w.shape).astype(w.dtype), None,
+            None, None)
 
 
 _from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
+
+
+def routed_rows(rows, start, x, weights, order, rank, sizes, w_gate, w_up,
+                w_down):
+    """What the sorted rows ``[start, start + rows)`` (``rows`` static) add
+    to the routed experts' part of the layer, (T, d) float32: the weighted
+    sum over each token's held pairs among them. ``x`` (T, d) in the
+    compute dtype, ``weights`` (T, k), ``order`` the pairs sorted by held
+    expert (at least ``start + rows`` long: padded past the last pair),
+    ``rank`` (T, k) its inverse, ``sizes`` (held,) the rows of each held
+    expert, the three expert weights as the parameters are. One trip of
+    :func:`_routed`."""
+    with jax.named_scope("moe_dispatch"):
+        ends = jnp.cumsum(sizes)
+        inside = lambda row: jnp.clip(row, start, start + rows)
+        here = inside(ends) - inside(ends - sizes)  # of each held expert
+        live = here.sum()
+        head = jax.lax.dynamic_slice_in_dim(order, start, rows)
+        local = rank - start
+        xs = _to_experts(x, head, local, live)
+        with jax.named_scope("moe_experts"):
+            w_gate, w_up, w_down = (w.astype(x.dtype)
+                                    for w in (w_gate, w_up, w_down))
+            h = nn.silu(jax.lax.ragged_dot(xs, w_gate, here)) \
+                * jax.lax.ragged_dot(xs, w_up, here)
+            ys = jax.lax.ragged_dot(h, w_down, here)
+        return _from_experts(ys, weights, head, local, live)
+
+
+def _over_live_rows(rows, order, sizes, trip):
+    """The sum of ``trip(order, start)`` (a tree of arrays, added in their
+    own types) over the starts 0, ``rows``, 2 ``rows`` ... below
+    ``sizes.sum()``, the live rows: the first trip always and in line, the
+    others (none, under any routing near even) in a loop whose count the
+    device reads off the routing. ``trip`` gets ``order`` padded to whole
+    trips."""
+    pairs = len(order)
+    if rows >= pairs:
+        return trip(order, 0)
+    order = jnp.concatenate(
+        [order, pairs + jnp.arange(-pairs % rows, dtype=order.dtype)])
+    with jax.named_scope("moe_dispatch"):
+        trips = -(-sizes.sum() // rows)
+
+        def more(carry):
+            i, total = carry
+            return i + 1, jax.tree_util.tree_map(
+                jnp.add, total, trip(order, i * rows))
+
+        return jax.lax.while_loop(lambda carry: carry[0] < trips, more,
+                                  (jnp.int32(1), trip(order, 0)))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(rows, x, weights, order, rank, sizes, w_gate, w_up, w_down):
+    """The routed experts' part of the layer, (T, d) float32, over as many
+    sorted rows as this step's routing fills: :func:`routed_rows` in trips
+    of ``rows`` (static) until every live row is done, so every held pair
+    is computed whatever the routing and a step pays for the trips it
+    needs. Both rules walk the rows themselves and keep nothing but the
+    inputs, so nothing ``T k`` rows long is ever built; the backward rule
+    computes each trip's forward again, as ``nn.remat`` around the block
+    does for everything else."""
+    return _over_live_rows(rows, order, sizes, lambda order, start: (
+        routed_rows(rows, start, x, weights, order, rank, sizes, w_gate,
+                    w_up, w_down)))
+
+
+def _routed_fwd(rows, *args):
+    return _routed(rows, *args), args
+
+
+def _routed_bwd(rows, args, dy):
+    x, weights, order, rank, sizes, w_gate, w_up, w_down = args
+
+    def back(order, start):
+        # float32 for the weights, as they are; x's in x's own type
+        return jax.vjp(
+            lambda x, weights, w_gate, w_up, w_down: routed_rows(
+                rows, start, x, weights, order, rank, sizes, w_gate, w_up,
+                w_down), x, weights, w_gate, w_up, w_down)[1](dy)
+
+    dx, dweights, *dws = _over_live_rows(rows, order, sizes, back)
+    return (dx, dweights, None, None, None, *dws)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class SharedRoutedMoe(nn.Module):
@@ -229,9 +348,14 @@ class SharedRoutedMoe(nn.Module):
     of absent experts behind them, and the held rows are multiplied by
     ``jax.lax.ragged_dot`` (on the TPU XLA's own grouped-matmul kernel,
     which does the work of the rows each expert got and no more: PERF.md
-    section 6, PR 27). Shapes are static, T x k rows, so no pair is ever
-    dropped, whatever the routing; what even routing leaves unused of them
-    costs element-wise passes, not matmuls.
+    section 6, PR 27). Shapes are static, and the gathers, SwiGLU and
+    weighted sums around the products cost what the buffer's length is,
+    not what the routing fills; so the routed part (:func:`_routed`) walks
+    the sorted rows in trips of :func:`routed_chunk` rows, as many as the
+    step's held pairs need, counted on the device: one under routing near
+    even, all ``T k`` rows when every pair lands here. No pair is ever
+    dropped, whatever the routing; a layer that holds every expert takes
+    its rows at once.
     """
 
     n_routed: int
@@ -252,9 +376,10 @@ class SharedRoutedMoe(nn.Module):
                              f"{e} routed experts")
         held = e // of
         first = which * held
+        rows = routed_chunk(t, k, held, e)
         profile.count_moe_layout(
             "/".join(self.path), held=held, of=e, first=first, top_k=k,
-            tokens=t)
+            tokens=t, rows=rows)
 
         experts = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
@@ -282,15 +407,9 @@ class SharedRoutedMoe(nn.Module):
             order = jnp.argsort(jnp.where(local, flat - first, held),
                                 stable=True).astype(jnp.int32)
             rank = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
-            local = local.reshape(t, k)
             sizes = jax.lax.dynamic_slice_in_dim(load, first, held)
-            xs = _to_experts(x.astype(dt), order, rank, local)
-        with jax.named_scope("moe_experts"):
-            h = nn.silu(jax.lax.ragged_dot(xs, w_gate.astype(dt), sizes)) \
-                * jax.lax.ragged_dot(xs, w_up.astype(dt), sizes)
-            ys = jax.lax.ragged_dot(h, w_down.astype(dt), sizes)
-        with jax.named_scope("moe_dispatch"):
-            y = _from_experts(ys, weights, order, rank, local)
+        y = _routed(rows, x.astype(dt), weights, order, rank, sizes,
+                    w_gate, w_up, w_down)
 
         # The shared expert: the block's dense MLP work, on every chip.
         wide = self.n_shared * self.hidden

@@ -91,7 +91,7 @@ _watching = False
 # Kernel name -> call shape -> what ops/attention.py's geometry counts.
 _geometry: Dict[str, Dict[str, Dict[str, int]]] = {}
 # Expert layer (its module path) -> what models/moe.py holds and routes.
-_moe_layout: Dict[str, Dict[str, int]] = {}
+_moe_layout: Dict[str, dict] = {}
 _ring_geometry: Dict[str, dict] = {}
 
 
@@ -197,10 +197,12 @@ def count_geometry(kernel: str, call: str, counts: Dict[str, int]) -> None:
         _geometry.setdefault(kernel, {})[call] = dict(counts)
 
 
-def count_moe_layout(layer: str, **counts: int) -> None:
+def count_moe_layout(layer: str, **counts) -> None:
     """``models/moe.py``, while an expert layer is traced: the experts this
     chip holds (``held`` of ``of``, from ``first``), the experts a token
-    takes (``top_k``) and the ``tokens`` of the call."""
+    takes (``top_k``), the ``tokens`` of the call and the sorted ``rows``
+    its routed experts run over at a time (as many trips a step as the
+    step's held pairs need: one, under routing near even)."""
     with _lock:
         _moe_layout[layer] = dict(counts)
 

@@ -33,6 +33,28 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
+def expert_rows(loads) -> str:
+    """What the epoch's load vectors (steps, expert layers, experts) say of
+    the expert layers: the last step's largest over mean load, the share
+    of layer-steps whose held pairs fit one trip over the sorted rows
+    (``counters()["moe_layout"]``: every layer of a model has the same),
+    and the held pairs over the rows computed for them."""
+    import numpy as np
+
+    from ddstore_tpu.utils import profile
+
+    lay = next(iter(profile.counters()["moe_layout"].values()))
+    rows = lay["rows"]
+    live = loads[..., lay["first"]:lay["first"] + lay["held"]].sum(-1)
+    trips = np.maximum(1, -(-live // rows))
+    return (f" expert load max/mean="
+            f"{float((loads[-1].max(-1) / loads[-1].mean(-1)).max()):.2f}"
+            f" one trip of {rows} sorted rows (of "
+            f"{lay['tokens'] * lay['top_k']}) in "
+            f"{float((trips == 1).mean()):.3f} of layer-steps,"
+            f" live/computed rows={live.sum() / (trips * rows).sum():.3f}")
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--epochs", type=int, default=2)
@@ -234,7 +256,7 @@ def main():
         tracing = trace(args.profile) if (args.profile and epoch == 0) \
             else contextlib.nullcontext()
         t0 = time.perf_counter()
-        tot, nb, loads = 0.0, 0, None
+        tot, nb, loads = 0.0, 0, []
         with tracing:
             for i, (tok, tgt) in enumerate(loader):
                 if args.steps is not None and i >= args.steps:
@@ -243,7 +265,8 @@ def main():
                     state, loss = step(state, tok, tgt, pos)
                 if model.arch is not None:
                     # beside the loss: tokens routed to each expert, a layer
-                    loss, loads = loss
+                    loss, load = loss
+                    loads.append(load)
                 tot += float(loss)
                 nb += 1
             # Flush the final async step before stop_trace / timing
@@ -256,8 +279,7 @@ def main():
             print(f"epoch {epoch}: loss={tot / max(1, nb):.4f} "
                   f"tokens/s={tps:.0f} "
                   f"loader_wait_share={m['loader_wait_share']:.4f}"
-                  + ("" if loads is None else " expert load max/mean="
-                     f"{float((loads.max(-1) / loads.mean(-1)).max()):.2f}"),
+                  + (expert_rows(np.stack(loads)) if loads else ""),
                   flush=True)
             if epoch == 0:
                 # Counted while the step was traced: the pairs each sp

@@ -86,10 +86,14 @@ def test_the_ring_calls_of_the_four_chip_cell_fit_the_chip(
 
 
 def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache):
-    """Published widths, a quarter of a step's tokens: the nine grouped
-    products (three forward, six backward) are XLA's ragged-dot kernels,
-    which size their grid from the rows each expert got."""
-    from ddstore_tpu.models.moe import SharedRoutedMoe
+    """Published widths, a quarter of a step's tokens: the grouped products
+    are XLA's ragged-dot kernels, which size their grid from the rows each
+    expert got. The first trip over the sorted rows and the loop's body
+    for the others each hold the forward's three, and in the backward rule
+    the forward's three again beside the transposes' six; XLA may share
+    the in-line trip's three between the two rules (here, where nothing is
+    rematerialised, it does)."""
+    from ddstore_tpu.models.moe import SharedRoutedMoe, routed_chunk
 
     layer = SharedRoutedMoe(64, 4, 1536, share=(0, 8), scaling=1.8)
     x = jax.ShapeDtypeStruct((4096, 2048), jnp.bfloat16, sharding=one_chip)
@@ -104,4 +108,6 @@ def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache):
 
     text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(params, x) \
         .compile().as_text()
-    assert text.count('op_name="ragged-dot-none"') == 9
+    assert routed_chunk(4096, 4, 8, 64) == 3072     # of 16,384 pairs
+    assert text.count('op_name="ragged-dot-none"') in (2 * (3 + 9) - 3,
+                                                       2 * (3 + 9))
