@@ -147,16 +147,21 @@ class MoeMlp(nn.Module):
 
 
 def route_noaux_tc(scores: jax.Array, bias: jax.Array, top_k: int,
-                   scaling: float) -> Tuple[jax.Array, jax.Array]:
-    """``noaux_tc`` routing without a group limit (DeepSeek-V3, GLM-4 MoE):
-    the ``top_k`` experts of ``scores + bias`` are chosen, the weights come
-    from the scores alone, normalised over the chosen and scaled. The bias
-    only steers the choice, so no gradient reaches it. ``scores`` (T, E)
-    float32 sigmoid outputs; returns ``(chosen (T, k) int32, weights (T, k)
-    float32)``."""
+                   scaling: float, eps: float = 0.0
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """``noaux_tc`` routing without a group limit (DeepSeek-V3, GLM-4 MoE,
+    LFM2's ``use_expert_bias``): the ``top_k`` experts of ``scores + bias``
+    are chosen, the weights come from the scores alone, normalised over the
+    chosen and scaled: ``scores[chosen] / (sum scores[chosen] + eps) *
+    scaling``. ``eps`` is the normaliser's epsilon as the family publishes
+    it (0 for ``glm4_moe_lite``, 1e-6 for ``lfm2_moe``; static, and at 0
+    the sum is divided by as it is). The bias only steers the choice, so no
+    gradient reaches it. ``scores`` (T, E) float32 sigmoid outputs; returns
+    ``(chosen (T, k) int32, weights (T, k) float32)``."""
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    return chosen, w / (total + eps if eps else total) * scaling
 
 
 def routed_chunk(tokens: int, top_k: int, held: int, n_routed: int) -> int:
@@ -333,6 +338,8 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 class SharedRoutedMoe(nn.Module):
     """Shared + routed SwiGLU experts with no dropped token, as one
     expert-parallel chip's share: ``(T, d) -> ((T, d), load (n_routed,))``.
+    ``n_shared = 0`` is a layer of routed experts alone: no shared
+    parameters and no shared products.
 
     ``share = (which, of)``: this chip is number ``which`` of ``of`` that
     divide the layer's ``n_routed`` experts between them, and holds the
@@ -364,6 +371,7 @@ class SharedRoutedMoe(nn.Module):
     share: Tuple[int, int] = (0, 1)
     scaling: float = 1.0
     n_shared: int = 1
+    route_eps: float = 0.0     # route_noaux_tc's normaliser epsilon
     compute_dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -395,7 +403,8 @@ class SharedRoutedMoe(nn.Module):
                     x.astype(jnp.float32)))
             bias = self.param("router_bias",
                               nn.initializers.normal(ROUTER_BIAS_STD), (e,))
-            chosen, weights = route_noaux_tc(scores, bias, k, self.scaling)
+            chosen, weights = route_noaux_tc(scores, bias, k, self.scaling,
+                                             self.route_eps)
             # For whoever asks with mutable=["intermediates"] (chip_smoke's
             # count of near-tie tokens); nothing otherwise.
             self.sow("intermediates", "chosen", chosen)
@@ -411,6 +420,8 @@ class SharedRoutedMoe(nn.Module):
         y = _routed(rows, x.astype(dt), weights, order, rank, sizes,
                     w_gate, w_up, w_down)
 
+        if not self.n_shared:
+            return y.astype(x.dtype), load
         # The shared expert: the block's dense MLP work, on every chip.
         wide = self.n_shared * self.hidden
         hs = nn.silu(nn.Dense(wide, use_bias=False, dtype=dt,

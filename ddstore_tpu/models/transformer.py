@@ -35,6 +35,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import flash_attention, mha_reference
+from ..ops.short_conv import gated_short_conv
 from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
                                  pipeline_apply,
                                  pipeline_interleaved,
@@ -43,6 +44,7 @@ from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
 from ..parallel.ring_attention import balanced_order, ring_attention
 from ..parallel.tp import (expert_rules, megatron_rules, shard_pytree,
                            shardings_of)
+from ..utils import profile
 from ..utils.profile import phase
 
 
@@ -142,10 +144,15 @@ class MlaMoeArch(NamedTuple):
     """A latent-attention (MLA), shared + routed expert, multi-token-
     prediction decoder, by the keys of its published ``config.json`` (the
     ``glm4_moe_lite`` / DeepSeek-V3 family). ``TransformerLM(arch=...)``
-    builds :class:`MlaBlock` layers from it; ``vocab``, ``dim``, ``heads``
-    and ``layers`` stay the model's own fields. ``n_routed_experts`` is the
-    router's width (the published count); ``expert_share = (which, of)`` is
-    this chip's share of them (:class:`~.moe.SharedRoutedMoe`)."""
+    builds :class:`DecoderBlock` layers from it; ``vocab``, ``dim``,
+    ``heads`` and ``layers`` stay the model's own fields.
+    ``n_routed_experts`` is the router's width (the published count);
+    ``expert_share = (which, of)`` is this chip's share of them
+    (:class:`~.moe.SharedRoutedMoe`).
+
+    What the model's one path for described architectures reads of an
+    ``arch`` (this one or :class:`Lfm2MoeArch`): ``mixer(layer)``, and the
+    fields the two name alike."""
 
     q_lora_rank: int
     kv_lora_rank: int
@@ -167,49 +174,132 @@ class MlaMoeArch(NamedTuple):
     bias_update_speed: float = 0.0   # noaux_tc: what a step moves each
     #                               expert's correction bias toward balance
     #                               (update_router_bias); 0 leaves it alone
+    tie_word_embeddings: bool = False   # the head is the embedding's matrix
+    route_eps: float = 0.0           # route_noaux_tc's normaliser epsilon
+
+    def mixer(self, layer: int) -> str:
+        """The kind of layer ``layer``'s mixer: a key of ``_MIXERS``."""
+        return "mla"
+
+
+class Lfm2MoeArch(NamedTuple):
+    """A gated-short-convolution / grouped-query-attention decoder with
+    bias-routed experts and no shared expert, by the keys of its published
+    ``config.json`` (``model_type`` ``lfm2_moe``): ``layer_types`` says a
+    layer which mixer it has (``conv`` or ``full_attention``), the first
+    ``first_k_dense_replace`` layers (the published ``num_dense_layers``)
+    have a dense SwiGLU MLP and the others ``num_experts_per_tok`` of
+    ``n_routed_experts`` routed experts (the router's width: the published
+    ``num_experts``), of which this chip holds ``expert_share``'s.
+    ``rms_norm_eps`` is the published ``norm_eps``. The fields
+    :class:`MlaMoeArch` has too mean what they mean there."""
+
+    layer_types: Tuple[str, ...]
+    num_key_value_heads: int
+    conv_L_cache: int                # taps of the short convolution
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    first_k_dense_replace: int
+    routed_scaling_factor: float = 1.0
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-5
+    n_shared_experts: int = 0
+    route_eps: float = 1e-6          # Lfm2MoeSparseMoeBlock's normaliser
+    tie_word_embeddings: bool = True
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.0
+    expert_share: Tuple[int, int] = (0, 1)
+    bias_update_speed: float = 0.0
+
+    def mixer(self, layer: int) -> str:
+        return self.layer_types[layer]
+
+
+def _refuse_unless(desc: Mapping[str, Any], built) -> None:
+    """Raises for a key of ``desc`` whose value is not the one built here
+    (``built``: pairs of key and that value; an absent key passes)."""
+    for key, want in built:
+        if desc.get(key, want) != want:
+            raise ValueError(f"{key}={desc[key]!r} is not built here "
+                             f"(only {key}={want!r})")
+
+
+def _mla_arch(desc: Mapping[str, Any]) -> MlaMoeArch:
+    _refuse_unless(desc, (
+        ("hidden_act", "silu"), ("topk_method", "noaux_tc"), ("n_group", 1),
+        ("topk_group", 1), ("norm_topk_prob", True),
+        ("attention_bias", False), ("rope_scaling", None),
+        ("partial_rotary_factor", 1),
+        ("num_key_value_heads", desc["num_attention_heads"])))
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    fields = {k: desc[k] for k in MlaMoeArch._fields if k in desc}
+    fields["n_routed_experts"] = int(desc["n_routed_experts"]) \
+        * int(ep["chips"])
+    fields["expert_share"] = (int(ep["chip"]), int(ep["chips"]))
+    return MlaMoeArch(**fields)
+
+
+def _lfm2_arch(desc: Mapping[str, Any]) -> Lfm2MoeArch:
+    _refuse_unless(desc, (
+        ("conv_bias", False), ("norm_topk_prob", True),
+        ("use_expert_bias", True), ("rope_scaling", None),
+        ("tie_embedding", True)))
+    kinds = tuple(desc["layer_types"])
+    if len(kinds) != int(desc["num_hidden_layers"]):
+        raise ValueError(f"layer_types has {len(kinds)} entries for "
+                         f"num_hidden_layers={desc['num_hidden_layers']}")
+    for kind in kinds:
+        if kind not in ("conv", "full_attention"):
+            raise ValueError(f"layer_types entry {kind!r} is not built "
+                             f"here (only 'conv' and 'full_attention')")
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    rope = desc.get("rope_parameters", desc)
+    fields = {k: desc[k] for k in Lfm2MoeArch._fields if k in desc}
+    fields.update(
+        layer_types=kinds,
+        n_routed_experts=int(desc["num_experts"]) * int(ep["chips"]),
+        first_k_dense_replace=int(desc["num_dense_layers"]),
+        rope_theta=float(rope["rope_theta"]),
+        rms_norm_eps=float(desc["norm_eps"]), tie_word_embeddings=True,
+        expert_share=(int(ep["chip"]), int(ep["chips"])))
+    return Lfm2MoeArch(**fields)
 
 
 def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     """A :class:`TransformerLM` from one description of the architecture:
-    either the dense block's own keys (``vocab``, ``dim``, ``heads``,
-    ``layers``, ``mlp_ratio``; ``experts`` / ``moe_top_k`` for the
-    capacity-bound ``MoeMlp``), or the keys of a published
-    latent-attention expert model's ``config.json`` (``q_lora_rank``
-    present), where ``n_routed_experts`` counts the experts held here, of
-    ``expert_parallel = {"chips": n, "chip": i}`` chips that share each
-    layer, and the router is ``chips`` times as wide. ``kw`` are further
-    ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
-    if "q_lora_rank" not in desc:
+    the dense block's own keys (``vocab``, ``dim``, ``heads``, ``layers``,
+    ``mlp_ratio``; ``experts`` / ``moe_top_k`` for the capacity-bound
+    ``MoeMlp``), or the keys of a published model's ``config.json``: a
+    latent-attention expert model (``q_lora_rank`` present;
+    :class:`MlaMoeArch`) or a short-convolution / grouped-query expert
+    model (``model_type`` ``lfm2_moe``, or ``layer_types`` beside
+    ``conv_L_cache``; :class:`Lfm2MoeArch`). In both, the key that counts
+    the routed experts (``n_routed_experts`` / ``num_experts``) counts the
+    experts held here, of ``expert_parallel = {"chips": n, "chip": i}``
+    chips that share each layer, and the router is ``chips`` times as
+    wide. What a description asks for and is not built raises, naming the
+    key and the value that is. ``kw`` are further ``TransformerLM`` fields
+    (``compute_dtype``, ``mesh``, ``remat``...)."""
+    if desc.get("model_type") == "lfm2_moe" or (
+            "layer_types" in desc and "conv_L_cache" in desc):
+        arch = _lfm2_arch(desc)
+    elif "q_lora_rank" in desc:
+        arch = _mla_arch(desc)
+    else:
         return TransformerLM(
             vocab=int(desc["vocab"]), dim=int(desc["dim"]),
             heads=int(desc["heads"]), layers=int(desc["layers"]),
             mlp_ratio=int(desc.get("mlp_ratio", 4)),
             n_experts=int(desc.get("experts", 0)),
             moe_top_k=int(desc.get("moe_top_k", 1)), **kw)
-    for key, want in (("hidden_act", "silu"), ("topk_method", "noaux_tc"),
-                      ("n_group", 1), ("topk_group", 1),
-                      ("norm_topk_prob", True), ("attention_bias", False),
-                      ("tie_word_embeddings", False), ("rope_scaling", None),
-                      ("partial_rotary_factor", 1)):
-        if desc.get(key, want) != want:
-            raise ValueError(f"{key}={desc[key]!r} is not built here "
-                             f"(only {want!r})")
-    if desc.get("num_key_value_heads", desc["num_attention_heads"]) \
-            != desc["num_attention_heads"]:
-        raise ValueError("latent attention has one key/value head a "
-                         "query head")
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    fields = {k: desc[k] for k in MlaMoeArch._fields if k in desc}
-    fields["n_routed_experts"] = int(desc["n_routed_experts"]) \
-        * int(ep["chips"])
-    fields["expert_share"] = (int(ep["chip"]), int(ep["chips"]))
     if "remat_policy" in desc and "remat_policy" not in kw:
         kw = dict(kw, remat=True, remat_policy=desc["remat_policy"])
     return TransformerLM(
         vocab=int(desc["vocab_size"]), dim=int(desc["hidden_size"]),
         heads=int(desc["num_attention_heads"]),
-        layers=int(desc["num_hidden_layers"]),
-        arch=MlaMoeArch(**fields), **kw)
+        layers=int(desc["num_hidden_layers"]), arch=arch, **kw)
 
 
 class RMSNorm(nn.Module):
@@ -240,58 +330,129 @@ def rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-class MlaBlock(nn.Module):
-    """Pre-RMSNorm decoder block of :class:`MlaMoeArch`: multi-head latent
-    attention computed uncompressed (training: per head q = [q_nope |
-    q_rope], k = [k_nope | k_rope], the rotary key one vector a position
-    shared by all heads), then a SwiGLU MLP (``dense``) or the shared +
-    routed experts. No biases. Returns ``x``, and the expert layer's load
-    vector beside it."""
+def _attend(q, k, v):
+    """Causal attention over (B, H, S, D) heads, K and V perhaps fewer
+    heads than Q (grouped-query): ``out`` alone. On the chip the kernel is
+    the only path, as in :class:`Block`: a length it cannot tile raises in
+    ``flash_attention`` rather than sliding to the S x S reference."""
+    if jax.default_backend() == "tpu":
+        return flash_attention(q, k, v, causal=True)[0]
+    return mha_reference(q, k, v, causal=True)[0]
+
+
+def _mla_mixer(blk: "DecoderBlock", x, positions):
+    """Multi-head latent attention computed uncompressed (training: per
+    head q = [q_nope | q_rope], k = [k_nope | k_rope], the rotary key one
+    vector a position shared by all heads), norm to output projection."""
+    b, s, _ = x.shape
+    a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
+    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
+    norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
+    nope, rot, vd = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
+    if vd != nope + rot:
+        raise NotImplementedError(
+            f"v_head_dim={vd} beside a query/key width of {nope + rot}: "
+            f"the flash kernels give K and V one width")
+    profile.count_mixer_layout("/".join(blk.path), kind="mla", heads=nh,
+                               kv_heads=nh, tokens=b * s)
+    h = norm("ln1")(x).astype(dt)
+    cq = norm("q_norm")(lin(a.q_lora_rank, "q_a")(h)).astype(dt)
+    q = lin(nh * (nope + rot), "q_b")(cq).reshape(b, s, nh, nope + rot)
+    kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
+    ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
+    kv = lin(nh * (nope + vd), "kv_b")(ckv).reshape(b, s, nh, nope + vd)
+    k_rope = rope(kva[..., None, a.kv_lora_rank:], positions, a.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], positions, a.rope_theta)],
+        axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rot))],
+        axis=-1)
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, kv[..., nope:]))
+    out = _attend(q, k, v)
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * vd).astype(dt)
+    return lin(blk.dim, "proj")(out)
+
+
+def _gqa_mixer(blk: "DecoderBlock", x, positions):
+    """Grouped-query attention (``Lfm2MoeAttention``): ``heads`` query
+    heads over ``num_key_value_heads`` K/V heads of ``dim / heads``, q and
+    k RMS-normed a head (one learned scale of the head's width each), then
+    rotary on the whole width; K and V go to the kernels as they are."""
+    b, s, _ = x.shape
+    a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
+    nkv, hd = a.num_key_value_heads, blk.dim // blk.heads
+    norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
+    profile.count_mixer_layout("/".join(blk.path), kind="full_attention",
+                               heads=nh, kv_heads=nkv, tokens=b * s)
+    h = norm("ln1")(x).astype(dt)
+    # [W_q | W_k | W_v] as one product
+    qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
+                   name="qkv")(h).reshape(b, s, nh + 2 * nkv, hd)
+    q, k, v = jnp.split(qkv, (nh, nh + nkv), axis=2)
+    q = rope(norm("q_norm")(q), positions, a.rope_theta).astype(dt)
+    k = rope(norm("k_norm")(k), positions, a.rope_theta).astype(dt)
+    out = _attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)))
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * hd).astype(dt)
+    return nn.Dense(blk.dim, use_bias=False, dtype=dt, name="proj")(out)
+
+
+def _conv_mixer(blk: "DecoderBlock", x, positions):
+    """Gated short convolution (``Lfm2ShortConv``): ``[Bg | Cg | u] = W_in
+    h``, a causal depthwise convolution of ``conv_L_cache`` taps over ``Bg
+    * u``, gated by ``Cg``, then ``W_out``
+    (:func:`~ddstore_tpu.ops.short_conv.gated_short_conv`). ``conv_taps``
+    is (taps, dim), the last row the current position's: the checkpoint's
+    (dim, 1, taps) weight transposed."""
+    a, dt = blk.arch, blk.compute_dtype
+    b, s, _ = x.shape
+    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
+    profile.count_mixer_layout("/".join(blk.path), kind="conv",
+                               taps=a.conv_L_cache, tokens=b * s)
+    with jax.named_scope("conv_mixer"):
+        h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
+        taps = blk.param(
+            "conv_taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
+            (a.conv_L_cache, blk.dim))
+        y = gated_short_conv(lin(3 * blk.dim, "in_proj")(h), taps)
+        return lin(blk.dim, "out_proj")(y)
+
+
+# A described layer's mixer, by the name ``arch.mixer(layer)`` gives it:
+# (block, x, positions) -> what the mixer adds to x, under the block's own
+# scope (its submodules are the block's).
+_MIXERS = {"mla": _mla_mixer, "full_attention": _gqa_mixer,
+           "conv": _conv_mixer}
+
+
+class DecoderBlock(nn.Module):
+    """Pre-RMSNorm decoder layer of a described architecture
+    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`): ``x + mixer(norm(x))``
+    with the mixer ``mixer`` names (``_MIXERS``: latent attention, rotary
+    grouped-query attention, gated short convolution), then a SwiGLU MLP
+    (``dense``) or the shared + routed experts. No biases. Returns ``x``,
+    and the expert layer's load vector beside it."""
 
     dim: int
     heads: int
-    arch: MlaMoeArch
+    arch: Any
     dense: bool
     compute_dtype: Any
+    mixer: str = "mla"
 
     @nn.compact
     def __call__(self, x, positions):
         b, s, _ = x.shape
-        a, dt, nh = self.arch, self.compute_dtype, self.heads
+        a, dt = self.arch, self.compute_dtype
         lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
                                        name=name)
-        norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
-        nope, rot, vd = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
 
         with jax.named_scope("attn"):
-            h = norm("ln1")(x).astype(dt)
-            cq = norm("q_norm")(lin(a.q_lora_rank, "q_a")(h)).astype(dt)
-            q = lin(nh * (nope + rot), "q_b")(cq).reshape(
-                b, s, nh, nope + rot)
-            kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
-            ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
-            kv = lin(nh * (nope + vd), "kv_b")(ckv).reshape(
-                b, s, nh, nope + vd)
-            k_rope = rope(kva[..., None, a.kv_lora_rank:], positions,
-                          a.rope_theta)
-            q = jnp.concatenate(
-                [q[..., :nope], rope(q[..., nope:], positions,
-                                     a.rope_theta)], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rot))],
-                axis=-1)
-            q, k, v = (t.transpose(0, 2, 1, 3)
-                       for t in (q, k, kv[..., nope:]))
-            if jax.default_backend() == "tpu":
-                # As in Block: on the chip the kernel is the only path.
-                out, _ = flash_attention(q, k, v, causal=True)
-            else:
-                out, _ = mha_reference(q, k, v, causal=True)
-            out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * vd).astype(dt)
-            x = x + lin(self.dim, "proj")(out)
+            x = x + _MIXERS[self.mixer](self, x, positions)
 
         with jax.named_scope("mlp"):
-            h = norm("ln2")(x).astype(dt)
+            h = RMSNorm(a.rms_norm_eps, name="ln2")(x).astype(dt)
             if self.dense:
                 h = nn.silu(lin(a.intermediate_size, "gate")(h)) \
                     * lin(a.intermediate_size, "up")(h)
@@ -301,8 +462,8 @@ class MlaBlock(nn.Module):
                 a.n_routed_experts, a.num_experts_per_tok,
                 a.moe_intermediate_size, share=a.expert_share,
                 scaling=a.routed_scaling_factor,
-                n_shared=a.n_shared_experts, compute_dtype=dt,
-                name="moe")(h.reshape(b * s, self.dim))
+                n_shared=a.n_shared_experts, route_eps=a.route_eps,
+                compute_dtype=dt, name="moe")(h.reshape(b * s, self.dim))
             return x + y.reshape(b, s, self.dim), load
 
 
@@ -335,7 +496,7 @@ class MtpModule(nn.Module):
 
     dim: int
     heads: int
-    arch: MlaMoeArch
+    arch: Any
     compute_dtype: Any
     remat: bool
     remat_policy: Optional[str]
@@ -349,8 +510,9 @@ class MtpModule(nn.Module):
                  RMSNorm(eps, name="hnorm")(hidden)], axis=-1).astype(dt)
             x = nn.Dense(self.dim, use_bias=False, dtype=dt,
                          name="eh_proj")(both)
-        cls = nn.remat(MlaBlock, policy=_remat_policy(self.remat_policy)) \
-            if self.remat else MlaBlock
+        cls = nn.remat(DecoderBlock,
+                       policy=_remat_policy(self.remat_policy)) \
+            if self.remat else DecoderBlock
         x, load = cls(self.dim, self.heads, self.arch, False, dt,
                       name="block")(x, positions)
         with jax.named_scope("head"):
@@ -400,13 +562,18 @@ class LMHead(nn.Module):
     rms_eps: Optional[float] = None   # set: the final norm is an RMSNorm
 
     @nn.compact
-    def __call__(self, x, features_only: bool = False):
+    def __call__(self, x, features_only: bool = False,
+                 table: Optional[jax.Array] = None):
+        """``table`` (vocab, dim): a tied head's matrix, the embedding's;
+        the module then has no ``head`` kernel of its own."""
         if self.rms_eps is None:
             x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
         else:
             x = RMSNorm(self.rms_eps, name="lnf")(x)
         if features_only:
             return x
+        if table is not None:
+            return x @ table.astype(jnp.float32).T
         return nn.Dense(self.vocab, use_bias=False, dtype=jnp.float32,
                         name="head")(x)
 
@@ -436,9 +603,11 @@ class TransformerLM(nn.Module):
     #                               most of full remat's memory win at a
     #                               fraction of its recompute cost), or
     #                               "names:flash_out,flash_lse" (_remat_policy)
-    arch: Optional[MlaMoeArch] = None   # set: MlaBlock layers (RMSNorm,
-    #                               rotary latent attention, SwiGLU, shared
-    #                               + routed experts, MTP) instead of Block
+    arch: Optional[Any] = None    # a described architecture (MlaMoeArch,
+    #                               Lfm2MoeArch): DecoderBlock layers
+    #                               (RMSNorm, the mixer arch.mixer(i) names,
+    #                               SwiGLU or routed experts, MTP) instead
+    #                               of Block
 
     @nn.compact
     def __call__(self, tokens, positions, return_features: bool = False,
@@ -469,8 +638,8 @@ class TransformerLM(nn.Module):
         features for the main head, position i predicting token i + 2
         (``next_tokens`` is the window's targets row)."""
         if self.arch is not None:
-            return self._mla_moe(tokens, positions, return_features,
-                                 next_tokens)
+            return self._described(tokens, positions, return_features,
+                                   next_tokens)
         if not ring_ordered:
             tokens, positions, token_mask = ring_order(
                 self.mesh, self.sp_axis, tokens, positions, token_mask)
@@ -497,32 +666,38 @@ class TransformerLM(nn.Module):
         return out
 
     @nn.nowrap
-    def _mla_moe(self, tokens, positions, return_features, next_tokens):
+    def _described(self, tokens, positions, return_features, next_tokens):
+        """The one path of every described architecture: what differs a
+        layer is read from ``arch`` (its mixer, whether its MLP is dense)."""
         a, dt = self.arch, self.compute_dtype
         if self.mesh is not None and self.mesh.shape.get(self.sp_axis,
                                                          1) > 1:
             raise NotImplementedError(
-                "latent attention is not wired to ring attention: a "
-                "sequence-parallel mesh needs the rotary key chunked with "
-                "the K/V it is part of")
+                "a described architecture is not wired to ring attention: "
+                "on a sequence-parallel mesh latent attention needs its "
+                "rotary key chunked with the K/V it is part of, and the "
+                "short convolution needs its two-row halo from the chip "
+                "that holds the rows before")
         embed = EmbedPE(self.vocab, self.dim, dt, sinusoid=False,
                         init_std=1.0, name="embed")
         with jax.named_scope("embed"):
             x = embed(tokens, positions)
-        block_cls = nn.remat(MlaBlock,
+        block_cls = nn.remat(DecoderBlock,
                              policy=_remat_policy(self.remat_policy)) \
-            if self.remat else MlaBlock
+            if self.remat else DecoderBlock
         loads = []
         for i in range(self.layers):
             dense = i < a.first_k_dense_replace
-            x = block_cls(self.dim, self.heads, a, dense, dt,
+            x = block_cls(self.dim, self.heads, a, dense, dt, a.mixer(i),
                           name=f"block{i}")(x, positions)
             if not dense:
                 x, load = x
                 loads.append(load)
         with jax.named_scope("head"):
+            table = embed.variables["params"]["tok"]["embedding"] \
+                if a.tie_word_embeddings else None
             out = LMHead(self.vocab, rms_eps=a.rms_norm_eps,
-                         name="lmhead")(x, return_features)
+                         name="lmhead")(x, return_features, table)
         mtp = None
         if a.num_nextn_predict_layers and next_tokens is not None:
             if a.num_nextn_predict_layers != 1:
@@ -618,8 +793,8 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
                     "Dense head", model.vocab, 2 * xent_block,
                     jnp.dtype(model.compute_dtype).name)
     if model.arch is not None:
-        return _mla_moe_loss(model, params, tokens, targets, positions,
-                             fused_xent, xent_block)
+        return _described_loss(model, params, tokens, targets, positions,
+                               fused_xent, xent_block)
     mutable = ("intermediates",) if model.n_experts > 0 else False
 
     if mutable:
@@ -666,13 +841,23 @@ def mtp_targets(targets):
     return shifted, jnp.broadcast_to(mask, targets.shape)
 
 
-def _mla_moe_loss(model, params, tokens, targets, positions, fused_xent,
-                  xent_block):
+def _head_kernel(model, params):
+    """The (dim, vocab) head matrix of an ``arch`` model: its own leaf, or
+    the embedding's transposed where the two are tied (one leaf, one Adam
+    state; its gradient is the sum of both uses)."""
+    p = params["params"]
+    if model.arch.tie_word_embeddings:
+        return p["embed"]["tok"]["embedding"].T
+    return p["lmhead"]["head"]["kernel"]
+
+
+def _described_loss(model, params, tokens, targets, positions, fused_xent,
+                    xent_block):
     """``(CE_main + mtp_loss_weight * CE_mtp, loads)`` of an ``arch``
     model: both heads are the main head, fused or not alike."""
     out, mtp, loads = model.apply(params, tokens, positions, fused_xent,
                                   next_tokens=targets)
-    w = params["params"]["lmhead"]["head"]["kernel"]
+    w = _head_kernel(model, params)
     dt = model.compute_dtype
 
     def nll(feats, tgt):

@@ -245,9 +245,15 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = False, q_offset: int = 0,
                   kv_offset: int = 0, scale: Optional[float] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Plain-XLA attention over (..., S, D); returns (out, lse in f32)."""
+    """Plain-XLA attention over (..., S, D); returns (out, lse in f32).
+    Grouped-query: ``k`` and ``v`` (..., H_kv, S, D) with ``H_kv`` dividing
+    ``q``'s H; query head h attends to K/V head ``h // (H / H_kv)`` (K and
+    V are repeated here: this is the reference)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if q.ndim >= 3 and k.shape[-3] != q.shape[-3]:
+        group = q.shape[-3] // k.shape[-3]
+        k, v = (jnp.repeat(t, group, axis=-3) for t in (k, v))
     s = jnp.einsum("...qd,...kd->...qk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -389,31 +395,36 @@ def _flash_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
-          interpret):
+          interpret, groups=None):
     """One flash ``pallas_call`` over ``(bh, S, .)`` operands. ``resident``
     says per operand whether its block rides the outer grid axis (it
     stays in VMEM across a row; the outputs all do) or the inner one (it
-    is streamed).
+    is streamed). ``groups`` says per operand how many of the grid's
+    ``bh`` share one of its leading entries (grouped-query attention: K
+    and V are ``(b h_kv, S, d)`` and query head ``bh`` reads entry ``bh //
+    group``, so no repeated K or V is ever in HBM); default 1 throughout.
 
     Grid steps run one after another on the core at ~0.35 us each before
     any work, and a step whose streamed block is new pays its DMA, so the
     fetched block is large and, causal, only live blocks are steps at all
     (PERF.md section 6, PR 26, has what the sizes were chosen from)."""
-    bhs = operands[0].shape[0]
+    bhs = operands[0].shape[0]     # the query side's b h
     q_side = geo.stream == "k"     # which side the outer axis walks
 
-    def block(width, on_outer):
+    def block(width, on_outer, group=1):
         rows = geo.block_q if on_outer == q_side else geo.block_k
+        lead = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
         if causal:
             def index(bh, t, outer, inner, code):
-                return bh, (outer if on_outer else inner)[t], 0
+                return lead(bh), (outer if on_outer else inner)[t], 0
         else:
             def index(bh, o, i):
-                return bh, o if on_outer else i, 0
+                return lead(bh), o if on_outer else i, 0
         return pl.BlockSpec((1, rows, width), index)
 
-    in_specs = [block(x.shape[-1], on_outer)
-                for x, on_outer in zip(operands, resident)]
+    in_specs = [block(x.shape[-1], on_outer, group)
+                for x, on_outer, group in zip(
+                    operands, resident, groups or (1,) * len(operands))]
     out_specs = [block(o.shape[-1], True) for o in out_shape]
     if causal:
         tables = _steps(geo)[:3]
@@ -438,12 +449,13 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
 def _fwd_impl(q, k, v, causal, scale, geo, interpret):
     """Runs the forward kernel; returns (out, lse)."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    h_kv, sk = k.shape[1:3]
     bhs = b * h
     out_f, lse_f = _call(
         functools.partial(_flash_kernel, scale=scale), "ddstore_flash_fwd",
         causal, geo,
-        (q.reshape(bhs, sq, d), k.reshape(bhs, sk, d), v.reshape(bhs, sk, d)),
+        (q.reshape(bhs, sq, d), k.reshape(b * h_kv, sk, d),
+         v.reshape(b * h_kv, sk, d)),
         (True, False, False),
         [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype),
          # lse carries a broadcast 128-lane dim purely so its block is
@@ -453,7 +465,7 @@ def _fwd_impl(q, k, v, causal, scale, geo, interpret):
         [pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running max
          pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running denom
          pltpu.VMEM((geo.block_q, d), jnp.float32)],    # running numerator
-        interpret)
+        interpret, (1, h // h_kv, h // h_kv))
     return out_f.reshape(b, h, sq, d), lse_f[..., 0].reshape(b, h, sq)
 
 
@@ -558,11 +570,12 @@ def _flash_bwd(causal, scale, geos, interpret, res, g):
     # they take their own block shapes.
     _, g_dq, g_dkv = geos
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    h_kv, sk = k.shape[1:3]
+    group = h // h_kv
     bhs = b * h
     qf = q.reshape(bhs, sq, d)
-    kf = k.reshape(bhs, sk, d)
-    vf = v.reshape(bhs, sk, d)
+    kf = k.reshape(b * h_kv, sk, d)
+    vf = v.reshape(b * h_kv, sk, d)
     dof = do.reshape(bhs, sq, d)
     # Per-row residual scalars packed into ONE 128-lane tensor: lane 0
     # carries c = delta - dlse (delta = rowsum(do*o); the lse cotangent
@@ -576,21 +589,27 @@ def _flash_bwd(causal, scale, geos, interpret, res, g):
     dta = jnp.pad(jnp.stack([c, lse.reshape(bhs, sq)], axis=-1),
                   ((0, 0), (0, 0), (0, 126)))
     operands = (qf, kf, vf, dof, dta)
+    groups = (1, group, group, 1, 1)
 
     (dq,) = _call(
         functools.partial(_bwd_dq_kernel, scale=scale), "ddstore_flash_dq",
         causal, g_dq, operands, (True, False, False, True, True),
         [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype)],
-        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret)
+        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret, groups)
+    # dk and dv come out a QUERY head (the grid's bh): a K/V head's are the
+    # sum over its group's, taken outside the kernel in float32.
     dk, dv = _call(
         functools.partial(_bwd_dkv_kernel, scale=scale), "ddstore_flash_dkv",
         causal, g_dkv, operands, (False, True, True, False, False),
         [jax.ShapeDtypeStruct((bhs, sk, d), k.dtype),
          jax.ShapeDtypeStruct((bhs, sk, d), v.dtype)],
         [pltpu.VMEM((g_dkv.block_k, d), jnp.float32),
-         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret)
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret, groups)
+    if group > 1:
+        dk, dv = (t.reshape(b, h_kv, group, sk, d).astype(jnp.float32)
+                  .sum(axis=2).astype(t.dtype) for t in (dk, dv))
+    return (dq.reshape(b, h, sq, d), dk.reshape(b, h_kv, sk, d),
+            dv.reshape(b, h_kv, sk, d))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -647,6 +666,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     ) -> Tuple[jax.Array, jax.Array]:
     """Pallas flash attention over (B, H, S, D); returns (out, lse).
 
+    Grouped-query attention: ``k`` and ``v`` may be (B, H_kv, S, D) with
+    ``H_kv`` dividing H; query head h reads K/V head ``h // (H / H_kv)``
+    through the kernels' index maps (the forward and dq never see a
+    repeated K or V; dkv writes a query head's dk, dv, summed over each
+    group outside the kernel).
+
     Differentiable: the backward pass is the standard recompute-p flash
     backward as two Pallas kernels (dq streaming K/V blocks; dk/dv
     streaming Q blocks), so training never materializes S×S. Sequence
@@ -664,7 +689,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernel under ``utils.profile.counters()["flash_geometry"]``.
     """
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    h_kv, sk = k.shape[1:3]
+    if h % h_kv or v.shape[1] != h_kv:
+        raise ValueError(f"{h} query heads over {h_kv} key and "
+                         f"{v.shape[1]} value heads: the K/V heads must be "
+                         f"alike and divide the query heads")
     fwd, bwd = _default_blocks(causal, sq, sk, d, q_offset, kv_offset)
     # An explicit block_q / block_k bounds all three kernels, as ever.
     fwd = (block_q or fwd[0], block_k or fwd[1])
@@ -709,7 +738,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         profile.count_geometry(
             name, f"{'causal' if causal else 'full'} bh{b * h} "
             f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "
-            f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k}",
+            f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k} kv{b * h_kv}",
             {f: getattr(geo, f) for f in (
                 "pairs_needed", "pairs_computed", "grid_steps",
                 "steps_fetching_dead")})

@@ -71,7 +71,7 @@ from typing import Deque, Dict, Iterator, List
 
 __all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
            "watch_compiles", "count_geometry", "count_moe_layout",
-           "count_ring_geometry", "counters"]
+           "count_mixer_layout", "count_ring_geometry", "counters"]
 
 # One reading of both clocks, taken together: perf_counter_ns (what a phase
 # records; CLOCK_MONOTONIC, as ddtrace) and the epoch clock a trace is
@@ -92,6 +92,8 @@ _watching = False
 _geometry: Dict[str, Dict[str, Dict[str, int]]] = {}
 # Expert layer (its module path) -> what models/moe.py holds and routes.
 _moe_layout: Dict[str, dict] = {}
+# Described layer (its module path) -> what its mixer is and works on.
+_mixer_layout: Dict[str, dict] = {}
 _ring_geometry: Dict[str, dict] = {}
 
 
@@ -207,6 +209,14 @@ def count_moe_layout(layer: str, **counts) -> None:
         _moe_layout[layer] = dict(counts)
 
 
+def count_mixer_layout(layer: str, **counts) -> None:
+    """``models/transformer.py``, while a described layer is traced: its
+    mixer's ``kind`` (``mla``, ``full_attention``, ``conv``), its ``heads``
+    and ``kv_heads`` or its ``taps``, and the ``tokens`` of the call."""
+    with _lock:
+        _mixer_layout[layer] = dict(counts)
+
+
 def count_ring_geometry(call: str, counts: dict) -> None:
     """``parallel/ring_attention.py``, while a ring is traced: ``n``,
     ``chunk_rows``, the ``order`` the sequence lies in, and per ring
@@ -225,7 +235,9 @@ def counters() -> dict:
     neither). ``flash_geometry[kernel][call]``: the causal geometry of
     every flash call traced so far (:func:`count_geometry`).
     ``moe_layout[layer]``: the share of every expert layer traced so far
-    (:func:`count_moe_layout`). ``ring_geometry[call]``: what each ring
+    (:func:`count_moe_layout`). ``mixer_layout[layer]``: the mixer of every
+    described layer traced so far (:func:`count_mixer_layout`).
+    ``ring_geometry[call]``: what each ring
     position of every ring traced so far needs and computes
     (:func:`count_ring_geometry`)."""
     with _lock:
@@ -233,5 +245,7 @@ def counters() -> dict:
                 "flash_geometry": {k: {c: dict(n) for c, n in d.items()}
                                    for k, d in _geometry.items()},
                 "moe_layout": {k: dict(d) for k, d in _moe_layout.items()},
+                "mixer_layout": {k: dict(d)
+                                 for k, d in _mixer_layout.items()},
                 "ring_geometry": {c: dict(d)
                                   for c, d in _ring_geometry.items()}}

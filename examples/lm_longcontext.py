@@ -113,9 +113,12 @@ def main():
                    help="build the model from a description of its "
                         "architecture instead of --vocab/--dim/--layers/"
                         "--experts: the keys of a published config.json "
-                        "(latent attention, shared + routed experts, MTP; "
-                        "e.g. benchmarks/configs/glm47-flash-ep8.json; "
-                        "with --dry-sizes its toy sizes)")
+                        "(latent attention, shared + routed experts, MTP: "
+                        "benchmarks/configs/glm47-flash-ep8.json; gated "
+                        "short convolutions among grouped-query attention, "
+                        "bias-routed experts, a tied head: "
+                        "benchmarks/configs/lfm2-8b-a1b-ep4.json; with "
+                        "--dry-sizes their toy sizes)")
     p.add_argument("--dry-sizes", action="store_true",
                    help="with --config: overlay the file's dry_run block "
                         "(toy widths for the CPU)")
@@ -146,7 +149,7 @@ def main():
     # dp*pp*tp becomes the sequence axis.
     sp = n_dev // (dp * pp * tp)
     if args.config:
-        sp = 1  # latent attention is not wired to ring attention
+        sp = 1  # a described architecture is not wired to ring attention
     axes = {"dp": dp}
     if pp > 1:
         axes["pp"] = pp
@@ -165,9 +168,10 @@ def main():
         n_used *= v
     mesh = make_mesh(axes, jax.local_devices()[:n_used])
 
-    # One description builds either architecture: the dense block (or its
-    # capacity-bound MoeMlp) from the flags, or a published latent-attention
-    # expert model from its config's keys.
+    # One description builds any architecture: the dense block (or its
+    # capacity-bound MoeMlp) from the flags, or a published expert model
+    # (latent attention; short convolutions among grouped-query attention)
+    # from its config's keys.
     desc = dict(vocab=args.vocab, dim=args.dim, heads=args.dim // 32,
                 layers=args.layers, experts=args.experts,
                 moe_top_k=args.moe_top_k)
@@ -282,8 +286,12 @@ def main():
                   + (expert_rows(np.stack(loads)) if loads else ""),
                   flush=True)
             if epoch == 0:
-                # Counted while the step was traced: the pairs each sp
-                # position's flash calls compute.
+                # Counted while the step was traced: what each described
+                # layer mixes with, and the pairs each sp position's flash
+                # calls compute.
+                for layer, mix in profile.counters()["mixer_layout"].items():
+                    print(f"layer {layer}: " + " ".join(
+                        f"{k}={v}" for k, v in mix.items()), flush=True)
                 for call, geo in profile.counters()["ring_geometry"].items():
                     print(f"mesh {dict(mesh.shape)} ring {call}: "
                           f"{geo['order']} order, {geo['chunk_rows']} rows "

@@ -1,7 +1,9 @@
 """Compiles, for a described TPU v5e and at the sizes the benchmark runs,
 what no interpret-mode test can refuse: the three flash kernels at head
-width 256 (VMEM) and at the ring's call shapes on four chips, and the
-expert layer's grouped products (XLA's own ragged-dot kernels). Nothing
+width 256 (VMEM), at the ring's call shapes on four chips and with
+grouped K/V heads, the gated short convolution's two kernels, the expert
+layer's grouped products (XLA's own ragged-dot kernels), and the whole
+step of the ``lfm2-8b-a1b-ep4.s8192.b4`` cell against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
 such test lives in this one file, and the topology is described inside a
 fixture: one process at a time may load the TPU's library
@@ -111,3 +113,94 @@ def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache):
     assert routed_chunk(4096, 4, 8, 64) == 3072     # of 16,384 pairs
     assert text.count('op_name="ragged-dot-none"') in (2 * (3 + 9) - 3,
                                                        2 * (3 + 9))
+
+
+def test_gqa_kernels_lower_with_kv_at_their_own_heads(one_chip,
+                                                      no_compile_cache):
+    """The LFM2 cell's one attention call a step: 32 query heads on 8 K/V
+    heads of 64 at S=8192, b=4. K and V enter the three kernels as (b x 8,
+    S, 64): no operand repeated to 32 heads reaches them."""
+    from ddstore_tpu.ops.attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((4, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 8, 8192, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def f(q, k, v):
+        out, _ = flash_attention(q, k, v, causal=True, interpret=False)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, kv, kv) \
+        .compile().as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln
+             and any(k in ln.split(" = ")[0] for k in (
+                 "ddstore_flash_fwd", "ddstore_flash_dq",
+                 "ddstore_flash_dkv"))]
+    assert len(calls) == 3
+    for ln in calls:
+        operands = ln.split("custom-call(")[1]
+        assert operands.count("bf16[32,8192,64]") >= 2, ln   # k and v
+        assert "bf16[128,8192,64]" in operands, ln           # q
+
+
+def test_short_conv_kernels_lower_for_the_chip(one_chip, no_compile_cache):
+    """A conv layer's call in the LFM2 cell: (4, 8192, 3 x 2048) bfloat16,
+    forward and backward kernels."""
+    from ddstore_tpu.ops.short_conv import gated_short_conv
+
+    x = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip)
+
+    def f(x, w):
+        y = gated_short_conv(x, w, interpret=False)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(x, w).compile() \
+        .as_text()
+    assert "ddstore_short_conv_fwd" in text
+    assert "ddstore_short_conv_bwd" in text
+
+
+def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
+                                          monkeypatch):
+    """``lfm2-8b-a1b-ep4.s8192.b4``'s whole train step at its published
+    widths, 32,768 tokens, compiled for the described chip: 507,820,288
+    parameters, and arguments + temporaries inside the v5e's 16.9 GB (the
+    described compile reads temporaries high: PERF.md section 7). The
+    model asks the backend which attention to run; the test says TPU."""
+    import json
+    import os
+
+    import optax
+
+    from ddstore_tpu.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "lfm2-8b-a1b-ep4.json")) as f:
+        cfg = json.load(f)
+    model = T.lm_from_description(cfg, compute_dtype=jnp.bfloat16)
+    lr = optax.linear_schedule(0.0, cfg["lr"], cfg["lr_warmup_steps"])
+    state = jax.eval_shape(
+        lambda k: T.create_train_state(k, model, lr=lr)[0],
+        jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(state.params)) \
+        == 507_820_288
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    tok = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one_chip)
+    step = T.make_train_step(model, optax.adam(lr))
+    compiled = step.lower(on_chip(state), tok, tok, tok).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 4.3e9 < total < 15e9, total
+    text = compiled.as_text()
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
+                   "ddstore_flash_dkv", "ddstore_short_conv_fwd",
+                   "ddstore_short_conv_bwd", "ragged-dot"):
+        assert kernel in text, kernel
