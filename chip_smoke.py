@@ -362,7 +362,8 @@ def kernel_leg(store, sets, mesh1, cfg, record):
         n = flash_reference_check(cfg["attn_s"], cfg["attn_s_misaligned"])
         say(f"    flash == reference on {jax.default_backend()}: {n} cases "
             f"(outputs and gradients, hd 64 and 128, causal, both ring "
-            f"offsets, S={cfg['attn_s_misaligned']} not a multiple of 16, "
+            f"offsets, sequence-major at hd 256 and at hd 128 on grouped "
+            f"K/V, S={cfg['attn_s_misaligned']} not a multiple of 16, "
             f"cond-of-kernels) in {time.perf_counter() - t0:.1f} s "
             f"including their compiles")
     return facts

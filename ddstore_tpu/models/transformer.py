@@ -331,19 +331,53 @@ def rope(x, positions, theta: float):
 
 
 def _attend(q, k, v):
-    """Causal attention over (B, H, S, D) heads, K and V perhaps fewer
-    heads than Q (grouped-query): ``out`` alone. On the chip the kernel is
-    the only path, as in :class:`Block`: a length it cannot tile raises in
-    ``flash_attention`` rather than sliding to the S x S reference."""
-    if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, causal=True)[0]
-    return mha_reference(q, k, v, causal=True)[0]
+    """Causal attention over (B, S, H, D) heads as the projections write
+    them, K and V perhaps fewer heads than Q (grouped-query): ``(out,
+    layout)``, ``out`` (B, S, H, D) and the layout the kernels took their
+    operands in. Heads of whole lanes go as they lie (``bshd``: nothing is
+    transposed on the way in, out or back); a narrower head is no block of
+    (B, S, H D), so those are transposed and go head-major (``bhsd``). On
+    the chip the kernel is the only path, as in :class:`Block`: a length it
+    cannot tile raises in ``flash_attention`` rather than sliding to the S
+    x S reference, which is what runs elsewhere (``reference``)."""
+    on_chip = jax.default_backend() == "tpu"
+    if on_chip and q.shape[-1] % 128 == 0:
+        return flash_attention(q, k, v, causal=True, layout="bshd")[0], "bshd"
+    attend = flash_attention if on_chip else mha_reference
+    out = attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True)[0]
+    return out.transpose(0, 2, 1, 3), "bhsd" if on_chip else "reference"
+
+
+class _LatentKV(nn.Module):
+    """``kv_b`` of latent attention: one ``(kv_lora_rank, H (nope + v))``
+    kernel in the checkpoint's column order (a head's key columns, then its
+    value columns), applied as two products: ``k_nope`` from the key
+    columns and V from its own. The columns are cut on the weight's side,
+    so V is written where attention reads it and is never a slice of a
+    448-wide activation (nor its gradient half of a concatenation). The
+    leaf, its initialisation and its name are ``nn.Dense``'s."""
+
+    heads: int
+    nope: int
+    v_dim: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, ckv):
+        nh, nope, vd = self.heads, self.nope, self.v_dim
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (ckv.shape[-1], nh * (nope + vd)))
+        w = kernel.astype(self.dtype).reshape(-1, nh, nope + vd)
+        return (ckv @ w[..., :nope].reshape(-1, nh * nope),
+                ckv @ w[..., nope:].reshape(-1, nh * vd))
 
 
 def _mla_mixer(blk: "DecoderBlock", x, positions):
     """Multi-head latent attention computed uncompressed (training: per
     head q = [q_nope | q_rope], k = [k_nope | k_rope], the rotary key one
-    vector a position shared by all heads), norm to output projection."""
+    vector a position shared by all heads), norm to output projection.
+    Heads stay where the projections write them, (B, S, H, D), from the
+    products through attention to ``proj``."""
     b, s, _ = x.shape
     a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
     lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
@@ -353,25 +387,23 @@ def _mla_mixer(blk: "DecoderBlock", x, positions):
         raise NotImplementedError(
             f"v_head_dim={vd} beside a query/key width of {nope + rot}: "
             f"the flash kernels give K and V one width")
-    profile.count_mixer_layout("/".join(blk.path), kind="mla", heads=nh,
-                               kv_heads=nh, tokens=b * s)
     h = norm("ln1")(x).astype(dt)
     cq = norm("q_norm")(lin(a.q_lora_rank, "q_a")(h)).astype(dt)
     q = lin(nh * (nope + rot), "q_b")(cq).reshape(b, s, nh, nope + rot)
     kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
     ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
-    kv = lin(nh * (nope + vd), "kv_b")(ckv).reshape(b, s, nh, nope + vd)
+    k_nope, v = _LatentKV(nh, nope, vd, dt, name="kv_b")(ckv)
     k_rope = rope(kva[..., None, a.kv_lora_rank:], positions, a.rope_theta)
     q = jnp.concatenate(
         [q[..., :nope], rope(q[..., nope:], positions, a.rope_theta)],
         axis=-1)
     k = jnp.concatenate(
-        [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rot))],
-        axis=-1)
-    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, kv[..., nope:]))
-    out = _attend(q, k, v)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * vd).astype(dt)
-    return lin(blk.dim, "proj")(out)
+        [k_nope.reshape(b, s, nh, nope),
+         jnp.broadcast_to(k_rope, (b, s, nh, rot))], axis=-1)
+    out, layout = _attend(q, k, v.reshape(b, s, nh, vd))
+    profile.count_mixer_layout("/".join(blk.path), kind="mla", heads=nh,
+                               kv_heads=nh, tokens=b * s, layout=layout)
+    return lin(blk.dim, "proj")(out.reshape(b, s, nh * vd).astype(dt))
 
 
 def _gqa_mixer(blk: "DecoderBlock", x, positions):
@@ -383,8 +415,6 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
     a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
     nkv, hd = a.num_key_value_heads, blk.dim // blk.heads
     norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
-    profile.count_mixer_layout("/".join(blk.path), kind="full_attention",
-                               heads=nh, kv_heads=nkv, tokens=b * s)
     h = norm("ln1")(x).astype(dt)
     # [W_q | W_k | W_v] as one product
     qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
@@ -392,8 +422,11 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
     q, k, v = jnp.split(qkv, (nh, nh + nkv), axis=2)
     q = rope(norm("q_norm")(q), positions, a.rope_theta).astype(dt)
     k = rope(norm("k_norm")(k), positions, a.rope_theta).astype(dt)
-    out = _attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)))
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * hd).astype(dt)
+    out, layout = _attend(q, k, v)
+    profile.count_mixer_layout("/".join(blk.path), kind="full_attention",
+                               heads=nh, kv_heads=nkv, tokens=b * s,
+                               layout=layout)
+    out = out.reshape(b, s, nh * hd).astype(dt)
     return nn.Dense(blk.dim, use_bias=False, dtype=dt, name="proj")(out)
 
 

@@ -395,37 +395,57 @@ def _flash_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
-          interpret, groups=None):
-    """One flash ``pallas_call`` over ``(bh, S, .)`` operands. ``resident``
-    says per operand whether its block rides the outer grid axis (it
-    stays in VMEM across a row; the outputs all do) or the inner one (it
-    is streamed). ``groups`` says per operand how many of the grid's
-    ``bh`` share one of its leading entries (grouped-query attention: K
-    and V are ``(b h_kv, S, d)`` and query head ``bh`` reads entry ``bh //
+          interpret, groups=None, heads=None):
+    """One flash ``pallas_call``. ``resident`` says per operand whether its
+    block rides the outer grid axis (it stays in VMEM across a row; the
+    outputs all do) or the inner one (it is streamed). ``groups`` says per
+    operand how many of the grid's ``bh`` share one of its heads
+    (grouped-query attention: query head ``bh`` reads K/V head ``bh //
     group``, so no repeated K or V is ever in HBM); default 1 throughout.
+
+    Head-major (``heads`` None) every operand and output is ``(b h, S, .)``
+    and a head is a leading entry. Sequence-major, ``heads`` is the query
+    heads' count: q, k, v, ``do`` and the outputs of their kind are ``(b, S,
+    h d)``, as a projection writes them, and a head is a column block of
+    whole lanes, ``(bh // heads, row block, (bh % heads) // group)``; the
+    128-lane statistics (``lse``, ``dta``) stay ``(b h, S, 128)``. A kernel
+    body sees the same ``(1, rows, d)`` tile either way.
 
     Grid steps run one after another on the core at ~0.35 us each before
     any work, and a step whose streamed block is new pays its DMA, so the
     fetched block is large and, causal, only live blocks are steps at all
     (PERF.md section 6, PR 26, has what the sizes were chosen from)."""
-    bhs = operands[0].shape[0]     # the query side's b h
+    bhs = operands[0].shape[0] * (heads or 1)     # the query side's b h
     q_side = geo.stream == "k"     # which side the outer axis walks
 
-    def block(width, on_outer, group=1):
+    def block(x, on_outer, group=1):
         rows = geo.block_q if on_outer == q_side else geo.block_k
-        lead = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
+        if heads is not None and x.shape[0] != bhs:
+            # (b, S, h d): told from the statistics by the leading
+            # dimension; where they agree (one head) so do the addresses
+            width = x.shape[-1] * group // heads
+            # bh is never negative: lax.div / lax.rem, since `//` and `%`
+            # lower through sign handling that tripled these kernels'
+            # lowering time (0.23 s a forward + backward call for 0.08)
+            lead = lambda bh: jax.lax.div(bh, jnp.int32(heads))
+            col = lambda bh: jax.lax.div(jax.lax.rem(bh, jnp.int32(heads)),
+                                         jnp.int32(group))
+        else:
+            width = x.shape[-1]
+            lead = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
+            col = lambda bh: 0
         if causal:
             def index(bh, t, outer, inner, code):
-                return lead(bh), (outer if on_outer else inner)[t], 0
+                return lead(bh), (outer if on_outer else inner)[t], col(bh)
         else:
             def index(bh, o, i):
-                return lead(bh), o if on_outer else i, 0
+                return lead(bh), o if on_outer else i, col(bh)
         return pl.BlockSpec((1, rows, width), index)
 
-    in_specs = [block(x.shape[-1], on_outer, group)
+    in_specs = [block(x, on_outer, group)
                 for x, on_outer, group in zip(
                     operands, resident, groups or (1,) * len(operands))]
-    out_specs = [block(o.shape[-1], True) for o in out_shape]
+    out_specs = [block(o, True) for o in out_shape]
     if causal:
         tables = _steps(geo)[:3]
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -446,18 +466,34 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
         out_shape=out_shape, interpret=interpret, **params)(*operands)
 
 
-def _fwd_impl(q, k, v, causal, scale, geo, interpret):
-    """Runs the forward kernel; returns (out, lse)."""
-    b, h, sq, d = q.shape
-    h_kv, sk = k.shape[1:3]
+def _dims(q, k, seq_major):
+    """``(b, h, h_kv, sq, sk, d)`` of a call's q and k: ``(B, S, H, D)``
+    sequence-major, ``(B, H, S, D)`` head-major."""
+    b, d = q.shape[0], q.shape[-1]
+    if seq_major:
+        (sq, h), (sk, h_kv) = q.shape[1:3], k.shape[1:3]
+    else:
+        (h, sq), (h_kv, sk) = q.shape[1:3], k.shape[1:3]
+    return b, h, h_kv, sq, sk, d
+
+
+def _flat(x, seq_major):
+    """A kernel's view of a head tensor: ``(b, S, h d)`` or ``(b h, S,
+    d)``, a reshape that moves nothing either way."""
+    return x.reshape(x.shape[:2] + (-1,)) if seq_major \
+        else x.reshape((-1,) + x.shape[2:])
+
+
+def _fwd_impl(q, k, v, causal, scale, geo, interpret, seq_major):
+    """Runs the forward kernel; returns (out as q lies, lse (b, h, S))."""
+    b, h, h_kv, sq, _, d = _dims(q, k, seq_major)
     bhs = b * h
+    qf = _flat(q, seq_major)
     out_f, lse_f = _call(
         functools.partial(_flash_kernel, scale=scale), "ddstore_flash_fwd",
-        causal, geo,
-        (q.reshape(bhs, sq, d), k.reshape(b * h_kv, sk, d),
-         v.reshape(b * h_kv, sk, d)),
+        causal, geo, (qf, _flat(k, seq_major), _flat(v, seq_major)),
         (True, False, False),
-        [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype),
+        [jax.ShapeDtypeStruct(qf.shape, q.dtype),
          # lse carries a broadcast 128-lane dim purely so its block is
          # (block_q, 128)-tile-aligned for the TPU lowering; lane 0 is
          # the value.
@@ -465,8 +501,8 @@ def _fwd_impl(q, k, v, causal, scale, geo, interpret):
         [pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running max
          pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running denom
          pltpu.VMEM((geo.block_q, d), jnp.float32)],    # running numerator
-        interpret, (1, h // h_kv, h // h_kv))
-    return out_f.reshape(b, h, sq, d), lse_f[..., 0].reshape(b, h, sq)
+        interpret, (1, h // h_kv, h // h_kv), h if seq_major else None)
+    return out_f.reshape(q.shape), lse_f[..., 0].reshape(b, h, sq)
 
 
 def _recompute_p(q, k, dta_ref, rows, shift, scale):
@@ -543,13 +579,14 @@ def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, geos, interpret):
-    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, geos, interpret, seq_major):
+    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret, seq_major)
 
 
-def _flash_fwd(q, k, v, causal, scale, geos, interpret):
-    out, lse = _fwd_impl(q, k, v, causal, scale, geos[0], interpret)
+def _flash_fwd(q, k, v, causal, scale, geos, interpret, seq_major):
+    out, lse = _fwd_impl(q, k, v, causal, scale, geos[0], interpret,
+                         seq_major)
     # Named for a rematerialised caller: under
     # ``save_only_these_names("flash_out", "flash_lse")`` the backward
     # pass finds both saved and this kernel does not run a second time
@@ -562,54 +599,62 @@ def _flash_fwd(q, k, v, causal, scale, geos, interpret):
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, geos, interpret, res, g):
+def _flash_bwd(causal, scale, geos, interpret, seq_major, res, g):
     q, k, v, out, lse = res
     do, dlse = g
     # The backward kernels stream different data patterns than the
     # forward (dq: k/v innermost; dkv: the whole q side innermost), so
     # they take their own block shapes.
     _, g_dq, g_dkv = geos
-    b, h, sq, d = q.shape
-    h_kv, sk = k.shape[1:3]
+    b, h, h_kv, sq, sk, d = _dims(q, k, seq_major)
     group = h // h_kv
     bhs = b * h
-    qf = q.reshape(bhs, sq, d)
-    kf = k.reshape(b * h_kv, sk, d)
-    vf = v.reshape(b * h_kv, sk, d)
-    dof = do.reshape(bhs, sq, d)
     # Per-row residual scalars packed into ONE 128-lane tensor: lane 0
     # carries c = delta - dlse (delta = rowsum(do*o); the lse cotangent
     # folds into the same term since ds = p*(dp - delta + dlse)), lane 1
     # carries lse. stack+pad lowers to a single fused 128-lane write —
     # per-lane .at[].set constructions each cost a full-tensor
     # dynamic-update-slice pass (~2 ms/layer on v5e, profiled).
-    delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.reshape(bhs, sq, d).astype(jnp.float32), axis=-1)
+    qf, kf, vf, dof = (_flat(t, seq_major) for t in (q, k, v, do))
+    if seq_major:
+        # what moves to the statistics' order is the (b, S, h) floats,
+        # never do or out
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1).reshape(bhs, sq)
+    else:
+        delta = jnp.sum(dof.astype(jnp.float32)
+                        * out.reshape(bhs, sq, d).astype(jnp.float32),
+                        axis=-1)
     c = delta - dlse.reshape(bhs, sq).astype(jnp.float32)
     dta = jnp.pad(jnp.stack([c, lse.reshape(bhs, sq)], axis=-1),
                   ((0, 0), (0, 0), (0, 126)))
     operands = (qf, kf, vf, dof, dta)
     groups = (1, group, group, 1, 1)
+    heads = h if seq_major else None
 
     (dq,) = _call(
         functools.partial(_bwd_dq_kernel, scale=scale), "ddstore_flash_dq",
         causal, g_dq, operands, (True, False, False, True, True),
-        [jax.ShapeDtypeStruct((bhs, sq, d), q.dtype)],
-        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret, groups)
+        [jax.ShapeDtypeStruct(qf.shape, q.dtype)],
+        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret, groups,
+        heads)
     # dk and dv come out a QUERY head (the grid's bh): a K/V head's are the
     # sum over its group's, taken outside the kernel in float32.
+    at_q_heads = qf.shape[:1] + (sk, qf.shape[2])
     dk, dv = _call(
         functools.partial(_bwd_dkv_kernel, scale=scale), "ddstore_flash_dkv",
         causal, g_dkv, operands, (False, True, True, False, False),
-        [jax.ShapeDtypeStruct((bhs, sk, d), k.dtype),
-         jax.ShapeDtypeStruct((bhs, sk, d), v.dtype)],
+        [jax.ShapeDtypeStruct(at_q_heads, k.dtype),
+         jax.ShapeDtypeStruct(at_q_heads, v.dtype)],
         [pltpu.VMEM((g_dkv.block_k, d), jnp.float32),
-         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret, groups)
+         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret, groups,
+        heads)
     if group > 1:
-        dk, dv = (t.reshape(b, h_kv, group, sk, d).astype(jnp.float32)
-                  .sum(axis=2).astype(t.dtype) for t in (dk, dv))
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h_kv, sk, d),
-            dv.reshape(b, h_kv, sk, d))
+        grouped, axis = ((b, sk, h_kv, group, d), 3) if seq_major \
+            else ((b, h_kv, group, sk, d), 2)
+        dk, dv = (t.reshape(grouped).astype(jnp.float32).sum(axis=axis)
+                  .astype(t.dtype) for t in (dk, dv))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -662,11 +707,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     bwd_blocks: Optional[Tuple[int, int, int, int]] = None,
-                    interpret: Optional[bool] = None
+                    interpret: Optional[bool] = None, layout: str = "bhsd"
                     ) -> Tuple[jax.Array, jax.Array]:
     """Pallas flash attention over (B, H, S, D); returns (out, lse).
 
-    Grouped-query attention: ``k`` and ``v`` may be (B, H_kv, S, D) with
+    ``layout`` says how the caller's q, k and v lie, as an einsum's
+    subscripts would: ``"bhsd"``, or ``"bshd"`` for (B, S, H, D) as a
+    projection writes them. The kernels then read each head as a column
+    block of ``(B, S, H D)`` and write ``out`` and the three gradients the
+    same way, so nothing is transposed on either side; that needs heads of
+    whole lanes (``D % 128 == 0``: narrower, a head is no legal block of
+    that array, and the caller transposes). ``lse`` is (B, H, S) in both.
+
+    Grouped-query attention: ``k`` and ``v`` may have ``H_kv`` heads with
     ``H_kv`` dividing H; query head h reads K/V head ``h // (H / H_kv)``
     through the kernels' index maps (the forward and dq never see a
     repeated K or V; dkv writes a query head's dk, dv, summed over each
@@ -688,12 +741,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     and fetches is ``causal_geometry``'s to say, and is recorded per
     kernel under ``utils.profile.counters()["flash_geometry"]``.
     """
-    b, h, sq, d = q.shape
-    h_kv, sk = k.shape[1:3]
-    if h % h_kv or v.shape[1] != h_kv:
+    if layout not in ("bhsd", "bshd"):
+        raise ValueError(f"layout {layout!r}: 'bhsd' or 'bshd'")
+    seq_major = layout == "bshd"
+    b, h, h_kv, sq, sk, d = _dims(q, k, seq_major)
+    if seq_major and d % _LANES:
+        raise ValueError(
+            f"layout 'bshd' at head width {d}: a head must be whole lanes "
+            f"(a multiple of {_LANES}) to be a block of (B, S, H D); "
+            f"transpose to (B, H, S, D)")
+    if h % h_kv or v.shape != k.shape:
         raise ValueError(f"{h} query heads over {h_kv} key and "
-                         f"{v.shape[1]} value heads: the K/V heads must be "
-                         f"alike and divide the query heads")
+                         f"{v.shape[2 if seq_major else 1]} value heads: "
+                         f"the K/V heads must be alike and divide the "
+                         f"query heads")
     fwd, bwd = _default_blocks(causal, sq, sk, d, q_offset, kv_offset)
     # An explicit block_q / block_k bounds all three kernels, as ever.
     fwd = (block_q or fwd[0], block_k or fwd[1])
@@ -738,9 +799,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         profile.count_geometry(
             name, f"{'causal' if causal else 'full'} bh{b * h} "
             f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "
-            f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k} kv{b * h_kv}",
+            f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k} {layout} "
+            f"kv{b * h_kv}",
             {f: getattr(geo, f) for f in (
                 "pairs_needed", "pairs_computed", "grid_steps",
                 "steps_fetching_dead")})
         geos.append(geo)
-    return _flash(q, k, v, causal, scale, tuple(geos), interpret)
+    return _flash(q, k, v, causal, scale, tuple(geos), interpret, seq_major)

@@ -36,23 +36,30 @@ def _check(name, got, want):
             f"|diff|={worst:.4f}")
 
 
-def _check_case(s, hd, causal, qo, ko):
+def _check_case(s, hd, causal, qo, ko, kv_heads=4, layout="bhsd"):
     kq, kk, kv = jax.random.split(jax.random.key(hd), 3)
     q = jax.random.normal(kq, (1, 4, s, hd), jnp.bfloat16)
-    k = jax.random.normal(kk, (1, 4, s, hd), jnp.bfloat16)
-    v = jax.random.normal(kv, (1, 4, s, hd), jnp.bfloat16)
+    k = jax.random.normal(kk, (1, kv_heads, s, hd), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, kv_heads, s, hd), jnp.bfloat16)
+    # the kernels take the heads as ``layout`` says, the reference (B, H,
+    # S, D): both are differentiated with respect to the latter
+    lay = (lambda t: t.transpose(0, 2, 1, 3)) if layout == "bshd" \
+        else (lambda t: t)
 
-    def lossf(fn):
+    def lossf(fn, lay, **kw):
         def f(q, k, v):
-            out, _ = fn(q, k, v, causal=causal, q_offset=qo, kv_offset=ko)
+            out, _ = fn(lay(q), lay(k), lay(v), causal=causal, q_offset=qo,
+                        kv_offset=ko, **kw)
             return (out.astype(jnp.float32) ** 2).sum()
         return f
 
     loss_f, grads_f = jax.jit(jax.value_and_grad(
-        lossf(flash_attention), argnums=(0, 1, 2)))(q, k, v)
+        lossf(flash_attention, lay, layout=layout),
+        argnums=(0, 1, 2)))(q, k, v)
     loss_r, grads_r = jax.jit(jax.value_and_grad(
-        lossf(mha_reference), argnums=(0, 1, 2)))(q, k, v)
-    tag = f"S={s} hd{hd} causal={causal} off=({qo},{ko})"
+        lossf(mha_reference, lambda t: t), argnums=(0, 1, 2)))(q, k, v)
+    tag = f"S={s} hd{hd} {layout} kv{kv_heads} causal={causal} " \
+          f"off=({qo},{ko})"
     # Loss is a sum over b*h*s*hd squared outputs; compare the mean.
     _check(f"{tag} loss", loss_f / q.size, loss_r / q.size)
     for nm, gf, gr in zip("qkv", grads_f, grads_r):
@@ -62,7 +69,8 @@ def _check_case(s, hd, causal, qo, ko):
 def flash_reference_check(s: int, s_misaligned: int = 0) -> int:
     """Assert flash == reference ON THE CURRENT BACKEND — outputs AND
     gradients, head_dim 64 and 128, at sequence length ``s``: plain,
-    causal, and two offset cases; then the causal ring's first step and
+    causal, and two offset cases; two sequence-major calls (width 256,
+    and width 128 on grouped K/V); then the causal ring's first step and
     its later ones on either side of ``src``. ``s_misaligned``
     (optional) adds one causal case at a length that is a multiple of 8
     but not of the bf16 tile's 16 rows. Returns the number of cases;
@@ -79,6 +87,11 @@ def flash_reference_check(s: int, s_misaligned: int = 0) -> int:
     if s_misaligned:
         _check_case(s_misaligned, 64, True, 0, 0)
         ncases += 1
+    # Sequence-major operands, a head a column block of (B, S, H D): the
+    # latent-attention width, and grouped K/V at the narrowest legal one.
+    _check_case(s, 256, True, 0, 0, layout="bshd")
+    _check_case(s, 128, True, s // 2, s // 2, kv_heads=2, layout="bshd")
+    ncases += 2
 
     # The causal ring's cases (parallel/ring_attention.py): the plain
     # causal call of its first step, then one unmasked kernel over two

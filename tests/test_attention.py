@@ -470,3 +470,78 @@ def test_causal_geometry_is_what_the_old_geometry_was_not():
                                stream)
         assert half.pairs_needed == 128 * 129 // 2
         assert half.pairs_computed == 128 * 128
+
+
+# ---------------------------------------------------------------------------
+# Sequence-major operands: a head is a column block of (B, S, H D).
+# ---------------------------------------------------------------------------
+
+def _both_layouts(d, h_kv, causal, offsets, blocks, h=2, b=2, s=128):
+    """``(out, lse, dq, dk, dv)`` of the same call head-major and
+    sequence-major, the latter's head tensors transposed back."""
+    ks = jax.random.split(jax.random.key(d + h_kv), 5)
+    q, do = (jax.random.normal(kk, (b, h, s, d), jnp.float32)
+             for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, h_kv, s, d), jnp.float32)
+            for kk in ks[2:4])
+    dlse = jax.random.normal(ks[4], (b, h, s), jnp.float32)
+    swap = lambda t: t.transpose(0, 2, 1, 3)
+
+    def run(layout):
+        lay = swap if layout == "bshd" else (lambda t: t)
+
+        def f(q, k, v):
+            out, lse = flash_attention(
+                lay(q), lay(k), lay(v), causal=causal, q_offset=offsets[0],
+                kv_offset=offsets[1], block_q=blocks[0], block_k=blocks[1],
+                layout=layout)
+            out = lay(out)
+            # a wholly masked row's lse is -inf: keep it out of the sum
+            kept = jnp.where(jnp.isfinite(lse), lse, 0.0)
+            return (out * do).sum() + (kept * dlse).sum(), (out, lse)
+
+        (_, (out, lse)), grads = jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, lse) + grads
+
+    return run("bhsd"), run("bshd")
+
+
+def _assert_bit_equal(head_major, seq_major):
+    for name, want, got in zip(("out", "lse", "dq", "dk", "dv"), head_major,
+                               seq_major):
+        assert got.shape == want.shape, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 64)])
+@pytest.mark.parametrize("d", [64, 128, 256])
+@pytest.mark.parametrize("causal,offsets", [(False, (0, 0)), (True, (0, 0)),
+                                            (True, (64, 0))],
+                         ids=["full", "causal", "causal-q64"])
+def test_sequence_major_call_equals_head_major(causal, offsets, d, blocks):
+    """The same three kernels addressed through ``(B, S, H D)``: ``out``,
+    ``lse`` (B, H, S in both) and the three gradients are the head-major
+    call's, bit for bit, since a kernel body sees the same tiles. Width 64
+    is no block of (B, S, H 64): refused by name (the model's ``_attend``
+    sends such heads head-major: ``tests/test_profile.py``)."""
+    if d % 128:
+        with pytest.raises(ValueError, match="head width 64.*whole lanes"):
+            _both_layouts(d, 2, causal, offsets, blocks)
+        return
+    _assert_bit_equal(*_both_layouts(d, 2, causal, offsets, blocks))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_sequence_major_call_carries_grouped_kv(causal):
+    """Two query heads on one K/V head of 128: the K/V column block is
+    ``(bh % H) // group``, and dk, dv are summed over the group as
+    head-major; against that call, bit for bit."""
+    _assert_bit_equal(*_both_layouts(128, 1, causal, (0, 0), (64, 64)))
+
+
+def test_flash_refuses_a_layout_it_does_not_know():
+    q = jnp.zeros((1, 128, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match="layout 'sbhd'"):
+        flash_attention(q, q, q, causal=True, layout="sbhd")

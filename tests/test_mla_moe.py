@@ -149,6 +149,72 @@ def test_mla_alone_against_a_written_out_softmax():
     np.testing.assert_allclose(got[0], want, atol=2e-5)
 
 
+# A latent-attention mixer's leaves, as a ``glm4_moe_lite`` checkpoint names
+# and shapes them (``kv_b`` one matrix, a head's key columns then its value
+# columns), at DESC's widths: 4 heads of 12 | 4 and 16.
+_MIXER_LEAVES = {
+    "ln1/scale": (32,), "q_a/kernel": (32, 24), "q_norm/scale": (24,),
+    "q_b/kernel": (24, 64), "kv_a/kernel": (32, 20), "kv_norm/scale": (16,),
+    "kv_b/kernel": (16, 112), "proj/kernel": (64, 32)}
+_DENSE_LEAVES = {"ln2/scale": (32,), "gate/kernel": (32, 96),
+                 "up/kernel": (32, 96), "down/kernel": (96, 32)}
+_EXPERT_LEAVES = {
+    "ln2/scale": (32,), "moe/router/kernel": (32, 16),
+    "moe/router_bias": (16,), "moe/shared_gate/kernel": (32, 24),
+    "moe/shared_up/kernel": (32, 24), "moe/shared_down/kernel": (24, 32),
+    "moe/w_gate": (2, 32, 24), "moe/w_up": (2, 32, 24),
+    "moe/w_down": (2, 24, 32)}
+
+
+@pytest.mark.parametrize("block,rest", [("block0", _DENSE_LEAVES),
+                                        ("block1", _EXPERT_LEAVES),
+                                        ("block2", _EXPERT_LEAVES),
+                                        ("mtp/block", _EXPERT_LEAVES)])
+def test_a_blocks_parameter_tree_is_the_written_one(built, block, rest):
+    """Paths and shapes of every leaf of a block against the written list:
+    taking V from its own product changed how ``kv_b`` is applied, not
+    what is stored (a checkpoint, Adam's state and the reference's mapping
+    see the same tree)."""
+    _, state, _ = built
+    tree = state.params["params"]
+    for name in block.split("/"):
+        tree = tree[name]
+    got = {"/".join(k.key for k in path): leaf.shape for path, leaf in
+           jax.tree_util.tree_leaves_with_path(tree)}
+    assert got == {**_MIXER_LEAVES, **rest}
+    assert all(leaf.dtype == jnp.float32
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def test_kv_b_is_the_leaf_a_dense_layer_would_draw():
+    """``_LatentKV`` under the name ``kv_b`` draws the kernel ``nn.Dense``
+    draws there, and its two products are that layer's output, the key
+    columns and the value columns of each head."""
+    import flax.linen as nn
+
+    class Split(nn.Module):
+        @nn.compact
+        def __call__(self, c):
+            return T._LatentKV(4, 12, 16, jnp.float32, name="kv_b")(c)
+
+    class Whole(nn.Module):
+        @nn.compact
+        def __call__(self, c):
+            return nn.Dense(112, use_bias=False, name="kv_b")(c)
+
+    c = jax.random.normal(jax.random.key(1), (2, 5, 16), jnp.float32)
+    split, whole = (m.init(jax.random.key(0), c) for m in (Split(), Whole()))
+    np.testing.assert_array_equal(split["params"]["kv_b"]["kernel"],
+                                  whole["params"]["kv_b"]["kernel"])
+    with jax.default_matmul_precision("highest"):
+        k_nope, v = Split().apply(split, c)
+        kv = Whole().apply(whole, c).reshape(2, 5, 4, 28)
+    np.testing.assert_allclose(k_nope.reshape(2, 5, 4, 12), kv[..., :12],
+                               atol=1e-6)
+    np.testing.assert_allclose(v.reshape(2, 5, 4, 16), kv[..., 12:],
+                               atol=1e-6)
+
+
 def test_router_selects_by_score_plus_bias_and_weighs_by_score():
     scores = jnp.asarray([[0.9, 0.8, 0.7, 0.1], [0.2, 0.6, 0.5, 0.4]])
     chosen, w = moe.route_noaux_tc(scores, jnp.zeros(4), 2, 1.8)

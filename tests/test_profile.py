@@ -218,3 +218,51 @@ def test_summary_names_what_it_measures():
         min(1.0, 0.25 / s["total_s"]))
     assert s["stage_enqueue"]["count"] == 0
     assert PipelineMetrics().loader_wait_share == 0.0
+
+
+@pytest.mark.parametrize("backend,head_dim,layout", [
+    ("tpu", 128, "bshd"), ("tpu", 64, "bhsd"), ("cpu", 128, "reference")])
+def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
+                                                   head_dim, layout):
+    """``flash_geometry``'s call key carries the operands' layout and
+    ``mixer_layout`` the one a described layer's kernels were called in:
+    heads of whole lanes go sequence-major, width 64 is transposed and goes
+    head-major, and off the chip the reference runs (the model asks the
+    backend; the test answers, and only traces)."""
+    import flax.linen as nn
+
+    from ddstore_tpu.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    arch = T.Lfm2MoeArch(
+        layer_types=("full_attention",), num_key_value_heads=1,
+        conv_L_cache=3, intermediate_size=16, moe_intermediate_size=8,
+        n_routed_experts=4, num_experts_per_tok=2, first_k_dense_replace=1)
+    # a batch of its own, so that no other case's call has this one's key
+    b, s, nh = (3 if backend == "tpu" else 5), 256, 2
+    class Mixer(nn.Module):        # what a mixer reads of its block
+        dim: int
+        heads: int
+        arch: object
+        compute_dtype: object
+
+        @nn.compact
+        def __call__(self, x, positions):
+            return T._gqa_mixer(self, x, positions)
+
+    mixer = Mixer(nh * head_dim, nh, arch, jnp.float32)
+    x = jax.ShapeDtypeStruct((b, s, nh * head_dim), jnp.float32)
+    pos = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    jax.eval_shape(mixer.init, jax.random.key(0), x, pos)
+    # the layer's key is its module path: empty, for a module on its own
+    mixed = profile.counters()["mixer_layout"][""]
+    assert mixed == dict(kind="full_attention", heads=nh, kv_heads=1,
+                         tokens=b * s, layout=layout)
+    calls = [call for call in
+             profile.counters()["flash_geometry"].get("ddstore_flash_fwd", {})
+             if call.startswith(f"causal bh{b * nh} q{s}+0 k{s}+0 "
+                                f"d{head_dim} ")]
+    if backend == "tpu":
+        assert len(calls) == 1 and calls[0].endswith(f" {layout} kv{b}")
+    else:
+        assert not calls

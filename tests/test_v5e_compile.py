@@ -1,7 +1,8 @@
 """Compiles, for a described TPU v5e and at the sizes the benchmark runs,
 what no interpret-mode test can refuse: the three flash kernels at head
 width 256 (VMEM), at the ring's call shapes on four chips and with
-grouped K/V heads, the gated short convolution's two kernels, the expert
+grouped K/V heads, the latent-attention mixer with the copies XLA puts
+around its kernels, the gated short convolution's two kernels, the expert
 layer's grouped products (XLA's own ragged-dot kernels), and the whole
 step of the ``lfm2-8b-a1b-ep4.s8192.b4`` cell against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
@@ -204,3 +205,70 @@ def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
                    "ddstore_flash_dkv", "ddstore_short_conv_fwd",
                    "ddstore_short_conv_bwd", "ragged-dot"):
         assert kernel in text, kernel
+
+
+@pytest.mark.parametrize("b,s,most", [(8, 2048, 7), (2, 8192, 8)])
+def test_mla_mixer_hands_the_kernels_what_its_products_write(
+        one_chip, no_compile_cache, monkeypatch, b, s, most):
+    """One latent-attention mixer of ``glm47-flash-ep8`` at its published
+    widths (20 heads of 192 | 64 and 256), forward and backward under the
+    cell's ``remat_policy``, at the two cells' shapes. The three kernels
+    take q, k, v (and ``do``) as ``bf16[b,S,5120]``, sequence-major, and fit
+    VMEM; and the module holds at most ``most`` transposing ``copy``
+    instructions of a 20 x 256-wide bfloat16 tensor: q and k after their
+    192 | 64 concatenations (forward and remat's), ``out`` into ``proj``,
+    ``dq`` and ``dk`` into their slices, and at (2, 8192) ``do`` (PERF.md
+    section 6, PR 32: 13 and 11 with head-major kernels and V sliced out of
+    a 448-wide product). The model asks the backend which attention to run;
+    the test says TPU."""
+    import json
+    import math
+    import os
+    import re
+
+    import flax.linen as nn
+
+    from ddstore_tpu.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm47-flash-ep8.json")) as f:
+        cfg = json.load(f)
+    lm = T.lm_from_description(cfg, compute_dtype=jnp.bfloat16)
+
+    class Mixer(nn.Module):
+        dim: int = lm.dim
+        heads: int = lm.heads
+        arch: object = lm.arch
+        compute_dtype: object = jnp.bfloat16
+
+        @nn.compact
+        def __call__(self, x, positions):
+            return x + T._mla_mixer(self, x, positions)
+
+    mixer = Mixer()
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    x = jax.ShapeDtypeStruct((b, s, lm.dim), jnp.float32)
+    pos = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    params = jax.eval_shape(mixer.init, jax.random.key(0), x, pos)
+    policy = T._remat_policy(cfg["remat_policy"])
+
+    def f(p, x, pos):
+        y = jax.checkpoint(mixer.apply, policy=policy)(p, x, pos)
+        return (y ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(
+        *on_chip((params, x, pos))).compile().as_text()
+    wide = f"bf16[{b},{s},5120]"
+    for kernel, operands in (("ddstore_flash_fwd", 3), ("ddstore_flash_dq", 4),
+                             ("ddstore_flash_dkv", 4)):
+        (call,) = [ln for ln in text.splitlines() if "custom-call(" in ln
+                   and kernel in ln.split(" = ")[0]]
+        assert call.split("custom-call(")[1].count(wide) == operands, call
+    copies = [m.group(1) for m in re.finditer(
+        r"= bf16\[([0-9,]+)\]\S* copy\(", text)
+        if math.prod(map(int, m.group(1).split(","))) == b * s * 5120]
+    assert 1 <= len(copies) <= most, copies
