@@ -17,14 +17,19 @@ create_train_state / make_train_step):
                 same store, the lowered step checked for the Mosaic
                 kernels, then flash against the XLA reference (outputs and
                 gradients) on this backend;
-* experts leg — one chip only: the two described expert models at their
+* experts leg — one chip only: the three described expert models at their
                 published widths (latent attention: dense layer + one
                 expert layer + MTP; short convolutions and grouped-query
-                attention: the five-layer pattern; S=2048), loss and every
-                gradient leaf against the plain float32 reference, and the
-                tokens the two route differently; the same again with every
-                pair routed to the held experts (every trip of the routed
-                part's loop, none dropped);
+                attention: the five-layer pattern; Mamba-2, rotary-free
+                attention and ungated experts: the nine one-branch layers;
+                S=2048), loss and every gradient leaf against the plain
+                float32 reference, and the tokens the two route
+                differently; the same again with every pair routed to the
+                held experts (every trip of the routed part's loop, none
+                dropped); then the chunked state-space scan and the biased
+                convolution's kernels alone, outputs and gradients, against
+                the recurrence walked a position at a time and the plain
+                shifted products;
 * ragged leg  — one short epoch of the examples/gnn_molecules.py path;
 * two timings the next issues need, labelled as smoke output.
 
@@ -64,14 +69,20 @@ REAL = dict(dry_run=False, vae_rows=16384, vae_batch=512,
             # read 2.3 % and 2.5 %; the LFM2 pattern's four, 4 of 32 experts
             # a token, 2.3 %, 3.7 %, 5.5 % and 6.2 % (chip, PR 31)
             experts_shape=(1, 2048), experts_tol=(1e-3, 0.3),
-            experts_flips={"glm47-flash-ep8": 0.05, "lfm2-8b-a1b-ep4": 0.10},
+            experts_flips={"glm47-flash-ep8": 0.05, "lfm2-8b-a1b-ep4": 0.10,
+                           "nemotron3-nano-ep16": 0.20},
+            # the scan alone: (S, heads, head width, groups, state, chunk),
+            # the published widths
+            ssd_shape=(2048, 64, 64, 8, 128, 128), ssd_tol=3e-2,
             graphs=256, chain_steps=5, stage_reps=20)
 DRY = dict(dry_run=True, vae_rows=2048, vae_batch=64,
            lm=dict(vocab=512, dim=64, heads=4, layers=2),
            lm_runs=((128, 4, 3), (256, 2, 2)),
            attn_s=128, attn_s_misaligned=136,
            experts_shape=(1, 64), experts_tol=(1e-5, 1e-3),
-           experts_flips={"glm47-flash-ep8": 0.0, "lfm2-8b-a1b-ep4": 0.0},
+           experts_flips={"glm47-flash-ep8": 0.0, "lfm2-8b-a1b-ep4": 0.0,
+                          "nemotron3-nano-ep16": 0.0},
+           ssd_shape=(64, 4, 8, 2, 16, 16), ssd_tol=1e-4,
            graphs=64, chain_steps=3, stage_reps=5)
 # Steps of the one-device VAE run a multi-device run is compared against.
 VAE_REF_STEPS = 5
@@ -384,23 +395,108 @@ def kernel_leg_multichip(store, sets, mesh, mesh1, cfg, record):
 
 
 def experts_leg(cfg, record):
-    """The two described expert architectures at their published widths,
+    """The three described expert architectures at their published widths,
     each the bf16 program against its plain float32 reference: loss, every
     gradient leaf, and how many tokens the two route differently (near-ties
     of the sigmoid scores); then the same with every pair routed to the
     held experts. ``glm47-flash-ep8`` cut to its dense layer, one expert
     layer and the MTP module; ``lfm2-8b-a1b-ep4`` at its five-layer pattern
-    (a dense conv layer, attention and three conv layers with experts)."""
+    (a dense conv layer, attention and three conv layers with experts);
+    ``nemotron3-nano-ep16`` at its nine one-branch layers (four Mamba-2,
+    four of ungated experts, one of rotary-free attention); then that
+    configuration's scan and convolution alone."""
     with leg("experts", record):
         _against_reference(cfg, "glm47-flash-ep8", "mla_moe_lm", layers=2)
         _against_reference(cfg, "lfm2-8b-a1b-ep4", "lfm2_moe_lm")
+        _against_reference(cfg, "nemotron3-nano-ep16", "nemotron_h_lm")
+        _scan_against_recurrence(cfg)
+
+
+def _reference_module(reference):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "smoke_ref_" + reference, os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmarks",
+            "reference", reference + ".py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    return ref
+
+
+def _scan_against_recurrence(cfg):
+    """``ops/ssd.py`` (chunked, bf16 operands on the chip) against the
+    reference's recurrence walked a position at a time in float32, and
+    ``ops/short_conv.py``'s biased convolution kernels against plain
+    shifted products: outputs and every gradient, by the norm of the
+    difference over the reference's norm."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddstore_tpu.ops.short_conv import short_conv
+    from ddstore_tpu.ops.ssd import ssd
+
+    ref = _reference_module("nemotron_h_lm")
+    s, h, p, g, n, chunk = cfg["ssd_shape"]
+    dtype = jnp.float32 if cfg["dry_run"] else jnp.bfloat16
+    rng = np.random.default_rng((SEED, 33))
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    x, B, C = normal(1, s, h, p), normal(1, s, g, n), normal(1, s, g, n)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
+                                        (1, s, h))), jnp.float32)
+    A = -jnp.asarray(rng.uniform(1, 16, (h,)), jnp.float32)
+    D, dy = normal(h), normal(1, s, h, p)
+    low = lambda t: t.astype(dtype)
+    # both are given what the program's operands round to
+    args = (low(x), dt, A, low(B), low(C), D)
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * dy).sum(),
+            argnums=range(6)))(*args)
+
+    def worst(got, want):
+        norm = lambda t: float(jnp.linalg.norm(t.astype(jnp.float32)))
+        return max(norm(a - b.astype(a.dtype)) / norm(b)
+                   for a, b in zip(jax.tree_util.tree_leaves(got),
+                                   jax.tree_util.tree_leaves(want)))
+
+    with jax.default_matmul_precision("highest"):
+        want = run(lambda x, dt, A, B, C, D: ref.scan_recurrence(
+            *(t.astype(jnp.float32) for t in (x, dt, A, B, C, D))))
+    err = worst(run(lambda *a: ssd(*a, chunk)), want)
+    say(f"    ssd S={s} H={h} P={p} G={g} N={n} chunk {chunk} in "
+        f"{jnp.dtype(dtype).name} == the recurrence in float32: output "
+        f"sum and six gradients, worst relative norm of the difference "
+        f"{err:.2e}")
+    if not err <= cfg["ssd_tol"]:
+        raise AssertionError(f"ssd != the recurrence: {err:.2e} (allowed "
+                             f"{cfg['ssd_tol']})")
+    c = h * p + 2 * g * n
+    xc, taps, bias, dyc = normal(1, s, c), normal(4, c), normal(c), \
+        normal(1, s, c)
+
+    def plain(x, taps, bias):
+        z = jnp.pad(x.astype(jnp.float32), ((0, 0), (3, 0), (0, 0)))
+        return jax.nn.silu(bias + sum(
+            taps[j] * jax.lax.slice_in_dim(z, j, j + s, axis=1)
+            for j in range(4)))
+
+    conv = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * dyc).sum(),
+        argnums=(0, 1, 2)))(low(xc), taps, bias)
+    err = worst(conv(short_conv), conv(plain))
+    say(f"    conv + bias + silu kernels over {c} channels == shifted "
+        f"products: worst relative norm of the difference {err:.2e}")
+    if not err <= cfg["ssd_tol"]:
+        raise AssertionError(f"short_conv != shifted products: {err:.2e} "
+                             f"(allowed {cfg['ssd_tol']})")
 
 
 def _against_reference(cfg, config, reference, layers=None):
     """One configuration of ``benchmarks/configs`` (``layers``: cut to that
     depth) against ``benchmarks/reference/<reference>.py``."""
-    import importlib.util
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -415,11 +511,7 @@ def _against_reference(cfg, config, reference, layers=None):
         desc["num_hidden_layers"] = layers
     if cfg["dry_run"]:
         desc.update(desc["dry_run"])
-    spec = importlib.util.spec_from_file_location(
-        "smoke_ref_" + reference, os.path.join(
-            root, "benchmarks", "reference", reference + ".py"))
-    ref = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ref)
+    ref = _reference_module(reference)
     batch, seq = cfg["experts_shape"]
     dtype = jnp.float32 if cfg["dry_run"] else jnp.bfloat16
     model = transformer.lm_from_description(desc, compute_dtype=dtype)

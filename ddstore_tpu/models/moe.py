@@ -33,9 +33,9 @@ Routing details:
 framework covers the full dp/tp/pp/sp/ep set.)
 
 :class:`SharedRoutedMoe` is the other expert layer, the one a published
-width can instantiate: shared + routed SwiGLU experts under ``noaux_tc``
-sigmoid routing with no dropped token, as one expert-parallel chip's
-share (told which experts it holds, it routes over all of them and
+width can instantiate: shared + routed experts (SwiGLU, or ungated
+relu²) under ``noaux_tc`` sigmoid routing with no dropped token, as one
+expert-parallel chip's share (told which experts it holds, it routes over all of them and
 computes its own part). ``MoeMlp`` stays what ``decode.py``,
 ``parallel/pipeline.py`` and ``parallel/tp.py`` build.
 """
@@ -248,15 +248,16 @@ def _from_experts_bwd(res, dy):
 _from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
 
 
-def routed_rows(rows, start, x, weights, order, rank, sizes, w_gate, w_up,
-                w_down):
+def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
     """What the sorted rows ``[start, start + rows)`` (``rows`` static) add
     to the routed experts' part of the layer, (T, d) float32: the weighted
     sum over each token's held pairs among them. ``x`` (T, d) in the
     compute dtype, ``weights`` (T, k), ``order`` the pairs sorted by held
     expert (at least ``start + rows`` long: padded past the last pair),
     ``rank`` (T, k) its inverse, ``sizes`` (held,) the rows of each held
-    expert, the three expert weights as the parameters are. One trip of
+    expert, ``ws`` the expert weights as the parameters are: ``(w_gate,
+    w_up, w_down)`` of SwiGLU experts, ``silu(gate) * up``, or ``(w_up,
+    w_down)`` of ungated ones, ``relu(up)^2``. One trip of
     :func:`_routed`."""
     with jax.named_scope("moe_dispatch"):
         ends = jnp.cumsum(sizes)
@@ -267,10 +268,12 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, w_gate, w_up,
         local = rank - start
         xs = _to_experts(x, head, local, live)
         with jax.named_scope("moe_experts"):
-            w_gate, w_up, w_down = (w.astype(x.dtype)
-                                    for w in (w_gate, w_up, w_down))
-            h = nn.silu(jax.lax.ragged_dot(xs, w_gate, here)) \
-                * jax.lax.ragged_dot(xs, w_up, here)
+            *w_in, w_down = (w.astype(x.dtype) for w in ws)
+            if len(w_in) == 2:
+                h = nn.silu(jax.lax.ragged_dot(xs, w_in[0], here)) \
+                    * jax.lax.ragged_dot(xs, w_in[1], here)
+            else:
+                h = jnp.square(nn.relu(jax.lax.ragged_dot(xs, w_in[0], here)))
             ys = jax.lax.ragged_dot(h, w_down, here)
         return _from_experts(ys, weights, head, local, live)
 
@@ -300,7 +303,7 @@ def _over_live_rows(rows, order, sizes, trip):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(rows, x, weights, order, rank, sizes, w_gate, w_up, w_down):
+def _routed(rows, x, weights, order, rank, sizes, ws):
     """The routed experts' part of the layer, (T, d) float32, over as many
     sorted rows as this step's routing fills: :func:`routed_rows` in trips
     of ``rows`` (static) until every live row is done, so every held pair
@@ -310,8 +313,7 @@ def _routed(rows, x, weights, order, rank, sizes, w_gate, w_up, w_down):
     computes each trip's forward again, as ``nn.remat`` around the block
     does for everything else."""
     return _over_live_rows(rows, order, sizes, lambda order, start: (
-        routed_rows(rows, start, x, weights, order, rank, sizes, w_gate,
-                    w_up, w_down)))
+        routed_rows(rows, start, x, weights, order, rank, sizes, ws)))
 
 
 def _routed_fwd(rows, *args):
@@ -319,27 +321,32 @@ def _routed_fwd(rows, *args):
 
 
 def _routed_bwd(rows, args, dy):
-    x, weights, order, rank, sizes, w_gate, w_up, w_down = args
+    x, weights, order, rank, sizes, ws = args
 
     def back(order, start):
         # float32 for the weights, as they are; x's in x's own type
         return jax.vjp(
-            lambda x, weights, w_gate, w_up, w_down: routed_rows(
-                rows, start, x, weights, order, rank, sizes, w_gate, w_up,
-                w_down), x, weights, w_gate, w_up, w_down)[1](dy)
+            lambda x, weights, ws: routed_rows(
+                rows, start, x, weights, order, rank, sizes, ws),
+            x, weights, ws)[1](dy)
 
-    dx, dweights, *dws = _over_live_rows(rows, order, sizes, back)
-    return (dx, dweights, None, None, None, *dws)
+    dx, dweights, dws = _over_live_rows(rows, order, sizes, back)
+    return (dx, dweights, None, None, None, dws)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class SharedRoutedMoe(nn.Module):
-    """Shared + routed SwiGLU experts with no dropped token, as one
+    """Shared + routed experts with no dropped token, as one
     expert-parallel chip's share: ``(T, d) -> ((T, d), load (n_routed,))``.
     ``n_shared = 0`` is a layer of routed experts alone: no shared
-    parameters and no shared products.
+    parameters and no shared products. ``activation`` is the experts' form,
+    routed and shared alike: ``swiglu`` (three matrices an expert,
+    ``down(silu(gate x) * up x)``) or ``relu2`` (two and no gate,
+    ``down(relu(up x)^2)``: no ``w_gate`` / ``shared_gate`` leaves). The
+    shared expert is ``n_shared * hidden`` wide, or ``shared_hidden`` where
+    its width is a key of its own.
 
     ``share = (which, of)``: this chip is number ``which`` of ``of`` that
     divide the layer's ``n_routed`` experts between them, and holds the
@@ -373,6 +380,8 @@ class SharedRoutedMoe(nn.Module):
     n_shared: int = 1
     route_eps: float = 0.0     # route_noaux_tc's normaliser epsilon
     compute_dtype: Any = jnp.bfloat16
+    activation: str = "swiglu"
+    shared_hidden: Optional[int] = None
 
     @nn.compact
     def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
@@ -382,6 +391,10 @@ class SharedRoutedMoe(nn.Module):
         if e % of or not 0 <= which < of:
             raise ValueError(f"share {self.share} does not divide "
                              f"{e} routed experts")
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation {self.activation!r} is not built "
+                             f"here (only 'swiglu' and 'relu2')")
+        gated = self.activation == "swiglu"
         held = e // of
         first = which * held
         rows = routed_chunk(t, k, held, e)
@@ -392,9 +405,10 @@ class SharedRoutedMoe(nn.Module):
         experts = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
             batch_axis=(0,))
-        w_gate = self.param("w_gate", experts, (held, d, self.hidden))
-        w_up = self.param("w_up", experts, (held, d, self.hidden))
-        w_down = self.param("w_down", experts, (held, self.hidden, d))
+        ws = tuple(self.param(name, experts, shape) for name, shape in (
+            ("w_gate", (held, d, self.hidden)),
+            ("w_up", (held, d, self.hidden)),
+            ("w_down", (held, self.hidden, d)))[0 if gated else 1:])
 
         with jax.named_scope("moe_dispatch"):
             # Router and its statistics in float32.
@@ -417,16 +431,18 @@ class SharedRoutedMoe(nn.Module):
                                 stable=True).astype(jnp.int32)
             rank = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
             sizes = jax.lax.dynamic_slice_in_dim(load, first, held)
-        y = _routed(rows, x.astype(dt), weights, order, rank, sizes,
-                    w_gate, w_up, w_down)
+        y = _routed(rows, x.astype(dt), weights, order, rank, sizes, ws)
 
         if not self.n_shared:
             return y.astype(x.dtype), load
         # The shared expert: the block's dense MLP work, on every chip.
-        wide = self.n_shared * self.hidden
-        hs = nn.silu(nn.Dense(wide, use_bias=False, dtype=dt,
-                              name="shared_gate")(x)) \
-            * nn.Dense(wide, use_bias=False, dtype=dt, name="shared_up")(x)
-        y = y + nn.Dense(d, use_bias=False, dtype=dt,
-                         name="shared_down")(hs).astype(jnp.float32)
+        wide = self.shared_hidden or self.n_shared * self.hidden
+        lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
+                                       name=name)
+        if gated:
+            hs = nn.silu(lin(wide, "shared_gate")(x)) \
+                * lin(wide, "shared_up")(x)
+        else:
+            hs = jnp.square(nn.relu(lin(wide, "shared_up")(x)))
+        y = y + lin(d, "shared_down")(hs).astype(jnp.float32)
         return y.astype(x.dtype), load

@@ -35,7 +35,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import flash_attention, mha_reference
-from ..ops.short_conv import gated_short_conv
+from ..ops.short_conv import gated_short_conv, short_conv
+from ..ops.ssd import ssd
 from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
                                  pipeline_apply,
                                  pipeline_interleaved,
@@ -151,8 +152,11 @@ class MlaMoeArch(NamedTuple):
     (:class:`~.moe.SharedRoutedMoe`).
 
     What the model's one path for described architectures reads of an
-    ``arch`` (this one or :class:`Lfm2MoeArch`): ``mixer(layer)``, and the
-    fields the two name alike."""
+    ``arch`` (this one, :class:`Lfm2MoeArch` or :class:`NemotronHArch`):
+    ``mixer(layer)``, the layer's mixer (a key of ``_MIXERS``) or None
+    where it has none; ``mlp(layer)``, its MLP (``"dense"``, or
+    ``"experts"``: such a layer returns a load vector) or None; and the
+    fields the three name alike."""
 
     q_lora_rank: int
     kv_lora_rank: int
@@ -176,10 +180,18 @@ class MlaMoeArch(NamedTuple):
     #                               (update_router_bias); 0 leaves it alone
     tie_word_embeddings: bool = False   # the head is the embedding's matrix
     route_eps: float = 0.0           # route_noaux_tc's normaliser epsilon
+    expert_activation: str = "swiglu"   # SharedRoutedMoe's ``activation``
+    moe_shared_expert_intermediate_size: Optional[int] = None   # the shared
+    #                               expert's width where it is a key of its
+    #                               own; None: n_shared x the routed width
 
-    def mixer(self, layer: int) -> str:
+    def mixer(self, layer: int) -> Optional[str]:
         """The kind of layer ``layer``'s mixer: a key of ``_MIXERS``."""
         return "mla"
+
+    def mlp(self, layer: int) -> Optional[str]:
+        """The kind of layer ``layer``'s MLP: ``dense`` or ``experts``."""
+        return "dense" if layer < self.first_k_dense_replace else "experts"
 
 
 class Lfm2MoeArch(NamedTuple):
@@ -191,8 +203,10 @@ class Lfm2MoeArch(NamedTuple):
     have a dense SwiGLU MLP and the others ``num_experts_per_tok`` of
     ``n_routed_experts`` routed experts (the router's width: the published
     ``num_experts``), of which this chip holds ``expert_share``'s.
-    ``rms_norm_eps`` is the published ``norm_eps``. The fields
-    :class:`MlaMoeArch` has too mean what they mean there."""
+    ``rms_norm_eps`` is the published ``norm_eps``. Heads are ``head_dim``
+    wide (None: ``dim / heads``), q and k RMS-normed a head (``qk_norm``)
+    and rotated (``rotary``). The fields :class:`MlaMoeArch` has too mean
+    what they mean there, ``mixer`` and ``mlp`` included."""
 
     layer_types: Tuple[str, ...]
     num_key_value_heads: int
@@ -212,9 +226,71 @@ class Lfm2MoeArch(NamedTuple):
     mtp_loss_weight: float = 0.0
     expert_share: Tuple[int, int] = (0, 1)
     bias_update_speed: float = 0.0
+    head_dim: Optional[int] = None
+    qk_norm: bool = True
+    rotary: bool = True
+    expert_activation: str = "swiglu"
+    moe_shared_expert_intermediate_size: Optional[int] = None
 
-    def mixer(self, layer: int) -> str:
+    def mixer(self, layer: int) -> Optional[str]:
         return self.layer_types[layer]
+
+    def mlp(self, layer: int) -> Optional[str]:
+        return "dense" if layer < self.first_k_dense_replace else "experts"
+
+
+class NemotronHArch(NamedTuple):
+    """A Mamba-2 / attention / expert hybrid every layer of which is one
+    branch alone, by the keys of its published ``config.json``
+    (``model_type`` ``nemotron_h``): ``pattern`` (the published
+    ``hybrid_override_pattern``) says a layer what it is: ``M`` a Mamba-2
+    mixer (``mamba_num_heads`` heads of ``mamba_head_dim``, state
+    ``ssm_state_size``, ``n_groups`` groups of B and C, ``conv_kernel``
+    biased taps, scanned in chunks of ``chunk_size``), ``*`` grouped-query
+    attention at ``head_dim`` with no rotary step and no q/k norm, ``E``
+    ``num_experts_per_tok`` of ``n_routed_experts`` ungated relu² experts
+    (the router's width; this chip holds ``expert_share``'s) beside a
+    shared expert ``moe_shared_expert_intermediate_size`` wide. No layer
+    has a second branch. ``rms_norm_eps`` is the published
+    ``layer_norm_epsilon``; ``time_step_*`` bound the step the Mamba
+    layers' ``dt_bias`` is drawn for. The fields :class:`MlaMoeArch` has too
+    mean what they mean there."""
+
+    pattern: str
+    num_key_value_heads: int
+    head_dim: int
+    mamba_num_heads: int
+    mamba_head_dim: int
+    ssm_state_size: int
+    n_groups: int
+    conv_kernel: int
+    chunk_size: int
+    moe_intermediate_size: int
+    moe_shared_expert_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    rms_norm_eps: float = 1e-5
+    route_eps: float = 1e-20         # NemotronHTopkRouter's normaliser
+    tie_word_embeddings: bool = False
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.0
+    expert_share: Tuple[int, int] = (0, 1)
+    bias_update_speed: float = 0.0
+    qk_norm: bool = False
+    rotary: bool = False
+    rope_theta: float = 10000.0      # published, read by nothing
+    expert_activation: str = "relu2"
+
+    def mixer(self, layer: int) -> Optional[str]:
+        return {"M": "mamba2", "*": "full_attention"}.get(self.pattern[layer])
+
+    def mlp(self, layer: int) -> Optional[str]:
+        return "experts" if self.pattern[layer] == "E" else None
 
 
 def _refuse_unless(desc: Mapping[str, Any], built) -> None:
@@ -267,22 +343,52 @@ def _lfm2_arch(desc: Mapping[str, Any]) -> Lfm2MoeArch:
     return Lfm2MoeArch(**fields)
 
 
+def _nemotron_arch(desc: Mapping[str, Any]) -> NemotronHArch:
+    _refuse_unless(desc, (
+        ("mamba_proj_bias", False), ("use_bias", False), ("mlp_bias", False),
+        ("attention_bias", False), ("use_conv_bias", True), ("n_group", 1),
+        ("topk_group", 1), ("norm_topk_prob", True),
+        ("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")))
+    pattern = str(desc["hybrid_override_pattern"])
+    if len(pattern) != int(desc["num_hidden_layers"]):
+        raise ValueError(f"hybrid_override_pattern has {len(pattern)} "
+                         f"layers for num_hidden_layers="
+                         f"{desc['num_hidden_layers']}")
+    for kind in pattern:
+        if kind not in "ME*":
+            raise ValueError(
+                f"hybrid_override_pattern layer {kind!r} is not built here "
+                f"(only 'M', 'E' and '*'; '-' is a dense MLP layer)")
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    fields = {k: desc[k] for k in NemotronHArch._fields if k in desc}
+    fields.update(
+        pattern=pattern,
+        n_routed_experts=int(desc["n_routed_experts"]) * int(ep["chips"]),
+        rms_norm_eps=float(desc["layer_norm_epsilon"]),
+        expert_share=(int(ep["chip"]), int(ep["chips"])))
+    return NemotronHArch(**fields)
+
+
 def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     """A :class:`TransformerLM` from one description of the architecture:
     the dense block's own keys (``vocab``, ``dim``, ``heads``, ``layers``,
     ``mlp_ratio``; ``experts`` / ``moe_top_k`` for the capacity-bound
     ``MoeMlp``), or the keys of a published model's ``config.json``: a
     latent-attention expert model (``q_lora_rank`` present;
-    :class:`MlaMoeArch`) or a short-convolution / grouped-query expert
+    :class:`MlaMoeArch`), a short-convolution / grouped-query expert
     model (``model_type`` ``lfm2_moe``, or ``layer_types`` beside
-    ``conv_L_cache``; :class:`Lfm2MoeArch`). In both, the key that counts
-    the routed experts (``n_routed_experts`` / ``num_experts``) counts the
-    experts held here, of ``expert_parallel = {"chips": n, "chip": i}``
-    chips that share each layer, and the router is ``chips`` times as
-    wide. What a description asks for and is not built raises, naming the
-    key and the value that is. ``kw`` are further ``TransformerLM`` fields
-    (``compute_dtype``, ``mesh``, ``remat``...)."""
-    if desc.get("model_type") == "lfm2_moe" or (
+    ``conv_L_cache``; :class:`Lfm2MoeArch`) or a Mamba-2 / attention /
+    expert hybrid of one-branch layers (``model_type`` ``nemotron_h``;
+    :class:`NemotronHArch`, its layers by ``hybrid_override_pattern``). In
+    all three, the key that counts the routed experts (``n_routed_experts``
+    / ``num_experts``) counts the experts held here, of ``expert_parallel =
+    {"chips": n, "chip": i}`` chips that share each layer, and the router
+    is ``chips`` times as wide. What a description asks for and is not
+    built raises, naming the key and the value that is. ``kw`` are further
+    ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
+    if desc.get("model_type") == "nemotron_h":
+        arch = _nemotron_arch(desc)
+    elif desc.get("model_type") == "lfm2_moe" or (
             "layer_types" in desc and "conv_L_cache" in desc):
         arch = _lfm2_arch(desc)
     elif "q_lora_rank" in desc:
@@ -407,25 +513,35 @@ def _mla_mixer(blk: "DecoderBlock", x, positions):
 
 
 def _gqa_mixer(blk: "DecoderBlock", x, positions):
-    """Grouped-query attention (``Lfm2MoeAttention``): ``heads`` query
-    heads over ``num_key_value_heads`` K/V heads of ``dim / heads``, q and
-    k RMS-normed a head (one learned scale of the head's width each), then
-    rotary on the whole width; K and V go to the kernels as they are."""
+    """Grouped-query attention (``Lfm2MoeAttention``,
+    ``NemotronHAttention``): ``heads`` query heads over
+    ``num_key_value_heads`` K/V heads of the arch's ``head_dim`` (``dim /
+    heads`` where it gives none); where the arch asks, q and k RMS-normed a
+    head (``qk_norm``: one learned scale of the head's width each) and
+    rotated on the whole width (``rotary``); K and V go to the kernels as
+    they are."""
     b, s, _ = x.shape
     a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
-    nkv, hd = a.num_key_value_heads, blk.dim // blk.heads
+    nkv, hd = a.num_key_value_heads, a.head_dim or blk.dim // blk.heads
     norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
     h = norm("ln1")(x).astype(dt)
     # [W_q | W_k | W_v] as one product
     qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
                    name="qkv")(h).reshape(b, s, nh + 2 * nkv, hd)
     q, k, v = jnp.split(qkv, (nh, nh + nkv), axis=2)
-    q = rope(norm("q_norm")(q), positions, a.rope_theta).astype(dt)
-    k = rope(norm("k_norm")(k), positions, a.rope_theta).astype(dt)
+
+    def prepared(t, name):
+        if a.qk_norm:
+            t = norm(name)(t)
+        if a.rotary:
+            t = rope(t, positions, a.rope_theta)
+        return t.astype(dt)
+
+    q, k = prepared(q, "q_norm"), prepared(k, "k_norm")
     out, layout = _attend(q, k, v)
     profile.count_mixer_layout("/".join(blk.path), kind="full_attention",
-                               heads=nh, kv_heads=nkv, tokens=b * s,
-                               layout=layout)
+                               heads=nh, kv_heads=nkv, head_dim=hd,
+                               tokens=b * s, layout=layout)
     out = out.reshape(b, s, nh * hd).astype(dt)
     return nn.Dense(blk.dim, use_bias=False, dtype=dt, name="proj")(out)
 
@@ -452,27 +568,104 @@ def _conv_mixer(blk: "DecoderBlock", x, positions):
         return lin(blk.dim, "out_proj")(y)
 
 
+class _GatedGroupNorm(nn.Module):
+    """``MambaRMSNormGated``, gate before norm: ``y * silu(z)``, RMS-normed
+    over each of ``groups`` equal groups of channels, times one learned
+    scale of all the channels; float32."""
+
+    groups: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, y, z):
+        f32 = jnp.float32
+        y = y.astype(f32) * nn.silu(z.astype(f32))
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
+        grouped = y.reshape(y.shape[:-1] + (self.groups, -1))
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True) + self.eps)
+        return grouped.reshape(y.shape) * scale
+
+
+def _dt_bias_init(a: NemotronHArch):
+    """``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform between
+    the arch's ``time_step_min`` and ``time_step_max``, floored at
+    ``time_step_floor`` (Mamba-2's initialisation)."""
+    def init(key, shape, dtype=jnp.float32):
+        lo, hi = math.log(a.time_step_min), math.log(a.time_step_max)
+        step = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, dtype, lo, hi)), a.time_step_floor)
+        return step + jnp.log(-jnp.expm1(-step))   # softplus's inverse
+    return init
+
+
+def _mamba2_mixer(blk: "DecoderBlock", x, positions):
+    """Mamba-2 (``NemotronHMamba2Mixer``): ``[z | xBC | dt] = W_in h``;
+    ``xBC`` through a causal depthwise convolution of ``conv_kernel``
+    biased taps and silu (:func:`~ddstore_tpu.ops.short_conv.short_conv`;
+    ``conv_taps`` (taps, channels), the last row the current position's),
+    then ``[x | B | C]``; the state-space scan over heads of
+    ``mamba_head_dim`` with ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` (:func:`~ddstore_tpu.ops.ssd.ssd`); the gated group norm
+    and ``W_out``. No position enters: the scan gives order."""
+    a, dt = blk.arch, blk.compute_dtype
+    b, s, _ = x.shape
+    nh, hp, g, n = (a.mamba_num_heads, a.mamba_head_dim, a.n_groups,
+                    a.ssm_state_size)
+    inner, conv = nh * hp, nh * hp + 2 * g * n
+    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
+    profile.count_mixer_layout(
+        "/".join(blk.path), kind="mamba2", heads=nh, head_dim=hp, state=n,
+        groups=g, chunk=a.chunk_size, taps=a.conv_kernel, tokens=b * s)
+    with jax.named_scope("mamba_mixer"):
+        h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
+        z, xbc, step = jnp.split(lin(inner + conv + nh, "in_proj")(h),
+                                 (inner, inner + conv), axis=-1)
+        taps = blk.param(
+            "conv_taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
+            (a.conv_kernel, conv))
+        bias = blk.param("conv_bias", nn.initializers.zeros, (conv,))
+        with jax.named_scope("mamba_conv"):
+            xbc = short_conv(xbc, taps, bias)
+        xs, B, C = jnp.split(xbc, (inner, inner + g * n), axis=-1)
+        step = nn.softplus(step.astype(jnp.float32) + blk.param(
+            "dt_bias", _dt_bias_init(a), (nh,)))
+        A = -jnp.exp(blk.param(
+            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, 1.0, 16.0)), (nh,)))
+        D = blk.param("D", nn.initializers.ones, (nh,))
+        y = ssd(xs.reshape(b, s, nh, hp), step, A, B.reshape(b, s, g, n),
+                C.reshape(b, s, g, n), D, a.chunk_size)
+        y = _GatedGroupNorm(g, a.rms_norm_eps, name="norm")(
+            y.reshape(b, s, inner), z)
+        return lin(blk.dim, "out_proj")(y.astype(dt))
+
+
 # A described layer's mixer, by the name ``arch.mixer(layer)`` gives it:
 # (block, x, positions) -> what the mixer adds to x, under the block's own
 # scope (its submodules are the block's).
 _MIXERS = {"mla": _mla_mixer, "full_attention": _gqa_mixer,
-           "conv": _conv_mixer}
+           "conv": _conv_mixer, "mamba2": _mamba2_mixer}
 
 
 class DecoderBlock(nn.Module):
     """Pre-RMSNorm decoder layer of a described architecture
-    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`): ``x + mixer(norm(x))``
-    with the mixer ``mixer`` names (``_MIXERS``: latent attention, rotary
-    grouped-query attention, gated short convolution), then a SwiGLU MLP
-    (``dense``) or the shared + routed experts. No biases. Returns ``x``,
-    and the expert layer's load vector beside it."""
+    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`, :class:`NemotronHArch`),
+    built of what the arch says the layer has: ``x + mixer(norm(x))`` with
+    the mixer ``mixer`` names (``_MIXERS``: latent attention, grouped-query
+    attention, gated short convolution, Mamba-2; None: no mixer and no
+    ``ln1``), then ``x + mlp(norm(x))`` with a SwiGLU MLP (``mlp`` =
+    ``"dense"``) or the shared + routed experts (``"experts"``; None: no
+    MLP and no ``ln2``). No biases. Returns ``x``, and an expert layer's
+    load vector beside it."""
 
     dim: int
     heads: int
     arch: Any
-    dense: bool
+    mlp: Optional[str]
     compute_dtype: Any
-    mixer: str = "mla"
+    mixer: Optional[str] = "mla"
 
     @nn.compact
     def __call__(self, x, positions):
@@ -481,12 +674,15 @@ class DecoderBlock(nn.Module):
         lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
                                        name=name)
 
-        with jax.named_scope("attn"):
-            x = x + _MIXERS[self.mixer](self, x, positions)
+        if self.mixer is not None:
+            with jax.named_scope("attn"):
+                x = x + _MIXERS[self.mixer](self, x, positions)
+        if self.mlp is None:
+            return x
 
         with jax.named_scope("mlp"):
             h = RMSNorm(a.rms_norm_eps, name="ln2")(x).astype(dt)
-            if self.dense:
+            if self.mlp == "dense":
                 h = nn.silu(lin(a.intermediate_size, "gate")(h)) \
                     * lin(a.intermediate_size, "up")(h)
                 return x + lin(self.dim, "down")(h)
@@ -496,7 +692,9 @@ class DecoderBlock(nn.Module):
                 a.moe_intermediate_size, share=a.expert_share,
                 scaling=a.routed_scaling_factor,
                 n_shared=a.n_shared_experts, route_eps=a.route_eps,
-                compute_dtype=dt, name="moe")(h.reshape(b * s, self.dim))
+                compute_dtype=dt, activation=a.expert_activation,
+                shared_hidden=a.moe_shared_expert_intermediate_size,
+                name="moe")(h.reshape(b * s, self.dim))
             return x + y.reshape(b, s, self.dim), load
 
 
@@ -546,7 +744,7 @@ class MtpModule(nn.Module):
         cls = nn.remat(DecoderBlock,
                        policy=_remat_policy(self.remat_policy)) \
             if self.remat else DecoderBlock
-        x, load = cls(self.dim, self.heads, self.arch, False, dt,
+        x, load = cls(self.dim, self.heads, self.arch, "experts", dt,
                       name="block")(x, positions)
         with jax.named_scope("head"):
             return RMSNorm(eps, name="norm")(x), load
@@ -637,10 +835,10 @@ class TransformerLM(nn.Module):
     #                               fraction of its recompute cost), or
     #                               "names:flash_out,flash_lse" (_remat_policy)
     arch: Optional[Any] = None    # a described architecture (MlaMoeArch,
-    #                               Lfm2MoeArch): DecoderBlock layers
-    #                               (RMSNorm, the mixer arch.mixer(i) names,
-    #                               SwiGLU or routed experts, MTP) instead
-    #                               of Block
+    #                               Lfm2MoeArch, NemotronHArch): DecoderBlock
+    #                               layers (RMSNorm, the mixer arch.mixer(i)
+    #                               and the MLP arch.mlp(i) name, MTP)
+    #                               instead of Block
 
     @nn.compact
     def __call__(self, tokens, positions, return_features: bool = False,
@@ -701,16 +899,20 @@ class TransformerLM(nn.Module):
     @nn.nowrap
     def _described(self, tokens, positions, return_features, next_tokens):
         """The one path of every described architecture: what differs a
-        layer is read from ``arch`` (its mixer, whether its MLP is dense)."""
+        layer is read from ``arch`` (its mixer, its MLP, either perhaps
+        none)."""
         a, dt = self.arch, self.compute_dtype
         if self.mesh is not None and self.mesh.shape.get(self.sp_axis,
                                                          1) > 1:
             raise NotImplementedError(
                 "a described architecture is not wired to ring attention: "
                 "on a sequence-parallel mesh latent attention needs its "
-                "rotary key chunked with the K/V it is part of, and the "
-                "short convolution needs its two-row halo from the chip "
-                "that holds the rows before")
+                "rotary key chunked with the K/V it is part of, grouped "
+                "K/V heads need the ring to rotate them at their own "
+                "count, the short convolutions need their two-row (Mamba's "
+                "three-row) halo from the chip that holds the rows before, "
+                "and the state-space scan needs its carried state from "
+                "that chip")
         embed = EmbedPE(self.vocab, self.dim, dt, sinusoid=False,
                         init_std=1.0, name="embed")
         with jax.named_scope("embed"):
@@ -720,10 +922,10 @@ class TransformerLM(nn.Module):
             if self.remat else DecoderBlock
         loads = []
         for i in range(self.layers):
-            dense = i < a.first_k_dense_replace
-            x = block_cls(self.dim, self.heads, a, dense, dt, a.mixer(i),
+            mlp = a.mlp(i)
+            x = block_cls(self.dim, self.heads, a, mlp, dt, a.mixer(i),
                           name=f"block{i}")(x, positions)
-            if not dense:
+            if mlp == "experts":
                 x, load = x
                 loads.append(load)
         with jax.named_scope("head"):
@@ -919,8 +1121,8 @@ def _described_loss(model, params, tokens, targets, positions, fused_xent,
 def _expert_layers(model: "TransformerLM"):
     """Paths of the expert layers' parameters, in the order of ``loads``."""
     a = model.arch
-    paths = [(f"block{i}", "moe")
-             for i in range(a.first_k_dense_replace, model.layers)]
+    paths = [(f"block{i}", "moe") for i in range(model.layers)
+             if a.mlp(i) == "experts"]
     if a.num_nextn_predict_layers:
         paths.append(("mtp", "block", "moe"))
     return paths

@@ -203,3 +203,154 @@ def gated_short_conv(bcu: jax.Array, taps: jax.Array, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _conv(bcu, taps, _fit_rows(bcu.shape[1], _ROWS), interpret)
+
+
+# ---------------------------------------------------------------------------
+# The plain form: a Mamba-2 layer's convolution (``NemotronHMamba2Mixer``).
+# No gates: ``y = silu(bias + sum_j taps[j] * x[t - (L - 1) + j])``, ``x``
+# zero before the sequence's start, products and the accumulation over the
+# taps in float32, ``y`` back in ``x``'s type. Its least work is bytes too
+# (x read, y written forward; x and dy read, dx written backward), so two
+# kernels of the same build as the gated pair (``ddstore_conv_silu_fwd`` /
+# ``_bwd``): blocks of rows, all channels, the last rows of ``x`` carried in
+# VMEM; the backward fetches the eight rows after its block of ``x`` and
+# ``dy`` and computes their pre-activation again for the transposed taps.
+# Taps and bias travel as one (8, C) block, the bias its last row, and their
+# gradients come back the same way.
+# ---------------------------------------------------------------------------
+
+
+def _pre_activation(w_ref, halo, z, lo, hi, taps):
+    """``bias + sum_j taps[j] * z[t - (L - 1) + j]`` for the rows of ``z``,
+    ``halo`` the ``_HALO`` rows before them."""
+    acc = w_ref[_HALO - 1:_HALO, lo:hi] + w_ref[taps - 1:taps, lo:hi] * z
+    for j in range(taps - 1):
+        acc = acc + w_ref[j:j + 1, lo:hi] * _shifted(halo, z, taps - 1 - j)
+    return acc
+
+
+def _silu_fwd_kernel(x_ref, w_ref, y_ref, tail_ref, *, c, taps):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        tail_ref[:] = jnp.zeros_like(tail_ref)
+
+    for lo, hi in _strips(c):
+        z = x_ref[0, :, lo:hi].astype(jnp.float32)
+        acc = _pre_activation(w_ref, tail_ref[:, lo:hi], z, lo, hi, taps)
+        y_ref[0, :, lo:hi] = (acc * jax.nn.sigmoid(acc)).astype(y_ref.dtype)
+        tail_ref[:, lo:hi] = z[z.shape[0] - _HALO:]
+
+
+def _silu_bwd_kernel(x_ref, dy_ref, xn_ref, dyn_ref, w_ref, dx_ref, dw_ref,
+                     tail_ref, *, c, taps):
+    b, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _():
+        tail_ref[:] = jnp.zeros_like(tail_ref)
+
+    @pl.when((b == 0) & (s == 0))
+    def _():
+        dw_ref[:] = jnp.zeros_like(dw_ref)
+
+    f32 = jnp.float32
+    last = s == pl.num_programs(1) - 1
+
+    def through_silu(dy, acc):
+        sig = jax.nn.sigmoid(acc)
+        return dy * sig * (1.0 + acc * (1.0 - sig))
+
+    for lo, hi in _strips(c):
+        z = x_ref[0, :, lo:hi].astype(f32)
+        tail = tail_ref[:, lo:hi]
+        mine = z[z.shape[0] - _HALO:]
+        dc = through_silu(dy_ref[0, :, lo:hi].astype(f32),
+                          _pre_activation(w_ref, tail, z, lo, hi, taps))
+        # the rows after this block: none after the sequence's last
+        dcn = jnp.where(last, 0.0, through_silu(
+            dyn_ref[0, :, lo:hi].astype(f32), _pre_activation(
+                w_ref, mine, xn_ref[0, :, lo:hi].astype(f32), lo, hi, taps)))
+        dz = w_ref[taps - 1:taps, lo:hi] * dc
+        dw_ref[taps - 1:taps, lo:hi] += jnp.sum(dc * z, axis=0,
+                                                keepdims=True)
+        dw_ref[_HALO - 1:_HALO, lo:hi] += jnp.sum(dc, axis=0, keepdims=True)
+        for j in range(taps - 1):
+            k = taps - 1 - j
+            dz = dz + w_ref[j:j + 1, lo:hi] * _shifted(dcn, dc, -k)
+            dw_ref[j:j + 1, lo:hi] += jnp.sum(
+                dc * _shifted(tail, z, k), axis=0, keepdims=True)
+        dx_ref[0, :, lo:hi] = dz.astype(dx_ref.dtype)
+        tail_ref[:, lo:hi] = mine
+
+
+def _taps_and_bias(taps, bias):
+    f32 = jnp.float32
+    return jnp.concatenate([
+        taps.astype(f32),
+        jnp.zeros((_HALO - 1 - taps.shape[0], taps.shape[1]), f32),
+        bias.astype(f32)[None]])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_silu(x, taps, bias, rows, interpret):
+    return _conv_silu_fwd(x, taps, bias, rows, interpret)[0]
+
+
+def _conv_silu_fwd(x, taps, bias, rows, interpret):
+    b, s, c = x.shape
+    with jax.named_scope("short_conv"):
+        y = pl.pallas_call(
+            functools.partial(_silu_fwd_kernel, c=c, taps=taps.shape[0]),
+            name="ddstore_conv_silu_fwd",
+            grid=(b, s // rows),
+            in_specs=[pl.BlockSpec((1, rows, c), lambda i, j: (i, j, 0)),
+                      pl.BlockSpec((_HALO, c), lambda i, j: (0, 0))],
+            out_specs=pl.BlockSpec((1, rows, c), lambda i, j: (i, j, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, s, c), x.dtype),
+            scratch_shapes=[pltpu.VMEM((_HALO, c), jnp.float32)],
+            interpret=interpret, **_params(interpret))(
+                x, _taps_and_bias(taps, bias))
+    return y, (x, taps, bias)
+
+
+def _conv_silu_bwd(rows, interpret, res, dy):
+    x, taps, bias = res
+    b, s, c = x.shape
+    n = taps.shape[0]
+    per, halos = rows // _HALO, s // _HALO
+    block = pl.BlockSpec((1, rows, c), lambda i, j: (i, j, 0))
+    after = pl.BlockSpec((1, _HALO, c), lambda i, j: (
+        i, jnp.minimum((j + 1) * per, halos - 1), 0))
+    whole = pl.BlockSpec((_HALO, c), lambda i, j: (0, 0))
+    dy = dy.astype(x.dtype)
+    with jax.named_scope("short_conv"):
+        dx, dw = pl.pallas_call(
+            functools.partial(_silu_bwd_kernel, c=c, taps=n),
+            name="ddstore_conv_silu_bwd",
+            grid=(b, s // rows),
+            in_specs=[block, block, after, after, whole],
+            out_specs=[block, whole],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((_HALO, c), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((_HALO, c), jnp.float32)],
+            interpret=interpret, **_params(interpret))(
+                x, dy, x, dy, _taps_and_bias(taps, bias))
+    return dx, dw[:n].astype(taps.dtype), dw[_HALO - 1].astype(bias.dtype)
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def short_conv(x: jax.Array, taps: jax.Array, bias: jax.Array, *,
+               interpret: Optional[bool] = None) -> jax.Array:
+    """``x`` (B, S, C), ``taps`` (L, C) with ``taps[L - 1]`` the current
+    position's, ``bias`` (C,): ``silu`` of the causal depthwise convolution
+    plus ``bias``, (B, S, C) in ``x``'s type. Differentiable in all three.
+    ``S`` must be a multiple of 8 and ``L`` at most 7: the carried halo's
+    rows, less the one the bias travels in."""
+    if not 1 <= taps.shape[0] < _HALO:
+        raise ValueError(f"{taps.shape[0]} taps: the block of taps and "
+                         f"bias holds {_HALO - 1} taps")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _conv_silu(x, taps, bias, _fit_rows(x.shape[1], _ROWS), interpret)

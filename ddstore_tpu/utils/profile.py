@@ -211,8 +211,11 @@ def count_moe_layout(layer: str, **counts) -> None:
 
 def count_mixer_layout(layer: str, **counts) -> None:
     """``models/transformer.py``, while a described layer is traced: its
-    mixer's ``kind`` (``mla``, ``full_attention``, ``conv``), its ``heads``
-    and ``kv_heads`` or its ``taps``, and the ``tokens`` of the call."""
+    mixer's ``kind`` (``mla``, ``full_attention``, ``conv``, ``mamba2``),
+    its ``heads`` and ``kv_heads`` (attention: with ``head_dim`` and the
+    ``layout`` its kernels ran in) or its ``taps`` (a Mamba-2 layer's
+    ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too), and the
+    ``tokens`` of the call."""
     with _lock:
         _mixer_layout[layer] = dict(counts)
 

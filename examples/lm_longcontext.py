@@ -117,8 +117,11 @@ def main():
                         "benchmarks/configs/glm47-flash-ep8.json; gated "
                         "short convolutions among grouped-query attention, "
                         "bias-routed experts, a tied head: "
-                        "benchmarks/configs/lfm2-8b-a1b-ep4.json; with "
-                        "--dry-sizes their toy sizes)")
+                        "benchmarks/configs/lfm2-8b-a1b-ep4.json; one-"
+                        "branch layers of Mamba-2, rotary-free attention "
+                        "and ungated relu² experts: "
+                        "benchmarks/configs/nemotron3-nano-ep16.json; "
+                        "with --dry-sizes their toy sizes)")
     p.add_argument("--dry-sizes", action="store_true",
                    help="with --config: overlay the file's dry_run block "
                         "(toy widths for the CPU)")
@@ -170,7 +173,8 @@ def main():
 
     # One description builds any architecture: the dense block (or its
     # capacity-bound MoeMlp) from the flags, or a published expert model
-    # (latent attention; short convolutions among grouped-query attention)
+    # (latent attention; short convolutions among grouped-query attention;
+    # one-branch layers of Mamba-2, attention and ungated experts)
     # from its config's keys.
     desc = dict(vocab=args.vocab, dim=args.dim, heads=args.dim // 32,
                 layers=args.layers, experts=args.experts,

@@ -557,7 +557,7 @@ def test_a_sequence_parallel_mesh_raises_with_the_halo_named():
     model = T.lm_from_description(DESC, compute_dtype=jnp.float32, mesh=mesh)
     state, _ = T.create_train_state(jax.random.key(0), model)
     tok, tgt, pos = batch(7)
-    with pytest.raises(NotImplementedError, match="two-row halo"):
+    with pytest.raises(NotImplementedError, match="two-row .*halo"):
         T.lm_loss(model, state.params, tok, tgt, pos)
 
 

@@ -103,7 +103,7 @@ def test_mla_alone_against_a_written_out_softmax():
                         intermediate_size=16, moe_intermediate_size=8,
                         n_routed_experts=4, num_experts_per_tok=2,
                         rope_theta=1e4)
-    blk = T.DecoderBlock(16, 2, arch, True, jnp.float32)
+    blk = T.DecoderBlock(16, 2, arch, "dense", jnp.float32)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(1, 5, 16)), jnp.float32)
     pos = jnp.asarray([[3, 4, 5, 6, 7]], jnp.int32)
@@ -428,7 +428,7 @@ def test_trips_of_any_length_add_up_to_the_same(live_share):
     def run(rows):
         def f(x, weights, *ws):
             y = sum(moe.routed_rows(rows, start, x, weights, order, rank,
-                                    sizes, *ws)
+                                    sizes, ws)
                     for start in range(0, t * k, rows))
             return (y * dy).sum(), y
         with jax.default_matmul_precision("highest"):

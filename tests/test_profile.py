@@ -257,7 +257,7 @@ def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
     # the layer's key is its module path: empty, for a module on its own
     mixed = profile.counters()["mixer_layout"][""]
     assert mixed == dict(kind="full_attention", heads=nh, kv_heads=1,
-                         tokens=b * s, layout=layout)
+                         head_dim=head_dim, tokens=b * s, layout=layout)
     calls = [call for call in
              profile.counters()["flash_geometry"].get("ddstore_flash_fwd", {})
              if call.startswith(f"causal bh{b * nh} q{s}+0 k{s}+0 "

@@ -3,8 +3,10 @@ what no interpret-mode test can refuse: the three flash kernels at head
 width 256 (VMEM), at the ring's call shapes on four chips and with
 grouped K/V heads, the latent-attention mixer with the copies XLA puts
 around its kernels, the gated short convolution's two kernels, the expert
-layer's grouped products (XLA's own ragged-dot kernels), and the whole
-step of the ``lfm2-8b-a1b-ep4.s8192.b4`` cell against the chip's memory. Nothing
+layer's grouped products (XLA's own ragged-dot kernels), the Mamba-2
+convolution's two kernels, and the whole step of the
+``lfm2-8b-a1b-ep4.s8192.b4`` and ``nemotron3-nano-ep16.s8192`` cells
+against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
 such test lives in this one file, and the topology is described inside a
 fixture: one process at a time may load the TPU's library
@@ -205,6 +207,80 @@ def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
                    "ddstore_flash_dkv", "ddstore_short_conv_fwd",
                    "ddstore_short_conv_bwd", "ragged-dot"):
         assert kernel in text, kernel
+
+
+def test_conv_silu_kernels_lower_for_the_chip(one_chip, no_compile_cache):
+    """A Mamba-2 layer's convolution in the ``nemotron3-nano-ep16`` cell:
+    (2, 8192, 6144) bfloat16 under four biased taps, forward and backward
+    kernels."""
+    from ddstore_tpu.ops.short_conv import short_conv
+
+    x = jax.ShapeDtypeStruct((2, 8192, 6144), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one_chip)
+
+    def f(x, w, b):
+        y = short_conv(x, w, b, interpret=False)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(x, w, b).compile() \
+        .as_text()
+    assert "ddstore_conv_silu_fwd" in text
+    assert "ddstore_conv_silu_bwd" in text
+
+
+def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """``nemotron3-nano-ep16.s8192``'s whole train step at its published
+    widths, 16,384 tokens, compiled for the described chip: 666,963,456
+    parameters, arguments + temporaries inside the v5e's 16.91 GB; its
+    attention through the sequence-major (``bshd``) kernels at 32 heads of
+    128 with K and V at their own 2 heads; its expert layers through
+    grouped products; its convolutions through their kernels. The model
+    asks the backend which attention to run; the test says TPU."""
+    import json
+    import os
+
+    import optax
+
+    from ddstore_tpu.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron3-nano-ep16.json")) as f:
+        cfg = json.load(f)
+    model = T.lm_from_description(cfg, compute_dtype=jnp.bfloat16)
+    lr = optax.linear_schedule(0.0, cfg["lr"], cfg["lr_warmup_steps"])
+    state = jax.eval_shape(
+        lambda k: T.create_train_state(k, model, lr=lr)[0],
+        jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(state.params)) \
+        == 666_963_456
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    step = T.make_train_step(model, optax.adam(lr))
+    compiled = step.lower(on_chip(state), tok, tok, tok).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 8.0e9 < total < 16.91e9, total
+    text = compiled.as_text()
+    for kernel in ("ddstore_conv_silu_fwd", "ddstore_conv_silu_bwd",
+                   "ragged-dot"):
+        assert kernel in text, kernel
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
+                   "ddstore_flash_dkv"):
+        calls = [ln for ln in text.splitlines() if "custom-call(" in ln
+                 and kernel in ln.split(" = ")[0]]
+        assert calls, kernel
+        for ln in calls:
+            operands = ln.split("custom-call(")[1]
+            assert "bf16[2,8192,4096]" in operands, ln            # q
+            assert operands.count("bf16[2,8192,256]") >= 2, ln    # k and v
 
 
 @pytest.mark.parametrize("b,s,most", [(8, 2048, 7), (2, 8192, 8)])
