@@ -36,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import flash_attention, mha_reference
 from ..ops.short_conv import gated_short_conv, short_conv
-from ..ops.ssd import ssd
+from ..ops.ssd import SCAN as SSD_SCAN, ssd
 from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
                                  pipeline_apply,
                                  pipeline_interleaved,
@@ -616,7 +616,8 @@ def _mamba2_mixer(blk: "DecoderBlock", x, positions):
     lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
     profile.count_mixer_layout(
         "/".join(blk.path), kind="mamba2", heads=nh, head_dim=hp, state=n,
-        groups=g, chunk=a.chunk_size, taps=a.conv_kernel, tokens=b * s)
+        groups=g, chunk=a.chunk_size, taps=a.conv_kernel, tokens=b * s,
+        scan=SSD_SCAN)
     with jax.named_scope("mamba_mixer"):
         h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
         z, xbc, step = jnp.split(lin(inner + conv + nh, "in_proj")(h),

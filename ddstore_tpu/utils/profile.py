@@ -214,8 +214,9 @@ def count_mixer_layout(layer: str, **counts) -> None:
     mixer's ``kind`` (``mla``, ``full_attention``, ``conv``, ``mamba2``),
     its ``heads`` and ``kv_heads`` (attention: with ``head_dim`` and the
     ``layout`` its kernels ran in) or its ``taps`` (a Mamba-2 layer's
-    ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too), and the
-    ``tokens`` of the call."""
+    ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too, and what
+    its ``scan`` ran as: ``pallas``, the kernels of ``ops/ssd.py``), and
+    the ``tokens`` of the call."""
     with _lock:
         _mixer_layout[layer] = dict(counts)
 

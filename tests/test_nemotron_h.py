@@ -248,6 +248,80 @@ def test_ssd_in_bfloat16_keeps_decays_and_states_in_float32():
                                atol=2 ** -6 * scale)
 
 
+def _scan_and_gradients(fn, args, dy):
+    return jax.value_and_grad(
+        lambda *a: (fn(*a).astype(jnp.float32) * dy).sum(),
+        argnums=range(6))(*args)
+
+
+@pytest.mark.parametrize("shape,chunk,low", [
+    (dict(b=1, s=48, h=4, p=8, g=2, n=16), 8, False),
+    (dict(b=1, s=32, h=8, p=4, g=2, n=8), 8, False),
+    (dict(b=1, s=24, h=2, p=8, g=2, n=16), 8, False),
+    (dict(b=1, s=16, h=4, p=8, g=2, n=16), 16, False),
+    (dict(b=1, s=8, h=4, p=8, g=2, n=16), 128, False),
+    (dict(b=2, s=32, h=4, p=8, g=1, n=16), 16, False),
+    (dict(b=1, s=16, h=2, p=128, g=1, n=8), 8, False),
+    (dict(b=2, s=32, h=4, p=8, g=2, n=16), 8, True)],
+    ids=["six-chunks-two-heads-a-group", "four-heads-a-group",
+         "one-head-a-group", "one-chunk", "shorter-than-a-chunk",
+         "batch-of-2-one-group", "a-head-a-lane-tile", "bfloat16"])
+def test_ssd_kernels_match_the_recurrence_in_all_six_gradients(shape, chunk,
+                                                               low):
+    """``ddstore_ssd_fwd`` / ``_bwd`` in interpreter mode: the output and
+    the gradient in ``x, dt, A, B, C, D`` against the recurrence walked a
+    position at a time, by the norm of the difference; in bfloat16 the
+    operands are rounded for both and the decays stay float32."""
+    args = _ssd_inputs(5, **shape)
+    if low:
+        args = tuple(a.astype(jnp.bfloat16) if i in (0, 3, 4) else a
+                     for i, a in enumerate(args))
+    dy = jnp.asarray(np.random.default_rng(6).normal(size=args[0].shape),
+                     jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = _scan_and_gradients(
+            lambda *a: ref.scan_recurrence(
+                *(t.astype(jnp.float32) for t in a)), args, dy)
+        got = _scan_and_gradients(lambda *a: ssd(*a, chunk), args, dy)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert g.shape == w.shape
+        assert np.linalg.norm(g - w) <= (2e-2 if low else 1e-4) \
+            * np.linalg.norm(w)
+
+
+@pytest.mark.parametrize("what", ["row", "group"])
+def test_ssd_carried_state_is_reset_at_each_row_and_group(what):
+    """The state a kernel carries from chunk to chunk in VMEM belongs to
+    one batch row and one group: moving row 0's ``x`` (group 0's ``B``)
+    moves row 0 (group 0's heads), later chunks included, and leaves row 1
+    (group 1's heads) as it was, output and gradient."""
+    x, dt, A, B, C, D = _ssd_inputs(7, b=2, s=32, h=4, p=8, g=2, n=16)
+    dt = dt * 0.05                   # decays slow enough to outlast chunks
+    if what == "row":
+        moved_args = (x.at[0, 3].add(1.0), dt, A, B, C, D)
+        mine, other = (lambda t: t[0]), (lambda t: t[1])
+    else:
+        moved_args = (x, dt, A, B.at[:, 3, 0].add(1.0), C, D)
+        mine, other = (lambda t: t[:, :, :2]), (lambda t: t[:, :, 2:])
+    dx = jax.grad(lambda x, *r: ssd(x, *r, 8).sum())
+    y0, y1 = ssd(x, dt, A, B, C, D, 8), ssd(*moved_args, 8)
+    np.testing.assert_array_equal(other(y0), other(y1))
+    assert (np.abs(mine(y1) - mine(y0)).max((0, 2) if what == "row" else
+                                           (0, 2, 3))[8:] > 0).all()
+    np.testing.assert_array_equal(other(dx(x, dt, A, B, C, D)),
+                                  other(dx(*moved_args)))
+
+
+def test_a_traced_mamba_layer_reports_its_scan_as_the_kernels(built):
+    # counted while the model's init was traced
+    layout = profile.counters()["mixer_layout"]
+    assert [layout[f"block{i}"]["scan"] for i in (0, 2, 4, 7)] \
+        == ["pallas"] * 4
+    assert "scan" not in layout["block5"]
+
+
 # ---------------------------------------------------------------------------
 # The biased convolution.
 # ---------------------------------------------------------------------------
@@ -567,7 +641,7 @@ def test_mixer_layout_counter_says_what_each_layer_mixes(built):
     layout = profile.counters()["mixer_layout"]
     assert layout["block0"] == dict(
         kind="mamba2", heads=8, head_dim=8, state=16, groups=2, chunk=8,
-        taps=4, tokens=layout["block0"]["tokens"])
+        taps=4, tokens=layout["block0"]["tokens"], scan="pallas")
     assert {k: layout["block5"][k]
             for k in ("kind", "heads", "kv_heads", "head_dim")} == dict(
                 kind="full_attention", heads=4, kv_heads=2, head_dim=16)

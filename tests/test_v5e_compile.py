@@ -4,7 +4,7 @@ width 256 (VMEM), at the ring's call shapes on four chips and with
 grouped K/V heads, the latent-attention mixer with the copies XLA puts
 around its kernels, the gated short convolution's two kernels, the expert
 layer's grouped products (XLA's own ragged-dot kernels), the Mamba-2
-convolution's two kernels, and the whole step of the
+convolution's and scan's two kernels each, and the whole step of the
 ``lfm2-8b-a1b-ep4.s8192.b4`` and ``nemotron3-nano-ep16.s8192`` cells
 against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
@@ -230,6 +230,45 @@ def test_conv_silu_kernels_lower_for_the_chip(one_chip, no_compile_cache):
     assert "ddstore_conv_silu_bwd" in text
 
 
+def _mosaic_calls(text, kernel):
+    return [ln for ln in text.splitlines() if "custom-call(" in ln
+            and kernel in ln.split(" = ")[0]]
+
+
+def test_ssd_kernels_lower_for_the_chip_and_keep_the_decay_in_vmem(
+        one_chip, no_compile_cache):
+    """A Mamba-2 layer's scan in the ``nemotron3-nano-ep16`` cell, handed
+    over as the mixer does (the three parts of one ``xBC``): (2, 8192), 64
+    heads of 64 on 8 groups of state 128, chunk 128, bfloat16. Forward and
+    backward kernels lower and fit VMEM, and around them the module holds
+    no float32 buffer of tokens x H x Q elements: the decay matrix, its
+    product with ``C B^T`` and the chunk states stay inside the kernels."""
+    import math
+    import re
+
+    from ddstore_tpu.ops.ssd import ssd
+
+    b, s, h, p, g, n, q = 2, 8192, 64, 64, 8, 128, 128
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    xbc = shape((b, s, h * p + 2 * g * n), jnp.bfloat16)
+    dt, A = shape((b, s, h), jnp.float32), shape((h,), jnp.float32)
+
+    def f(xbc, dt, A, D):
+        x, B, C = jnp.split(xbc, (h * p, h * p + g * n), axis=-1)
+        y = ssd(x.reshape(b, s, h, p), dt, A, B.reshape(b, s, g, n),
+                C.reshape(b, s, g, n), D, q, interpret=False)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3))).lower(
+        xbc, dt, A, A).compile().as_text()
+    assert len(_mosaic_calls(text, "ddstore_ssd_fwd")) == 1
+    assert len(_mosaic_calls(text, "ddstore_ssd_bwd")) == 1
+    largest = max(math.prod(int(d) for d in dims.split(","))
+                  for dims in re.findall(r"f32\[([0-9,]+)\]", text))
+    assert largest < b * s * h * q, largest
+    assert f"bf16[{b},{s // q},{n},{h * p}]" in text     # the chunks' states
+
+
 def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
                                               monkeypatch):
     """``nemotron3-nano-ep16.s8192``'s whole train step at its published
@@ -272,10 +311,13 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
     for kernel in ("ddstore_conv_silu_fwd", "ddstore_conv_silu_bwd",
                    "ragged-dot"):
         assert kernel in text, kernel
+    # a Mamba layer's scan: the forward, remat's second forward (which
+    # writes the chunks' states) and the backward, one kernel each
+    assert len(_mosaic_calls(text, "ddstore_ssd_fwd")) == 2 * 4
+    assert len(_mosaic_calls(text, "ddstore_ssd_bwd")) == 4
     for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
                    "ddstore_flash_dkv"):
-        calls = [ln for ln in text.splitlines() if "custom-call(" in ln
-                 and kernel in ln.split(" = ")[0]]
+        calls = _mosaic_calls(text, kernel)
         assert calls, kernel
         for ln in calls:
             operands = ln.split("custom-call(")[1]
