@@ -324,11 +324,15 @@ def _routed_bwd(rows, args, dy):
     x, weights, order, rank, sizes, ws = args
 
     def back(order, start):
-        # float32 for the weights, as they are; x's in x's own type
-        return jax.vjp(
-            lambda x, weights, ws: routed_rows(
-                rows, start, x, weights, order, rank, sizes, ws),
-            x, weights, ws)[1](dy)
+        # float32 for the weights, as they are; x's in x's own type. The
+        # trip's forward again is the pass ``recompute`` in a trace
+        # (``profile.describe``), its transposes the backward's.
+        with jax.named_scope(profile.RECOMPUTE):
+            pull = jax.vjp(
+                lambda x, weights, ws: routed_rows(
+                    rows, start, x, weights, order, rank, sizes, ws),
+                x, weights, ws)[1]
+        return pull(dy)
 
     dx, dweights, dws = _over_live_rows(rows, order, sizes, back)
     return (dx, dweights, None, None, None, dws)
@@ -439,10 +443,11 @@ class SharedRoutedMoe(nn.Module):
         wide = self.shared_hidden or self.n_shared * self.hidden
         lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
                                        name=name)
-        if gated:
-            hs = nn.silu(lin(wide, "shared_gate")(x)) \
-                * lin(wide, "shared_up")(x)
-        else:
-            hs = jnp.square(nn.relu(lin(wide, "shared_up")(x)))
-        y = y + lin(d, "shared_down")(hs).astype(jnp.float32)
-        return y.astype(x.dtype), load
+        with jax.named_scope("shared_expert"):
+            if gated:
+                hs = nn.silu(lin(wide, "shared_gate")(x)) \
+                    * lin(wide, "shared_up")(x)
+            else:
+                hs = jnp.square(nn.relu(lin(wide, "shared_up")(x)))
+            ys = lin(d, "shared_down")(hs).astype(jnp.float32)
+        return (y + ys).astype(x.dtype), load

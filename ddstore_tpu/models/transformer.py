@@ -93,8 +93,9 @@ class Block(nn.Module):
 
         with jax.named_scope("attn"):
             h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(dt)
-            qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=dt,
-                           name="qkv")(h)
+            with jax.named_scope("mix_in"):
+                qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=dt,
+                               name="qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             to_heads = lambda t: t.reshape(b, s, self.heads, hd).transpose(
                 0, 2, 1, 3)
@@ -115,8 +116,10 @@ class Block(nn.Module):
             else:
                 out, _ = mha_reference(q, k, v, causal=True)
             out = out.transpose(0, 2, 1, 3).reshape(b, s, self.dim).astype(dt)
-            x = x + nn.Dense(self.dim, use_bias=False, dtype=dt,
-                             name="proj")(out)
+            with jax.named_scope("mix_out"):
+                out = nn.Dense(self.dim, use_bias=False, dtype=dt,
+                               name="proj")(out)
+            x = x + out
 
         with jax.named_scope("mlp"):
             h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(dt)
@@ -135,9 +138,12 @@ class Block(nn.Module):
                 self.sow("intermediates", "moe_aux", aux)
                 x = x + y.reshape(b, s, self.dim).astype(dt)
             else:
-                h = nn.Dense(self.mlp_ratio * self.dim, dtype=dt, name="up")(h)
-                h = nn.gelu(h)
-                x = x + nn.Dense(self.dim, dtype=dt, name="down")(h)
+                with jax.named_scope("dense_mlp"):
+                    h = nn.Dense(self.mlp_ratio * self.dim, dtype=dt,
+                                 name="up")(h)
+                    h = nn.gelu(h)
+                    h = nn.Dense(self.dim, dtype=dt, name="down")(h)
+                x = x + h
         return x
 
 
@@ -494,11 +500,15 @@ def _mla_mixer(blk: "DecoderBlock", x, positions):
             f"v_head_dim={vd} beside a query/key width of {nope + rot}: "
             f"the flash kernels give K and V one width")
     h = norm("ln1")(x).astype(dt)
-    cq = norm("q_norm")(lin(a.q_lora_rank, "q_a")(h)).astype(dt)
-    q = lin(nh * (nope + rot), "q_b")(cq).reshape(b, s, nh, nope + rot)
-    kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
-    ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
-    k_nope, v = _LatentKV(nh, nope, vd, dt, name="kv_b")(ckv)
+    with jax.named_scope("mix_in"):
+        cq = lin(a.q_lora_rank, "q_a")(h)
+        with jax.named_scope("mix_norm"):
+            cq = norm("q_norm")(cq).astype(dt)
+        q = lin(nh * (nope + rot), "q_b")(cq).reshape(b, s, nh, nope + rot)
+        kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
+        with jax.named_scope("mix_norm"):
+            ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
+        k_nope, v = _LatentKV(nh, nope, vd, dt, name="kv_b")(ckv)
     k_rope = rope(kva[..., None, a.kv_lora_rank:], positions, a.rope_theta)
     q = jnp.concatenate(
         [q[..., :nope], rope(q[..., nope:], positions, a.rope_theta)],
@@ -509,7 +519,8 @@ def _mla_mixer(blk: "DecoderBlock", x, positions):
     out, layout = _attend(q, k, v.reshape(b, s, nh, vd))
     profile.count_mixer_layout("/".join(blk.path), kind="mla", heads=nh,
                                kv_heads=nh, tokens=b * s, layout=layout)
-    return lin(blk.dim, "proj")(out.reshape(b, s, nh * vd).astype(dt))
+    with jax.named_scope("mix_out"):
+        return lin(blk.dim, "proj")(out.reshape(b, s, nh * vd).astype(dt))
 
 
 def _gqa_mixer(blk: "DecoderBlock", x, positions):
@@ -526,13 +537,15 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
     norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
     h = norm("ln1")(x).astype(dt)
     # [W_q | W_k | W_v] as one product
-    qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
-                   name="qkv")(h).reshape(b, s, nh + 2 * nkv, hd)
+    with jax.named_scope("mix_in"):
+        qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
+                       name="qkv")(h).reshape(b, s, nh + 2 * nkv, hd)
     q, k, v = jnp.split(qkv, (nh, nh + nkv), axis=2)
 
     def prepared(t, name):
         if a.qk_norm:
-            t = norm(name)(t)
+            with jax.named_scope("mix_norm"):
+                t = norm(name)(t)
         if a.rotary:
             t = rope(t, positions, a.rope_theta)
         return t.astype(dt)
@@ -543,7 +556,9 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
                                heads=nh, kv_heads=nkv, head_dim=hd,
                                tokens=b * s, layout=layout)
     out = out.reshape(b, s, nh * hd).astype(dt)
-    return nn.Dense(blk.dim, use_bias=False, dtype=dt, name="proj")(out)
+    with jax.named_scope("mix_out"):
+        return nn.Dense(blk.dim, use_bias=False, dtype=dt,
+                        name="proj")(out)
 
 
 def _conv_mixer(blk: "DecoderBlock", x, positions):
@@ -564,8 +579,11 @@ def _conv_mixer(blk: "DecoderBlock", x, positions):
             "conv_taps", nn.initializers.variance_scaling(
                 1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
             (a.conv_L_cache, blk.dim))
-        y = gated_short_conv(lin(3 * blk.dim, "in_proj")(h), taps)
-        return lin(blk.dim, "out_proj")(y)
+        with jax.named_scope("mix_in"):
+            bcu = lin(3 * blk.dim, "in_proj")(h)
+        y = gated_short_conv(bcu, taps)
+        with jax.named_scope("mix_out"):
+            return lin(blk.dim, "out_proj")(y)
 
 
 class _GatedGroupNorm(nn.Module):
@@ -620,8 +638,9 @@ def _mamba2_mixer(blk: "DecoderBlock", x, positions):
         scan=SSD_SCAN)
     with jax.named_scope("mamba_mixer"):
         h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
-        z, xbc, step = jnp.split(lin(inner + conv + nh, "in_proj")(h),
-                                 (inner, inner + conv), axis=-1)
+        with jax.named_scope("mix_in"):
+            z, xbc, step = jnp.split(lin(inner + conv + nh, "in_proj")(h),
+                                     (inner, inner + conv), axis=-1)
         taps = blk.param(
             "conv_taps", nn.initializers.variance_scaling(
                 1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
@@ -638,9 +657,11 @@ def _mamba2_mixer(blk: "DecoderBlock", x, positions):
         D = blk.param("D", nn.initializers.ones, (nh,))
         y = ssd(xs.reshape(b, s, nh, hp), step, A, B.reshape(b, s, g, n),
                 C.reshape(b, s, g, n), D, a.chunk_size)
-        y = _GatedGroupNorm(g, a.rms_norm_eps, name="norm")(
-            y.reshape(b, s, inner), z)
-        return lin(blk.dim, "out_proj")(y.astype(dt))
+        with jax.named_scope("mix_norm"):
+            y = _GatedGroupNorm(g, a.rms_norm_eps, name="norm")(
+                y.reshape(b, s, inner), z)
+        with jax.named_scope("mix_out"):
+            return lin(blk.dim, "out_proj")(y.astype(dt))
 
 
 # A described layer's mixer, by the name ``arch.mixer(layer)`` gives it:
@@ -684,9 +705,11 @@ class DecoderBlock(nn.Module):
         with jax.named_scope("mlp"):
             h = RMSNorm(a.rms_norm_eps, name="ln2")(x).astype(dt)
             if self.mlp == "dense":
-                h = nn.silu(lin(a.intermediate_size, "gate")(h)) \
-                    * lin(a.intermediate_size, "up")(h)
-                return x + lin(self.dim, "down")(h)
+                with jax.named_scope("dense_mlp"):
+                    h = nn.silu(lin(a.intermediate_size, "gate")(h)) \
+                        * lin(a.intermediate_size, "up")(h)
+                    h = lin(self.dim, "down")(h)
+                return x + h
             from .moe import SharedRoutedMoe
             y, load = SharedRoutedMoe(
                 a.n_routed_experts, a.num_experts_per_tok,
@@ -719,6 +742,16 @@ def _remat_policy(name: Optional[str]):
     return policy
 
 
+def _rematted(cls, module: nn.Module, names, remat: bool,
+              policy: Optional[str]):
+    """``cls``, under ``nn.remat`` with the policy of that name where
+    ``remat``, for ``module``'s blocks ``names``:
+    ``counters()["remat"]`` says of each which it is and what it saves."""
+    for name in names:
+        profile.count_remat("/".join(module.path + (name,)), remat, policy)
+    return nn.remat(cls, policy=_remat_policy(policy)) if remat else cls
+
+
 class MtpModule(nn.Module):
     """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
     section 2.2; layer ``num_hidden_layers`` of a ``glm4_moe`` checkpoint):
@@ -742,9 +775,8 @@ class MtpModule(nn.Module):
                  RMSNorm(eps, name="hnorm")(hidden)], axis=-1).astype(dt)
             x = nn.Dense(self.dim, use_bias=False, dtype=dt,
                          name="eh_proj")(both)
-        cls = nn.remat(DecoderBlock,
-                       policy=_remat_policy(self.remat_policy)) \
-            if self.remat else DecoderBlock
+        cls = _rematted(DecoderBlock, self, ("block",), self.remat,
+                        self.remat_policy)
         x, load = cls(self.dim, self.heads, self.arch, "experts", dt,
                       name="block")(x, positions)
         with jax.named_scope("head"):
@@ -878,11 +910,9 @@ class TransformerLM(nn.Module):
         with jax.named_scope("embed"):
             x = EmbedPE(self.vocab, self.dim, self.compute_dtype,
                         name="embed")(tokens, positions)
-        if self.remat:
-            block_cls = nn.remat(Block,
-                                 policy=_remat_policy(self.remat_policy))
-        else:
-            block_cls = Block
+        block_cls = _rematted(
+            Block, self, [f"block{i}" for i in range(self.layers)],
+            self.remat, self.remat_policy)
         for i in range(self.layers):
             x = block_cls(self.dim, self.heads, self.mlp_ratio,
                           self.compute_dtype, self.mesh, self.sp_axis,
@@ -918,9 +948,9 @@ class TransformerLM(nn.Module):
                         init_std=1.0, name="embed")
         with jax.named_scope("embed"):
             x = embed(tokens, positions)
-        block_cls = nn.remat(DecoderBlock,
-                             policy=_remat_policy(self.remat_policy)) \
-            if self.remat else DecoderBlock
+        block_cls = _rematted(
+            DecoderBlock, self, [f"block{i}" for i in range(self.layers)],
+            self.remat, self.remat_policy)
         loads = []
         for i in range(self.layers):
             mlp = a.mlp(i)

@@ -30,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils.profile import RECOMPUTE
+
 NEG_INF = float("-inf")
 
 
@@ -132,7 +134,9 @@ def _bwd(block, compute_dtype, res, g):
     dt = compute_dtype or x.dtype
 
     def body(dx, i):
-        lg = _logits_block(x, wp, i, block, v, compute_dtype)
+        # the forward's product again: the pass ``recompute`` in a trace
+        with jax.named_scope(RECOMPUTE):
+            lg = _logits_block(x, wp, i, block, v, compute_dtype)
         p = jnp.exp(lg - lse[:, None])  # softmax block; 0 at padded cols
         t_local = targets - i * block
         in_blk = (t_local >= 0) & (t_local < block)

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..utils.profile import RECOMPUTE
+
 __all__ = ["pipeline_apply", "pipeline_1f1b", "pipeline_interleaved",
            "pipeline_interleaved_1f1b",
            "stack_stage_params", "interleave_stage_params",
@@ -467,7 +469,8 @@ def pipeline_interleaved_1f1b(stage_fn: Callable, loss_fn: Callable,
             my_b = sel(params, wc)
             cot_in = jnp.where((d == s - 1) & (w == v - 1), dy_last,
                                bwd_buf).astype(y.dtype)
-            _, svjp = jax.vjp(stage_fn, my_b, a_stash)
+            with jax.named_scope(RECOMPUTE):   # the stage's forward again
+                _, svjp = jax.vjp(stage_fn, my_b, a_stash)
             if with_aux:
                 side_cot = jnp.where(active_b, aux_weight / m, 0.0)
                 dmy, da = svjp((cot_in, side_cot.astype(jnp.float32)))

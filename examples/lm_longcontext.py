@@ -303,6 +303,19 @@ def main():
                           f"{geo['pairs_computed']} of needed "
                           f"{geo['pairs_needed']}, largest over mean "
                           f"{geo['max_over_mean']:.2f}", flush=True)
+                # Which blocks compute their forward twice, what the
+                # devices hold (where a runtime counts it), and what was
+                # compiled against what the cache had.
+                counted = profile.counters()
+                for block, kept in counted["remat"].items():
+                    print(f"block {block}: " + " ".join(
+                        f"{k}={v}" for k, v in kept.items()), flush=True)
+                for dev, mem in counted.get("memory", {}).items():
+                    print(f"memory {dev}: " + " ".join(
+                        f"{k}={v}" for k, v in mem.items()), flush=True)
+                print("compile cache: " + " ".join(
+                    f"{k}={v}" for k, v in counted["compile_cache"].items()),
+                    flush=True)
     if args.generate > 0 and store.rank == 0:
         # KV-cached greedy continuation of the first window's prefix —
         # on a learned repeated-pattern corpus the continuation should

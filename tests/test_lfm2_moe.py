@@ -573,3 +573,29 @@ def test_mixer_layout_counter_says_what_each_layer_mixes(built):
     assert {k: moe_layout["block1/moe"][k]
             for k in ("held", "of", "first", "top_k")} == dict(
                 held=2, of=8, first=2, top_k=4)
+
+
+# -- the step by kind of work and by pass (ISSUE 35) ------------------------
+
+
+def test_the_step_by_kind_of_work_and_pass(monkeypatch):
+    """The conv and attention mixers' projections, the q/k norms and the
+    dense layer's MLP under names of their own, forward, recomputed under
+    ``nn.remat`` and transposed; the convolution's kernels by their own."""
+    from test_transformer import (EMITS, passes_of, replayed_products,
+                                  step_names)
+
+    model = T.lm_from_description(
+        DESC, compute_dtype=jnp.float32, remat=True,
+        remat_policy="names:flash_out,flash_lse")
+    found, entered, op_names = step_names(monkeypatch, model, B, S)
+    assert entered == EMITS["lfm2_moe"]
+    every = {"forward", "recompute", "backward"}
+    for scope in ("mix_in", "mix_norm", "mix_out", "dense_mlp",
+                  "moe_dispatch", "moe_experts"):
+        assert passes_of(found, scope) == every, scope
+    assert "shared_expert" not in {kind for kind, _ in found}
+    assert passes_of(found, "ddstore_short_conv_fwd") == {"forward",
+                                                          "recompute"}
+    assert passes_of(found, "ddstore_short_conv_bwd") == {"backward"}
+    assert replayed_products(op_names)

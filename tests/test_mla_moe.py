@@ -653,3 +653,38 @@ def test_flash_at_head_width_256_matches_the_reference(s):
     geo = profile.counters()["flash_geometry"]["ddstore_flash_dkv"]
     call = next(c for c in geo if f"q{s}+0" in c and "d256" in c)
     assert "blocks 1024x1024 sub 128x256" in call
+
+
+# -- the step by kind of work and by pass (ISSUE 35) ------------------------
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_step_by_kind_of_work_and_pass(monkeypatch, remat):
+    """Latent attention's projection chain, its latent norms, the dense
+    layer's MLP and the shared expert under names of their own, in every
+    pass; the routed part's replay of its forward under ``recompute`` even
+    where no block is rematerialised."""
+    from test_transformer import (EMITS, passes_of, replayed_products,
+                                  step_names)
+
+    kw = dict(remat=True, remat_policy="names:flash_out,flash_lse") \
+        if remat else {}
+    model = T.lm_from_description(DESC, compute_dtype=jnp.float32, **kw)
+    found, entered, op_names = step_names(monkeypatch, model, B, S)
+    assert entered == EMITS["mla_moe"]
+    again = {"recompute"} if remat else set()
+    for scope in ("mix_in", "mix_norm", "mix_out", "dense_mlp",
+                  "shared_expert"):
+        assert passes_of(found, scope) == {"forward", "backward"} | again, \
+            scope
+    for scope in ("moe_dispatch", "moe_experts"):
+        assert passes_of(found, scope) == {"forward", "recompute",
+                                           "backward"}, scope
+    # (unrematerialised, XLA shares the forward's products with the replay
+    # and only the replay's cheaper operations stay under the marker)
+    assert replayed_products(op_names) or not remat
+    counted = profile.counters()["remat"]
+    assert set(counted) >= {"block0", "block1", "block2", "mtp/block"}
+    assert counted["mtp/block"] == counted["block0"] == {
+        "remat": remat, "policy": kw.get("remat_policy"),
+        "saved": ["flash_out", "flash_lse"] if remat else []}

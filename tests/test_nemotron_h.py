@@ -676,3 +676,35 @@ def test_the_older_described_models_keep_their_leaves_and_losses(
                     for p, v in flat)
     assert hashlib.sha256("\n".join(listed).encode()).hexdigest()[:16] \
         == names
+
+
+# -- the step by kind of work and by pass (ISSUE 35) ------------------------
+
+
+def test_the_step_by_kind_of_work_and_pass(monkeypatch):
+    """The Mamba and attention mixers' projections, the gated norm and the
+    shared expert under names of their own, forward, recomputed under
+    ``nn.remat`` and transposed (a one-branch layer ends in its output
+    projection, which nothing needs a second time); the scan's and the
+    convolution's kernels by their own."""
+    from test_transformer import (EMITS, passes_of, replayed_products,
+                                  step_names)
+
+    model = T.lm_from_description(
+        DESC, compute_dtype=jnp.float32, remat=True,
+        remat_policy="names:flash_out,flash_lse")
+    found, entered, op_names = step_names(monkeypatch, model, B, S)
+    assert entered == EMITS["nemotron_h"]
+    every = {"forward", "recompute", "backward"}
+    for scope in ("mix_in", "mix_norm", "shared_expert", "moe_dispatch",
+                  "moe_experts"):
+        assert passes_of(found, scope) == every, scope
+    assert passes_of(found, "mix_out") == {"forward", "backward"}
+    assert "dense_mlp" not in {kind for kind, _ in found}
+    for kernel in ("ddstore_ssd_fwd", "ddstore_conv_silu_fwd"):
+        assert passes_of(found, kernel) == {"forward", "recompute"}, kernel
+    for kernel in ("ddstore_ssd_bwd", "ddstore_conv_silu_bwd"):
+        assert passes_of(found, kernel) == {"backward"}, kernel
+    assert replayed_products(op_names)
+    assert profile.counters()["remat"]["block8"]["saved"] == [
+        "flash_out", "flash_lse"]

@@ -5,6 +5,8 @@ no-ops without an active trace)."""
 import collections
 import glob
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -195,12 +197,13 @@ def test_compile_counters_are_kept_per_function(monkeypatch, tmp_path):
     jax.jit(ddstore_test_counted).lower(jnp.ones(5)).compile()
     mine = profile.counters()["compile_s"]["ddstore_test_counted"]
     # one key a function, whatever prefix JAX reports each stage under
-    assert set(mine) == {"trace_s", "lower_s"}
+    assert set(mine) == {"trace_s", "lower_s", "backend_s"}
     assert all(v > 0 for v in mine.values())
     jax.jit(ddstore_test_counted).lower(jnp.ones(6)).compile()
     again = profile.counters()["compile_s"]["ddstore_test_counted"]
     assert all(again[k] > mine[k] for k in mine)
-    assert set(profile.counters()) == {"compile_s", "flash_geometry",
+    assert set(profile.counters()) == {"compile_s", "compile_cache", "remat",
+                                       "memory", "flash_geometry",
                                        "moe_layout", "mixer_layout",
                                        "ring_geometry"}
 
@@ -266,3 +269,228 @@ def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
         assert len(calls) == 1 and calls[0].endswith(f" {layout} kv{b}")
     else:
         assert not calls
+
+
+# -- the step's names as a grammar, and what the program counts (ISSUE 35) --
+
+STEP = "jit(ddstore_lm_train_step)"
+LM = "jvp(TransformerLM)"
+
+
+@pytest.mark.parametrize("op_name,scopes,which", [
+    # as the lowered steps of the four architectures have them
+    (f"{STEP}/{LM}/block0/attn/mix_in/qkv/dot_general",
+     ("attn", "mix_in"), "forward"),
+    (f"{STEP}/TransformerLM/block0/mlp/dense_mlp/up/dot_general",
+     ("mlp", "dense_mlp"), "forward"),                   # no gradient taken
+    (f"{STEP}/transpose({LM})/block1/attn/ddstore_flash_dkv/pallas_call",
+     ("attn", "ddstore_flash_dkv"), "backward"),
+    (f"{STEP}/transpose(jvp(head))/jit(log_softmax)/mul",
+     ("head",), "backward"),
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/rematted_computation/block2/"
+     "attn/mamba_mixer/mix_norm/norm/mul",
+     ("attn", "mamba_mixer", "mix_norm"), "recompute"),
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/add_any",
+     ("mlp",), "backward"),
+    # moe._routed_bwd: the replay under the marker, its transposes beside it
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/recompute/"
+     "jvp(moe_dispatch)/moe_experts/dot_general",
+     ("mlp", "moe_dispatch", "moe_experts"), "recompute"),
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/"
+     "transpose(jvp(moe_dispatch))/moe_experts/dot_general",
+     ("mlp", "moe_dispatch", "moe_experts"), "backward"),
+    # the parent's replay, unmarked: a jvp inside the transposed side
+    (f"{STEP}/transpose({LM})/block1/mlp/moe/jvp(moe_dispatch)/gather",
+     ("mlp", "moe_dispatch"), "backward"),
+    (f"{STEP}/transpose(jvp(head))/while/body/recompute/dot_general",
+     ("head",), "recompute"),
+    (f"{STEP}/optimizer/add", ("optimizer",), "update"),
+    (f"{STEP}/transpose({LM})/mtp/mtp/{LM}/mtp/mtp/checkpoint/block/attn/"
+     f"transpose;{STEP}/optimizer/add", ("mtp", "attn"), "backward"),
+    (f"{STEP}/{LM}/embed/embed/tok/jit(_take)/gather", ("embed",),
+     "forward"),
+    (f"{STEP}/{LM}/block0/attn/shard_map/ring_step/ddstore_flash_fwd/"
+     "pallas_call", ("attn", "ring_step", "ddstore_flash_fwd"), "forward"),
+    (f"{STEP}/{LM}/jit(head)/mul", (), "forward"),       # a function's name
+    ("ragged-dot-none", (), None),                       # XLA's own
+    ("", (), None),
+], ids=["forward", "plain", "kernel-transposed", "transposed-scope",
+        "rematted", "remat-backward", "marker", "marker-transposes",
+        "unmarked-replay", "xent-replay", "optimizer", "joined-names",
+        "module-repeats-scope", "ring", "jit-is-no-scope", "xla-name",
+        "empty"])
+def test_describe_reads_scopes_and_pass(op_name, scopes, which):
+    assert profile.describe(op_name) == (scopes, which)
+    assert which in profile.PASSES + (None,)
+    assert set(scopes) <= set(profile.STEP_SCOPES) - {profile.RECOMPUTE}
+
+
+def test_the_docstring_lists_the_vocabulary_from_the_data():
+    for name, what in profile.STEP_SCOPES.items():
+        assert f"``{name}``\n    {what}" in profile.__doc__
+    assert "%(" not in profile.__doc__
+    for span in ("ddstore:device_fetch", "ddstore:device_exchange"):
+        assert span in profile.__doc__
+    with pytest.raises(TypeError):
+        trace("/nowhere", create_perfetto_link=True)   # needed a network
+
+
+class _Chip:
+    """A device whose runtime counts its memory, as a TPU's does."""
+
+    def __str__(self):
+        return "TPU_0(fake)"
+
+    def memory_stats(self):
+        return {"bytes_in_use": 5, "peak_bytes_in_use": 7,
+                "bytes_reserved": 2, "peak_bytes_reserved": 3,
+                "bytes_limit": 11, "num_allocs": 13}
+
+
+@pytest.mark.parametrize("fake", [False, True], ids=["cpu", "counting"])
+def test_memory_is_read_where_a_backend_is_up(monkeypatch, fake):
+    """``counters()["memory"]`` and a closed phase's ``memory``: the five
+    keys of every local device that counts them (the CPU's runtime does
+    not; a stand-in does)."""
+    if fake:
+        monkeypatch.setattr(jax, "local_devices", lambda: [_Chip()])
+    jax.devices()
+    with profile.phase("ddstore:test_memory"):
+        pass
+    for memory in (profile.counters()["memory"],
+                   profile.phases()[-1]["memory"]):
+        counting = [d for d in jax.local_devices() if d.memory_stats()]
+        assert set(memory) == {str(d) for d in counting}
+        for stats in memory.values():
+            assert set(stats) == {"bytes_in_use", "peak_bytes_in_use",
+                                  "bytes_reserved", "peak_bytes_reserved",
+                                  "bytes_limit"}
+        if fake:
+            assert memory["TPU_0(fake)"]["peak_bytes_reserved"] == 3
+
+
+@pytest.mark.parametrize("imports", ["", "import jax; "],
+                         ids=["no-jax", "jax-imported"])
+def test_asking_for_memory_brings_no_backend_up(imports):
+    """A data-only owner never imports JAX, and rank 0 opens its store
+    before it chooses its platform: ``phase`` and ``counters`` read the
+    memory only of a backend that is already there."""
+    code = imports + (
+        "import sys; from ddstore_tpu.utils import profile\n"
+        "with profile.phase('ddstore:test_owner'): pass\n"
+        "assert 'memory' not in profile.counters()\n"
+        "assert 'memory' not in profile.phases()[-1]\n"
+        "if 'jax' in sys.modules:\n"
+        "    from jax._src import xla_bridge\n"
+        "    assert not xla_bridge.backends_are_initialized()\n"
+        "else:\n"
+        "    assert not any(m.startswith('jaxlib') for m in sys.modules)\n"
+        "print('ok')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu")
+    done = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0 and done.stdout.strip() == "ok", done.stderr
+
+
+def test_compile_cache_counts_a_compile_and_its_re_read(tmp_path):
+    """A program compiled and written is a miss, the same program read
+    back a hit; its backend seconds count both."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    profile.watch_compiles()
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+
+        def ddstore_test_cached(x):
+            return jnp.sin(x) * 5 + 2
+
+        x = jnp.ones(7)      # its own small programs, before the count
+        before = profile.counters()["compile_cache"]
+        jax.jit(ddstore_test_cached).lower(x).compile()
+        after = profile.counters()["compile_cache"]
+        assert (after["misses"], after["hits"]) == (before["misses"] + 1,
+                                                    before["hits"])
+        first = profile.counters()["compile_s"]["ddstore_test_cached"]
+        jax.clear_caches()   # the process's own; the directory keeps its
+        jax.jit(ddstore_test_cached).lower(x).compile()
+        again = profile.counters()["compile_cache"]
+        assert (again["misses"], again["hits"]) == (after["misses"],
+                                                    after["hits"] + 1)
+        second = profile.counters()["compile_s"]["ddstore_test_cached"]
+        assert second["backend_s"] > first["backend_s"] > 0
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("remat,policy,saved", [
+    (False, None, []), (True, None, []),
+    (True, "names:flash_out,flash_lse", ["flash_out", "flash_lse"]),
+    (True, "dots_with_no_batch_dims_saveable", [])])
+def test_remat_counter_says_what_recompute_holds(remat, policy, saved):
+    from ddstore_tpu.models import transformer as T
+
+    model = T.TransformerLM(vocab=32, dim=16, heads=2, layers=2,
+                            remat=remat, remat_policy=policy)
+    tok = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    jax.eval_shape(model.init, jax.random.key(0), tok, tok)
+    counted = profile.counters()["remat"]
+    want = {"remat": remat, "policy": policy if remat else None,
+            "saved": saved}
+    assert counted["block0"] == counted["block1"] == want
+
+
+def test_the_benchmarks_partition_reads_a_recorded_slice_by_pass(
+        monkeypatch):
+    """``benchmarks/ddbench/passes.py`` (loaded by path: the benchmark is
+    not a package of the program) over the slice of a v5e trace recorded
+    with the program's names, ``benchmarks/tests/data``: 29.8 ms of
+    ``dense-lm-d1024.s2048`` across a step boundary, the tail of a backward
+    pass, the optimizer, the next step's first forward kernel."""
+    import gzip
+    import importlib.util
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    spec = importlib.util.spec_from_file_location(
+        "ddbench_passes_by_path", os.path.join(bench, "ddbench", "passes.py"))
+    passes = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(passes)
+    from ddbench import tracered
+
+    def text(suffix):
+        with gzip.open(os.path.join(bench, "tests", "data",
+                                    "v5e_s2048_names_slice" + suffix),
+                       "rt") as f:
+            return f.read()
+
+    reduced = tracered.reduce_profile(
+        ProfileData.from_text_proto(text(".xspace.txt.gz")))
+    table, unknown = passes.partition(reduced, text(".hlo.txt.gz"),
+                                      profile.describe)
+    assert sum(table.values()) == pytest.approx(reduced.busy_s(), abs=1e-12)
+    assert reduced.busy_s() == pytest.approx(0.029831576, abs=1e-9)
+    by_kind = collections.defaultdict(set)
+    for (kind, which), seconds in table.items():
+        assert seconds > 0 and kind in set(profile.STEP_SCOPES) | {
+            passes.NO_SCOPE, passes.NO_NAME}
+        by_kind[kind].add(which)
+    assert by_kind["ddstore_flash_fwd"] == {"forward"}
+    assert by_kind["ddstore_flash_dq"] == by_kind["ddstore_flash_dkv"] \
+        == {"backward"}
+    assert by_kind["optimizer"] == {"update"}          # the Adam fusions
+    assert by_kind[passes.NO_NAME] == {passes.UNKNOWN}
+    assert "recompute" not in set().union(*by_kind.values())
+    assert sum(unknown.values()) == pytest.approx(
+        table[(passes.NO_NAME, passes.UNKNOWN)])

@@ -3,14 +3,20 @@ the unsharded step (exactness oracle — ring attention is exact), plus
 store-fed training where token windows are fetched from the distributed
 store."""
 
+import os
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ddstore_tpu import DDStore, SingleGroup
 from ddstore_tpu.data import DeviceLoader, DistributedSampler, ShardedDataset
 from ddstore_tpu.models import transformer
 from ddstore_tpu.parallel import make_mesh
+from ddstore_tpu.utils import profile
 
 
 def _data(key, b, s, vocab):
@@ -108,3 +114,101 @@ def test_the_step_carries_its_scope_names():
     assert '"ring_step/' in text
     assert "transpose(jvp(TransformerLM))/block1/attn/" in text
     assert "jvp(TransformerLM)/block0/mlp/" in text
+
+
+# -- the step by kind of work and by pass (ISSUE 35) ------------------------
+
+# The ``jax.named_scope`` names each architecture's step enters (the
+# kernels' ``pallas_call(name=)`` are ``tests/test_v5e_compile.py``'s: the
+# CPU runs attention's reference). Each model test file holds its
+# architecture to its row; together they are ``STEP_SCOPES``.
+COMMON = {"embed", "attn", "mlp", "head", "optimizer", "mix_in", "mix_out"}
+EMITS = {
+    "dense": COMMON | {"dense_mlp", "ring_step", "recompute"},
+    "mla_moe": COMMON | {"mix_norm", "dense_mlp", "shared_expert",
+                         "moe_dispatch", "moe_experts", "mtp", "recompute"},
+    "lfm2_moe": COMMON | {"mix_norm", "dense_mlp", "conv_mixer",
+                          "short_conv", "moe_dispatch", "moe_experts",
+                          "recompute"},
+    "nemotron_h": COMMON | {"mix_norm", "mamba_mixer", "mamba_conv",
+                            "short_conv", "ssd", "shared_expert",
+                            "moe_dispatch", "moe_experts", "recompute"},
+}
+
+
+def step_names(monkeypatch, model, batch, seq, **step_kw):
+    """``({(innermost scope, pass)}, {names}, {op_names})`` of ``model``'s
+    train step compiled at a toy size: every operation's ``op_name``
+    through ``profile.describe``, the ``jax.named_scope`` names the
+    program's own files entered while it was traced, and the ``op_name``s
+    themselves."""
+    entered, real = set(), jax.named_scope
+    ours = os.sep + "ddstore_tpu" + os.sep
+
+    def recording(name, *args, **kwargs):
+        if ours in sys._getframe(1).f_code.co_filename:
+            entered.add(name)
+        return real(name, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "named_scope", recording)
+    state, tx = transformer.create_train_state(
+        jax.random.key(0), model, mesh=step_kw.get("mesh"))
+    if "mesh" in step_kw:
+        step_kw["state"] = state
+    step = transformer.make_train_step(model, tx, **step_kw)
+    tok = jnp.zeros((batch, seq), jnp.int32)
+    pos = jnp.tile(jnp.arange(seq, dtype=jnp.int32), (batch, 1))
+    text = step.lower(state, tok, tok, pos).compile().as_text()
+    found, op_names = set(), set(re.findall(r'op_name="([^"]*)"', text))
+    for op_name in op_names:
+        scopes, which = profile.describe(op_name)
+        if scopes:
+            found.add((scopes[-1], which))
+    return found, entered, op_names
+
+
+def replayed_products(op_names):
+    """The expert products ``moe._routed_bwd`` computes again, by their
+    ``op_name``: under the program's marker, which alone makes them the
+    pass ``recompute`` (without it JAX names them as the backward's)."""
+    marked = [n for n in op_names
+              if "/recompute/" in n and "moe_experts" in n
+              and n.endswith("dot_general")]
+    for n in marked:
+        assert profile.describe(n)[1] == "recompute", n
+        assert profile.describe(n.replace("/recompute/", "/"))[1] \
+            == "backward", n
+    return marked
+
+
+def passes_of(found, scope):
+    return {which for kind, which in found if kind == scope}
+
+
+def test_the_four_architectures_emit_the_vocabulary_between_them():
+    kernels = {n for n in profile.STEP_SCOPES if n.startswith("ddstore_")}
+    assert set().union(*EMITS.values()) | kernels == set(profile.STEP_SCOPES)
+    assert len(kernels) == 9
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_dense_step_by_kind_of_work_and_pass(monkeypatch, remat):
+    """``Block``'s projections and MLP under names of their own, forward
+    and transposed; under ``nn.remat`` a second time; without it the only
+    recomputation is the fused head's, which its rule marks."""
+    mesh = make_mesh({"dp": 2, "sp": 2}, jax.devices()[:4])
+    model = transformer.TransformerLM(
+        vocab=64, dim=32, heads=4, layers=2, compute_dtype=jnp.float32,
+        mesh=mesh, remat=remat)
+    found, entered, _ = step_names(monkeypatch, model, 2, 16, mesh=mesh,
+                                   fused_xent=True)
+    assert entered == EMITS["dense"]
+    again = {"recompute"} if remat else set()
+    for scope in ("mix_in", "mix_out", "dense_mlp", "ring_step"):
+        assert passes_of(found, scope) == {"forward", "backward"} | again, \
+            scope
+    assert passes_of(found, "head") == {"forward", "backward", "recompute"}
+    assert passes_of(found, "optimizer") == {"update"}
+    assert passes_of(found, "embed") == {"forward", "backward"}
+    want = {"remat": remat, "policy": None, "saved": []}
+    assert profile.counters()["remat"]["block1"] == want

@@ -207,6 +207,13 @@ def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
                    "ddstore_flash_dkv", "ddstore_short_conv_fwd",
                    "ddstore_short_conv_bwd", "ragged-dot"):
         assert kernel in text, kernel
+    # by pass: the flash forward's output is saved by name, so only the
+    # convolution's forward kernel runs again under nn.remat
+    assert _kernel_passes(text) == {
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
+        "ddstore_flash_dkv": {"backward"},
+        "ddstore_short_conv_fwd": {"forward", "recompute"},
+        "ddstore_short_conv_bwd": {"backward"}}
 
 
 def test_conv_silu_kernels_lower_for_the_chip(one_chip, no_compile_cache):
@@ -228,6 +235,26 @@ def test_conv_silu_kernels_lower_for_the_chip(one_chip, no_compile_cache):
         .as_text()
     assert "ddstore_conv_silu_fwd" in text
     assert "ddstore_conv_silu_bwd" in text
+
+
+def _kernel_passes(text):
+    """``{kernel: {pass}}`` of a compiled step's Mosaic calls: each by the
+    name the program gave it, which its ``op_name`` carries inside the
+    layer's scopes, in the pass ``profile.describe`` reads around them."""
+    import re
+
+    from ddstore_tpu.utils import profile
+
+    found = {}
+    for ln in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in ln:
+            continue
+        m = re.search(r'op_name="([^"]*)"', ln)
+        scopes, which = profile.describe(m.group(1) if m else "")
+        if scopes and scopes[-1].startswith("ddstore_"):
+            assert scopes[-1] in profile.STEP_SCOPES, scopes
+            found.setdefault(scopes[-1], set()).add(which)
+    return found
 
 
 def _mosaic_calls(text, kernel):
@@ -315,6 +342,13 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
     # writes the chunks' states) and the backward, one kernel each
     assert len(_mosaic_calls(text, "ddstore_ssd_fwd")) == 2 * 4
     assert len(_mosaic_calls(text, "ddstore_ssd_bwd")) == 4
+    assert _kernel_passes(text) == {
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
+        "ddstore_flash_dkv": {"backward"},
+        "ddstore_ssd_fwd": {"forward", "recompute"},
+        "ddstore_ssd_bwd": {"backward"},
+        "ddstore_conv_silu_fwd": {"forward", "recompute"},
+        "ddstore_conv_silu_bwd": {"backward"}}
     for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
                    "ddstore_flash_dkv"):
         calls = _mosaic_calls(text, kernel)
