@@ -49,6 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import moe_gmm
 from ..utils import profile
 
 # The router's correction bias is a seeded, non-zero leaf: its trained
@@ -269,12 +270,23 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
         xs = _to_experts(x, head, local, live)
         with jax.named_scope("moe_experts"):
             *w_in, w_down = (w.astype(x.dtype) for w in ws)
+            # the experts' width in whole lane tiles, on the copies in the
+            # compute type: zero columns in, zero rows out (both
+            # activations keep 0 at 0, so ``h``'s extra columns are zero)
+            extra = moe_gmm.padded(w_down.shape[1]) - w_down.shape[1]
+            if extra:
+                w_in = [jnp.pad(w, ((0, 0), (0, 0), (0, extra)))
+                        for w in w_in]
+                w_down = jnp.pad(w_down, ((0, 0), (0, extra), (0, 0)))
+            # one walk over the groups' rows for the trip's products
+            product = functools.partial(
+                moe_gmm.moe_gmm, sizes=here,
+                steps=moe_gmm.row_steps(here, rows))
             if len(w_in) == 2:
-                h = nn.silu(jax.lax.ragged_dot(xs, w_in[0], here)) \
-                    * jax.lax.ragged_dot(xs, w_in[1], here)
+                h = nn.silu(product(xs, w_in[0])) * product(xs, w_in[1])
             else:
-                h = jnp.square(nn.relu(jax.lax.ragged_dot(xs, w_in[0], here)))
-            ys = jax.lax.ragged_dot(h, w_down, here)
+                h = jnp.square(nn.relu(product(xs, w_in[0])))
+            ys = product(h, w_down)
         return _from_experts(ys, weights, head, local, live)
 
 
@@ -364,9 +376,12 @@ class SharedRoutedMoe(nn.Module):
 
     Every (token, chosen expert) pair is sorted by held expert, the pairs
     of absent experts behind them, and the held rows are multiplied by
-    ``jax.lax.ragged_dot`` (on the TPU XLA's own grouped-matmul kernel,
-    which does the work of the rows each expert got and no more: PERF.md
-    section 6, PR 27). Shapes are static, and the gathers, SwiGLU and
+    ``ops/moe_gmm.py``'s Pallas kernels (``ddstore_moe_gmm``, and in the
+    backward its transposed form and ``ddstore_moe_tgmm``; ``lax.ragged_dot``'s
+    contract, the work of the rows each expert got and no more, on copies
+    of the held matrices in the compute type whose experts' width is
+    padded with zeros to whole lane tiles: PERF.md section 6, PR 36).
+    Shapes are static, and the gathers, SwiGLU and
     weighted sums around the products cost what the buffer's length is,
     not what the routing fills; so the routed part (:func:`_routed`) walks
     the sorted rows in trips of :func:`routed_chunk` rows, as many as the
@@ -402,9 +417,12 @@ class SharedRoutedMoe(nn.Module):
         held = e // of
         first = which * held
         rows = routed_chunk(t, k, held, e)
+        wide = moe_gmm.padded(self.hidden)
         profile.count_moe_layout(
             "/".join(self.path), held=held, of=e, first=first, top_k=k,
-            tokens=t, rows=rows)
+            tokens=t, rows=rows, products=moe_gmm.PRODUCTS,
+            tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
+            **({"padded_to": wide} if wide != self.hidden else {}))
 
         experts = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,

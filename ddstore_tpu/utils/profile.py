@@ -127,9 +127,10 @@ STEP_SCOPES = {
                      "expert, products and activation.",
     "moe_dispatch": "inside ``mlp``, ``models/moe.py``: router, top-k, "
                     "sorts, the gathers to and from the experts' rows.",
-    "moe_experts": "inside ``moe_dispatch``: the grouped products and the "
-                   "activation between them (XLA's ragged-dot kernels "
-                   "lose the name: ``op_name`` ``ragged-dot-*``).",
+    "moe_experts": "inside ``moe_dispatch``: the grouped products (the "
+                   "kernels of ``ops/moe_gmm.py``, which keep the name), "
+                   "the activation between them and the held matrices' "
+                   "copies in the compute type.",
     "recompute": "a marker, not a kind of work: what a hand-written rule "
                  "computes again inside its backward (``moe._routed_bwd``, "
                  "``xent._bwd``, the 1F1B schedule's stage replay). Pass "
@@ -150,6 +151,12 @@ STEP_SCOPES = {
     "ddstore_ssd_fwd": "``ops/ssd.py``: the chunked scan, forward.",
     "ddstore_ssd_bwd": "``ops/ssd.py``: its backward (computes the "
                        "output again inside its body).",
+    "ddstore_moe_gmm": "``ops/moe_gmm.py``: an expert layer's sorted rows "
+                       "times their experts' matrices, and the rows' "
+                       "cotangent (the same kernel, the matrices "
+                       "transposed).",
+    "ddstore_moe_tgmm": "``ops/moe_gmm.py``: the experts' matrices' "
+                        "cotangent.",
 }
 # The marker on a recomputation JAX does not label (``nn.remat``'s own is
 # ``rematted_computation``).
@@ -208,7 +215,12 @@ def describe(op_name: str) -> Tuple[Tuple[str, ...], Optional[str]]:
     module inside it given once (``embed/embed``), the marker left out.
     ``pass``, one of :data:`PASSES`: under ``optimizer`` ``update``; else
     computed again (``nn.remat``'s ``rematted_computation``, or the
-    program's marker :data:`RECOMPUTE` not itself transposed)
+    program's marker :data:`RECOMPUTE` not itself transposed nor inside a
+    second ``transpose(``: the first is the backward the replay is part of;
+    a rule of a ``jax.custom_vjp`` that a ``jax.vjp`` under the marker pulls
+    back is named by the stack it is called under, ``transpose(``, and the
+    stack its forward was traced under, marker and all:
+    ``.../moe/transpose(block1)/mlp/moe/recompute/.../ddstore_moe_tgmm``)
     ``recompute``; else on the transposed side (a ``transpose(`` anywhere:
     a ``jax.vjp`` inside a hand-written backward writes ``jvp(`` inside it,
     and is the backward's still) ``backward``; else ``forward``. ``None``
@@ -218,15 +230,16 @@ def describe(op_name: str) -> Tuple[Tuple[str, ...], Optional[str]]:
     parts = op_name.split(";", 1)[0].split("/")
     if not parts[0].startswith(_ROOTS):
         return (), None
-    scopes, transposed, again = [], False, False
+    scopes, transposed, again = [], 0, False
     for part in parts[1:]:
         wrappers = []
         while (m := _WRAPPED.match(part)):
             wrappers.append(m.group(1))
             part = m.group(2)
-        transposed |= "transpose" in wrappers
+        transposed += "transpose" in wrappers
         if part == "rematted_computation" or (
-                part == RECOMPUTE and "transpose" not in wrappers):
+                part == RECOMPUTE and "transpose" not in wrappers
+                and transposed <= 1):
             again = True
         elif part in STEP_SCOPES and part != RECOMPUTE \
                 and not any(w in _CALLS for w in wrappers) \
@@ -392,7 +405,11 @@ def count_moe_layout(layer: str, **counts) -> None:
     chip holds (``held`` of ``of``, from ``first``), the experts a token
     takes (``top_k``), the ``tokens`` of the call and the sorted ``rows``
     its routed experts run over at a time (as many trips a step as the
-    step's held pairs need: one, under routing near even)."""
+    step's held pairs need: one, under routing near even); what its grouped
+    ``products`` ran as (``pallas``, the kernels of ``ops/moe_gmm.py``),
+    their ``tiles`` (``in`` / ``out`` of the experts' width, each ``{gmm,
+    gmm_t, tgmm: (tm, tk, tn)}``) and, where the experts' width was padded
+    to whole lane tiles, ``padded_to``."""
     with _lock:
         _moe_layout[layer] = dict(counts)
 

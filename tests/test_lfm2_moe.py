@@ -573,6 +573,12 @@ def test_mixer_layout_counter_says_what_each_layer_mixes(built):
     assert {k: moe_layout["block1/moe"][k]
             for k in ("held", "of", "first", "top_k")} == dict(
                 held=2, of=8, first=2, top_k=4)
+    from test_transformer import assert_the_layout_names_the_products
+    model = built[0]
+    for i in (2, 3, 4):
+        assert_the_layout_names_the_products(
+            moe_layout[f"block{i}/moe"], model.dim,
+            model.arch.moe_intermediate_size)
 
 
 # -- the step by kind of work and by pass (ISSUE 35) ------------------------
@@ -582,8 +588,8 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch):
     """The conv and attention mixers' projections, the q/k norms and the
     dense layer's MLP under names of their own, forward, recomputed under
     ``nn.remat`` and transposed; the convolution's kernels by their own."""
-    from test_transformer import (EMITS, passes_of, replayed_products,
-                                  step_names)
+    from test_transformer import (EMITS, assert_the_products_kernels_passes,
+                                  passes_of, replayed_products, step_names)
 
     model = T.lm_from_description(
         DESC, compute_dtype=jnp.float32, remat=True,
@@ -599,3 +605,4 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch):
                                                           "recompute"}
     assert passes_of(found, "ddstore_short_conv_bwd") == {"backward"}
     assert replayed_products(op_names)
+    assert_the_products_kernels_passes(found)

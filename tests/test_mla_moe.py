@@ -296,6 +296,11 @@ def test_moe_layout_counter_says_what_is_held(built):
     # the sorted rows of one trip: at this size, every pair
     assert mine["rows"] == mine["tokens"] * 4
     assert set(layout) >= {"block1/moe", "block2/moe", "mtp/block/moe"}
+    from test_transformer import assert_the_layout_names_the_products
+    model = built[0]
+    for layer in ("block1/moe", "block2/moe", "mtp/block/moe"):
+        assert_the_layout_names_the_products(
+            layout[layer], model.dim, model.arch.moe_intermediate_size)
 
 
 @pytest.mark.parametrize("tokens,top_k,held,n_routed,want", [
@@ -447,13 +452,15 @@ def test_a_layer_that_holds_every_expert_takes_its_rows_at_once():
     x = jnp.zeros((TRIP_T, 32), jnp.float32)
     layer = _layer((0, 1))
     p = jax.eval_shape(layer.init, jax.random.key(0), x)
-    text = jax.jit(layer.apply).lower(p, x).as_text()
+    # the traced program: a kernel is one equation there (lowered for the
+    # interpreter, its grid is a loop too)
+    text = str(jax.make_jaxpr(layer.apply)(p, x))
     assert _rows_of_a_bare_layer() == 4096
-    assert "stablehlo.while" not in text
-    cut = jax.jit(_layer((0, 8)).apply).lower(
+    assert "ddstore_moe_gmm" in text and "while[" not in text
+    cut = jax.make_jaxpr(_layer((0, 8)).apply)(
         jax.eval_shape(_layer((0, 8)).init, jax.random.key(0), x), x)
     assert _rows_of_a_bare_layer() == 1024
-    assert "stablehlo.while" in cut.as_text()
+    assert "while[" in str(cut)
 
 
 def test_trips_take_no_more_temporaries_than_every_row_at_once(monkeypatch):
@@ -664,8 +671,8 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch, remat):
     layer's MLP and the shared expert under names of their own, in every
     pass; the routed part's replay of its forward under ``recompute`` even
     where no block is rematerialised."""
-    from test_transformer import (EMITS, passes_of, replayed_products,
-                                  step_names)
+    from test_transformer import (EMITS, assert_the_products_kernels_passes,
+                                  passes_of, replayed_products, step_names)
 
     kw = dict(remat=True, remat_policy="names:flash_out,flash_lse") \
         if remat else {}
@@ -677,12 +684,14 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch, remat):
                   "shared_expert"):
         assert passes_of(found, scope) == {"forward", "backward"} | again, \
             scope
-    for scope in ("moe_dispatch", "moe_experts"):
-        assert passes_of(found, scope) == {"forward", "recompute",
-                                           "backward"}, scope
-    # (unrematerialised, XLA shares the forward's products with the replay
-    # and only the replay's cheaper operations stay under the marker)
+    every = {"forward", "recompute", "backward"}
+    assert passes_of(found, "moe_experts") == every
+    # (unrematerialised, XLA shares the forward's products and gathers with
+    # the replay and only the replay's activation stays under the marker)
+    assert passes_of(found, "moe_dispatch") == every - (
+        set() if remat else {"recompute"})
     assert replayed_products(op_names) or not remat
+    assert_the_products_kernels_passes(found, replayed=remat)
     counted = profile.counters()["remat"]
     assert set(counted) >= {"block0", "block1", "block2", "mtp/block"}
     assert counted["mtp/block"] == counted["block0"] == {

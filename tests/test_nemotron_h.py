@@ -651,6 +651,11 @@ def test_mixer_layout_counter_says_what_each_layer_mixes(built):
     assert {k: moe_layout["block1/moe"][k]
             for k in ("held", "of", "first", "top_k")} == dict(
                 held=2, of=8, first=2, top_k=3)
+    from test_transformer import assert_the_layout_names_the_products
+    for i in (1, 3, 6, 8):
+        assert_the_layout_names_the_products(
+            moe_layout[f"block{i}/moe"], model.dim,
+            model.arch.moe_intermediate_size)
 
 
 # ---------------------------------------------------------------------------
@@ -687,8 +692,8 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch):
     ``nn.remat`` and transposed (a one-branch layer ends in its output
     projection, which nothing needs a second time); the scan's and the
     convolution's kernels by their own."""
-    from test_transformer import (EMITS, passes_of, replayed_products,
-                                  step_names)
+    from test_transformer import (EMITS, assert_the_products_kernels_passes,
+                                  passes_of, replayed_products, step_names)
 
     model = T.lm_from_description(
         DESC, compute_dtype=jnp.float32, remat=True,
@@ -706,5 +711,6 @@ def test_the_step_by_kind_of_work_and_pass(monkeypatch):
     for kernel in ("ddstore_ssd_bwd", "ddstore_conv_silu_bwd"):
         assert passes_of(found, kernel) == {"backward"}, kernel
     assert replayed_products(op_names)
+    assert_the_products_kernels_passes(found)
     assert profile.counters()["remat"]["block8"]["saved"] == [
         "flash_out", "flash_lse"]

@@ -299,6 +299,19 @@ LM = "jvp(TransformerLM)"
     (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/"
      "transpose(jvp(moe_dispatch))/moe_experts/dot_general",
      ("mlp", "moe_dispatch", "moe_experts"), "backward"),
+    # a custom_vjp's rule pulled back by the replay's jax.vjp: named by the
+    # stack at the pull, transpose(, the stack at its forward (marker and all)
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/"
+     "transpose(block1)/mlp/moe/recompute/jvp(moe_dispatch)/moe_experts/"
+     "ddstore_moe_tgmm", ("mlp", "moe_dispatch", "moe_experts",
+                          "ddstore_moe_tgmm"), "backward"),
+    (f"{STEP}/transpose({LM})/block1/mlp/moe/transpose(transpose({LM}))/"
+     "block1/mlp/moe/recompute/jvp(moe_dispatch)/moe_experts/"
+     "ddstore_moe_gmm", ("mlp", "moe_dispatch", "moe_experts",
+                         "ddstore_moe_gmm"), "backward"),
+    (f"{STEP}/transpose({LM})/{LM}/checkpoint/block1/mlp/moe/recompute/"
+     "jvp(moe_dispatch)/moe_experts/ddstore_moe_gmm",
+     ("mlp", "moe_dispatch", "moe_experts", "ddstore_moe_gmm"), "recompute"),
     # the parent's replay, unmarked: a jvp inside the transposed side
     (f"{STEP}/transpose({LM})/block1/mlp/moe/jvp(moe_dispatch)/gather",
      ("mlp", "moe_dispatch"), "backward"),
@@ -316,7 +329,8 @@ LM = "jvp(TransformerLM)"
     ("", (), None),
 ], ids=["forward", "plain", "kernel-transposed", "transposed-scope",
         "rematted", "remat-backward", "marker", "marker-transposes",
-        "unmarked-replay", "xent-replay", "optimizer", "joined-names",
+        "rule-pulled-back-remat", "rule-pulled-back-plain",
+        "kernel-replayed", "unmarked-replay", "xent-replay", "optimizer", "joined-names",
         "module-repeats-scope", "ring", "jit-is-no-scope", "xla-name",
         "empty"])
 def test_describe_reads_scopes_and_pass(op_name, scopes, which):

@@ -170,25 +170,59 @@ def step_names(monkeypatch, model, batch, seq, **step_kw):
 def replayed_products(op_names):
     """The expert products ``moe._routed_bwd`` computes again, by their
     ``op_name``: under the program's marker, which alone makes them the
-    pass ``recompute`` (without it JAX names them as the backward's)."""
+    pass ``recompute`` (without it JAX names them as the backward's). The
+    kernels' transposed rules, which the replay's ``jax.vjp`` pulls back,
+    carry the marker too, behind a second ``transpose(``: the backward's."""
     marked = [n for n in op_names
               if "/recompute/" in n and "moe_experts" in n
               and n.endswith("dot_general")]
-    for n in marked:
-        assert profile.describe(n)[1] == "recompute", n
+    replayed = [n for n in marked if profile.describe(n)[1] == "recompute"]
+    for n in replayed:
         assert profile.describe(n.replace("/recompute/", "/"))[1] \
             == "backward", n
-    return marked
+    for n in set(marked) - set(replayed):
+        assert profile.describe(n)[1] == "backward", n
+        before = n.split("/recompute/")[0].split("/")
+        assert sum(part.startswith("transpose(") for part in before) == 2, n
+        assert "/ddstore_moe_" in n, n
+    return replayed
 
 
 def passes_of(found, scope):
     return {which for kind, which in found if kind == scope}
 
 
+def assert_the_products_kernels_passes(found, replayed=True):
+    """An expert layer's grouped products by their kernels' own names:
+    ``ddstore_moe_gmm`` forward, again (``nn.remat``'s second forward and
+    ``moe._routed_bwd``'s replay; where nothing is rematerialised XLA may
+    share the replay's with the forward's) and, transposed, in the
+    backward; ``ddstore_moe_tgmm`` in the backward alone."""
+    every = {"forward", "recompute", "backward"}
+    got = passes_of(found, "ddstore_moe_gmm")
+    assert every - (set() if replayed else {"recompute"}) <= got <= every
+    assert passes_of(found, "ddstore_moe_tgmm") == {"backward"}
+
+
+def assert_the_layout_names_the_products(entry, d, hidden):
+    """``counters()["moe_layout"][layer]``: what the grouped products ran
+    as, the tiles of each, and the width the experts' was padded to."""
+    from ddstore_tpu.ops import moe_gmm
+
+    assert entry["products"] == "pallas"
+    wide = moe_gmm.padded(hidden)
+    assert entry.get("padded_to") == (wide if wide != hidden else None)
+    assert set(entry["tiles"]) == {"in", "out"}
+    for (k, n), forms in zip(((d, wide), (wide, d)), entry["tiles"].values()):
+        assert set(forms) == {"gmm", "gmm_t", "tgmm"}
+        for tm, tk, tn in forms.values():
+            assert entry["rows"] % tm == 0 and k % tk == 0 and n % tn == 0
+
+
 def test_the_four_architectures_emit_the_vocabulary_between_them():
     kernels = {n for n in profile.STEP_SCOPES if n.startswith("ddstore_")}
     assert set().union(*EMITS.values()) | kernels == set(profile.STEP_SCOPES)
-    assert len(kernels) == 9
+    assert len(kernels) == 11
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])

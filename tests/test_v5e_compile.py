@@ -3,7 +3,8 @@ what no interpret-mode test can refuse: the three flash kernels at head
 width 256 (VMEM), at the ring's call shapes on four chips and with
 grouped K/V heads, the latent-attention mixer with the copies XLA puts
 around its kernels, the gated short convolution's two kernels, the expert
-layer's grouped products (XLA's own ragged-dot kernels), the Mamba-2
+layer's grouped products (``ops/moe_gmm.py``'s kernels at the three
+configurations' widths), the Mamba-2
 convolution's and scan's two kernels each, and the whole step of the
 ``lfm2-8b-a1b-ep4.s8192.b4`` and ``nemotron3-nano-ep16.s8192`` cells
 against the chip's memory. Nothing
@@ -90,15 +91,19 @@ def test_the_ring_calls_of_the_four_chip_cell_fit_the_chip(
         assert kernel in text
 
 
-def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache):
+def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache,
+                                                 monkeypatch):
     """Published widths, a quarter of a step's tokens: the grouped products
-    are XLA's ragged-dot kernels, which size their grid from the rows each
+    are ``ops/moe_gmm.py``'s kernels, whose steps follow the rows each
     expert got. The first trip over the sorted rows and the loop's body
     for the others each hold the forward's three, and in the backward rule
-    the forward's three again beside the transposes' six; XLA may share
-    the in-line trip's three between the two rules (here, where nothing is
-    rematerialised, it does)."""
+    the forward's three again beside the transposes' six (three of them
+    the same kernel, three the matrices' cotangent); XLA may share the
+    in-line trip's three between the two rules (here, where nothing is
+    rematerialised, it does). No ragged-dot is left."""
     from ddstore_tpu.models.moe import SharedRoutedMoe, routed_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     layer = SharedRoutedMoe(64, 4, 1536, share=(0, 8), scaling=1.8)
     x = jax.ShapeDtypeStruct((4096, 2048), jnp.bfloat16, sharding=one_chip)
@@ -114,8 +119,36 @@ def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache):
     text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(params, x) \
         .compile().as_text()
     assert routed_chunk(4096, 4, 8, 64) == 3072     # of 16,384 pairs
-    assert text.count('op_name="ragged-dot-none"') in (2 * (3 + 9) - 3,
-                                                       2 * (3 + 9))
+    assert "ragged-dot" not in text
+    assert len(_mosaic_calls(text, "ddstore_moe_gmm")) in (2 * (3 + 6) - 3,
+                                                           2 * (3 + 6))
+    assert len(_mosaic_calls(text, "ddstore_moe_tgmm")) == 2 * 3
+
+
+@pytest.mark.parametrize("rows,d,hidden", [
+    (9216, 2688, 1856), (49152, 2048, 1792), (12288, 2048, 1536)],
+    ids=["nemotron3-nano-ep16", "lfm2-8b-a1b-ep4", "glm47-flash-ep8"])
+def test_grouped_product_kernels_fit_the_chip_at_the_cells_shapes(
+        one_chip, no_compile_cache, rows, d, hidden):
+    """A trip's six products of each configuration (into the experts'
+    width and out of it: the product, the rows' cotangent, the matrices'),
+    at the tiles the rule gives their shapes, the experts' width padded to
+    whole lane tiles: they lower and fit VMEM."""
+    from ddstore_tpu.ops import moe_gmm
+
+    wide = moe_gmm.padded(hidden)
+    shape = lambda dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dt, sharding=one_chip)
+
+    def f(x, w_in, w_out, sizes):
+        gmm = lambda a, b: moe_gmm.moe_gmm(a, b, sizes, interpret=False)
+        return (gmm(gmm(x, w_in), w_out).astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(
+        shape((rows, d)), shape((8, d, wide)), shape((8, wide, d)),
+        shape((8,), jnp.int32)).compile().as_text()
+    assert len(_mosaic_calls(text, "ddstore_moe_gmm")) == 4
+    assert len(_mosaic_calls(text, "ddstore_moe_tgmm")) == 2
 
 
 def test_gqa_kernels_lower_with_kv_at_their_own_heads(one_chip,
@@ -205,15 +238,16 @@ def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
     text = compiled.as_text()
     for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
                    "ddstore_flash_dkv", "ddstore_short_conv_fwd",
-                   "ddstore_short_conv_bwd", "ragged-dot"):
+                   "ddstore_short_conv_bwd"):
         assert kernel in text, kernel
+    assert "ragged-dot" not in text
     # by pass: the flash forward's output is saved by name, so only the
     # convolution's forward kernel runs again under nn.remat
     assert _kernel_passes(text) == {
         "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
         "ddstore_flash_dkv": {"backward"},
         "ddstore_short_conv_fwd": {"forward", "recompute"},
-        "ddstore_short_conv_bwd": {"backward"}}
+        "ddstore_short_conv_bwd": {"backward"}, **_PRODUCTS_PASSES}
 
 
 def test_conv_silu_kernels_lower_for_the_chip(one_chip, no_compile_cache):
@@ -255,6 +289,13 @@ def _kernel_passes(text):
             assert scopes[-1] in profile.STEP_SCOPES, scopes
             found.setdefault(scopes[-1], set()).add(which)
     return found
+
+
+# The grouped products' kernels in a step: the forward, ``_routed_bwd``'s
+# replay (``nn.remat``'s second forward needs none: the rule keeps the
+# layer's inputs and nothing of its products) and the transposed side.
+_PRODUCTS_PASSES = {"ddstore_moe_gmm": {"forward", "recompute", "backward"},
+                    "ddstore_moe_tgmm": {"backward"}}
 
 
 def _mosaic_calls(text, kernel):
@@ -335,9 +376,15 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 8.0e9 < total < 16.91e9, total
     text = compiled.as_text()
-    for kernel in ("ddstore_conv_silu_fwd", "ddstore_conv_silu_bwd",
-                   "ragged-dot"):
+    for kernel in ("ddstore_conv_silu_fwd", "ddstore_conv_silu_bwd"):
         assert kernel in text, kernel
+    assert "ragged-dot" not in text
+    # an expert layer's two products: the forward's, the replay's, their
+    # transposes (two of each form), in the first trip and in the loop's
+    # body for the others; the widths padded 1856 -> 1920
+    assert len(_mosaic_calls(text, "ddstore_moe_gmm")) == 2 * 4 * (2 + 2 + 2)
+    assert len(_mosaic_calls(text, "ddstore_moe_tgmm")) == 2 * 4 * 2
+    assert "bf16[8,2688,1920]" in text and "bf16[8,1920,2688]" in text
     # a Mamba layer's scan: the forward, remat's second forward (which
     # writes the chunks' states) and the backward, one kernel each
     assert len(_mosaic_calls(text, "ddstore_ssd_fwd")) == 2 * 4
@@ -348,7 +395,7 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
         "ddstore_ssd_fwd": {"forward", "recompute"},
         "ddstore_ssd_bwd": {"backward"},
         "ddstore_conv_silu_fwd": {"forward", "recompute"},
-        "ddstore_conv_silu_bwd": {"backward"}}
+        "ddstore_conv_silu_bwd": {"backward"}, **_PRODUCTS_PASSES}
     for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
                    "ddstore_flash_dkv"):
         calls = _mosaic_calls(text, kernel)
