@@ -404,12 +404,15 @@ def experts_leg(cfg, record):
     (a dense conv layer, attention and three conv layers with experts);
     ``nemotron3-nano-ep16`` at its nine one-branch layers (four Mamba-2,
     four of ungated experts, one of rotary-free attention); then that
-    configuration's scan and convolution alone."""
+    configuration's scan and convolution alone; then ``sdar-30b-a3b-ep8``
+    cut to two layers, a first step's loss and gradient under the
+    block-diffusion mask."""
     with leg("experts", record):
         _against_reference(cfg, "glm47-flash-ep8", "mla_moe_lm", layers=2)
         _against_reference(cfg, "lfm2-8b-a1b-ep4", "lfm2_moe_lm")
         _against_reference(cfg, "nemotron3-nano-ep16", "nemotron_h_lm")
         _scan_against_recurrence(cfg)
+        _diffusion_against_reference(cfg)
 
 
 def _reference_module(reference):
@@ -607,6 +610,68 @@ def _against_reference(cfg, config, reference, layers=None):
             f"experts leg, {config}, every pair held: loss {loss_err:.2e} "
             f"(allowed {rtol}), worst gradient leaf {rel[worst]:.2e} at "
             f"{worst} (allowed {gtol}), held pairs {live.tolist()}")
+
+
+def _diffusion_against_reference(cfg, config="sdar-30b-a3b-ep8", layers=2):
+    """The block-diffusion configuration cut to ``layers``, a first step
+    under the mask: the bf16 program's loss and every gradient leaf, for the
+    draw of step 0, against ``benchmarks/reference/sdar_moe_lm.py`` given
+    the same draw. On the chip the flash kernels run the mask; the
+    reference builds it dense from its rules."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddstore_tpu.models import transformer
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           config + ".json")) as f:
+        desc = json.load(f)
+    desc["num_hidden_layers"] = layers
+    if cfg["dry_run"]:
+        desc.update(desc["dry_run"])
+    ref = _reference_module("sdar_moe_lm")
+    batch, seq = cfg["experts_shape"]
+    dtype = jnp.float32 if cfg["dry_run"] else jnp.bfloat16
+    model = transformer.lm_from_description(desc, compute_dtype=dtype)
+    params = transformer.create_train_state(jax.random.key(SEED),
+                                            model)[0].params
+    a = model.arch
+    # ids below the mask token
+    tok = np.random.default_rng((SEED, 37)).integers(
+        0, a.mask_token, (batch, seq), dtype=np.int32)
+    pos = np.tile(np.arange(seq, dtype=np.int32), (batch, 1))
+    key = transformer.diffusion_key(a, 0)
+    masked, t = transformer.diffusion_noise(a, key, batch, seq)
+    (loss, loads), grads = jax.jit(jax.value_and_grad(
+        lambda p: transformer.lm_loss(model, p, tok, None, pos,
+                                      noise_key=key), has_aux=True))(params)
+    arch = dict(a._asdict(), heads=model.heads)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(
+        p, tok, masked, t, pos, arch=arch, token_block=1024)))(params)
+    norm = lambda x: float(jnp.linalg.norm(x.astype(jnp.float32)))
+    rel = {jax.tree_util.keystr(path): norm(g - w) / norm(w)
+           for (path, g), w in zip(
+               jax.tree_util.tree_flatten_with_path(grads)[0],
+               jax.tree_util.tree_leaves(want_grads)) if norm(w) > 0}
+    worst = max(rel, key=rel.get)
+    loss_err = abs(float(loss) - float(want)) / abs(float(want))
+    which, of = a.expert_share
+    held = a.n_routed_experts // of
+    say(f"    {config} b={batch} S={seq} ({2 * seq} positions, blocks of "
+        f"{a.block_length}, {int(masked.sum())} masked): loss "
+        f"{float(loss):.6f}, reference {float(want):.6f} (relative "
+        f"{loss_err:.2e}); gradient leaves {len(rel)}, relative norm of "
+        f"the difference median {sorted(rel.values())[len(rel) // 2]:.2e}, "
+        f"worst {rel[worst]:.2e} at {worst}; held experts' load "
+        f"{np.asarray(loads)[:, which * held:(which + 1) * held].tolist()}")
+    rtol, gtol = cfg["experts_tol"]
+    if loss_err > rtol or rel[worst] > gtol:
+        raise AssertionError(
+            f"experts leg, {config}: loss {loss_err:.2e} (allowed {rtol}), "
+            f"worst gradient leaf {rel[worst]:.2e} at {worst} (allowed "
+            f"{gtol})")
 
 
 def ragged_leg(store, sets, mesh, cfg, record):

@@ -165,6 +165,18 @@ def route_noaux_tc(scores: jax.Array, bias: jax.Array, top_k: int,
     return chosen, w / (total + eps if eps else total) * scaling
 
 
+def route_softmax(logits: jax.Array, top_k: int) -> Tuple[jax.Array,
+                                                         jax.Array]:
+    """Qwen3-MoE's routing (``norm_topk_prob`` true; SDAR's): a softmax
+    over all the router's ``logits`` (T, E) in float32, the ``top_k``
+    largest chosen, their probabilities renormalised over the chosen. No
+    bias, no scaling. Returns ``(chosen (T, k) int32, weights (T, k)
+    float32)``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, chosen = jax.lax.top_k(probs, top_k)
+    return chosen, w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 def routed_chunk(tokens: int, top_k: int, held: int, n_routed: int) -> int:
     """The sorted rows :class:`SharedRoutedMoe` runs its routed experts over
     at a time: the rows even routing sends to this chip's ``held`` of
@@ -367,8 +379,10 @@ class SharedRoutedMoe(nn.Module):
     ``share = (which, of)``: this chip is number ``which`` of ``of`` that
     divide the layer's ``n_routed`` experts between them, and holds the
     consecutive ``n_routed // of`` from ``which * n_routed // of``. It
-    routes over all ``n_routed`` (sigmoid scores, :func:`route_noaux_tc`),
-    computes every (token, chosen expert) pair whose expert it holds, and
+    routes over all ``n_routed`` (``scoring`` ``sigmoid``: sigmoid scores
+    and a correction bias, :func:`route_noaux_tc`; ``softmax``:
+    :func:`route_softmax`, no ``router_bias`` leaf, ``scaling`` and
+    ``route_eps`` unread), computes every (token, chosen expert) pair whose expert it holds, and
     adds the shared expert, which every chip computes alike. What the other
     chips' experts would add is not here and nothing stands in for it: on
     one chip the layer runs without its exchange. ``load`` counts the
@@ -401,6 +415,7 @@ class SharedRoutedMoe(nn.Module):
     compute_dtype: Any = jnp.bfloat16
     activation: str = "swiglu"
     shared_hidden: Optional[int] = None
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
@@ -413,6 +428,9 @@ class SharedRoutedMoe(nn.Module):
         if self.activation not in ("swiglu", "relu2"):
             raise ValueError(f"activation {self.activation!r} is not built "
                              f"here (only 'swiglu' and 'relu2')")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r} is not built here "
+                             f"(only 'sigmoid' and 'softmax')")
         gated = self.activation == "swiglu"
         held = e // of
         first = which * held
@@ -421,7 +439,7 @@ class SharedRoutedMoe(nn.Module):
         profile.count_moe_layout(
             "/".join(self.path), held=held, of=e, first=first, top_k=k,
             tokens=t, rows=rows, products=moe_gmm.PRODUCTS,
-            tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
+            scoring=self.scoring, tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
             **({"padded_to": wide} if wide != self.hidden else {}))
 
         experts = nn.initializers.variance_scaling(
@@ -434,13 +452,17 @@ class SharedRoutedMoe(nn.Module):
 
         with jax.named_scope("moe_dispatch"):
             # Router and its statistics in float32.
-            scores = jax.nn.sigmoid(nn.Dense(
-                e, use_bias=False, dtype=jnp.float32, name="router")(
-                    x.astype(jnp.float32)))
-            bias = self.param("router_bias",
-                              nn.initializers.normal(ROUTER_BIAS_STD), (e,))
-            chosen, weights = route_noaux_tc(scores, bias, k, self.scaling,
-                                             self.route_eps)
+            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              name="router")(x.astype(jnp.float32))
+            if self.scoring == "softmax":
+                chosen, weights = route_softmax(logits, k)
+            else:
+                bias = self.param(
+                    "router_bias", nn.initializers.normal(ROUTER_BIAS_STD),
+                    (e,))
+                chosen, weights = route_noaux_tc(
+                    jax.nn.sigmoid(logits), bias, k, self.scaling,
+                    self.route_eps)
             # For whoever asks with mutable=["intermediates"] (chip_smoke's
             # count of near-tie tokens); nothing otherwise.
             self.sow("intermediates", "chosen", chosen)

@@ -34,7 +34,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.attention import flash_attention, mha_reference
+from ..ops.attention import BlockDiffusion, flash_attention, mha_reference
 from ..ops.short_conv import gated_short_conv, short_conv
 from ..ops.ssd import SCAN as SSD_SCAN, ssd
 from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
@@ -299,6 +299,80 @@ class NemotronHArch(NamedTuple):
         return "experts" if self.pattern[layer] == "E" else None
 
 
+class SdarMoeArch(NamedTuple):
+    """A grouped-query-attention decoder of softmax-routed experts trained
+    by block diffusion, by the keys of its published ``config.json``
+    (``model_type`` ``sdar_moe``; the layer is Qwen3-MoE's): every layer
+    ``heads`` query heads on ``num_key_value_heads`` K/V heads of
+    ``head_dim``, q and k RMS-normed a head and rotated, then
+    ``num_experts_per_tok`` of ``n_routed_experts`` SwiGLU experts (the
+    router's width: the published ``num_experts``; this chip holds
+    ``expert_share``'s) chosen by a softmax over all of them and
+    renormalised, no shared expert, no dense layer, an untied head.
+
+    The objective is the arch's (:func:`lm_loss`, :func:`diffusion_noise`):
+    a window is cut into blocks of ``block_length``, each block draws a
+    masking probability t uniform on ``[noise_low, 1]`` and every position
+    of it is replaced by ``mask_token`` with that probability (``None``:
+    the vocabulary's last id); the trunk runs on ``[noised ; clean]``, both
+    halves at the window's positions, under the block-diffusion mask
+    (:class:`~ddstore_tpu.ops.attention.BlockDiffusion`), and the loss is
+    the cross-entropy of the masked positions against the window's own
+    tokens, weighted 1 / t, over all the window's positions. The draw of
+    step n is keyed by ``(noise_seed, n)``. ``block_length = 0`` is the
+    same trunk as a causal next-token model.
+
+    The fields are what a description sets. What the shared layers read of
+    an arch besides (:class:`Lfm2MoeArch`'s fields of those names) is the
+    same in every model of this type: constants of the class, which a
+    description that gives another value is refused for."""
+
+    num_key_value_heads: int
+    head_dim: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    block_length: int
+    noise_low: float
+    mask_token: Optional[int] = None
+    noise_seed: int = 0
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    expert_share: Tuple[int, int] = (0, 1)
+
+    router_scoring = "softmax"
+    n_shared_experts = 0
+    moe_shared_expert_intermediate_size = None
+    expert_activation = "swiglu"
+    routed_scaling_factor = 1.0      # unread by softmax routing
+    route_eps = 0.0                  # unread by softmax routing
+    bias_update_speed = 0.0          # the router has no bias
+    tie_word_embeddings = False
+    num_nextn_predict_layers = 0
+    mtp_loss_weight = 0.0
+    qk_norm = True
+    rotary = True
+
+    def mixer(self, layer: int) -> Optional[str]:
+        return "full_attention"
+
+    def mlp(self, layer: int) -> Optional[str]:
+        return "experts"
+
+
+# What a ``sdar_moe`` description sets of :class:`SdarMoeArch` under the
+# field's own name (the published keys, then the objective's), and the
+# class's constants, which it may repeat and not change.
+_SDAR_KEYS = ("num_key_value_heads", "head_dim", "moe_intermediate_size",
+              "num_experts_per_tok", "rope_theta", "rms_norm_eps",
+              "block_length", "noise_low", "mask_token", "noise_seed")
+_SDAR_FIXED = ("router_scoring", "n_shared_experts",
+               "moe_shared_expert_intermediate_size", "expert_activation",
+               "routed_scaling_factor", "route_eps", "bias_update_speed",
+               "tie_word_embeddings", "num_nextn_predict_layers",
+               "mtp_loss_weight", "qk_norm", "rotary")
+
+
 def _refuse_unless(desc: Mapping[str, Any], built) -> None:
     """Raises for a key of ``desc`` whose value is not the one built here
     (``built``: pairs of key and that value; an absent key passes)."""
@@ -375,6 +449,20 @@ def _nemotron_arch(desc: Mapping[str, Any]) -> NemotronHArch:
     return NemotronHArch(**fields)
 
 
+def _sdar_arch(desc: Mapping[str, Any]) -> SdarMoeArch:
+    _refuse_unless(desc, (
+        ("use_sliding_window", False), ("rope_scaling", None),
+        ("attention_bias", False), ("norm_topk_prob", True),
+        ("mlp_only_layers", []), ("decoder_sparse_step", 1),
+        ("hidden_act", "silu"),
+        *((k, getattr(SdarMoeArch, k)) for k in _SDAR_FIXED)))
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    return SdarMoeArch(
+        **{k: desc[k] for k in _SDAR_KEYS if k in desc},
+        n_routed_experts=int(desc["num_experts"]) * int(ep["chips"]),
+        expert_share=(int(ep["chip"]), int(ep["chips"])))
+
+
 def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     """A :class:`TransformerLM` from one description of the architecture:
     the dense block's own keys (``vocab``, ``dim``, ``heads``, ``layers``,
@@ -385,14 +473,25 @@ def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     model (``model_type`` ``lfm2_moe``, or ``layer_types`` beside
     ``conv_L_cache``; :class:`Lfm2MoeArch`) or a Mamba-2 / attention /
     expert hybrid of one-branch layers (``model_type`` ``nemotron_h``;
-    :class:`NemotronHArch`, its layers by ``hybrid_override_pattern``). In
-    all three, the key that counts the routed experts (``n_routed_experts``
+    :class:`NemotronHArch`, its layers by ``hybrid_override_pattern``) or a
+    block-diffusion model of softmax-routed experts (``model_type``
+    ``sdar_moe``; :class:`SdarMoeArch`: the published keys, and the
+    objective's ``block_length``, ``noise_low``, ``mask_token``,
+    ``noise_seed``, and no other field of the arch; refused:
+    ``use_sliding_window`` true, a ``rope_scaling``, ``attention_bias``
+    true, ``norm_topk_prob`` false, a non-empty ``mlp_only_layers``, a
+    ``decoder_sparse_step`` other than 1, ``tie_word_embeddings`` true, and
+    any other value than the class's for what every model of the type has
+    alike, ``router_scoring`` or ``n_shared_experts`` say).
+    In all four, the key that counts the routed experts (``n_routed_experts``
     / ``num_experts``) counts the experts held here, of ``expert_parallel =
     {"chips": n, "chip": i}`` chips that share each layer, and the router
     is ``chips`` times as wide. What a description asks for and is not
     built raises, naming the key and the value that is. ``kw`` are further
     ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
-    if desc.get("model_type") == "nemotron_h":
+    if desc.get("model_type") == "sdar_moe":
+        arch = _sdar_arch(desc)
+    elif desc.get("model_type") == "nemotron_h":
         arch = _nemotron_arch(desc)
     elif desc.get("model_type") == "lfm2_moe" or (
             "layer_types" in desc and "conv_L_cache" in desc):
@@ -442,9 +541,10 @@ def rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _attend(q, k, v):
-    """Causal attention over (B, S, H, D) heads as the projections write
-    them, K and V perhaps fewer heads than Q (grouped-query): ``(out,
+def _attend(q, k, v, mask=None):
+    """Causal attention (under ``mask``, a
+    :class:`~ddstore_tpu.ops.attention.BlockDiffusion`: that mask's) over
+    (B, S, H, D) heads as the projections write them, K and V perhaps fewer heads than Q (grouped-query): ``(out,
     layout)``, ``out`` (B, S, H, D) and the layout the kernels took their
     operands in. Heads of whole lanes go as they lie (``bshd``: nothing is
     transposed on the way in, out or back); a narrower head is no block of
@@ -453,10 +553,11 @@ def _attend(q, k, v):
     cannot tile raises in ``flash_attention`` rather than sliding to the S
     x S reference, which is what runs elsewhere (``reference``)."""
     on_chip = jax.default_backend() == "tpu"
+    how = {"causal": True} if mask is None else {"mask": mask}
     if on_chip and q.shape[-1] % 128 == 0:
-        return flash_attention(q, k, v, causal=True, layout="bshd")[0], "bshd"
+        return flash_attention(q, k, v, layout="bshd", **how)[0], "bshd"
     attend = flash_attention if on_chip else mha_reference
-    out = attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True)[0]
+    out = attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), **how)[0]
     return out.transpose(0, 2, 1, 3), "bhsd" if on_chip else "reference"
 
 
@@ -551,10 +652,14 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
         return t.astype(dt)
 
     q, k = prepared(q, "q_norm"), prepared(k, "k_norm")
-    out, layout = _attend(q, k, v)
-    profile.count_mixer_layout("/".join(blk.path), kind="full_attention",
-                               heads=nh, kv_heads=nkv, head_dim=hd,
-                               tokens=b * s, layout=layout)
+    # a block-diffusion arch's sequence is [noised ; clean]
+    blocks = getattr(a, "block_length", 0)
+    mask = BlockDiffusion(blocks, s // 2) if blocks else None
+    out, layout = _attend(q, k, v, mask)
+    profile.count_mixer_layout(
+        "/".join(blk.path), kind="full_attention", heads=nh, kv_heads=nkv,
+        head_dim=hd, tokens=b * s, layout=layout,
+        **({"mask": f"block_diffusion {blocks}"} if blocks else {}))
     out = out.reshape(b, s, nh * hd).astype(dt)
     with jax.named_scope("mix_out"):
         return nn.Dense(blk.dim, use_bias=False, dtype=dt,
@@ -718,6 +823,7 @@ class DecoderBlock(nn.Module):
                 n_shared=a.n_shared_experts, route_eps=a.route_eps,
                 compute_dtype=dt, activation=a.expert_activation,
                 shared_hidden=a.moe_shared_expert_intermediate_size,
+                scoring=getattr(a, "router_scoring", "sigmoid"),
                 name="moe")(h.reshape(b * s, self.dim))
             return x + y.reshape(b, s, self.dim), load
 
@@ -1015,7 +1121,7 @@ def moe_aux_sum(collections) -> jax.Array:
 def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
             fused_xent: Optional[bool] = None,
             xent_block: int = 8192, mesh: Optional[Mesh] = None,
-            tp_axis: str = "tp"):
+            tp_axis: str = "tp", noise_key: Optional[jax.Array] = None):
     """The LM training loss — THE shared path of :func:`make_train_step`
     and the pipelined step (so what the benchmark runs is what trains).
 
@@ -1035,6 +1141,11 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
     has sp > 1 they are laid out in the ring's order here, once, before the
     embedding (:func:`ring_order`); the loss is a token mean, so nothing is
     brought back.
+
+    An arch with a ``block_length`` (:class:`SdarMoeArch`) trains by block
+    diffusion, not next-token prediction: ``targets`` go unread (a position's
+    target is the window's own token), ``noise_key`` draws the step's noise
+    (:func:`diffusion_key`), and the loss is :func:`_diffusion_loss`'s.
     """
     tokens, targets, positions = ring_order(
         model.mesh, model.sp_axis, tokens, targets, positions)
@@ -1058,6 +1169,9 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
                     "accumulation); pass fused_xent=False for the f32 "
                     "Dense head", model.vocab, 2 * xent_block,
                     jnp.dtype(model.compute_dtype).name)
+    if getattr(model.arch, "block_length", 0):
+        return _diffusion_loss(model, params, tokens, positions, noise_key,
+                               fused_xent, xent_block)
     if model.arch is not None:
         return _described_loss(model, params, tokens, targets, positions,
                                fused_xent, xent_block)
@@ -1149,6 +1263,76 @@ def _described_loss(model, params, tokens, targets, positions, fused_xent,
     return loss, loads
 
 
+def diffusion_key(arch, step) -> jax.Array:
+    """The key of training step ``step``'s noise: ``(noise_seed, step)``."""
+    return jax.random.fold_in(jax.random.key(arch.noise_seed), step)
+
+
+def diffusion_noise(arch, key, batch: int, seq: int):
+    """One step's draw of the block-diffusion objective for ``batch``
+    windows of ``seq`` positions: ``(masked (batch, seq) bool, t (batch,
+    seq // block_length) float32)``. Every block draws its masking
+    probability t uniform on ``[noise_low, 1]`` (the linear schedule,
+    clipped: under ``1 / block_length`` a block seldom holds a masked
+    position and its weight 1 / t grows without bound) and every position
+    is masked with its block's t, independently. A function of the key and
+    the shape alone, so whoever has both has the step's draw."""
+    if seq % arch.block_length:
+        raise ValueError(f"windows of {seq} positions are not whole blocks "
+                         f"of block_length={arch.block_length}")
+    for_t, for_mask = jax.random.split(key)
+    t = jax.random.uniform(for_t, (batch, seq // arch.block_length),
+                           jnp.float32, arch.noise_low, 1.0)
+    masked = jax.random.uniform(for_mask, (batch, seq)) \
+        < jnp.repeat(t, arch.block_length, axis=1)
+    return masked, t
+
+
+def _diffusion_loss(model, params, tokens, positions, key, fused_xent,
+                    xent_block):
+    """``(loss, loads)`` of a block-diffusion arch (SDAR, arXiv:2510.06303,
+    with the objective and mask of BD3-LM, arXiv:2503.09573): the window
+    ``tokens`` (B, S) noised by :func:`diffusion_noise`, the trunk on
+    ``[noised ; clean]`` (2 S positions, both halves at ``positions``)
+    under the block-diffusion mask, the head over the noised half's rows
+    alone, each at its own position (no shift), and ``1 / (B S) sum over
+    masked i of CE(logits_i, tokens_i) / t_block(i)``. Rows that are not
+    masked weigh 0 and are computed all the same: the step's shapes do not
+    follow the draw."""
+    a = model.arch
+    if key is None:
+        raise ValueError("a block-diffusion arch draws its noise from "
+                         "noise_key (diffusion_key(arch, step))")
+    b, s = tokens.shape
+    mask_token = model.vocab - 1 if a.mask_token is None else a.mask_token
+    profile.count_diffusion(
+        model.name or "", block_length=a.block_length, window=s,
+        positions=2 * s, noise_low=a.noise_low, mask_token=mask_token,
+        noise_seed=a.noise_seed)
+    with jax.named_scope("diffusion_noise"):
+        masked, t = diffusion_noise(a, key, b, s)
+        both = jnp.concatenate(
+            [jnp.where(masked, mask_token, tokens), tokens], axis=1)
+        weight = masked / jnp.repeat(t, a.block_length, axis=1)
+    out, _, loads = model.apply(
+        params, both, jnp.concatenate([positions, positions], axis=1),
+        fused_xent)
+    out = out[:, :s]
+    with jax.named_scope("head"):
+        if fused_xent:
+            from ..ops.xent import fused_linear_xent
+
+            dt = model.compute_dtype
+            per = fused_linear_xent(
+                out.reshape(b * s, -1).astype(dt), _head_kernel(model, params),
+                tokens.reshape(-1), _balanced_block(model.vocab, xent_block),
+                dt).reshape(b, s)
+        else:
+            per = -jnp.take_along_axis(jax.nn.log_softmax(out, axis=-1),
+                                       tokens[..., None], axis=-1)[..., 0]
+        return (per * weight).sum() / (b * s), loads
+
+
 def _expert_layers(model: "TransformerLM"):
     """Paths of the expert layers' parameters, in the order of ``loads``."""
     a = model.arch
@@ -1170,16 +1354,19 @@ def _router_biases(model: "TransformerLM", params) -> jax.Array:
     return jnp.stack(out)
 
 
+def _updated_at(node, path, update):
+    """``node`` with the dict at ``path`` replaced by ``update`` of it."""
+    if not path:
+        return update(node)
+    return dict(node, **{path[0]: _updated_at(node[path[0]], path[1:],
+                                              update)})
+
+
 def _with_router_biases(model: "TransformerLM", params, biases):
     """``params`` with the stacked ``biases`` in their leaves' place."""
-    def put(node, path, bias):
-        if not path:
-            return dict(node, router_bias=bias)
-        return dict(node, **{path[0]: put(node[path[0]], path[1:], bias)})
-
     p = params["params"]
     for path, bias in zip(_expert_layers(model), biases):
-        p = put(p, path, bias)
+        p = _updated_at(p, path, lambda node: dict(node, router_bias=bias))
     return dict(params, params=p)
 
 
@@ -1230,6 +1417,89 @@ def balance_router_bias(model: "TransformerLM", state: "TrainState", tokens,
         params=_with_router_biases(model, state.params, biases))
 
 
+# Batches :func:`place_experts` measures the experts' loads over.
+PLACEMENT_BATCHES = 4
+
+
+def place_experts(model: "TransformerLM", state: "TrainState", tokens,
+                  positions) -> "TrainState":
+    """Set-up for a **fresh** seeded expert model, where which of the
+    router's experts a chip holds is the deployment's to choose: a
+    load-aware placement, as expert-parallel deployments make it (the
+    experts are laid out over the chips so that every chip gets about the
+    same share of the pairs). A router with seeded weights sends most
+    positions to a few experts (every hidden state shares a large common
+    part, and a block-diffusion window's masked positions share their
+    embedding), so whether those few fall among the ``held`` consecutive
+    experts of this chip, and with them how much the chip works, swings
+    with the seed several-fold. This measures each expert's load over the
+    batches ``tokens`` (n, B, S; ``PLACEMENT_BATCHES`` of them is what the
+    callers give), forward passes only (a block-diffusion arch's under the
+    noise of steps 0..n-1), splits each layer's experts over the ``of``
+    chips, the heaviest first onto the chip that has least so far, and
+    relabels them in that order: a permutation of each router's columns
+    (and of a correction bias where there is one), nothing else; a layer at
+    a time from the first, each measured once those before it are placed.
+
+    **The held set is relabelled, the matrices are not moved.** The router,
+    its choice and its weights are what they were, but the held matrices
+    stay where they lie and answer to other columns of the router than
+    before. That is the same model only while every expert's matrices are
+    an identical seeded draw, which holds for a state that has not stepped
+    and no other: a state whose step or Adam count is past 0 (trained, or
+    restored from training) raises, because there this would hand trained
+    experts to columns that were not theirs. Nothing balances the routing
+    itself."""
+    if int(state.step) or int(state.opt_state[0].count):
+        raise ValueError(
+            f"place_experts relabels the router's columns over matrices "
+            f"that stay where they lie, which is the same model on fresh "
+            f"seeded experts alone: this state is at step "
+            f"{int(state.step)}, Adam count "
+            f"{int(state.opt_state[0].count)}")
+    a = model.arch
+    which, of = a.expert_share
+    noisy = bool(getattr(a, "block_length", 0))
+
+    @jax.jit
+    def measure(params, tokens):
+        def body(i, total):
+            key = diffusion_key(a, i) if noisy else None
+            return total + lm_loss(model, params, tokens[i], tokens[i],
+                                   positions, noise_key=key)[1]
+
+        return jax.lax.fori_loop(
+            0, tokens.shape[0], body,
+            jnp.zeros((len(_expert_layers(model)), a.n_routed_experts),
+                      jnp.int32))
+
+    held = a.n_routed_experts // of
+    params = state.params
+
+    def relabelled(node, order):
+        new = dict(node, router=dict(
+            node["router"], kernel=node["router"]["kernel"][:, order]))
+        if "router_bias" in node:
+            new["router_bias"] = node["router_bias"][order]
+        return new
+
+    # A layer at a time, measured again after the layers before it are
+    # placed: what a chip's experts add goes on to the next layer, so a
+    # layer's routing follows the placement of those before it.
+    for layer, path in enumerate(_expert_layers(model)):
+        load = np.asarray(measure(params, tokens))[layer]
+        chips, got = [[] for _ in range(of)], np.zeros(of)
+        for expert in np.argsort(-load, kind="stable"):
+            to = min((c for c in range(of) if len(chips[c]) < held),
+                     key=lambda c: got[c])
+            chips[to].append(int(expert))
+            got[to] += load[expert]
+        order = np.concatenate(chips)
+        params = dict(params, params=_updated_at(
+            params["params"], path, lambda node: relabelled(node, order)))
+    return state._replace(params=params)
+
+
 # One-shot flag for the fused-xent auto-enable notice (ADVICE r3 #3).
 _FUSED_AUTO_LOGGED = False
 
@@ -1248,12 +1518,16 @@ def create_train_state(rng: jax.Array, model: TransformerLM,
     # Init through a mesh-free clone: the param structure is identical and
     # tracing ring attention would demand init shapes divisible by the
     # mesh axes.
-    tok = jnp.zeros((1, 8), jnp.int32)
+    # (a block-diffusion arch's sequence is two halves of whole blocks, each
+    # a length the kernels tile)
+    blocks = getattr(model.arch, "block_length", 0)
+    n = 2 * max(8, blocks) if blocks else 8
+    tok = jnp.zeros((1, n), jnp.int32)
     init_model = model.clone(mesh=None)
     # An MTP module's parameters exist only when it is given next tokens.
     mtp = {"next_tokens": tok} if model.arch is not None else {}
     init = lambda key: init_model.init(
-        key, tok, jnp.tile(jnp.arange(8), (1, 1)), **mtp)
+        key, tok, jnp.tile(jnp.arange(n), (1, 1)), **mtp)
     if model.arch is not None:
         # One program for every leaf: at 706 M parameters the eager
         # leaf-by-leaf init is dozens of small programs, each compiled on
@@ -1317,9 +1591,13 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
     models, where the Switch aux and capacity clipping see chunk-sized
     token sets (the same microbatching caveat as make_pp_train_step)."""
 
-    def lossf(params, tok, tgt, pos):
+    def lossf(params, tok, tgt, pos, key=None):
         return lm_loss(model, params, tok, tgt, pos,
-                       fused_xent=fused_xent, mesh=mesh)
+                       fused_xent=fused_xent, mesh=mesh, noise_key=key)
+
+    # A block-diffusion arch's step draws its noise from (noise_seed, step),
+    # a micro-step's from that split; every other model has no key.
+    noisy = bool(getattr(model.arch, "block_length", 0))
 
     # An ``arch`` model's loss comes with its expert layers' load vectors;
     # the step then returns ``(loss, loads)`` where the others return the
@@ -1329,9 +1607,10 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
 
     def ddstore_lm_train_step(state: TrainState, tokens, targets,
                               positions):
+        key = diffusion_key(model.arch, state.step) if noisy else None
         if accum_steps == 1:
             loss, grads = value_and_grad(
-                state.params, tokens, targets, positions)
+                state.params, tokens, targets, positions, key)
         else:
             if tokens.shape[0] % accum_steps:
                 raise ValueError(f"batch {tokens.shape[0]} not divisible "
@@ -1355,7 +1634,8 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
                      model.arch.n_routed_experts), jnp.int32))
             (gsum, lsum), _ = jax.lax.scan(
                 body, (zeros, lzero),
-                (split(tokens), split(targets), split(positions)))
+                (split(tokens), split(targets), split(positions),
+                 jax.random.split(key, accum_steps) if noisy else None))
             grads = jax.tree_util.tree_map(
                 lambda g, p: (g / accum_steps).astype(p.dtype),
                 gsum, state.params)

@@ -23,6 +23,10 @@ Design notes (TPU):
   counts what a call computes and fetches from the same functions the
   kernels' set-up runs on, and ``utils.profile.counters()`` keeps it per
   compiled shape. ``causal=False`` lowers as it always did;
+* the block-diffusion training mask (:class:`BlockDiffusion`: a noised and
+  a clean half in one sequence) is a description, never a dense array: its
+  grid is the same kind of enumeration over the mask's three live
+  quadrants, and only the blocks its edges cross are masked;
 * on CPU (tests) the identical kernel runs in interpreter mode;
 * the three kernels carry stable names (``ddstore_flash_fwd``,
   ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
@@ -33,7 +37,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +104,53 @@ def _live_rows(col_lo, cols, row_lo, rows, n):
             _tiles(col_lo + cols - 1 - row_lo + rows - 1, rows, n))
 
 
+class BlockDiffusion(NamedTuple):
+    """The training mask of block diffusion (BD3-LM, arXiv:2503.09573
+    section 4; SDAR, arXiv:2510.06303) as a description: the sequence is
+    ``[noised ; clean]``, two halves of ``half`` positions each, both cut
+    into blocks of ``block``. With i, j positions inside their halves: a
+    noised query i sees noised key j iff ``j // block == i // block`` (its
+    own block) and clean key j iff ``j // block < i // block`` (the blocks
+    before it); a clean query i sees clean key j iff ``j // block <= i //
+    block``; no clean query sees a noised key. ``half**2 + half * block``
+    live pairs of ``(2 half)**2``. ``block = 1`` makes every noised
+    position the last of a causal sequence whose earlier tokens are
+    clean."""
+    block: int
+    half: int
+
+
+# A tile's quadrant under the mask: noised queries on noised keys (their
+# own block: a band on the diagonal), noised on clean (the blocks before),
+# clean on clean (those and its own). Clean on noised is dead throughout.
+_NN, _NC, _CC = 0, 1, 2
+
+
+def block_diffusion_mask(mask: BlockDiffusion) -> np.ndarray:
+    """The dense ``(2 half, 2 half)`` boolean form of ``mask``, rows
+    queries: for the references and the tests, never for the kernels."""
+    blk = np.arange(mask.half) // mask.block
+    same, before = blk[None, :] == blk[:, None], blk[None, :] < blk[:, None]
+    return np.block([[same, before], [np.zeros_like(same), same | before]])
+
+
+def _mask_tiles(kind, block, q_lo, rows, k_lo, cols):
+    """``(live, full)`` of tiles of one quadrant ``kind``, rows ``[q_lo,
+    q_lo + rows)`` by columns ``[k_lo, k_lo + cols)`` in their halves' own
+    coordinates (arrays broadcast): whether any pair of the tile is live,
+    and whether all are. THE classification under the mask, of blocks over
+    the grid and of lane-wide tiles inside a block, and of the counter."""
+    qb_lo, qb_hi = q_lo // block, (q_lo + rows - 1) // block
+    kb_lo, kb_hi = k_lo // block, (k_lo + cols - 1) // block
+    diagonal = (kb_lo <= qb_hi) & (qb_lo <= kb_hi)
+    one_block = (kb_lo == qb_hi) & (qb_lo == kb_hi)
+    live = np.select([kind == _NN, kind == _NC], [diagonal, kb_lo < qb_hi],
+                     kb_lo <= qb_hi)
+    full = np.select([kind == _NN, kind == _NC], [one_block, kb_hi < qb_lo],
+                     kb_hi <= qb_lo)
+    return live, full
+
+
 class FlashGeometry(NamedTuple):
     """How one kernel tiles one call: what its set-up builds the grid, the
     index maps and the strips from, and what that costs per batch*head
@@ -117,10 +168,16 @@ class FlashGeometry(NamedTuple):
     pairs_computed: int        # pairs in the tiles a body runs over
     grid_steps: int
     steps_fetching_dead: int   # DMAs of a streamed block no live step uses
+    mask: Optional[BlockDiffusion] = None   # set: this mask, not the causal
+    blocks_live: int = 0       # under ``mask``: blocks holding a live pair
 
 
 def _steps(geo):
-    """``_enumerate`` for the call ``geo`` describes."""
+    """``_enumerate`` (``_enumerate_masked`` under a mask) for the call
+    ``geo`` describes."""
+    if geo.mask is not None:
+        return _enumerate_masked(geo.mask, geo.block_q, geo.block_k,
+                                 geo.stream)
     return _enumerate(geo.sq, geo.sk, geo.block_q, geo.block_k,
                       geo.q_offset, geo.kv_offset, geo.stream)
 
@@ -151,14 +208,97 @@ def _enumerate(sq, sk, bq, bk, q_offset, kv_offset, stream):
         keep = live | ((first_live == nq) & (iq == nq - 1))
     shift = q_offset + iq * bq - kv_offset - ik * bk
     shifts, variant = np.unique(shift[live & ~past], return_inverse=True)
+    code = _codes(keep, live, past, variant)
+    return tuple(np.asarray(a[keep], np.int32) for a in (outer, inner, code)
+                 ) + (tuple(int(x) for x in shifts),)
+
+
+def _codes(keep, live, full, variant):
+    """The step codes of ``_enumerate`` / ``_enumerate_masked`` from the
+    blocks kept as steps, the live ones, those live throughout and the
+    index of each partly live one's body."""
     code = np.full(live.shape, _NOTHING)
-    code[live & past] = _INTERIOR
-    code[live & ~past] = _DIAGONAL + variant.ravel()
+    code[live & full] = _INTERIOR
+    code[live & ~full] = _DIAGONAL + variant.ravel()
     kept = np.cumsum(keep, axis=1)
     code |= np.where(keep & (kept == 1), _FIRST, 0)
     code |= np.where(keep & (kept == kept[:, -1:]), _LAST, 0)
-    return tuple(np.asarray(a[keep], np.int32) for a in (outer, inner, code)
-                 ) + (tuple(int(x) for x in shifts),)
+    return code
+
+
+@functools.lru_cache(maxsize=256)
+def _enumerate_masked(mask, bq, bk, stream):
+    """``_enumerate`` under a :class:`BlockDiffusion` mask: the steps of
+    one kernel over the ``2 half`` positions, only the blocks holding a
+    live pair, none of which crosses a half (``bq`` and ``bk`` divide
+    ``half``). A block the mask's edges cross is ``_DIAGONAL + v``, ``v``
+    indexing the last result: the static ``(quadrant, first row - first
+    column)`` of those blocks, both in their halves' coordinates."""
+    n = 2 * mask.half
+    nq, nk = n // bq, n // bk
+    outer, inner = np.indices((nq, nk) if stream == "k" else (nk, nq))
+    iq, ik = (outer, inner) if stream == "k" else (inner, outer)
+    q_clean, k_clean = iq * bq >= mask.half, ik * bk >= mask.half
+    q_lo, k_lo = iq * bq % mask.half, ik * bk % mask.half
+    kind = np.where(k_clean, np.where(q_clean, _CC, _NC), _NN)
+    live, full = _mask_tiles(kind, mask.block, q_lo, bq, k_lo, bk)
+    live &= k_clean | ~q_clean
+    # every query has its own block's keys and every key its own block's
+    # queries: no row of the grid is dead throughout
+    assert live.any(axis=1).all()
+    keys, variant = np.unique((kind * n + q_lo - k_lo)[live & ~full],
+                              return_inverse=True)
+    code = _codes(live, live, full, variant)
+    variants = tuple((int(key + mask.half) // n,
+                      int(key + mask.half) % n - mask.half) for key in keys)
+    return tuple(np.asarray(a[live], np.int32) for a in (outer, inner, code)
+                 ) + (variants,)
+
+
+class _Band(NamedTuple):
+    """What a strip under the mask keeps of its scores: the pairs whose
+    column's block less their row's, counted from the strip's corner
+    (a block's multiple), is at most ``hi`` and, where ``lo`` is set, at
+    least ``lo``. Blocks are ``1 << log2b`` long."""
+    lo: Any
+    hi: Any
+    log2b: int
+
+
+def _band(kind, shift, log2b):
+    """The band of a strip of quadrant ``kind`` whose first row lies
+    ``shift`` (static; a block's multiple) past its first
+    column."""
+    d = shift >> log2b
+    return _Band(d if kind == _NN else None, d - (kind == _NC), log2b)
+
+
+def _masked_strips(geo, variant):
+    """``_strips`` under the mask, for a block of quadrant and shift
+    ``variant``: the same static strips, each over the contiguous run of
+    tiles on its other side that hold a live pair, ``(rows, cols, band)``
+    (``band`` None: every pair of the strip is live)."""
+    kind, shift = variant
+    block = geo.mask.block
+    bq, bk, tq, tk = geo.block_q, geo.block_k, geo.sub_q, geo.sub_k
+    q0, k0 = max(shift, 0), max(-shift, 0)
+    nq, nk = np.arange(bq // tq), np.arange(bk // tk)
+    for g in (nq if geo.stream == "k" else nk):
+        if geo.stream == "k":
+            live, full = _mask_tiles(kind, block, q0 + g * tq, tq,
+                                     k0 + nk * tk, tk)
+        else:
+            live, full = _mask_tiles(kind, block, q0 + nq * tq, tq,
+                                     k0 + g * tk, tk)
+        if not live.any():
+            continue
+        lo, hi = int(np.argmax(live)), int(len(live) - np.argmax(live[::-1]))
+        assert live[lo:hi].all()
+        rows, cols = (slice(g * tq, (g + 1) * tq), slice(lo * tk, hi * tk)) \
+            if geo.stream == "k" else (
+            slice(lo * tq, hi * tq), slice(g * tk, (g + 1) * tk))
+        yield rows, cols, None if full[lo:hi].all() else _band(
+            kind, shift + rows.start - cols.start, block.bit_length() - 1)
 
 
 def _strips(geo, shift):
@@ -167,7 +307,11 @@ def _strips(geo, shift):
     static slices of the block. A forward or dq strip is ``sub_q`` rows by
     every ``sub_k``-wide tile up to the last live one; a dkv strip is
     ``sub_k`` columns by every ``sub_q``-high tile from the first live one.
-    One matmul chain each, masked by its own shift."""
+    One matmul chain each, masked by its own shift. Under a mask ``shift``
+    is a variant of ``_enumerate_masked`` (``_masked_strips``)."""
+    if geo.mask is not None:
+        yield from _masked_strips(geo, shift)
+        return
     bq, bk, tq, tk = geo.block_q, geo.block_k, geo.sub_q, geo.sub_k
     if geo.stream == "k":
         for g in range(bq // tq):
@@ -202,8 +346,31 @@ def causal_geometry(sq: int, sk: int, blocks: Tuple[int, int],
     blocks by their strips, dead blocks nothing), ``grid_steps`` and
     ``steps_fetching_dead``."""
     (bq, bk), (tq, tk) = blocks, sub
-    geo = FlashGeometry(sq, sk, bq, bk, tq, tk, q_offset, kv_offset, stream,
-                        0, 0, 0, 0)
+    needed = np.clip(q_offset + np.arange(sq) - kv_offset + 1, 0, sk).sum()
+    return _counted(FlashGeometry(sq, sk, bq, bk, tq, tk, q_offset,
+                                  kv_offset, stream, int(needed), 0, 0, 0))
+
+
+@functools.lru_cache(maxsize=256)
+def mask_geometry(mask: BlockDiffusion, blocks: Tuple[int, int],
+                  sub: Tuple[int, int], stream: str = "k") -> FlashGeometry:
+    """``causal_geometry`` of one kernel call under ``mask``, counted the
+    same way from ``_enumerate_masked`` and ``_masked_strips``;
+    ``pairs_needed`` is the mask's live pairs, ``half**2 + half * block``,
+    ``grid_steps`` the blocks visited and ``blocks_live`` those that hold a
+    live pair."""
+    n = 2 * mask.half
+    geo = _counted(FlashGeometry(
+        n, n, *blocks, *sub, 0, 0, stream,
+        mask.half * (mask.half + mask.block), 0, 0, 0, mask))
+    what = _steps(geo)[2] & (_FIRST - 1)
+    return geo._replace(blocks_live=int((what != _NOTHING).sum()))
+
+
+def _counted(geo):
+    """``geo`` with what its kernel computes, steps over and fetches for
+    nothing filled in."""
+    bq, bk = geo.block_q, geo.block_k
     outer, inner, code, shifts = _steps(geo)
     what = code & (_FIRST - 1)
     if _static_diagonal(geo, shifts):
@@ -214,14 +381,12 @@ def causal_geometry(sq: int, sk: int, blocks: Tuple[int, int],
         in_strips = [bq * bk] * len(shifts)
     computed = (what == _INTERIOR).sum() * bq * bk + sum(
         in_strips[v - _DIAGONAL] for v in what[what >= _DIAGONAL])
-    needed = np.clip(q_offset + np.arange(sq) - kv_offset + 1, 0, sk).sum()
     # The pipeline issues a DMA when the streamed block's index changes;
     # the block then serves every step until the next change. A DMA is
     # spent on the dead when none of the steps it serves is live.
     dma = np.cumsum(np.concatenate([[True], inner[1:] != inner[:-1]]))
     serves_live = np.bincount(dma, weights=what != _NOTHING) > 0
-    return geo._replace(pairs_needed=int(needed),
-                        pairs_computed=int(computed),
+    return geo._replace(pairs_computed=int(computed),
                         grid_steps=len(code),
                         steps_fetching_dead=int((~serves_live[1:]).sum()))
 
@@ -243,9 +408,12 @@ def _sub_tile(block: int, want: int) -> int:
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = False, q_offset: int = 0,
-                  kv_offset: int = 0, scale: Optional[float] = None
+                  kv_offset: int = 0, scale: Optional[float] = None,
+                  mask: Optional[BlockDiffusion] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """Plain-XLA attention over (..., S, D); returns (out, lse in f32).
+    ``mask``: the block-diffusion mask in its dense form, over ``S = 2
+    half`` positions.
     Grouped-query: ``k`` and ``v`` (..., H_kv, S, D) with ``H_kv`` dividing
     ``q``'s H; query head h attends to K/V head ``h // (H / H_kv)`` (K and
     V are repeated here: this is the reference)."""
@@ -260,6 +428,8 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qpos = q_offset + jnp.arange(q.shape[-2])[:, None]
         kpos = kv_offset + jnp.arange(k.shape[-2])[None, :]
         s = jnp.where(kpos <= qpos, s, NEG_INF)
+    if mask is not None:
+        s = jnp.where(block_diffusion_mask(mask), s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     # Fully-masked rows (possible in ring steps) must yield out=0, lse=-inf
     # without NaNs: exp(-inf - -inf) is guarded by zeroing those rows.
@@ -277,10 +447,32 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def _causal_mask(s, shift):
     """Keep ``s[r, c]`` where global key position <= query position:
-    ``shift`` = first row's position - first column's."""
+    ``shift`` = first row's position - first column's. A :class:`_Band`
+    for ``shift``: keep what the band keeps (the block-diffusion mask)."""
+    if isinstance(shift, _Band):
+        blocks = [jax.lax.shift_right_logical(
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, axis),
+            jnp.int32(shift.log2b)) for axis in (1, 0)]
+        across = blocks[0] - blocks[1]
+        keep = across <= shift.hi
+        if shift.lo is not None:
+            keep &= across >= shift.lo
+        return jnp.where(keep, s, NEG_INF)
     col_minus_row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                      - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
     return jnp.where(col_minus_row <= shift, s, NEG_INF)
+
+
+def _traced_band(mask, q_lo, k_lo):
+    """The band of a whole block from its traced first row and column in
+    the sequence of both halves: ``_band`` where quadrant and shift are
+    the device's to work out (more variants than bodies)."""
+    log2b = mask.block.bit_length() - 1
+    q_clean, k_clean = q_lo >= mask.half, k_lo >= mask.half
+    d = ((q_lo - jnp.where(q_clean, mask.half, 0))
+         - (k_lo - jnp.where(k_clean, mask.half, 0))) >> log2b
+    hi = d - (k_clean & ~q_clean).astype(jnp.int32)
+    return _Band(jnp.where(k_clean, -2 * mask.half, d), hi, log2b)
 
 
 class _Step(NamedTuple):
@@ -338,8 +530,11 @@ def _grid_kernel(kernel, causal, geo):
                 iq, ik = outer_ref[t], inner_ref[t]
                 if geo.stream == "q":
                     iq, ik = ik, iq
-                shift = (geo.q_offset + iq * geo.block_q
-                         - geo.kv_offset - ik * geo.block_k)
+                shift = _traced_band(
+                    geo.mask, iq * geo.block_q, ik * geo.block_k) \
+                    if geo.mask is not None else (
+                    geo.q_offset + iq * geo.block_q
+                    - geo.kv_offset - ik * geo.block_k)
                 pl.when(what >= _DIAGONAL)(
                     lambda: update(_WHOLE, _WHOLE, shift))
 
@@ -660,17 +855,18 @@ def _flash_bwd(causal, scale, geos, interpret, seq_major, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _fit_block(block: int, s: int) -> int:
-    """Largest multiple of 8 that divides ``s`` and is <= ``block``
-    (0 if none — i.e. s is not a multiple of 8)."""
+def _fit_block(block: int, s: int, grain: int = 8) -> int:
+    """Largest multiple of ``grain`` (8: the sublane tile) that divides
+    ``s`` and is <= ``block`` (0 if none — i.e. s is not a multiple of
+    it)."""
     block = min(block, s)
-    for b in range(block - block % 8, 7, -8):
+    for b in range(block - block % grain, grain - 1, -grain):
         if s % b == 0:
             return b
     return 0
 
 
-def _default_blocks(causal, sq, sk, d, q_offset, kv_offset):
+def _default_blocks(causal, sq, sk, d, q_offset, kv_offset, masked=False):
     """``((block_q, block_k) forward, (block_q, block_k) dq and dkv)``
     from what a call can see; PERF.md section 6 (PR 26, and PR 27 for
     heads wider than 128) has the v5e times they were chosen from. Heads
@@ -683,14 +879,16 @@ def _default_blocks(causal, sq, sk, d, q_offset, kv_offset):
     grid step is work. Otherwise 512 x 2048 below S=8192; at it and beyond
     the backward kernels and the non-causal forward take 1024 x 1024
     (2048-wide q blocks exceed VMEM there), the causal forward stays at
-    512 x 2048: its diagonal strips want the width."""
+    512 x 2048: its diagonal strips want the width. A call under a
+    block-diffusion mask (``masked``) takes the causal sizes of its ``2
+    half`` positions, the short whole-call form apart."""
     if d > 128:
         # Chosen on the chip at width 256 (PERF.md section 6, PR 27): every
         # q/k/v/do/acc tile is twice as deep, 512 x 2048 and wider do not
         # fit VMEM at S=8192, and of what fits 1024 x 1024 is the fastest
         # for all three kernels at S=2048 and S=8192 alike.
         return (1024, 1024), (1024, 1024)
-    if causal and max(sq, sk) <= 2048:
+    if causal and not masked and max(sq, sk) <= 2048:
         short = (min(sq, 1024), sk), (sq, sk)
         if not any(_INTERIOR in _enumerate(sq, sk, *blocks, q_offset,
                                            kv_offset, "k")[2] & (_FIRST - 1)
@@ -707,9 +905,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     bwd_blocks: Optional[Tuple[int, int, int, int]] = None,
-                    interpret: Optional[bool] = None, layout: str = "bhsd"
+                    interpret: Optional[bool] = None, layout: str = "bhsd",
+                    mask: Optional[BlockDiffusion] = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """Pallas flash attention over (B, H, S, D); returns (out, lse).
+
+    ``mask``: the block-diffusion training mask as a description
+    (:class:`BlockDiffusion`; q, k and v are then the ``2 half`` positions
+    ``[noised ; clean]``, and ``causal`` and the offsets stay unset). The
+    three kernels step over the blocks that hold a live pair and no others,
+    and mask the strips the mask's edges cross; blocks are fitted to the
+    half, so none lies in two quadrants. The block length is a power of two
+    of at most a lane tile, so every strip starts on a block's edge.
 
     ``layout`` says how the caller's q, k and v lie, as an einsum's
     subscripts would: ``"bhsd"``, or ``"bshd"`` for (B, S, H, D) as a
@@ -755,7 +962,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          f"{v.shape[2 if seq_major else 1]} value heads: "
                          f"the K/V heads must be alike and divide the "
                          f"query heads")
-    fwd, bwd = _default_blocks(causal, sq, sk, d, q_offset, kv_offset)
+    grain, fit_to = 8, (sq, sk)
+    if mask is not None:
+        if causal or q_offset or kv_offset:
+            raise ValueError("a block-diffusion mask is not causal and "
+                             "takes no offsets")
+        if mask.block & (mask.block - 1) or not 0 < mask.block <= _LANES \
+                or mask.half % mask.block or sq != sk or sq != 2 * mask.half:
+            raise ValueError(
+                f"{mask} over ({sq},{sk}) positions: the block length must "
+                f"be a power of two of at most {_LANES} that divides the "
+                f"half, and q and k both halves long")
+        grain, fit_to = max(8, mask.block), (mask.half, mask.half)
+    fwd, bwd = _default_blocks(causal or mask is not None, sq, sk, d,
+                               q_offset, kv_offset, mask is not None)
     # An explicit block_q / block_k bounds all three kernels, as ever.
     fwd = (block_q or fwd[0], block_k or fwd[1])
     if bwd_blocks is None:
@@ -768,11 +988,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # divisible by 8 therefore works with the big TPU-tuned defaults
     # (e.g. sq=640 fits block_q=320); a misaligned length fails with the
     # same error on every backend, not just at TPU lowering time.
-    block_q, block_k = _fit_block(fwd[0], sq), _fit_block(fwd[1], sk)
-    bwd_blocks = tuple(_fit_block(bl, s_) for bl, s_
-                       in zip(bwd_blocks, (sq, sk, sq, sk)))
+    block_q, block_k = (_fit_block(bl, s_, grain)
+                        for bl, s_ in zip(fwd, fit_to))
+    bwd_blocks = tuple(_fit_block(bl, s_, grain) for bl, s_
+                       in zip(bwd_blocks, fit_to * 2))
     if not (block_q and block_k and all(bwd_blocks)):
-        raise ValueError(f"seq lens ({sq},{sk}) must be multiples of 8 "
+        raise ValueError(f"seq lens {fit_to} must be multiples of {grain} "
                          f"(TPU tile alignment)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -785,24 +1006,31 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             ("ddstore_flash_fwd", "k", block_q, block_k),
             ("ddstore_flash_dq", "k") + bwd_blocks[:2],
             ("ddstore_flash_dkv", "q") + bwd_blocks[2:]):
-        if causal:
+        kind, counted = "causal" if causal else "full", (
+            "pairs_needed", "pairs_computed", "grid_steps",
+            "steps_fetching_dead")
+        if causal or mask is not None:
             # A strip is _STRIP of the side its accumulator lives on; the
             # other side is cut at the lane width, up to the diagonal.
             strip = (_STRIP_WIDE if d > 128 else _STRIP)[name]
             sub = (_sub_tile(bq, strip), _sub_tile(bk, _LANES)) \
                 if stream == "k" else (
                 _sub_tile(bq, _LANES), _sub_tile(bk, strip))
-            geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
-                                  kv_offset, stream)
+            if mask is None:
+                geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
+                                      kv_offset, stream)
+            else:
+                geo = mask_geometry(mask, (bq, bk), sub, stream)
+                kind = f"blockdiff{mask.block}"
+                counted += ("blocks_live",)
         else:
             geo = _dense_geometry(sq, sk, bq, bk, stream)
         profile.count_geometry(
-            name, f"{'causal' if causal else 'full'} bh{b * h} "
+            name, f"{kind} bh{b * h} "
             f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "
             f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k} {layout} "
-            f"kv{b * h_kv}",
-            {f: getattr(geo, f) for f in (
-                "pairs_needed", "pairs_computed", "grid_steps",
-                "steps_fetching_dead")})
+            f"kv{b * h_kv}", {f: getattr(geo, f) for f in counted})
         geos.append(geo)
-    return _flash(q, k, v, causal, scale, tuple(geos), interpret, seq_major)
+    # an enumerated grid, causal or under the mask: ``_call``'s one switch
+    return _flash(q, k, v, causal or mask is not None, scale, tuple(geos),
+                  interpret, seq_major)

@@ -86,6 +86,7 @@ from typing import Deque, Dict, Iterator, List, Optional, Tuple
 __all__ = ["trace", "annotate", "step_annotate", "phase", "phases",
            "watch_compiles", "count_geometry", "count_moe_layout",
            "count_mixer_layout", "count_ring_geometry", "count_remat",
+           "count_diffusion",
            "counters", "STEP_SCOPES", "PASSES", "RECOMPUTE", "describe"]
 
 # The names a step's operations may carry in their ``op_name``, outermost
@@ -102,6 +103,9 @@ STEP_SCOPES = {
            "residual, dense or experts.",
     "head": "``TransformerLM``, ``lm_loss``: final norm, vocabulary "
             "product and loss (the MTP term's inside ``mtp``).",
+    "diffusion_noise": "``lm_loss`` of a block-diffusion arch: the step's "
+                       "draw, the noised window beside the clean one and "
+                       "the rows' weights. Forward only.",
     "optimizer": "``make_train_step``: the optimizer's update and the "
                  "router-bias rule. Its operations are the pass ``update``.",
     "mtp": "``TransformerLM``: around the multi-token-prediction module, "
@@ -199,6 +203,8 @@ _moe_layout: Dict[str, dict] = {}
 # Described layer (its module path) -> what its mixer is and works on.
 _mixer_layout: Dict[str, dict] = {}
 _ring_geometry: Dict[str, dict] = {}
+# Block-diffusion model (its module path) -> its objective's constants.
+_diffusion: Dict[str, dict] = {}
 # Block (its module path) -> whether it is rematerialised, and what is saved.
 _remat: Dict[str, dict] = {}
 
@@ -395,7 +401,10 @@ def watch_compiles() -> None:
 def count_geometry(kernel: str, call: str, counts: Dict[str, int]) -> None:
     """``ops/attention.py``, while a flash call is traced: what ``kernel``
     will do for a call of this shape, per batch*head (``pairs_needed``,
-    ``pairs_computed``, ``grid_steps``, ``steps_fetching_dead``)."""
+    ``pairs_computed``, ``grid_steps``, ``steps_fetching_dead``; a call
+    under the block-diffusion mask, ``blockdiff<B>`` in its key: the mask's
+    live pairs, the blocks its grid visits as ``grid_steps`` and, of them,
+    ``blocks_live`` that hold a live pair)."""
     with _lock:
         _geometry.setdefault(kernel, {})[call] = dict(counts)
 
@@ -405,7 +414,8 @@ def count_moe_layout(layer: str, **counts) -> None:
     chip holds (``held`` of ``of``, from ``first``), the experts a token
     takes (``top_k``), the ``tokens`` of the call and the sorted ``rows``
     its routed experts run over at a time (as many trips a step as the
-    step's held pairs need: one, under routing near even); what its grouped
+    step's held pairs need: one, under routing near even), the router's
+    ``scoring`` (``sigmoid`` or ``softmax``); what its grouped
     ``products`` ran as (``pallas``, the kernels of ``ops/moe_gmm.py``),
     their ``tiles`` (``in`` / ``out`` of the experts' width, each ``{gmm,
     gmm_t, tgmm: (tm, tk, tn)}``) and, where the experts' width was padded
@@ -420,8 +430,9 @@ def count_mixer_layout(layer: str, **counts) -> None:
     its ``heads`` and ``kv_heads`` (attention: with ``head_dim`` and the
     ``layout`` its kernels ran in) or its ``taps`` (a Mamba-2 layer's
     ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too, and what
-    its ``scan`` ran as: ``pallas``, the kernels of ``ops/ssd.py``), and
-    the ``tokens`` of the call."""
+    its ``scan`` ran as: ``pallas``, the kernels of ``ops/ssd.py``), the
+    ``tokens`` of the call and, where attention ran under another mask than
+    the causal, that ``mask``."""
     with _lock:
         _mixer_layout[layer] = dict(counts)
 
@@ -434,6 +445,17 @@ def count_ring_geometry(call: str, counts: dict) -> None:
     which no position waits for another's kernels)."""
     with _lock:
         _ring_geometry[call] = dict(counts)
+
+
+def count_diffusion(model: str, **counts) -> None:
+    """``models/transformer.py``, while a block-diffusion loss is traced:
+    ``block_length``, the ``window``'s and the trunk's ``positions``,
+    ``noise_low``, ``mask_token``, ``noise_seed``. What a step drew is a
+    function of these and its number
+    (``transformer.diffusion_noise(arch, diffusion_key(arch, step), ...)``)
+    and is recorded nowhere: the step's shapes do not follow it."""
+    with _lock:
+        _diffusion.setdefault(model, {}).update(counts)
 
 
 def count_remat(block: str, remat: bool, policy: Optional[str]) -> None:
@@ -472,7 +494,9 @@ def counters() -> dict:
     described layer traced so far (:func:`count_mixer_layout`).
     ``ring_geometry[call]``: what each ring
     position of every ring traced so far needs and computes
-    (:func:`count_ring_geometry`)."""
+    (:func:`count_ring_geometry`). ``diffusion[model]``: the objective of
+    every block-diffusion model traced so far and the draws asked about
+    (:func:`count_diffusion`)."""
     memory = _memory()
     with _lock:
         out = {"compile_s": {f: dict(d) for f, d in _compile_s.items()},
@@ -485,7 +509,8 @@ def counters() -> dict:
                "mixer_layout": {k: dict(d)
                                 for k, d in _mixer_layout.items()},
                "ring_geometry": {c: dict(d)
-                                 for c, d in _ring_geometry.items()}}
+                                 for c, d in _ring_geometry.items()},
+               "diffusion": {k: dict(d) for k, d in _diffusion.items()}}
     if memory is not None:
         out["memory"] = memory
     return out

@@ -121,6 +121,8 @@ def main():
                         "branch layers of Mamba-2, rotary-free attention "
                         "and ungated relu² experts: "
                         "benchmarks/configs/nemotron3-nano-ep16.json; "
+                        "block-diffusion training over softmax-routed "
+                        "experts: benchmarks/configs/sdar-30b-a3b-ep8.json; "
                         "with --dry-sizes their toy sizes)")
     p.add_argument("--dry-sizes", action="store_true",
                    help="with --config: overlay the file's dry_run block "
@@ -174,7 +176,8 @@ def main():
     # One description builds any architecture: the dense block (or its
     # capacity-bound MoeMlp) from the flags, or a published expert model
     # (latent attention; short convolutions among grouped-query attention;
-    # one-branch layers of Mamba-2, attention and ungated experts)
+    # one-branch layers of Mamba-2, attention and ungated experts; a
+    # block-diffusion model, whose objective is its description's too)
     # from its config's keys.
     desc = dict(vocab=args.vocab, dim=args.dim, heads=args.dim // 32,
                 layers=args.layers, experts=args.experts,
@@ -241,14 +244,24 @@ def main():
                                            state=state,
                                            accum_steps=args.accum_steps)
         batch = 2 * dp
-        if model.arch is not None:
+        if model.arch is not None and getattr(
+                model.arch, "router_scoring", "sigmoid") == "sigmoid":
             # A seeded router is far from the balance a model in training
-            # keeps: bring the correction biases there on the first windows.
+            # keeps: bring the correction biases there on the first windows
+            # (a softmax router has no such bias and no such rule).
             n = min(4, len(windows) // batch)
             cut = lambda a: jnp.asarray(a[:n * batch]).reshape(
                 n, batch, args.seq)
             state = transformer.balance_router_bias(
                 model, state, cut(windows), cut(nexts),
+                jnp.tile(jnp.arange(args.seq, dtype=jnp.int32), (batch, 1)))
+        elif model.arch is not None:
+            # No bias to move: the deployment chooses which experts this
+            # chip holds, by their load on the first windows.
+            n = min(transformer.PLACEMENT_BATCHES, len(windows) // batch)
+            state = transformer.place_experts(
+                model, state, jnp.asarray(windows[:n * batch]).reshape(
+                    n, batch, args.seq),
                 jnp.tile(jnp.arange(args.seq, dtype=jnp.int32), (batch, 1)))
 
     sampler = DistributedSampler(len(ds), store.world_group.size,
@@ -296,6 +309,9 @@ def main():
                 for layer, mix in profile.counters()["mixer_layout"].items():
                     print(f"layer {layer}: " + " ".join(
                         f"{k}={v}" for k, v in mix.items()), flush=True)
+                for name, d in profile.counters()["diffusion"].items():
+                    print(f"block diffusion {name or 'lm'}: " + " ".join(
+                        f"{k}={v}" for k, v in d.items()), flush=True)
                 for call, geo in profile.counters()["ring_geometry"].items():
                     print(f"mesh {dict(mesh.shape)} ring {call}: "
                           f"{geo['order']} order, {geo['chunk_rows']} rows "

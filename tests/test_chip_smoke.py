@@ -59,11 +59,12 @@ def test_chip_smoke_dry_run(n_dev):
     for name in ("store", kernel, "ragged"):
         assert f"--- leg {name} ok" in proc.stdout
     if n_dev == 1:
-        # the experts leg: the three described configurations against their
+        # the experts leg: the described configurations against their
         # references, then the scan and the convolution alone
         assert "--- leg experts ok" in proc.stdout
         for line in ("glm47-flash-ep8 b=1", "lfm2-8b-a1b-ep4 b=1",
-                     "nemotron3-nano-ep16 b=1", "== the recurrence in float32",
+                     "nemotron3-nano-ep16 b=1", "sdar-30b-a3b-ep8 b=1",
+                     "== the recurrence in float32",
                      "conv + bias + silu kernels"):
             assert line in proc.stdout, line
 

@@ -205,7 +205,7 @@ def test_compile_counters_are_kept_per_function(monkeypatch, tmp_path):
     assert set(profile.counters()) == {"compile_s", "compile_cache", "remat",
                                        "memory", "flash_geometry",
                                        "moe_layout", "mixer_layout",
-                                       "ring_geometry"}
+                                       "ring_geometry", "diffusion"}
 
 
 def test_summary_names_what_it_measures():

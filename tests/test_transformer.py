@@ -133,6 +133,8 @@ EMITS = {
     "nemotron_h": COMMON | {"mix_norm", "mamba_mixer", "mamba_conv",
                             "short_conv", "ssd", "shared_expert",
                             "moe_dispatch", "moe_experts", "recompute"},
+    "sdar_moe": COMMON | {"mix_norm", "moe_dispatch", "moe_experts",
+                          "recompute", "diffusion_noise"},
 }
 
 
@@ -219,7 +221,7 @@ def assert_the_layout_names_the_products(entry, d, hidden):
             assert entry["rows"] % tm == 0 and k % tk == 0 and n % tn == 0
 
 
-def test_the_four_architectures_emit_the_vocabulary_between_them():
+def test_the_architectures_emit_the_vocabulary_between_them():
     kernels = {n for n in profile.STEP_SCOPES if n.startswith("ddstore_")}
     assert set().union(*EMITS.values()) | kernels == set(profile.STEP_SCOPES)
     assert len(kernels) == 11

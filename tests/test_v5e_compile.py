@@ -6,8 +6,8 @@ around its kernels, the gated short convolution's two kernels, the expert
 layer's grouped products (``ops/moe_gmm.py``'s kernels at the three
 configurations' widths), the Mamba-2
 convolution's and scan's two kernels each, and the whole step of the
-``lfm2-8b-a1b-ep4.s8192.b4`` and ``nemotron3-nano-ep16.s8192`` cells
-against the chip's memory. Nothing
+``lfm2-8b-a1b-ep4.s8192.b4``, ``nemotron3-nano-ep16.s8192`` and
+``sdar-30b-a3b-ep8.s8192.b1`` cells against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
 such test lives in this one file, and the topology is described inside a
 fixture: one process at a time may load the TPU's library
@@ -404,6 +404,69 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
             operands = ln.split("custom-call(")[1]
             assert "bf16[2,8192,4096]" in operands, ln            # q
             assert operands.count("bf16[2,8192,256]") >= 2, ln    # k and v
+
+
+def test_the_sdar_cell_step_fits_the_chip(one_chip, no_compile_cache,
+                                          monkeypatch):
+    """``sdar-30b-a3b-ep8.s8192.b1``'s whole train step at its published
+    widths, one window of 8,192 tokens = 16,384 positions, compiled for the
+    described chip: 645,623,296 parameters (the configuration file's own
+    count), arguments + temporaries inside the v5e's 16.91 GB; its
+    attention through the sequence-major kernels under the block-diffusion
+    mask, one call a layer over both halves, 32 heads of 128 with K and V
+    at their own 4 heads; its experts through grouped products at 768, six
+    lane tiles and no padding. The model asks the backend which attention
+    to run; the test says TPU."""
+    import json
+    import os
+
+    import optax
+
+    from ddstore_tpu.models import transformer as T
+    from ddstore_tpu.utils import profile
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "sdar-30b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    model = T.lm_from_description(cfg, compute_dtype=jnp.bfloat16)
+    lr = optax.linear_schedule(0.0, cfg["lr"], cfg["lr_warmup_steps"])
+    state = jax.eval_shape(
+        lambda k: T.create_train_state(k, model, lr=lr)[0],
+        jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(state.params)) \
+        == cfg["parameters"] == 645_623_296
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    step = T.make_train_step(model, optax.adam(lr))
+    compiled = step.lower(on_chip(state), tok, tok, tok).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 7.7e9 < total < 16.91e9, total
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert "bf16[16,2048,768]" in text and "bf16[16,768,2048]" in text
+    assert _kernel_passes(text) == {
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
+        "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
+                   "ddstore_flash_dkv"):
+        calls = _mosaic_calls(text, kernel)
+        assert len(calls) == 6, kernel
+        for ln in calls:
+            operands = ln.split("custom-call(")[1]
+            assert "bf16[1,16384,4096]" in operands, ln            # q
+            assert operands.count("bf16[1,16384,512]") >= 2, ln    # k and v
+        # no dead block is a step of any grid
+        geo, = [c for call, c in profile.counters()["flash_geometry"][
+            kernel].items() if call.startswith("blockdiff4 bh32 q16384")]
+        assert geo["grid_steps"] == geo["blocks_live"]
+        assert geo["pairs_needed"] == 8192 * 8192 + 4 * 8192
+        assert geo["pairs_computed"] < 1.13 * geo["pairs_needed"]
 
 
 @pytest.mark.parametrize("b,s,most", [(8, 2048, 7), (2, 8192, 8)])
