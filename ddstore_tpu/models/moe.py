@@ -49,7 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import moe_gmm
+from ..ops import moe_combine, moe_gmm
 from ..utils import profile
 
 # The router's correction bias is a seeded, non-zero leaf: its trained
@@ -187,63 +187,51 @@ def routed_chunk(tokens: int, top_k: int, held: int, n_routed: int) -> int:
     return min(pairs, -(-3 * pairs * held // (2 * n_routed * 1024)) * 1024)
 
 
-def _pairs_rows(rows, rank, live):
-    """``rows[rank]`` with the pairs that are not among the first ``live``
-    of ``rows`` (C, d) set to zero (selected away, never multiplied), choice
-    first: (k, T, d) from ``rank`` (T, k), which may point before or past
-    ``rows``; those pairs read some row and lose it. One gather of T rows a
-    choice: with k between T and d the chip re-tiles the whole result."""
-    rank = rank.T
-    picked = rows.at[jnp.clip(rank, 0, len(rows) - 1)].get(
-        mode="promise_in_bounds")
-    return jnp.where(((rank >= 0) & (rank < live))[..., None], picked, 0)
-
-
 @jax.custom_vjp
-def _to_experts(x, head, rank, live):
+def _to_experts(x, head, rank, sizes):
     """Rows of ``x`` (T, d) laid out for the grouped product: row r is the
     token of pair ``head[r]`` (pairs are (token, choice), k a token; a
     ``head`` past the last pair is padding). ``rank`` (T, k) is the inverse
-    (the row of each pair, counted from ``head``'s first); the first
-    ``live`` rows are pairs whose experts are held here. The transpose is
-    written out as a gather through ``rank``, not left to autodiff's
+    (the row of each pair, counted from ``head``'s first); ``sizes`` the
+    rows of each held expert among these, the first ``sizes.sum()`` rows
+    the pairs whose experts are held here. The transpose is
+    ``ops/moe_combine.py``'s kernel with unit weights, not autodiff's
     scatter-add, and reads only the held pairs' rows: the others'
     cotangents are not the grouped product's to define."""
     return x.at[head // rank.shape[1]].get(mode="clip")
 
 
-def _to_experts_fwd(x, head, rank, live):
-    return _to_experts(x, head, rank, live), (rank, live)
+def _to_experts_fwd(x, head, rank, sizes):
+    return _to_experts(x, head, rank, sizes), (rank, sizes)
 
 
 def _to_experts_bwd(res, dxs):
-    rank, live = res
-    dx = _pairs_rows(dxs, rank, live).astype(jnp.float32)
-    return dx.sum(axis=0).astype(dxs.dtype), None, None, None
+    rank, sizes = res
+    return (moe_combine.moe_combine(dxs, rank, sizes, dtype=dxs.dtype),
+            None, None, None)
 
 
 _to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
 
 
 @jax.custom_vjp
-def _from_experts(ys, w, head, rank, live):
+def _from_experts(ys, w, head, rank, sizes):
     """``y[t] = sum_j w[t, j] ys[rank[t, j]]`` over the held pairs among
     these rows, float32: the weighted way back from the grouped product's
-    rows (same layout as :func:`_to_experts`). Rows of absent experts are
-    selected away, never multiplied by zero. Gathers of wide rows both
-    ways; the weights' cotangent is taken row by row in the sorted layout,
-    where only ``len(head)`` rows are."""
-    return (_pairs_rows(ys, rank, live).astype(jnp.float32)
-            * w.T[..., None]).sum(axis=0)
+    rows (same layout as :func:`_to_experts`), ``ops/moe_combine.py``'s
+    kernel, which reads the held pairs' rows and no others. The weights'
+    cotangent is taken row by row in the sorted layout, where only
+    ``len(head)`` rows are."""
+    return moe_combine.moe_combine(ys, rank, sizes, w)
 
 
-def _from_experts_fwd(ys, w, head, rank, live):
-    return _from_experts(ys, w, head, rank, live), (ys, w, head, rank, live)
+def _from_experts_fwd(ys, w, head, rank, sizes):
+    return _from_experts(ys, w, head, rank, sizes), (ys, w, head, rank, sizes)
 
 
 def _from_experts_bwd(res, dy):
-    ys, w, head, rank, live = res
-    held = jnp.arange(len(head)) < live
+    ys, w, head, rank, sizes = res
+    held = jnp.arange(len(head)) < sizes.sum()
     dyr = dy.at[head // rank.shape[1]].get(mode="clip")
     dys = jnp.where(
         held[:, None],
@@ -276,10 +264,9 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
         ends = jnp.cumsum(sizes)
         inside = lambda row: jnp.clip(row, start, start + rows)
         here = inside(ends) - inside(ends - sizes)  # of each held expert
-        live = here.sum()
         head = jax.lax.dynamic_slice_in_dim(order, start, rows)
         local = rank - start
-        xs = _to_experts(x, head, local, live)
+        xs = _to_experts(x, head, local, here)
         with jax.named_scope("moe_experts"):
             *w_in, w_down = (w.astype(x.dtype) for w in ws)
             # the experts' width in whole lane tiles, on the copies in the
@@ -299,7 +286,7 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
             else:
                 h = jnp.square(nn.relu(product(xs, w_in[0])))
             ys = product(h, w_down)
-        return _from_experts(ys, weights, head, local, live)
+        return _from_experts(ys, weights, head, local, here)
 
 
 def _over_live_rows(rows, order, sizes, trip):
@@ -395,9 +382,10 @@ class SharedRoutedMoe(nn.Module):
     contract, the work of the rows each expert got and no more, on copies
     of the held matrices in the compute type whose experts' width is
     padded with zeros to whole lane tiles: PERF.md section 6, PR 36).
-    Shapes are static, and the gathers, SwiGLU and
-    weighted sums around the products cost what the buffer's length is,
-    not what the routing fills; so the routed part (:func:`_routed`) walks
+    Shapes are static, and the gathers and SwiGLU around the products cost
+    what the buffer's length is, not what the routing fills (the way back
+    to the tokens, ``ops/moe_combine.py``'s kernel, reads the held pairs'
+    rows alone); so the routed part (:func:`_routed`) walks
     the sorted rows in trips of :func:`routed_chunk` rows, as many as the
     step's held pairs need, counted on the device: one under routing near
     even, all ``T k`` rows when every pair lands here. No pair is ever
@@ -439,6 +427,7 @@ class SharedRoutedMoe(nn.Module):
         profile.count_moe_layout(
             "/".join(self.path), held=held, of=e, first=first, top_k=k,
             tokens=t, rows=rows, products=moe_gmm.PRODUCTS,
+            combine=moe_combine.COMBINE,
             scoring=self.scoring, tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
             **({"padded_to": wide} if wide != self.hidden else {}))
 

@@ -130,7 +130,9 @@ STEP_SCOPES = {
     "shared_expert": "inside ``mlp``: ``SharedRoutedMoe``'s shared "
                      "expert, products and activation.",
     "moe_dispatch": "inside ``mlp``, ``models/moe.py``: router, top-k, "
-                    "sorts, the gathers to and from the experts' rows.",
+                    "sorts, the gathers to and from the experts' rows (the "
+                    "way back, and the way there's transpose, is the "
+                    "kernel ``ddstore_moe_combine``).",
     "moe_experts": "inside ``moe_dispatch``: the grouped products (the "
                    "kernels of ``ops/moe_gmm.py``, which keep the name), "
                    "the activation between them and the held matrices' "
@@ -161,6 +163,11 @@ STEP_SCOPES = {
                        "transposed).",
     "ddstore_moe_tgmm": "``ops/moe_gmm.py``: the experts' matrices' "
                         "cotangent.",
+    "ddstore_moe_combine": "``ops/moe_combine.py``, inside ``moe_dispatch``: "
+                           "the weighted way back from the experts' rows to "
+                           "the tokens, and with unit weights the transpose "
+                           "of the way there, reading the held pairs' rows "
+                           "alone.",
 }
 # The marker on a recomputation JAX does not label (``nn.remat``'s own is
 # ``rematted_computation``).
@@ -416,7 +423,9 @@ def count_moe_layout(layer: str, **counts) -> None:
     its routed experts run over at a time (as many trips a step as the
     step's held pairs need: one, under routing near even), the router's
     ``scoring`` (``sigmoid`` or ``softmax``); what its grouped
-    ``products`` ran as (``pallas``, the kernels of ``ops/moe_gmm.py``),
+    ``products`` ran as (``pallas``, the kernels of ``ops/moe_gmm.py``) and
+    its way back from the experts' rows, ``combine`` (``pallas``,
+    ``ops/moe_combine.py``'s kernel),
     their ``tiles`` (``in`` / ``out`` of the experts' width, each ``{gmm,
     gmm_t, tgmm: (tm, tk, tn)}``) and, where the experts' width was padded
     to whole lane tiles, ``padded_to``."""

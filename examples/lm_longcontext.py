@@ -38,7 +38,9 @@ def expert_rows(loads) -> str:
     the expert layers: the last step's largest over mean load, the share
     of layer-steps whose held pairs fit one trip over the sorted rows
     (``counters()["moe_layout"]``: every layer of a model has the same),
-    and the held pairs over the rows computed for them."""
+    the held pairs over the rows computed for them, and over the layer's
+    ``T k`` pairs, mean over layers and steps: the share of the pairs'
+    rows the way back (``ddstore_moe_combine``) read."""
     import numpy as np
 
     from ddstore_tpu.utils import profile
@@ -52,7 +54,10 @@ def expert_rows(loads) -> str:
             f" one trip of {rows} sorted rows (of "
             f"{lay['tokens'] * lay['top_k']}) in "
             f"{float((trips == 1).mean()):.3f} of layer-steps,"
-            f" live/computed rows={live.sum() / (trips * rows).sum():.3f}")
+            f" live/computed rows={live.sum() / (trips * rows).sum():.3f}"
+            f" way back ({lay['combine']}) read "
+            f"{float(live.mean()) / (lay['tokens'] * lay['top_k']):.4f}"
+            f" of the T k pairs' rows")
 
 
 def main():

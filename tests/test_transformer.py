@@ -204,6 +204,10 @@ def assert_the_products_kernels_passes(found, replayed=True):
     got = passes_of(found, "ddstore_moe_gmm")
     assert every - (set() if replayed else {"recompute"}) <= got <= every
     assert passes_of(found, "ddstore_moe_tgmm") == {"backward"}
+    # the way back in the forward (``_routed_bwd``'s replay of it is dead:
+    # the compiled step drops it), the way there's transpose backward
+    assert passes_of(found, "ddstore_moe_combine") == {"forward",
+                                                         "backward"}
 
 
 def assert_the_layout_names_the_products(entry, d, hidden):
@@ -211,7 +215,7 @@ def assert_the_layout_names_the_products(entry, d, hidden):
     as, the tiles of each, and the width the experts' was padded to."""
     from ddstore_tpu.ops import moe_gmm
 
-    assert entry["products"] == "pallas"
+    assert entry["products"] == "pallas" and entry["combine"] == "pallas"
     wide = moe_gmm.padded(hidden)
     assert entry.get("padded_to") == (wide if wide != hidden else None)
     assert set(entry["tiles"]) == {"in", "out"}
@@ -224,7 +228,7 @@ def assert_the_layout_names_the_products(entry, d, hidden):
 def test_the_architectures_emit_the_vocabulary_between_them():
     kernels = {n for n in profile.STEP_SCOPES if n.startswith("ddstore_")}
     assert set().union(*EMITS.values()) | kernels == set(profile.STEP_SCOPES)
-    assert len(kernels) == 11
+    assert len(kernels) == 12
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])

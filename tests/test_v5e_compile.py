@@ -4,7 +4,8 @@ width 256 (VMEM), at the ring's call shapes on four chips and with
 grouped K/V heads, the latent-attention mixer with the copies XLA puts
 around its kernels, the gated short convolution's two kernels, the expert
 layer's grouped products (``ops/moe_gmm.py``'s kernels at the three
-configurations' widths), the Mamba-2
+configurations' widths) and its way back (``ops/moe_combine.py``'s kernel
+at the four configurations' shapes), the Mamba-2
 convolution's and scan's two kernels each, and the whole step of the
 ``lfm2-8b-a1b-ep4.s8192.b4``, ``nemotron3-nano-ep16.s8192`` and
 ``sdar-30b-a3b-ep8.s8192.b1`` cells against the chip's memory. Nothing
@@ -151,6 +152,37 @@ def test_grouped_product_kernels_fit_the_chip_at_the_cells_shapes(
     assert len(_mosaic_calls(text, "ddstore_moe_tgmm")) == 2
 
 
+@pytest.mark.parametrize("tokens,k,held,of,d", [
+    (16384, 8, 16, 128, 2048), (32768, 4, 8, 32, 2048),
+    (16384, 6, 8, 128, 2688), (16384, 4, 8, 64, 2048)],
+    ids=["sdar-30b-a3b-ep8", "lfm2-8b-a1b-ep4", "nemotron3-nano-ep16",
+         "glm47-flash-ep8"])
+def test_the_way_back_kernel_fits_the_chip_at_the_cells_shapes(
+        one_chip, no_compile_cache, tokens, k, held, of, d):
+    """``ddstore_moe_combine`` over a trip of each configuration, with
+    float32 weights (the way back: three products a block) and with unit
+    weights into the rows' type (the way there's transpose), at the tiles
+    the rule gives: its two stages, sized for every pair of a tile live,
+    fit VMEM, and its DMAs move whole tiles of the rows."""
+    from ddstore_tpu.models.moe import routed_chunk
+    from ddstore_tpu.ops import moe_combine
+
+    shape = lambda dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dt, sharding=one_chip)
+
+    def f(rows, rank, sizes, w):
+        back = moe_combine.moe_combine(rows, rank, sizes, w, interpret=False)
+        there = moe_combine.moe_combine(rows, rank, sizes,
+                                        dtype=rows.dtype, interpret=False)
+        return back, there
+
+    text = jax.jit(f).lower(
+        shape((routed_chunk(tokens, k, held, of), d)),
+        shape((tokens, k), jnp.int32), shape((held,), jnp.int32),
+        shape((tokens, k), jnp.float32)).compile().as_text()
+    assert len(_mosaic_calls(text, "ddstore_moe_combine")) == 2
+
+
 def test_gqa_kernels_lower_with_kv_at_their_own_heads(one_chip,
                                                       no_compile_cache):
     """The LFM2 cell's one attention call a step: 32 query heads on 8 K/V
@@ -293,9 +325,12 @@ def _kernel_passes(text):
 
 # The grouped products' kernels in a step: the forward, ``_routed_bwd``'s
 # replay (``nn.remat``'s second forward needs none: the rule keeps the
-# layer's inputs and nothing of its products) and the transposed side.
+# layer's inputs and nothing of its products) and the transposed side; the
+# way back from the experts' rows in the forward (its replay is dead) and,
+# with unit weights, as the way there's transpose.
 _PRODUCTS_PASSES = {"ddstore_moe_gmm": {"forward", "recompute", "backward"},
-                    "ddstore_moe_tgmm": {"backward"}}
+                    "ddstore_moe_tgmm": {"backward"},
+                    "ddstore_moe_combine": {"forward", "backward"}}
 
 
 def _mosaic_calls(text, kernel):
