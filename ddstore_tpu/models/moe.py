@@ -33,7 +33,7 @@ Routing details:
 framework covers the full dp/tp/pp/sp/ep set.)
 
 :class:`SharedRoutedMoe` is the other expert layer, the one a published
-width can instantiate: shared + routed experts (SwiGLU, or ungated
+width can instantiate: shared + routed experts (SwiGLU, ReGLU, or ungated
 relu²) under ``noaux_tc`` sigmoid routing with no dropped token, as one
 expert-parallel chip's share (told which experts it holds, it routes over all of them and
 computes its own part). ``MoeMlp`` stays what ``decode.py``,
@@ -249,7 +249,12 @@ def _from_experts_bwd(res, dy):
 _from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
 
 
-def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
+# A gated expert's gate: ``down(gate(w_gate x) * w_up x)``.
+_GATES = {"swiglu": nn.silu, "reglu": nn.relu}
+
+
+def routed_rows(rows, start, x, weights, order, rank, sizes, ws,
+                activation="swiglu"):
     """What the sorted rows ``[start, start + rows)`` (``rows`` static) add
     to the routed experts' part of the layer, (T, d) float32: the weighted
     sum over each token's held pairs among them. ``x`` (T, d) in the
@@ -257,8 +262,9 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
     expert (at least ``start + rows`` long: padded past the last pair),
     ``rank`` (T, k) its inverse, ``sizes`` (held,) the rows of each held
     expert, ``ws`` the expert weights as the parameters are: ``(w_gate,
-    w_up, w_down)`` of SwiGLU experts, ``silu(gate) * up``, or ``(w_up,
-    w_down)`` of ungated ones, ``relu(up)^2``. One trip of
+    w_up, w_down)`` of gated experts (``activation`` ``swiglu``, ``silu(gate)
+    * up``, or ``reglu``, ``relu(gate) * up``), or ``(w_up, w_down)`` of
+    ungated ones (``relu2``), ``relu(up)^2``. One trip of
     :func:`_routed`."""
     with jax.named_scope("moe_dispatch"):
         ends = jnp.cumsum(sizes)
@@ -282,7 +288,8 @@ def routed_rows(rows, start, x, weights, order, rank, sizes, ws):
                 moe_gmm.moe_gmm, sizes=here,
                 steps=moe_gmm.row_steps(here, rows))
             if len(w_in) == 2:
-                h = nn.silu(product(xs, w_in[0])) * product(xs, w_in[1])
+                h = _GATES[activation](product(xs, w_in[0])) \
+                    * product(xs, w_in[1])
             else:
                 h = jnp.square(nn.relu(product(xs, w_in[0])))
             ys = product(h, w_down)
@@ -313,8 +320,8 @@ def _over_live_rows(rows, order, sizes, trip):
                                   (jnp.int32(1), trip(order, 0)))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(rows, x, weights, order, rank, sizes, ws):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(rows, activation, x, weights, order, rank, sizes, ws):
     """The routed experts' part of the layer, (T, d) float32, over as many
     sorted rows as this step's routing fills: :func:`routed_rows` in trips
     of ``rows`` (static) until every live row is done, so every held pair
@@ -324,14 +331,15 @@ def _routed(rows, x, weights, order, rank, sizes, ws):
     computes each trip's forward again, as ``nn.remat`` around the block
     does for everything else."""
     return _over_live_rows(rows, order, sizes, lambda order, start: (
-        routed_rows(rows, start, x, weights, order, rank, sizes, ws)))
+        routed_rows(rows, start, x, weights, order, rank, sizes, ws,
+                    activation)))
 
 
-def _routed_fwd(rows, *args):
-    return _routed(rows, *args), args
+def _routed_fwd(rows, activation, *args):
+    return _routed(rows, activation, *args), args
 
 
-def _routed_bwd(rows, args, dy):
+def _routed_bwd(rows, activation, args, dy):
     x, weights, order, rank, sizes, ws = args
 
     def back(order, start):
@@ -341,7 +349,8 @@ def _routed_bwd(rows, args, dy):
         with jax.named_scope(profile.RECOMPUTE):
             pull = jax.vjp(
                 lambda x, weights, ws: routed_rows(
-                    rows, start, x, weights, order, rank, sizes, ws),
+                    rows, start, x, weights, order, rank, sizes, ws,
+                    activation),
                 x, weights, ws)[1]
         return pull(dy)
 
@@ -358,8 +367,9 @@ class SharedRoutedMoe(nn.Module):
     ``n_shared = 0`` is a layer of routed experts alone: no shared
     parameters and no shared products. ``activation`` is the experts' form,
     routed and shared alike: ``swiglu`` (three matrices an expert,
-    ``down(silu(gate x) * up x)``) or ``relu2`` (two and no gate,
-    ``down(relu(up x)^2)``: no ``w_gate`` / ``shared_gate`` leaves). The
+    ``down(silu(gate x) * up x)``), ``reglu`` (three, ``down(relu(gate x) *
+    up x)``) or ``relu2`` (two and no gate, ``down(relu(up x)^2)``: no
+    ``w_gate`` / ``shared_gate`` leaves). The
     shared expert is ``n_shared * hidden`` wide, or ``shared_hidden`` where
     its width is a key of its own.
 
@@ -391,6 +401,10 @@ class SharedRoutedMoe(nn.Module):
     even, all ``T k`` rows when every pair lands here. No pair is ever
     dropped, whatever the routing; a layer that holds every expert takes
     its rows at once.
+
+    ``logits`` (T, n_routed) float32, where given: the router's, taken by
+    the caller from another input than the rows multiplied (a router placed
+    before attention); the layer then has no ``router`` leaf of its own.
     """
 
     n_routed: int
@@ -406,20 +420,21 @@ class SharedRoutedMoe(nn.Module):
     scoring: str = "sigmoid"
 
     @nn.compact
-    def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x, logits: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
         t, d = x.shape
         e, k, dt = self.n_routed, self.top_k, self.compute_dtype
         which, of = self.share
         if e % of or not 0 <= which < of:
             raise ValueError(f"share {self.share} does not divide "
                              f"{e} routed experts")
-        if self.activation not in ("swiglu", "relu2"):
+        if self.activation not in ("swiglu", "reglu", "relu2"):
             raise ValueError(f"activation {self.activation!r} is not built "
-                             f"here (only 'swiglu' and 'relu2')")
+                             f"here (only 'swiglu', 'reglu' and 'relu2')")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring {self.scoring!r} is not built here "
                              f"(only 'sigmoid' and 'softmax')")
-        gated = self.activation == "swiglu"
+        gated = self.activation in _GATES
         held = e // of
         first = which * held
         rows = routed_chunk(t, k, held, e)
@@ -428,7 +443,9 @@ class SharedRoutedMoe(nn.Module):
             "/".join(self.path), held=held, of=e, first=first, top_k=k,
             tokens=t, rows=rows, products=moe_gmm.PRODUCTS,
             combine=moe_combine.COMBINE,
-            scoring=self.scoring, tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
+            scoring=self.scoring, activation=self.activation,
+            early_router=logits is not None,
+            tiles=moe_gmm.layer_tiles(rows, d, wide, dt),
             **({"padded_to": wide} if wide != self.hidden else {}))
 
         experts = nn.initializers.variance_scaling(
@@ -441,8 +458,9 @@ class SharedRoutedMoe(nn.Module):
 
         with jax.named_scope("moe_dispatch"):
             # Router and its statistics in float32.
-            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
-                              name="router")(x.astype(jnp.float32))
+            if logits is None:
+                logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                                  name="router")(x.astype(jnp.float32))
             if self.scoring == "softmax":
                 chosen, weights = route_softmax(logits, k)
             else:
@@ -464,7 +482,8 @@ class SharedRoutedMoe(nn.Module):
                                 stable=True).astype(jnp.int32)
             rank = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
             sizes = jax.lax.dynamic_slice_in_dim(load, first, held)
-        y = _routed(rows, x.astype(dt), weights, order, rank, sizes, ws)
+        y = _routed(rows, self.activation, x.astype(dt), weights, order,
+                    rank, sizes, ws)
 
         if not self.n_shared:
             return y.astype(x.dtype), load
@@ -474,7 +493,7 @@ class SharedRoutedMoe(nn.Module):
                                        name=name)
         with jax.named_scope("shared_expert"):
             if gated:
-                hs = nn.silu(lin(wide, "shared_gate")(x)) \
+                hs = _GATES[self.activation](lin(wide, "shared_gate")(x)) \
                     * lin(wide, "shared_up")(x)
             else:
                 hs = jnp.square(nn.relu(lin(wide, "shared_up")(x)))

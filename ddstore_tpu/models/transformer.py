@@ -360,6 +360,84 @@ class SdarMoeArch(NamedTuple):
         return "experts"
 
 
+class SmallThinkerArch(NamedTuple):
+    """A decoder of grouped-query attention, window and full mixed, over
+    softmax-routed ReGLU experts whose router reads the layer's input before
+    attention, by the keys of its published ``config.json`` (PowerInfer
+    SmallThinker; the catalog's row gives no ``model_type``): every layer
+    ``heads`` query heads on ``num_key_value_heads`` K/V heads of
+    ``head_dim``, no q/k norm; layer i rotates q and k on the whole head
+    width where ``rope_layout[i]`` is 1 (no position step at all where it is
+    0: NoPE) and sees only the ``sliding_window`` keys up to its own where
+    ``sliding_window_layout[i]`` is 1 (causal where it is 0). Then
+    ``num_experts_per_tok`` of ``n_routed_experts`` experts ``down(relu(gate
+    h) * up h)`` (the router's width: the published
+    ``moe_num_primary_experts``; this chip holds ``expert_share``'s) chosen
+    by a softmax over the router's logits and renormalised, the router
+    reading ``ln1(x)``, the normed input of attention, and the experts
+    ``ln2(x + attn)``. No shared expert, no dense layer, an untied head;
+    next-token cross-entropy.
+
+    The fields are what a description sets; the class's constants are the
+    same in every model of this type, and a description that gives another
+    value is refused, as :class:`SdarMoeArch`'s."""
+
+    num_key_value_heads: int
+    head_dim: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    rope_layout: Tuple[int, ...]
+    sliding_window_layout: Tuple[int, ...]
+    sliding_window: int
+    rope_theta: float = 1500000.0
+    rms_norm_eps: float = 1e-6
+    expert_share: Tuple[int, int] = (0, 1)
+
+    router_scoring = "softmax"
+    router_input = "ln1"             # the router reads attention's input
+    n_shared_experts = 0
+    moe_shared_expert_intermediate_size = None
+    expert_activation = "reglu"
+    routed_scaling_factor = 1.0      # unread by softmax routing
+    route_eps = 0.0                  # unread by softmax routing
+    bias_update_speed = 0.0          # the router has no bias
+    tie_word_embeddings = False
+    num_nextn_predict_layers = 0
+    mtp_loss_weight = 0.0
+    qk_norm = False
+
+    def mixer(self, layer: int) -> Optional[str]:
+        return "sliding_attention" if self.sliding_window_layout[layer] \
+            else "full_attention"
+
+    def mlp(self, layer: int) -> Optional[str]:
+        return "experts"
+
+    def attention(self, layer: int) -> Tuple[bool, Optional[int]]:
+        """``(rotary, window)`` of layer ``layer``'s attention: whether it
+        rotates q and k, and its window (None: causal)."""
+        return (bool(self.rope_layout[layer]),
+                self.sliding_window if self.sliding_window_layout[layer]
+                else None)
+
+
+# What a ``smallthinker`` description's published keys set of
+# :class:`SmallThinkerArch`, by field, and the class's constants.
+_SMALLTHINKER_KEYS = {
+    "num_key_value_heads": "num_key_value_heads", "head_dim": "head_dim",
+    "moe_intermediate_size": "moe_ffn_hidden_size",
+    "num_experts_per_tok": "moe_num_active_primary_experts",
+    "sliding_window": "sliding_window_size", "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_norm_eps"}
+_SMALLTHINKER_FIXED = ("router_scoring", "router_input", "n_shared_experts",
+                       "moe_shared_expert_intermediate_size",
+                       "expert_activation", "routed_scaling_factor",
+                       "route_eps", "bias_update_speed",
+                       "tie_word_embeddings", "num_nextn_predict_layers",
+                       "mtp_loss_weight", "qk_norm")
+
+
 # What a ``sdar_moe`` description sets of :class:`SdarMoeArch` under the
 # field's own name (the published keys, then the objective's), and the
 # class's constants, which it may repeat and not change.
@@ -463,6 +541,29 @@ def _sdar_arch(desc: Mapping[str, Any]) -> SdarMoeArch:
         expert_share=(int(ep["chip"]), int(ep["chips"])))
 
 
+def _smallthinker_arch(desc: Mapping[str, Any]) -> SmallThinkerArch:
+    _refuse_unless(desc, (
+        ("rope_scaling", None), ("attention_bias", False),
+        ("norm_topk_prob", True), ("moe_primary_router_apply_softmax", True),
+        ("moe_enable_early_router", True),
+        ("moe_enable_secondary_experts", False),
+        *((k, getattr(SmallThinkerArch, k)) for k in _SMALLTHINKER_FIXED)))
+    layers = int(desc["num_hidden_layers"])
+    layouts = {}
+    for key in ("rope_layout", "sliding_window_layout"):
+        layouts[key] = tuple(int(x) for x in desc[key])
+        if len(layouts[key]) != layers or not set(layouts[key]) <= {0, 1}:
+            raise ValueError(f"{key} {list(desc[key])}: one 0 or 1 for each "
+                             f"of num_hidden_layers={layers}")
+    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
+    return SmallThinkerArch(
+        **{field: desc[key] for field, key in _SMALLTHINKER_KEYS.items()
+           if key in desc}, **layouts,
+        n_routed_experts=int(desc["moe_num_primary_experts"])
+        * int(ep["chips"]),
+        expert_share=(int(ep["chip"]), int(ep["chips"])))
+
+
 def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     """A :class:`TransformerLM` from one description of the architecture:
     the dense block's own keys (``vocab``, ``dim``, ``heads``, ``layers``,
@@ -482,14 +583,26 @@ def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
     true, ``norm_topk_prob`` false, a non-empty ``mlp_only_layers``, a
     ``decoder_sparse_step`` other than 1, ``tie_word_embeddings`` true, and
     any other value than the class's for what every model of the type has
-    alike, ``router_scoring`` or ``n_shared_experts`` say).
-    In all four, the key that counts the routed experts (``n_routed_experts``
-    / ``num_experts``) counts the experts held here, of ``expert_parallel =
+    alike, ``router_scoring`` or ``n_shared_experts`` say) or a decoder of
+    window and full attention over early-routed ReGLU experts
+    (``model_type`` ``smallthinker``, or ``moe_num_primary_experts`` beside
+    ``sliding_window_layout``; :class:`SmallThinkerArch`: its layers by
+    ``rope_layout`` and ``sliding_window_layout``; refused: a
+    ``rope_scaling``, ``attention_bias`` true, ``norm_topk_prob`` or
+    ``moe_primary_router_apply_softmax`` false, an early router off,
+    secondary experts on, and another value for a constant of the class).
+    In all five, the key that counts the routed experts (``n_routed_experts``
+    / ``num_experts`` / ``moe_num_primary_experts``) counts the experts held
+    here, of ``expert_parallel =
     {"chips": n, "chip": i}`` chips that share each layer, and the router
     is ``chips`` times as wide. What a description asks for and is not
     built raises, naming the key and the value that is. ``kw`` are further
     ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
-    if desc.get("model_type") == "sdar_moe":
+    if desc.get("model_type") == "smallthinker" or (
+            "moe_num_primary_experts" in desc
+            and "sliding_window_layout" in desc):
+        arch = _smallthinker_arch(desc)
+    elif desc.get("model_type") == "sdar_moe":
         arch = _sdar_arch(desc)
     elif desc.get("model_type") == "nemotron_h":
         arch = _nemotron_arch(desc)
@@ -541,9 +654,10 @@ def rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _attend(q, k, v, mask=None):
+def _attend(q, k, v, mask=None, window=None):
     """Causal attention (under ``mask``, a
-    :class:`~ddstore_tpu.ops.attention.BlockDiffusion`: that mask's) over
+    :class:`~ddstore_tpu.ops.attention.BlockDiffusion`: that mask's; with
+    ``window``, a sliding window of that many keys) over
     (B, S, H, D) heads as the projections write them, K and V perhaps fewer heads than Q (grouped-query): ``(out,
     layout)``, ``out`` (B, S, H, D) and the layout the kernels took their
     operands in. Heads of whole lanes go as they lie (``bshd``: nothing is
@@ -554,6 +668,8 @@ def _attend(q, k, v, mask=None):
     x S reference, which is what runs elsewhere (``reference``)."""
     on_chip = jax.default_backend() == "tpu"
     how = {"causal": True} if mask is None else {"mask": mask}
+    if window is not None:
+        how["window"] = window
     if on_chip and q.shape[-1] % 128 == 0:
         return flash_attention(q, k, v, layout="bshd", **how)[0], "bshd"
     attend = flash_attention if on_chip else mha_reference
@@ -624,19 +740,31 @@ def _mla_mixer(blk: "DecoderBlock", x, positions):
         return lin(blk.dim, "proj")(out.reshape(b, s, nh * vd).astype(dt))
 
 
-def _gqa_mixer(blk: "DecoderBlock", x, positions):
+def _layer_attention(a, blk):
+    """``(rotary, window)`` of block ``blk``'s attention: the arch's own
+    for the block's layer where it has a pattern of attention kinds
+    (``attention``), else its one ``rotary`` and no window."""
+    per_layer = getattr(a, "attention", None)
+    return per_layer(blk.layer) if per_layer else (a.rotary, None)
+
+
+def _gqa_mixer(blk: "DecoderBlock", x, positions, normed=None):
     """Grouped-query attention (``Lfm2MoeAttention``,
     ``NemotronHAttention``): ``heads`` query heads over
     ``num_key_value_heads`` K/V heads of the arch's ``head_dim`` (``dim /
     heads`` where it gives none); where the arch asks, q and k RMS-normed a
     head (``qk_norm``: one learned scale of the head's width each) and
-    rotated on the whole width (``rotary``); K and V go to the kernels as
-    they are."""
+    rotated on the whole width (``rotary``, or a layer's own where the arch
+    has a pattern of attention kinds, which also gives the layer's sliding
+    window: such a layer's kernels run under the scope ``window``); K and V
+    go to the kernels as they are. ``normed``: ``ln1(x)`` where the block
+    has it already."""
     b, s, _ = x.shape
     a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
     nkv, hd = a.num_key_value_heads, a.head_dim or blk.dim // blk.heads
     norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
-    h = norm("ln1")(x).astype(dt)
+    h = norm("ln1")(x).astype(dt) if normed is None else normed
+    rotary, window = _layer_attention(a, blk)
     # [W_q | W_k | W_v] as one product
     with jax.named_scope("mix_in"):
         qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
@@ -647,7 +775,7 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
         if a.qk_norm:
             with jax.named_scope("mix_norm"):
                 t = norm(name)(t)
-        if a.rotary:
+        if rotary:
             t = rope(t, positions, a.rope_theta)
         return t.astype(dt)
 
@@ -655,11 +783,21 @@ def _gqa_mixer(blk: "DecoderBlock", x, positions):
     # a block-diffusion arch's sequence is [noised ; clean]
     blocks = getattr(a, "block_length", 0)
     mask = BlockDiffusion(blocks, s // 2) if blocks else None
-    out, layout = _attend(q, k, v, mask)
+    extra = {"mask": f"block_diffusion {blocks}"} if blocks else {}
+    if window is None:
+        out, layout = _attend(q, k, v, mask)
+    else:
+        # what the kernels are handed, for whoever asks with
+        # mutable=["intermediates"] (the benchmark's check of the window's
+        # statistics); nothing otherwise
+        blk.sow("intermediates", "window_qk", (q, k, v))
+        with jax.named_scope("window"):
+            out, layout = _attend(q, k, v, window=window)
+    if hasattr(a, "attention"):
+        extra.update(window=window, rotary=rotary)
     profile.count_mixer_layout(
         "/".join(blk.path), kind="full_attention", heads=nh, kv_heads=nkv,
-        head_dim=hd, tokens=b * s, layout=layout,
-        **({"mask": f"block_diffusion {blocks}"} if blocks else {}))
+        head_dim=hd, tokens=b * s, layout=layout, **extra)
     out = out.reshape(b, s, nh * hd).astype(dt)
     with jax.named_scope("mix_out"):
         return nn.Dense(blk.dim, use_bias=False, dtype=dt,
@@ -773,19 +911,24 @@ def _mamba2_mixer(blk: "DecoderBlock", x, positions):
 # (block, x, positions) -> what the mixer adds to x, under the block's own
 # scope (its submodules are the block's).
 _MIXERS = {"mla": _mla_mixer, "full_attention": _gqa_mixer,
-           "conv": _conv_mixer, "mamba2": _mamba2_mixer}
+           "sliding_attention": _gqa_mixer, "conv": _conv_mixer,
+           "mamba2": _mamba2_mixer}
 
 
 class DecoderBlock(nn.Module):
     """Pre-RMSNorm decoder layer of a described architecture
-    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`, :class:`NemotronHArch`),
-    built of what the arch says the layer has: ``x + mixer(norm(x))`` with
+    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`, :class:`NemotronHArch`,
+    :class:`SdarMoeArch`, :class:`SmallThinkerArch`), built of what the arch says the layer has: ``x + mixer(norm(x))`` with
     the mixer ``mixer`` names (``_MIXERS``: latent attention, grouped-query
     attention, gated short convolution, Mamba-2; None: no mixer and no
     ``ln1``), then ``x + mlp(norm(x))`` with a SwiGLU MLP (``mlp`` =
     ``"dense"``) or the shared + routed experts (``"experts"``; None: no
     MLP and no ``ln2``). No biases. Returns ``x``, and an expert layer's
-    load vector beside it."""
+    load vector beside it. Where the arch's router reads the layer's input
+    (``router_input`` ``ln1``), the layer's one ``router`` leaf is the
+    block's own: its logits are taken from ``ln1(x)`` before attention runs
+    and handed to the experts, which run on ``ln2(x + attn)``. ``layer``:
+    the layer's index, for an arch whose attention differs a layer."""
 
     dim: int
     heads: int
@@ -793,6 +936,7 @@ class DecoderBlock(nn.Module):
     mlp: Optional[str]
     compute_dtype: Any
     mixer: Optional[str] = "mla"
+    layer: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -801,7 +945,17 @@ class DecoderBlock(nn.Module):
         lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt,
                                        name=name)
 
-        if self.mixer is not None:
+        logits = None
+        if getattr(a, "router_input", "ln2") == "ln1":
+            with jax.named_scope("attn"):
+                h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
+            with jax.named_scope("mlp"), jax.named_scope("moe_dispatch"):
+                logits = nn.Dense(a.n_routed_experts, use_bias=False,
+                                  dtype=jnp.float32, name="router")(
+                    h.reshape(b * s, self.dim).astype(jnp.float32))
+            with jax.named_scope("attn"):
+                x = x + _MIXERS[self.mixer](self, x, positions, h)
+        elif self.mixer is not None:
             with jax.named_scope("attn"):
                 x = x + _MIXERS[self.mixer](self, x, positions)
         if self.mlp is None:
@@ -824,7 +978,7 @@ class DecoderBlock(nn.Module):
                 compute_dtype=dt, activation=a.expert_activation,
                 shared_hidden=a.moe_shared_expert_intermediate_size,
                 scoring=getattr(a, "router_scoring", "sigmoid"),
-                name="moe")(h.reshape(b * s, self.dim))
+                name="moe")(h.reshape(b * s, self.dim), logits)
             return x + y.reshape(b, s, self.dim), load
 
 
@@ -974,7 +1128,8 @@ class TransformerLM(nn.Module):
     #                               fraction of its recompute cost), or
     #                               "names:flash_out,flash_lse" (_remat_policy)
     arch: Optional[Any] = None    # a described architecture (MlaMoeArch,
-    #                               Lfm2MoeArch, NemotronHArch): DecoderBlock
+    #                               Lfm2MoeArch, NemotronHArch, SdarMoeArch,
+    #                               SmallThinkerArch): DecoderBlock
     #                               layers (RMSNorm, the mixer arch.mixer(i)
     #                               and the MLP arch.mlp(i) name, MTP)
     #                               instead of Block
@@ -1060,7 +1215,7 @@ class TransformerLM(nn.Module):
         loads = []
         for i in range(self.layers):
             mlp = a.mlp(i)
-            x = block_cls(self.dim, self.heads, a, mlp, dt, a.mixer(i),
+            x = block_cls(self.dim, self.heads, a, mlp, dt, a.mixer(i), i,
                           name=f"block{i}")(x, positions)
             if mlp == "experts":
                 x, load = x
@@ -1475,6 +1630,8 @@ def place_experts(model: "TransformerLM", state: "TrainState", tokens,
 
     held = a.n_routed_experts // of
     params = state.params
+    # an early router is the block's leaf, not the expert layer's
+    early = getattr(a, "router_input", "ln2") == "ln1"
 
     def relabelled(node, order):
         new = dict(node, router=dict(
@@ -1496,7 +1653,8 @@ def place_experts(model: "TransformerLM", state: "TrainState", tokens,
             got[to] += load[expert]
         order = np.concatenate(chips)
         params = dict(params, params=_updated_at(
-            params["params"], path, lambda node: relabelled(node, order)))
+            params["params"], path[:-1] if early else path,
+            lambda node: relabelled(node, order)))
     return state._replace(params=params)
 
 

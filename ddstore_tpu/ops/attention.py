@@ -27,6 +27,10 @@ Design notes (TPU):
   a clean half in one sequence) is a description, never a dense array: its
   grid is the same kind of enumeration over the mask's three live
   quadrants, and only the blocks its edges cross are masked;
+* a sliding window (``window=W``: a query sees its own key and the W - 1
+  before it) is a second, lower edge of the causal mask: a block wholly
+  before the window is no step either, and a block either edge crosses is
+  computed in strips that end at both;
 * on CPU (tests) the identical kernel runs in interpreter mode;
 * the three kernels carry stable names (``ddstore_flash_fwd``,
   ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
@@ -169,14 +173,21 @@ class FlashGeometry(NamedTuple):
     grid_steps: int
     steps_fetching_dead: int   # DMAs of a streamed block no live step uses
     mask: Optional[BlockDiffusion] = None   # set: this mask, not the causal
-    blocks_live: int = 0       # under ``mask``: blocks holding a live pair
+    blocks_live: int = 0       # under ``mask`` or ``window``: blocks holding
+    #                            a live pair
+    window: Optional[int] = None   # set: causal with keys > query - window
 
 
 def _steps(geo):
-    """``_enumerate`` (``_enumerate_masked`` under a mask) for the call
-    ``geo`` describes."""
+    """``_enumerate`` (``_enumerate_masked`` under a mask,
+    ``_enumerate_window`` under a window) for the call ``geo``
+    describes."""
     if geo.mask is not None:
         return _enumerate_masked(geo.mask, geo.block_q, geo.block_k,
+                                 geo.stream)
+    if geo.window is not None:
+        return _enumerate_window(geo.sq, geo.sk, geo.block_q, geo.block_k,
+                                 geo.q_offset, geo.kv_offset, geo.window,
                                  geo.stream)
     return _enumerate(geo.sq, geo.sk, geo.block_q, geo.block_k,
                       geo.q_offset, geo.kv_offset, geo.stream)
@@ -255,6 +266,62 @@ def _enumerate_masked(mask, bq, bk, stream):
                  ) + (variants,)
 
 
+def _window_tiles(row_lo, rows, col_lo, cols, window):
+    """``(live, full)`` of tiles, rows ``[row_lo, row_lo + rows)`` by
+    columns ``[col_lo, col_lo + cols)`` in global positions (arrays
+    broadcast), under a sliding window: a pair is live iff ``0 <= row - col
+    < window``. THE classification under a window, of blocks over the grid
+    and of lane-wide tiles inside a block, and of the counter."""
+    near = row_lo - (col_lo + cols - 1)        # the least row - col of a tile
+    far = row_lo + rows - 1 - col_lo           # and the greatest
+    return (far >= 0) & (near < window), (near >= 0) & (far < window)
+
+
+@functools.lru_cache(maxsize=256)
+def _enumerate_window(sq, sk, bq, bk, q_offset, kv_offset, window, stream):
+    """``_enumerate`` under a sliding window of ``window`` keys: only the
+    blocks holding a live pair are steps (a row dead throughout keeps one,
+    to write its zeros, as there); a block wholly before the window is dead
+    like one wholly after the diagonal. A block either edge crosses is
+    ``_DIAGONAL + v``, ``v`` indexing the last result: the static first row
+    - first column of those blocks."""
+    nq, nk = sq // bq, sk // bk
+    outer, inner = np.indices((nq, nk) if stream == "k" else (nk, nq))
+    iq, ik = (outer, inner) if stream == "k" else (inner, outer)
+    live, full = _window_tiles(q_offset + iq * bq, bq, kv_offset + ik * bk,
+                               bk, window)
+    last = 0 if stream == "k" else inner.shape[1] - 1
+    keep = live | (~live.any(axis=1, keepdims=True) & (inner == last))
+    shift = q_offset + iq * bq - kv_offset - ik * bk
+    shifts, variant = np.unique(shift[live & ~full], return_inverse=True)
+    code = _codes(keep, live, full, variant)
+    return tuple(np.asarray(a[keep], np.int32) for a in (outer, inner, code)
+                 ) + (tuple(int(x) for x in shifts),)
+
+
+class _Edges(NamedTuple):
+    """What a strip under a sliding window keeps of its scores: the pairs
+    whose column less their row is at most ``hi`` (the diagonal) and at
+    least ``lo`` (the window's lower edge), counted from the strip's
+    corner; None where that edge cuts nothing of the strip."""
+    lo: Any
+    hi: Any
+
+
+def _window_strips(geo, shift):
+    """``_strips`` under a window, for a block whose first row lies
+    ``shift`` past its first column: ``_runs``' strips, ``(rows, cols,
+    edges)`` (``edges`` None: every pair of the strip is live)."""
+    w = geo.window
+    for rows, cols, _ in _runs(
+            geo, lambda *tile: _window_tiles(*tile, w), shift, 0):
+        s = shift + rows.start - cols.start
+        edges = _Edges(s - w + 1 if rows.start - rows.stop + 1 < s - w + 1
+                       else None,
+                       s if cols.stop - cols.start - 1 > s else None)
+        yield rows, cols, None if edges == (None, None) else edges
+
+
 class _Band(NamedTuple):
     """What a strip under the mask keeps of its scores: the pairs whose
     column's block less their row's, counted from the strip's corner
@@ -273,23 +340,20 @@ def _band(kind, shift, log2b):
     return _Band(d if kind == _NN else None, d - (kind == _NC), log2b)
 
 
-def _masked_strips(geo, variant):
-    """``_strips`` under the mask, for a block of quadrant and shift
-    ``variant``: the same static strips, each over the contiguous run of
-    tiles on its other side that hold a live pair, ``(rows, cols, band)``
-    (``band`` None: every pair of the strip is live)."""
-    kind, shift = variant
-    block = geo.mask.block
+def _runs(geo, classify, row0, col0):
+    """The static strips of a block an edge of a mask crosses: each
+    ``sub_q`` rows (forward, dq) or ``sub_k`` columns (dkv) over the
+    contiguous run of lane-wide tiles on its other side that hold a live
+    pair, ``(rows, cols, full)`` (``full``: every pair of the run is live).
+    ``classify(row_lo, rows, col_lo, cols)`` is the mask's ``(live, full)``
+    of tiles, the block's first row and column at ``row0``, ``col0``."""
     bq, bk, tq, tk = geo.block_q, geo.block_k, geo.sub_q, geo.sub_k
-    q0, k0 = max(shift, 0), max(-shift, 0)
     nq, nk = np.arange(bq // tq), np.arange(bk // tk)
     for g in (nq if geo.stream == "k" else nk):
         if geo.stream == "k":
-            live, full = _mask_tiles(kind, block, q0 + g * tq, tq,
-                                     k0 + nk * tk, tk)
+            live, full = classify(row0 + g * tq, tq, col0 + nk * tk, tk)
         else:
-            live, full = _mask_tiles(kind, block, q0 + nq * tq, tq,
-                                     k0 + g * tk, tk)
+            live, full = classify(row0 + nq * tq, tq, col0 + g * tk, tk)
         if not live.any():
             continue
         lo, hi = int(np.argmax(live)), int(len(live) - np.argmax(live[::-1]))
@@ -297,7 +361,19 @@ def _masked_strips(geo, variant):
         rows, cols = (slice(g * tq, (g + 1) * tq), slice(lo * tk, hi * tk)) \
             if geo.stream == "k" else (
             slice(lo * tq, hi * tq), slice(g * tk, (g + 1) * tk))
-        yield rows, cols, None if full[lo:hi].all() else _band(
+        yield rows, cols, bool(full[lo:hi].all())
+
+
+def _masked_strips(geo, variant):
+    """``_strips`` under the mask, for a block of quadrant and shift
+    ``variant``: ``_runs``' strips, ``(rows, cols, band)`` (``band`` None:
+    every pair of the strip is live)."""
+    kind, shift = variant
+    block = geo.mask.block
+    for rows, cols, full in _runs(
+            geo, functools.partial(_mask_tiles, kind, block), max(shift, 0),
+            max(-shift, 0)):
+        yield rows, cols, None if full else _band(
             kind, shift + rows.start - cols.start, block.bit_length() - 1)
 
 
@@ -308,9 +384,13 @@ def _strips(geo, shift):
     every ``sub_k``-wide tile up to the last live one; a dkv strip is
     ``sub_k`` columns by every ``sub_q``-high tile from the first live one.
     One matmul chain each, masked by its own shift. Under a mask ``shift``
-    is a variant of ``_enumerate_masked`` (``_masked_strips``)."""
+    is a variant of ``_enumerate_masked`` (``_masked_strips``); under a
+    window the strips end at both edges (``_window_strips``)."""
     if geo.mask is not None:
         yield from _masked_strips(geo, shift)
+        return
+    if geo.window is not None:
+        yield from _window_strips(geo, shift)
         return
     bq, bk, tq, tk = geo.block_q, geo.block_k, geo.sub_q, geo.sub_k
     if geo.stream == "k":
@@ -338,17 +418,27 @@ def _static_diagonal(geo, shifts):
 @functools.lru_cache(maxsize=256)
 def causal_geometry(sq: int, sk: int, blocks: Tuple[int, int],
                     sub: Tuple[int, int], q_offset: int = 0,
-                    kv_offset: int = 0, stream: str = "k") -> FlashGeometry:
+                    kv_offset: int = 0, stream: str = "k",
+                    window: Optional[int] = None) -> FlashGeometry:
     """Geometry of one causal kernel call, counted per batch*head from
     what the kernel's set-up itself runs on (``_steps``, ``_strips``):
     ``pairs_needed`` (what ``benchmarks/ddbench/flops.py`` counts: s(s+1)/2
     at zero offsets), ``pairs_computed`` (interior blocks whole, diagonal
     blocks by their strips, dead blocks nothing), ``grid_steps`` and
-    ``steps_fetching_dead``."""
+    ``steps_fetching_dead``. Under a sliding ``window``, ``pairs_needed``
+    is the window's (sum of min(i + 1, window) at zero offsets), and
+    ``blocks_live`` the blocks of the grid holding a live pair."""
     (bq, bk), (tq, tk) = blocks, sub
-    needed = np.clip(q_offset + np.arange(sq) - kv_offset + 1, 0, sk).sum()
+    if window is None:
+        needed = np.clip(q_offset + np.arange(sq) - kv_offset + 1, 0,
+                         sk).sum()
+    else:
+        row = q_offset + np.arange(sq) - kv_offset      # in key positions
+        needed = np.clip(np.minimum(row, sk - 1)
+                         - np.maximum(row - window + 1, 0) + 1, 0, None).sum()
     return _counted(FlashGeometry(sq, sk, bq, bk, tq, tk, q_offset,
-                                  kv_offset, stream, int(needed), 0, 0, 0))
+                                  kv_offset, stream, int(needed), 0, 0, 0,
+                                  window=window))
 
 
 @functools.lru_cache(maxsize=256)
@@ -360,16 +450,15 @@ def mask_geometry(mask: BlockDiffusion, blocks: Tuple[int, int],
     ``grid_steps`` the blocks visited and ``blocks_live`` those that hold a
     live pair."""
     n = 2 * mask.half
-    geo = _counted(FlashGeometry(
+    return _counted(FlashGeometry(
         n, n, *blocks, *sub, 0, 0, stream,
         mask.half * (mask.half + mask.block), 0, 0, 0, mask))
-    what = _steps(geo)[2] & (_FIRST - 1)
-    return geo._replace(blocks_live=int((what != _NOTHING).sum()))
 
 
 def _counted(geo):
     """``geo`` with what its kernel computes, steps over and fetches for
-    nothing filled in."""
+    nothing filled in, and under a mask or a window the blocks of its grid
+    that hold a live pair."""
     bq, bk = geo.block_q, geo.block_k
     outer, inner, code, shifts = _steps(geo)
     what = code & (_FIRST - 1)
@@ -386,9 +475,12 @@ def _counted(geo):
     # spent on the dead when none of the steps it serves is live.
     dma = np.cumsum(np.concatenate([[True], inner[1:] != inner[:-1]]))
     serves_live = np.bincount(dma, weights=what != _NOTHING) > 0
+    masked = geo.mask is not None or geo.window is not None
     return geo._replace(pairs_computed=int(computed),
                         grid_steps=len(code),
-                        steps_fetching_dead=int((~serves_live[1:]).sum()))
+                        steps_fetching_dead=int((~serves_live[1:]).sum()),
+                        blocks_live=int((what != _NOTHING).sum()) if masked
+                        else 0)
 
 
 def _dense_geometry(sq, sk, bq, bk, stream):
@@ -409,11 +501,13 @@ def _sub_tile(block: int, want: int) -> int:
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = False, q_offset: int = 0,
                   kv_offset: int = 0, scale: Optional[float] = None,
-                  mask: Optional[BlockDiffusion] = None
+                  mask: Optional[BlockDiffusion] = None,
+                  window: Optional[int] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """Plain-XLA attention over (..., S, D); returns (out, lse in f32).
     ``mask``: the block-diffusion mask in its dense form, over ``S = 2
-    half`` positions.
+    half`` positions. ``window`` (causal only): a query also sees no key
+    ``window`` or more positions before it.
     Grouped-query: ``k`` and ``v`` (..., H_kv, S, D) with ``H_kv`` dividing
     ``q``'s H; query head h attends to K/V head ``h // (H / H_kv)`` (K and
     V are repeated here: this is the reference)."""
@@ -424,10 +518,15 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k, v = (jnp.repeat(t, group, axis=-3) for t in (k, v))
     s = jnp.einsum("...qd,...kd->...qk", q, k,
                    preferred_element_type=jnp.float32) * scale
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal mask's lower edge")
     if causal:
         qpos = q_offset + jnp.arange(q.shape[-2])[:, None]
         kpos = kv_offset + jnp.arange(k.shape[-2])[None, :]
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
+        keep = kpos <= qpos
+        if window is not None:
+            keep &= kpos > qpos - window
+        s = jnp.where(keep, s, NEG_INF)
     if mask is not None:
         s = jnp.where(block_diffusion_mask(mask), s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -448,7 +547,15 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def _causal_mask(s, shift):
     """Keep ``s[r, c]`` where global key position <= query position:
     ``shift`` = first row's position - first column's. A :class:`_Band`
-    for ``shift``: keep what the band keeps (the block-diffusion mask)."""
+    for ``shift``: keep what the band keeps (the block-diffusion mask); an
+    :class:`_Edges`: what lies between its edges (a sliding window)."""
+    if isinstance(shift, _Edges):
+        col_minus_row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                         - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+        keep = [col_minus_row <= shift.hi if shift.hi is not None else None,
+                col_minus_row >= shift.lo if shift.lo is not None else None]
+        keep = [k for k in keep if k is not None]
+        return jnp.where(functools.reduce(jnp.logical_and, keep), s, NEG_INF)
     if isinstance(shift, _Band):
         blocks = [jax.lax.shift_right_logical(
             jax.lax.broadcasted_iota(jnp.int32, s.shape, axis),
@@ -535,6 +642,8 @@ def _grid_kernel(kernel, causal, geo):
                     if geo.mask is not None else (
                     geo.q_offset + iq * geo.block_q
                     - geo.kv_offset - ik * geo.block_k)
+                if geo.window is not None:
+                    shift = _Edges(shift - geo.window + 1, shift)
                 pl.when(what >= _DIAGONAL)(
                     lambda: update(_WHOLE, _WHOLE, shift))
 
@@ -880,8 +989,9 @@ def _default_blocks(causal, sq, sk, d, q_offset, kv_offset, masked=False):
     the backward kernels and the non-causal forward take 1024 x 1024
     (2048-wide q blocks exceed VMEM there), the causal forward stays at
     512 x 2048: its diagonal strips want the width. A call under a
-    block-diffusion mask (``masked``) takes the causal sizes of its ``2
-    half`` positions, the short whole-call form apart."""
+    block-diffusion mask or a sliding window (``masked``) takes the causal
+    sizes of its ``2 half`` (or S) positions, the short whole-call form
+    apart."""
     if d > 128:
         # Chosen on the chip at width 256 (PERF.md section 6, PR 27): every
         # q/k/v/do/acc tile is twice as deep, 512 x 2048 and wider do not
@@ -906,9 +1016,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: Optional[int] = None,
                     bwd_blocks: Optional[Tuple[int, int, int, int]] = None,
                     interpret: Optional[bool] = None, layout: str = "bhsd",
-                    mask: Optional[BlockDiffusion] = None
+                    mask: Optional[BlockDiffusion] = None,
+                    window: Optional[int] = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """Pallas flash attention over (B, H, S, D); returns (out, lse).
+
+    ``window`` (with ``causal``): a sliding window, query position i sees
+    key positions ``i - window < j <= i`` (its own and the ``window - 1``
+    before it). The three kernels step over the blocks that hold such a
+    pair and no others: a block wholly before the window is no grid step
+    and fetches nothing, as one wholly after the diagonal, and only the
+    strips the window's two edges cross are masked. A window no shorter
+    than every query's causal reach is the causal call itself.
 
     ``mask``: the block-diffusion training mask as a description
     (:class:`BlockDiffusion`; q, k and v are then the ``2 half`` positions
@@ -963,6 +1082,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          f"the K/V heads must be alike and divide the "
                          f"query heads")
     grain, fit_to = 8, (sq, sk)
+    if window is not None:
+        if not causal or mask is not None or window < 1:
+            raise ValueError(f"window={window}: a sliding window is a "
+                             f"causal call's lower edge, at least one key")
+        if window >= q_offset + sq - kv_offset:
+            window = None          # it cuts nothing: the causal call
     if mask is not None:
         if causal or q_offset or kv_offset:
             raise ValueError("a block-diffusion mask is not causal and "
@@ -975,7 +1100,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"half, and q and k both halves long")
         grain, fit_to = max(8, mask.block), (mask.half, mask.half)
     fwd, bwd = _default_blocks(causal or mask is not None, sq, sk, d,
-                               q_offset, kv_offset, mask is not None)
+                               q_offset, kv_offset,
+                               mask is not None or window is not None)
     # An explicit block_q / block_k bounds all three kernels, as ever.
     fwd = (block_q or fwd[0], block_k or fwd[1])
     if bwd_blocks is None:
@@ -1016,7 +1142,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             sub = (_sub_tile(bq, strip), _sub_tile(bk, _LANES)) \
                 if stream == "k" else (
                 _sub_tile(bq, _LANES), _sub_tile(bk, strip))
-            if mask is None:
+            if window is not None:
+                geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
+                                      kv_offset, stream, window)
+                kind = f"window{window}"
+                counted += ("blocks_live",)
+            elif mask is None:
                 geo = causal_geometry(sq, sk, (bq, bk), sub, q_offset,
                                       kv_offset, stream)
             else:

@@ -123,6 +123,10 @@ STEP_SCOPES = {
     "short_conv": "``ops/short_conv.py``: a causal depthwise convolution, "
                   "gated or biased, both rules.",
     "ssd": "``ops/ssd.py``: the state-space scan, both rules.",
+    "window": "inside ``attn``: a sliding-window layer's attention "
+              "(``_gqa_mixer`` of an arch whose attention differs a layer): "
+              "its flash kernels and what their rules compute around "
+              "them.",
     "ring_step": "``parallel/ring_attention.py``: one step of the ring "
                  "(attend, combine, rotate).",
     "dense_mlp": "inside ``mlp``: a dense layer's up / (gate) / down "
@@ -409,8 +413,9 @@ def count_geometry(kernel: str, call: str, counts: Dict[str, int]) -> None:
     """``ops/attention.py``, while a flash call is traced: what ``kernel``
     will do for a call of this shape, per batch*head (``pairs_needed``,
     ``pairs_computed``, ``grid_steps``, ``steps_fetching_dead``; a call
-    under the block-diffusion mask, ``blockdiff<B>`` in its key: the mask's
-    live pairs, the blocks its grid visits as ``grid_steps`` and, of them,
+    under the block-diffusion mask, ``blockdiff<B>`` in its key, or under a
+    sliding window, ``window<W>``: the mask's or the window's live pairs,
+    the blocks its grid visits as ``grid_steps`` and, of them,
     ``blocks_live`` that hold a live pair)."""
     with _lock:
         _geometry.setdefault(kernel, {})[call] = dict(counts)
@@ -422,7 +427,9 @@ def count_moe_layout(layer: str, **counts) -> None:
     takes (``top_k``), the ``tokens`` of the call and the sorted ``rows``
     its routed experts run over at a time (as many trips a step as the
     step's held pairs need: one, under routing near even), the router's
-    ``scoring`` (``sigmoid`` or ``softmax``); what its grouped
+    ``scoring`` (``sigmoid`` or ``softmax``), the experts' ``activation``
+    (``swiglu``, ``reglu``, ``relu2``), whether the router's logits were
+    handed in (``early_router``: taken before attention); what its grouped
     ``products`` ran as (``pallas``, the kernels of ``ops/moe_gmm.py``) and
     its way back from the experts' rows, ``combine`` (``pallas``,
     ``ops/moe_combine.py``'s kernel),
@@ -441,7 +448,8 @@ def count_mixer_layout(layer: str, **counts) -> None:
     ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too, and what
     its ``scan`` ran as: ``pallas``, the kernels of ``ops/ssd.py``), the
     ``tokens`` of the call and, where attention ran under another mask than
-    the causal, that ``mask``."""
+    the causal, that ``mask``; for an arch whose attention differs a layer,
+    the layer's ``window`` (None: causal) and whether it is ``rotary``."""
     with _lock:
         _mixer_layout[layer] = dict(counts)
 

@@ -128,6 +128,9 @@ def main():
                         "benchmarks/configs/nemotron3-nano-ep16.json; "
                         "block-diffusion training over softmax-routed "
                         "experts: benchmarks/configs/sdar-30b-a3b-ep8.json; "
+                        "window and full attention over early-routed ReGLU "
+                        "experts: "
+                        "benchmarks/configs/smallthinker-21b-a3b-ep4.json; "
                         "with --dry-sizes their toy sizes)")
     p.add_argument("--dry-sizes", action="store_true",
                    help="with --config: overlay the file's dry_run block "
@@ -324,6 +327,18 @@ def main():
                           f"{geo['pairs_computed']} of needed "
                           f"{geo['pairs_needed']}, largest over mean "
                           f"{geo['max_over_mean']:.2f}", flush=True)
+                # What a sliding window's kernels step over: 1.00 visited
+                # over live is a grid of no block wholly outside the window
+                # (the kernels run on the chip; the CPU takes the reference)
+                for kernel, calls in profile.counters()[
+                        "flash_geometry"].items():
+                    for call, geo in calls.items():
+                        if call.startswith("window"):
+                            seen = geo["grid_steps"] / geo["blocks_live"]
+                            print(f"flash {call} {kernel}: pairs needed "
+                                  f"{geo['pairs_needed']} computed "
+                                  f"{geo['pairs_computed']} visited/live "
+                                  f"{seen:.2f}", flush=True)
                 # Which blocks compute their forward twice, what the
                 # devices hold (where a runtime counts it), and what was
                 # compiled against what the cache had.

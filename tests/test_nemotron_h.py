@@ -520,7 +520,7 @@ def test_an_expert_is_down_of_relu_squared_of_up():
 
 def test_the_layer_refuses_an_activation_it_does_not_build():
     layer = moe.SharedRoutedMoe(8, 2, 24, activation="gelu")
-    with pytest.raises(ValueError, match="'swiglu' and 'relu2'"):
+    with pytest.raises(ValueError, match="'swiglu', 'reglu' and 'relu2'"):
         layer.init(jax.random.key(0), jnp.zeros((8, 32)))
 
 

@@ -135,6 +135,8 @@ EMITS = {
                             "moe_dispatch", "moe_experts", "recompute"},
     "sdar_moe": COMMON | {"mix_norm", "moe_dispatch", "moe_experts",
                           "recompute", "diffusion_noise"},
+    "smallthinker": COMMON | {"moe_dispatch", "moe_experts", "recompute",
+                              "window"},
 }
 
 

@@ -7,8 +7,9 @@ layer's grouped products (``ops/moe_gmm.py``'s kernels at the three
 configurations' widths) and its way back (``ops/moe_combine.py``'s kernel
 at the four configurations' shapes), the Mamba-2
 convolution's and scan's two kernels each, and the whole step of the
-``lfm2-8b-a1b-ep4.s8192.b4``, ``nemotron3-nano-ep16.s8192`` and
-``sdar-30b-a3b-ep8.s8192.b1`` cells against the chip's memory. Nothing
+``lfm2-8b-a1b-ep4.s8192.b4``, ``nemotron3-nano-ep16.s8192``,
+``sdar-30b-a3b-ep8.s8192.b1`` and ``smallthinker-21b-a3b-ep4.s16384.b1``
+cells against the chip's memory. Nothing
 runs and no time is read; a compile that passes is not a chip run. Every
 such test lives in this one file, and the topology is described inside a
 fixture: one process at a time may load the TPU's library
@@ -502,6 +503,77 @@ def test_the_sdar_cell_step_fits_the_chip(one_chip, no_compile_cache,
         assert geo["grid_steps"] == geo["blocks_live"]
         assert geo["pairs_needed"] == 8192 * 8192 + 4 * 8192
         assert geo["pairs_computed"] < 1.13 * geo["pairs_needed"]
+
+
+def test_the_smallthinker_cell_step_fits_the_chip(one_chip, no_compile_cache,
+                                                  monkeypatch):
+    """``smallthinker-21b-a3b-ep4.s16384.b1``'s whole train step at its
+    published widths, one window of 16,384 tokens, compiled for the
+    described chip: 656,529,920 parameters (the configuration file's own
+    count), arguments + temporaries inside the v5e's 16.91 GB; its
+    attention through the sequence-major kernels, one causal call (the full
+    layer) and three under the 4,096-key window, 28 query heads on K and V
+    at their own 4 (a group of 7), the windowed calls' grids the blocks
+    holding a live pair and no others; its ReGLU experts through grouped
+    products at 768. The model asks the backend which attention to run;
+    the test says TPU."""
+    import json
+    import os
+    import re
+
+    import optax
+
+    from ddstore_tpu.models import transformer as T
+    from ddstore_tpu.utils import profile
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "smallthinker-21b-a3b-ep4.json")) as f:
+        cfg = json.load(f)
+    model = T.lm_from_description(cfg, compute_dtype=jnp.bfloat16)
+    lr = optax.linear_schedule(0.0, cfg["lr"], cfg["lr_warmup_steps"])
+    state = jax.eval_shape(
+        lambda k: T.create_train_state(k, model, lr=lr)[0],
+        jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(state.params)) \
+        == cfg["parameters"] == 656_529_920
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    tok = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    step = T.make_train_step(model, optax.adam(lr))
+    compiled = step.lower(on_chip(state), tok, tok, tok).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 7.8e9 < total < 16.91e9, total
+    print(f"arguments {m.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f} GB")
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert "bf16[16,2560,768]" in text and "bf16[16,768,2560]" in text
+    assert _kernel_passes(text) == {
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
+        "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
+                   "ddstore_flash_dkv"):
+        calls = _mosaic_calls(text, kernel)
+        assert len(calls) == 4, kernel
+        windowed = 0
+        for ln in calls:
+            operands = ln.split("custom-call(")[1]
+            assert "bf16[1,16384,3584]" in operands, ln            # q
+            assert operands.count("bf16[1,16384,512]") >= 2, ln    # k and v
+            op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+            windowed += "window" in profile.describe(op_name)[0]
+        assert windowed == 3, kernel
+        geo, = [c for call, c in profile.counters()["flash_geometry"][
+            kernel].items() if call.startswith("window4096 bh28 q16384")]
+        assert geo["grid_steps"] == geo["blocks_live"]
+        assert geo["pairs_needed"] == 4096 * 4097 // 2 + 12288 * 4096
+        assert geo["pairs_computed"] < 1.2 * geo["pairs_needed"]
+        print(kernel, geo)
 
 
 @pytest.mark.parametrize("b,s,most", [(8, 2048, 7), (2, 8192, 8)])
