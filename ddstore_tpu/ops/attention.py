@@ -31,6 +31,11 @@ Design notes (TPU):
   before it) is a second, lower edge of the causal mask: a block wholly
   before the window is no step either, and a block either edge crosses is
   computed in strips that end at both;
+* the forward takes a step's scores a sub-tile at a time against a
+  reference max known before them (``_flash_kernel``): keys on sublanes,
+  queries on lanes, no block-wide f32 scores written out; a step whose
+  sums pass e**``_TAU`` is taken again by the two-pass body, and lane 1 of
+  its statistics output counts that (``forward_fallbacks``);
 * on CPU (tests) the identical kernel runs in interpreter mode;
 * the three kernels carry stable names (``ddstore_flash_fwd``,
   ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
@@ -544,30 +549,29 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype), lse
 
 
-def _causal_mask(s, shift):
+def _causal_mask(s, shift, keys_axis=1):
     """Keep ``s[r, c]`` where global key position <= query position:
     ``shift`` = first row's position - first column's. A :class:`_Band`
     for ``shift``: keep what the band keeps (the block-diffusion mask); an
-    :class:`_Edges`: what lies between its edges (a sliding window)."""
+    :class:`_Edges`: what lies between its edges (a sliding window).
+    ``keys_axis=0``: ``s`` is transposed, keys on its rows."""
+    col, row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+                for axis in (keys_axis, 1 - keys_axis))
     if isinstance(shift, _Edges):
-        col_minus_row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                         - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+        col_minus_row = col - row
         keep = [col_minus_row <= shift.hi if shift.hi is not None else None,
                 col_minus_row >= shift.lo if shift.lo is not None else None]
         keep = [k for k in keep if k is not None]
         return jnp.where(functools.reduce(jnp.logical_and, keep), s, NEG_INF)
     if isinstance(shift, _Band):
-        blocks = [jax.lax.shift_right_logical(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, axis),
-            jnp.int32(shift.log2b)) for axis in (1, 0)]
-        across = blocks[0] - blocks[1]
+        log2b = jnp.int32(shift.log2b)
+        across = (jax.lax.shift_right_logical(col, log2b)
+                  - jax.lax.shift_right_logical(row, log2b))
         keep = across <= shift.hi
         if shift.lo is not None:
             keep &= across >= shift.lo
         return jnp.where(keep, s, NEG_INF)
-    col_minus_row = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-    return jnp.where(col_minus_row <= shift, s, NEG_INF)
+    return jnp.where(col - row <= shift, s, NEG_INF)
 
 
 def _traced_band(mask, q_lo, k_lo):
@@ -584,12 +588,15 @@ def _traced_band(mask, q_lo, k_lo):
 
 class _Step(NamedTuple):
     """One grid step as a kernel body sees it: whether it opens / closes
-    its row of the accumulation, and ``run(update)``, which calls
+    its row of the accumulation; ``run(update, done=None)``, which calls
     ``update(rows, cols, shift)`` over what is live of the step's block
-    (``shift`` None: no mask)."""
+    (``shift`` None: no mask) and then ``done()``, once, inside the same
+    body; and ``whole()``, the mask of the whole block (traced: the same
+    for every step's body)."""
     first: jax.Array
     last: jax.Array
     run: Callable
+    whole: Callable
 
 
 _WHOLE = slice(None)
@@ -606,8 +613,13 @@ def _grid_kernel(kernel, causal, geo):
     if not causal:
         def dense(*refs):
             inner, n = pl.program_id(2), pl.num_programs(2)
-            kernel(_Step(inner == 0, inner == n - 1,
-                         lambda update: update(_WHOLE, _WHOLE, None)), *refs)
+
+            def run(update, done=None):
+                update(_WHOLE, _WHOLE, None)
+                if done:
+                    done()
+            kernel(_Step(inner == 0, inner == n - 1, run, lambda: None),
+                   *refs)
         return dense
 
     _, _, codes, shifts = _steps(geo)
@@ -621,81 +633,250 @@ def _grid_kernel(kernel, causal, geo):
         code = code_ref[t]
         what = code & (_FIRST - 1)
 
-        def run(update):
-            def strips(shift):
-                for strip in _strips(geo, shift):
+        def whole():
+            iq, ik = outer_ref[t], inner_ref[t]
+            if geo.stream == "q":
+                iq, ik = ik, iq
+            if geo.mask is not None:
+                return _traced_band(geo.mask, iq * geo.block_q,
+                                    ik * geo.block_k)
+            shift = (geo.q_offset + iq * geo.block_q
+                     - geo.kv_offset - ik * geo.block_k)
+            return shift if geo.window is None else _Edges(
+                shift - geo.window + 1, shift)
+
+        def run(update, done=None):
+            def body(strips):
+                for strip in strips:
                     update(*strip)
+                if done:
+                    done()
 
             if has_interior:
                 pl.when(what == _INTERIOR)(
-                    lambda: update(_WHOLE, _WHOLE, None))
+                    lambda: body([(_WHOLE, _WHOLE, None)]))
             if static:
                 for v, shift in enumerate(shifts):
-                    pl.when(what == _DIAGONAL + v)(
-                        functools.partial(strips, shift))
+                    pl.when(what == _DIAGONAL + v)(functools.partial(
+                        lambda shift: body(_strips(geo, shift)), shift))
             else:
-                iq, ik = outer_ref[t], inner_ref[t]
-                if geo.stream == "q":
-                    iq, ik = ik, iq
-                shift = _traced_band(
-                    geo.mask, iq * geo.block_q, ik * geo.block_k) \
-                    if geo.mask is not None else (
-                    geo.q_offset + iq * geo.block_q
-                    - geo.kv_offset - ik * geo.block_k)
-                if geo.window is not None:
-                    shift = _Edges(shift - geo.window + 1, shift)
                 pl.when(what >= _DIAGONAL)(
-                    lambda: update(_WHOLE, _WHOLE, shift))
+                    lambda: body([(_WHOLE, _WHOLE, whole())]))
 
-        kernel(_Step((code & _FIRST) != 0, (code & _LAST) != 0, run), *refs)
+        kernel(_Step((code & _FIRST) != 0, (code & _LAST) != 0, run, whole),
+               *refs)
     return enumerated
 
 
+def _sub_mask(shift, ro, co):
+    """The mask ``shift`` of a strip (``_causal_mask``'s argument) as seen
+    from its sub-tile whose corner lies ``ro`` rows and ``co`` columns in
+    (both a block's multiple under a :class:`_Band`)."""
+    if shift is None:
+        return None
+    if isinstance(shift, _Band):
+        d = (co >> shift.log2b) - (ro >> shift.log2b)
+        return _Band(None if shift.lo is None else shift.lo - d,
+                     shift.hi - d, shift.log2b)
+    if isinstance(shift, _Edges):
+        return _Edges(*(None if e is None else e - (co - ro)
+                        for e in shift))
+    return shift - (co - ro)
+
+
+def _static_keep(shift, rows, cols):
+    """What ``_causal_mask(s, shift)`` keeps of a ``(rows, cols)`` tile, as a
+    numpy array; None where the mask is the device's to work out."""
+    if shift is None:
+        return np.ones((rows, cols), bool)
+    lo, hi, log2b = (shift.lo, shift.hi, shift.log2b) \
+        if isinstance(shift, _Band) else (
+        (shift.lo, shift.hi, 0) if isinstance(shift, _Edges)
+        else (None, shift, 0))
+    if not all(e is None or isinstance(e, (int, np.integer))
+               for e in (lo, hi)):
+        return None
+    across = (np.arange(cols)[None, :] >> log2b) \
+        - (np.arange(rows)[:, None] >> log2b)
+    keep = np.ones((rows, cols), bool)
+    if hi is not None:
+        keep &= across <= hi
+    if lo is not None:
+        keep &= across >= lo
+    return keep
+
+
+# The forward's one-pass body (``_flash_kernel``). A row group's scores
+# are taken a sub-tile at a time and go from the MXU through exp, the row
+# sum and the cast straight into the P.V product, against a reference
+# known before they are: the row's running max of the earlier steps, or on
+# its first the row max of one sub-tile in which each of the group's live
+# rows has a live pair. The true max is reduced alongside, and the step
+# commits, moved to it, where every row's sum against the reference is at
+# most e**_TAU; a step where one is not (a score that far above the
+# reference, an overflow, no finite reference) is taken again by the
+# two-pass body. e**_TAU (1.4e12) times 64 steps of a row (S = 131,072 at
+# the 2048-wide blocks) leaves f32 room for values up to |v| ~ 3e24.
+_TAU = 28.0
+_ONE_PASS_BOUND = math.exp(_TAU)
+
+
+def _one_pass_tile(d, block_q, block_k):
+    """Queries and keys of the forward's sub-tiles at head width ``d``,
+    each width's own since the MXU's share differs. Chosen by the static
+    schedule of the described v5e's compiler, bundles of a grid step that
+    passes its guard (PERF.md section 6, PR 40): at width 64 the 512 x
+    2048 interior takes 3,311 for the two-pass body's 5,213 (512 x 512;
+    512 x 256 about 3,700, 256 x 128 about 6,900); at 128 4,524 for 5,203
+    (512 x 256; 512 x 512 about 4,900); at 256 the 1024 x 1024 interior
+    8,199 for 8,697 (1024 x 512)."""
+    rows, cols = (512, 512) if d <= 64 else (512, 256) if d <= 128 \
+        else (1024, 512)
+    return min(rows, block_q), min(cols, block_k)
+
+
 def _flash_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, scale):
+                  acc_scr, fb_scr, m_new, l_new, acc_new, retry, *, scale,
+                  tile):
+    """The forward, in one pass. Its state lies transposed, queries on
+    lanes: the running max, sum and fallback count ``(1, block_q)``, the
+    numerator ``(D, block_q)``; scores are ``(keys, queries)`` tiles, so a
+    row's statistics are sums and maxima over sublanes and ``m`` is a lane
+    vector that broadcasts down a tile for nothing. A step's row groups
+    write the new state of their rows to ``m_new``, ``l_new`` and
+    ``acc_new``, which are copied in once every group of the step passed
+    its guard; otherwise ``retry`` is set and the whole block is taken
+    again by the two-pass body, one body for every kind of step."""
     @pl.when(step.first)
     def _():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        fb_scr[:] = jnp.zeros_like(fb_scr)
 
-    # A K/V tile entirely in the future contributes nothing and is never
-    # computed; one entirely in the PAST needs no mask (the iota, compare
-    # and select on a tile are pure VPU work and the kernel is VPU-bound).
-    # ``step.run`` decides which tiles run and how.
-    def update(rows, cols, shift):
-        q = q_ref[0, rows, :]                                # (tq, D)
-        k = k_ref[0, cols, :]                                # (tk, D)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if shift is not None:
-            s = _causal_mask(s, shift)
+    retry[0] = 0
 
-        m_prev = m_scr[rows, :1]                             # (tq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Rows with everything masked so far keep m=-inf; safe_m keeps the
-        # subtraction finite and exp(-inf - 0) = 0 zeroes their p exactly
-        # (no full-block select needed).
-        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    def scores_t(q_t, cols, shift):
+        s = jnp.dot(k_ref[0, cols, :], q_t,
+                    preferred_element_type=jnp.float32) * scale
+        return s if shift is None else _causal_mask(s, shift, keys_axis=0)
+
+    def pv_t(cols, p):
+        # (D, queries): the keys' axis of v and of p contracted
+        return jax.lax.dot_general(
+            v_ref[0, cols, :], p.astype(v_ref.dtype),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def two_pass(rows, cols, shift, into):
+        m_out, l_out, acc_out = into
+        s = scores_t(q_ref[0, rows, :].T, cols, shift)      # (tk, tq)
+        m_prev = m_scr[:, rows]                              # (1, tq)
+        m = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        # rows with everything masked so far keep m = -inf; safe_m keeps
+        # the subtraction finite and exp(-inf - 0) = 0 zeroes their p
+        safe_m = jnp.where(jnp.isfinite(m), m, 0.0)
         p = jnp.exp(s - safe_m)
         corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_new = l_scr[rows, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[rows, :] = acc_scr[rows, :] * corr + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0, cols, :],
-            preferred_element_type=jnp.float32)
-        m_scr[rows, :] = jnp.broadcast_to(m_new, (m_new.shape[0], 128))
-        l_scr[rows, :] = jnp.broadcast_to(l_new, (l_new.shape[0], 128))
+        l_out[:, rows] = l_scr[:, rows] * corr + jnp.sum(p, axis=0,
+                                                         keepdims=True)
+        acc_out[:, rows] = acc_scr[:, rows] * corr + pv_t(cols, p)
+        m_out[:, rows] = m
 
-    step.run(update)
+    pending = []                        # (rows, guard) of this step's groups
+
+    def one_pass(rows, cols, shift):
+        r0, r1, _ = rows.indices(q_ref.shape[1])
+        c0, c1, _ = cols.indices(k_ref.shape[1])
+        for ro in range(r0, r1, tile[0]):
+            group(slice(ro, min(ro + tile[0], r1)), c0, c1,
+                  _sub_mask(shift, ro - r0, 0))
+
+    def group(rows, c0, c1, shift):
+        keep = _static_keep(shift, rows.stop - rows.start, c1 - c0)
+        subs = []                       # (columns, mask, kept) live ones
+        for co in range(0, c1 - c0, tile[1]):
+            cw = min(tile[1], c1 - c0 - co)
+            kept = None if keep is None else keep[:, co:co + cw]
+            if kept is None or kept.any():
+                subs.append((slice(c0 + co, c0 + co + cw),
+                             None if kept is not None and kept.all()
+                             else _sub_mask(shift, 0, co), kept))
+        if keep is None:
+            ref, dead_rows = 0, False   # a traced mask: the guard decides
+        else:
+            live = keep.any(axis=1)
+            refs = [i for i, (_, _, kept) in enumerate(subs)
+                    if (kept.any(axis=1) >= live).all()]
+            if not refs:                # no sub-tile each live row sees
+                two_pass(rows, slice(c0, c1), shift,
+                         (m_new, l_new, acc_new))
+                pending.append((rows, None))
+                return
+            ref, dead_rows = refs[0], not live.all()
+        q_t = q_ref[0, rows, :].T                            # (D, tq)
+        m_old = m_scr[:, rows]                               # (1, tq)
+        s_ref = scores_t(q_t, subs[ref][0], subs[ref][1])
+        m_ref = jnp.where(jnp.isfinite(m_old), m_old,
+                          jnp.max(s_ref, axis=0, keepdims=True))
+        # a row the step does not reach keeps m = -inf and adds nothing
+        base = jnp.where(jnp.isfinite(m_ref), m_ref, 0.0) if dead_rows \
+            else m_ref
+        # the true max is reduced alongside; no exp waits on it
+        l, acc, top = 0.0, 0.0, m_ref
+        for i in [ref] + [i for i in range(len(subs)) if i != ref]:
+            cols, mask, _ = subs[i]
+            s = s_ref if i == ref else scores_t(q_t, cols, mask)
+            top = jnp.maximum(top, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - base)
+            l = l + jnp.sum(p, axis=0, keepdims=True)
+            acc = acc + pv_t(cols, p)
+        # The state moves to the true max: lse = m + log(l) with l summed
+        # against the reference reads 2.3e-5 from the float32 reference on
+        # the chip, against the true max 1e-6 (PERF.md section 6, PR 40).
+        safe_top = jnp.where(jnp.isfinite(top), top, 0.0)
+        corr = jnp.where(jnp.isfinite(top), jnp.exp(base - safe_top), 0.0)
+        m_new[:, rows] = top
+        l_new[:, rows] = (l_scr[:, rows] + l) * corr
+        acc_new[:, rows] = (acc_scr[:, rows] + acc) * corr
+        pending.append((rows, jnp.all(l <= _ONE_PASS_BOUND)))
+
+    def done():
+        def commit():
+            for rows, _ in pending:
+                m_scr[:, rows] = m_new[:, rows]
+                l_scr[:, rows] = l_new[:, rows]
+                acc_scr[:, rows] = acc_new[:, rows]
+
+        guards = [ok for _, ok in pending if ok is not None]
+        if guards:
+            ok = functools.reduce(jnp.logical_and, guards)
+            pl.when(ok)(commit)
+
+            @pl.when(jnp.logical_not(ok))
+            def _():
+                retry[0] = 1
+        else:
+            commit()
+        pending.clear()
+
+    step.run(one_pass, done)
+
+    @pl.when(retry[0] != 0)
+    def _():
+        two_pass(_WHOLE, _WHOLE, step.whole(), (m_scr, l_scr, acc_scr))
+        fb_scr[:] = fb_scr[:] + 1.0
 
     @pl.when(step.last)
     def _():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        m = m_scr[:, :1]
-        lse = jnp.where(jnp.isfinite(m),
-                        m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / l).T.astype(o_ref.dtype)
+        m = m_scr[:]
+        lse = jnp.where(jnp.isfinite(m), m + jnp.log(l), NEG_INF)
+        # lane 0 the lse, lane 1 the steps a row took the two-pass fallback
+        shape = lse_ref.shape[:0:-1]                         # (128, tq)
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        lse_ref[0] = jnp.where(lane == 1, jnp.broadcast_to(fb_scr[:], shape),
+                               jnp.broadcast_to(lse, shape)).T
 
 
 def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
@@ -718,16 +899,36 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
     Grid steps run one after another on the core at ~0.35 us each before
     any work, and a step whose streamed block is new pays its DMA, so the
     fetched block is large and, causal, only live blocks are steps at all
-    (PERF.md section 6, PR 26, has what the sizes were chosen from)."""
-    bhs = operands[0].shape[0] * (heads or 1)     # the query side's b h
+    (PERF.md section 6, PR 26, has what the sizes were chosen from).
+
+    The ``pallas_call`` is built once per static configuration
+    (``_pallas``), so the layers of a model that make the same call trace
+    each kernel once."""
+    if causal:
+        operands = tuple(jnp.asarray(t) for t in _steps(geo)[:3]) \
+            + tuple(operands)
+    return _pallas(kernel.func, tuple(sorted(kernel.keywords.items())),
+                   name, causal, geo,
+                   tuple(x.shape for x in operands[3 if causal else 0:]),
+                   tuple(resident), tuple(out_shape), tuple(scratch),
+                   interpret, groups, heads)(*operands)
+
+
+@functools.lru_cache(maxsize=256)
+def _pallas(fn, keywords, name, causal, geo, shapes, resident, out_shape,
+            scratch, interpret, groups, heads):
+    """``_call``'s ``pallas_call`` of ``functools.partial(fn,
+    **keywords)`` over operands of ``shapes`` (the step tables first,
+    causal)."""
+    bhs = shapes[0][0] * (heads or 1)     # the query side's b h
     q_side = geo.stream == "k"     # which side the outer axis walks
 
-    def block(x, on_outer, group=1):
+    def block(shape, on_outer, group=1):
         rows = geo.block_q if on_outer == q_side else geo.block_k
-        if heads is not None and x.shape[0] != bhs:
+        if heads is not None and shape[0] != bhs:
             # (b, S, h d): told from the statistics by the leading
             # dimension; where they agree (one head) so do the addresses
-            width = x.shape[-1] * group // heads
+            width = shape[-1] * group // heads
             # bh is never negative: lax.div / lax.rem, since `//` and `%`
             # lower through sign handling that tripled these kernels'
             # lowering time (0.23 s a forward + backward call for 0.08)
@@ -735,7 +936,7 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
             col = lambda bh: jax.lax.div(jax.lax.rem(bh, jnp.int32(heads)),
                                          jnp.int32(group))
         else:
-            width = x.shape[-1]
+            width = shape[-1]
             lead = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
             col = lambda bh: 0
         if causal:
@@ -746,17 +947,15 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
                 return lead(bh), o if on_outer else i, col(bh)
         return pl.BlockSpec((1, rows, width), index)
 
-    in_specs = [block(x, on_outer, group)
-                for x, on_outer, group in zip(
-                    operands, resident, groups or (1,) * len(operands))]
-    out_specs = [block(o, True) for o in out_shape]
+    in_specs = [block(shape, on_outer, group)
+                for shape, on_outer, group in zip(
+                    shapes, resident, groups or (1,) * len(shapes))]
+    out_specs = [block(o.shape, True) for o in out_shape]
     if causal:
-        tables = _steps(geo)[:3]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(bhs, len(tables[0])),
+            num_scalar_prefetch=3, grid=(bhs, len(_steps(geo)[0])),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
         sems = ("parallel", "arbitrary")
-        operands = tuple(jnp.asarray(t) for t in tables) + tuple(operands)
     else:
         n_q, n_k = geo.sq // geo.block_q, geo.sk // geo.block_k
         grid_spec = pl.GridSpec(
@@ -766,8 +965,9 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(dimension_semantics=sems)}
     return pl.pallas_call(
-        _grid_kernel(kernel, causal, geo), name=name, grid_spec=grid_spec,
-        out_shape=out_shape, interpret=interpret, **params)(*operands)
+        _grid_kernel(functools.partial(fn, **dict(keywords)), causal, geo),
+        name=name, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret, **params)
 
 
 def _dims(q, k, seq_major):
@@ -789,24 +989,35 @@ def _flat(x, seq_major):
 
 
 def _fwd_impl(q, k, v, causal, scale, geo, interpret, seq_major):
-    """Runs the forward kernel; returns (out as q lies, lse (b, h, S))."""
+    """Runs the forward kernel; returns (out as q lies, lse (b, h, S), the
+    steps each row took the two-pass fallback (b, h, S))."""
     b, h, h_kv, sq, _, d = _dims(q, k, seq_major)
     bhs = b * h
     qf = _flat(q, seq_major)
+    bq = geo.block_q
+    kernel = functools.partial(_flash_kernel, scale=scale,
+                               tile=_one_pass_tile(d, bq, geo.block_k))
+    scratch = [pltpu.VMEM((1, bq), jnp.float32),         # running max
+               pltpu.VMEM((1, bq), jnp.float32),         # running denom
+               pltpu.VMEM((d, bq), jnp.float32),         # running numerator
+               pltpu.VMEM((1, bq), jnp.float32),         # fallbacks
+               # the step's new state, until its guard passes
+               pltpu.VMEM((1, bq), jnp.float32),
+               pltpu.VMEM((1, bq), jnp.float32),
+               pltpu.VMEM((d, bq), jnp.float32),
+               pltpu.SMEM((1,), jnp.int32)]              # retry the step
     out_f, lse_f = _call(
-        functools.partial(_flash_kernel, scale=scale), "ddstore_flash_fwd",
+        kernel, "ddstore_flash_fwd",
         causal, geo, (qf, _flat(k, seq_major), _flat(v, seq_major)),
         (True, False, False),
         [jax.ShapeDtypeStruct(qf.shape, q.dtype),
-         # lse carries a broadcast 128-lane dim purely so its block is
-         # (block_q, 128)-tile-aligned for the TPU lowering; lane 0 is
-         # the value.
-         jax.ShapeDtypeStruct((bhs, sq, 128), jnp.float32)],
-        [pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running max
-         pltpu.VMEM((geo.block_q, 128), jnp.float32),   # running denom
-         pltpu.VMEM((geo.block_q, d), jnp.float32)],    # running numerator
+         # lse carries a 128-lane dim so its block is (block_q, 128)-tile-
+         # aligned for the TPU lowering; lane 0 is the value, lane 1 the
+         # fallback count.
+         jax.ShapeDtypeStruct((bhs, sq, 128), jnp.float32)], scratch,
         interpret, (1, h // h_kv, h // h_kv), h if seq_major else None)
-    return out_f.reshape(q.shape), lse_f[..., 0].reshape(b, h, sq)
+    return (out_f.reshape(q.shape), lse_f[..., 0].reshape(b, h, sq),
+            lse_f[..., 1].reshape(b, h, sq))
 
 
 def _recompute_p(q, k, dta_ref, rows, shift, scale):
@@ -885,12 +1096,13 @@ def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, scale, geos, interpret, seq_major):
-    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret, seq_major)
+    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret,
+                     seq_major)[:2]
 
 
 def _flash_fwd(q, k, v, causal, scale, geos, interpret, seq_major):
-    out, lse = _fwd_impl(q, k, v, causal, scale, geos[0], interpret,
-                         seq_major)
+    out, lse, _ = _fwd_impl(q, k, v, causal, scale, geos[0], interpret,
+                            seq_major)
     # Named for a rematerialised caller: under
     # ``save_only_these_names("flash_out", "flash_lse")`` the backward
     # pass finds both saved and this kernel does not run a second time
@@ -1065,8 +1277,32 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (``_default_blocks``); the backward's follow an explicit
     block_q/block_k unless overridden. What a causal call then computes
     and fetches is ``causal_geometry``'s to say, and is recorded per
-    kernel under ``utils.profile.counters()["flash_geometry"]``.
+    kernel under ``utils.profile.counters()["flash_geometry"]``, with the
+    forward's body (``one_pass`` or ``two_pass``), its sub-tile and
+    ``_TAU``.
     """
+    return _flash(q, k, v, *_plan(
+        q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        scale=scale, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks,
+        interpret=interpret, layout=layout, mask=mask, window=window))
+
+
+def forward_fallbacks(q: jax.Array, k: jax.Array, v: jax.Array,
+                      **kwargs) -> jax.Array:
+    """The forward kernel of ``flash_attention(q, k, v, **kwargs)``, and
+    of it, per query row (B, H, S), how many of the row's grid steps its
+    row group took the two-pass body again for (the one-pass body's guard
+    failed): 0 on ordinary inputs."""
+    causal, scale, geos, interpret, seq_major = _plan(q, k, v, **kwargs)
+    return _fwd_impl(q, k, v, causal, scale, geos[0], interpret,
+                     seq_major)[2]
+
+
+def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
+          block_q=None, block_k=None, bwd_blocks=None, interpret=None,
+          layout="bhsd", mask=None, window=None):
+    """``flash_attention``'s checks and geometry: the static arguments of
+    ``_flash`` after q, k and v."""
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout {layout!r}: 'bhsd' or 'bshd'")
     seq_major = layout == "bshd"
@@ -1156,12 +1392,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 counted += ("blocks_live",)
         else:
             geo = _dense_geometry(sq, sk, bq, bk, stream)
+        counts = {f: getattr(geo, f) for f in counted}
+        if name == "ddstore_flash_fwd":
+            tile = _one_pass_tile(d, bq, bk)
+            counts.update(body="one_pass", tau=_TAU,
+                          tile=f"{tile[0]}x{tile[1]}")
         profile.count_geometry(
             name, f"{kind} bh{b * h} "
             f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "
             f"blocks {bq}x{bk} sub {geo.sub_q}x{geo.sub_k} {layout} "
-            f"kv{b * h_kv}", {f: getattr(geo, f) for f in counted})
+            f"kv{b * h_kv}", counts)
         geos.append(geo)
     # an enumerated grid, causal or under the mask: ``_call``'s one switch
-    return _flash(q, k, v, causal or mask is not None, scale, tuple(geos),
-                  interpret, seq_major)
+    return causal or mask is not None, scale, tuple(geos), interpret, \
+        seq_major

@@ -327,12 +327,19 @@ def main():
                           f"{geo['pairs_computed']} of needed "
                           f"{geo['pairs_needed']}, largest over mean "
                           f"{geo['max_over_mean']:.2f}", flush=True)
-                # What a sliding window's kernels step over: 1.00 visited
-                # over live is a grid of no block wholly outside the window
-                # (the kernels run on the chip; the CPU takes the reference)
+                # Which body each forward call lowered (one_pass: its
+                # sub-tile and tau), and what a sliding window's kernels
+                # step over: 1.00 visited over live is a grid of no block
+                # wholly outside the window (the kernels run on the chip;
+                # the CPU takes the reference)
                 for kernel, calls in profile.counters()[
                         "flash_geometry"].items():
                     for call, geo in calls.items():
+                        if "body" in geo:
+                            print(f"flash {call} {kernel}: body "
+                                  f"{geo['body']}" + "".join(
+                                      f" {k} {geo[k]}" for k in ("tile", "tau")
+                                      if k in geo), flush=True)
                         if call.startswith("window"):
                             seen = geo["grid_steps"] / geo["blocks_live"]
                             print(f"flash {call} {kernel}: pairs needed "

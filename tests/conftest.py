@@ -41,3 +41,15 @@ def pytest_report_header(config):
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Cuts the flash forward's sub-tiles to ``tile`` (rows, columns), so
+    that a small call holds several of them a strip:
+    ``small_tiles((64, 64))``."""
+    def cut(tile):
+        from ddstore_tpu.ops import attention
+        monkeypatch.setattr(attention, "_one_pass_tile", lambda d, bq, bk: (
+            min(tile[0], bq), min(tile[1], bk)))
+    return cut

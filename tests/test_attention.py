@@ -66,6 +66,78 @@ def test_flash_offsets_match_reference():
         assert (np.asarray(out_f)[~np.isfinite(np.asarray(lse_f))] == 0).all()
 
 
+# ---------------------------------------------------------------------------
+# The forward's one-pass body: scores a sub-tile at a time against a
+# reference max known before them; a row group whose sum against it passes
+# e**tau is taken again by the two-pass body, and lane 1 of the kernel's
+# statistics counts that per row (``forward_fallbacks``).
+# ---------------------------------------------------------------------------
+
+
+_ROW, _COL = 200, 150      # query 200's score on key 150 is raised
+
+
+@pytest.mark.parametrize("kw,tile,jump,falls_back", [
+    (dict(block_q=64, block_k=256), (64, 64), 0.0, False),
+    (dict(block_q=64, block_k=256), (64, 64), 60.0, True),
+    (dict(block_q=64, block_k=64), (64, 64), 60.0, True),
+    (dict(block_q=64, block_k=256), (64, 64), 20.0, False),
+    (dict(block_q=64, block_k=256), (48, 96), 60.0, True),
+    (dict(block_q=64, block_k=64, kv_offset=128), (32, 32), 60.0, False),
+    (dict(block_q=8, block_k=512, s=512), (8, 64), 60.0, True)],
+    ids=["ordinary", "jump-after-the-first-sub-tile", "jump-in-a-later-step",
+         "jump-under-tau", "sub-tiles-that-do-not-divide-the-strips",
+         "fully-masked-rows", "traced-shift"])
+def test_the_one_pass_forward_and_its_fallback(small_tiles, kw, tile, jump,
+                                               falls_back):
+    """Out and lse equal the reference's to 2e-5 whether or not a row group
+    falls back; the raised row counts its fallback in lane 1, the head
+    whose scores are ordinary counts none. A raised key in the row's
+    future (``kv_offset``: rows 0-127 see no key at all) raises nothing;
+    under a traced shift (64 diagonal positions) the same guard holds."""
+    from ddstore_tpu.ops.attention import forward_fallbacks
+    small_tiles(tile)
+    kw = dict(causal=True, **kw)
+    s = kw.pop("s", 256)
+    q, k, v = _qkv(21, b=1, h=2, s=s, d=32)
+    qr = q[0, 0, _ROW]
+    # scale * q . k = jump on (row, col): the rest of the row scores ~N(0, 1)
+    k = k.at[0, 0, _COL].set(qr * jump * np.sqrt(32) / jnp.dot(qr, qr))
+    out_r, lse_r = mha_reference(q, k, v, causal=True,
+                                 kv_offset=kw.get("kv_offset", 0))
+    out_f, lse_f = flash_attention(q, k, v, **kw)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_r),
+                               atol=2e-5, rtol=2e-5)
+    seen = np.isfinite(np.asarray(lse_r))
+    np.testing.assert_array_equal(np.isfinite(np.asarray(lse_f)), seen)
+    np.testing.assert_allclose(np.asarray(lse_f)[seen],
+                               np.asarray(lse_r)[seen], atol=2e-5, rtol=2e-5)
+    fallbacks = np.asarray(forward_fallbacks(q, k, v, **kw))
+    assert fallbacks.shape == (1, 2, s)
+    assert (fallbacks[0, 1] == 0).all()
+    if falls_back:
+        assert fallbacks[0, 0, _ROW] >= 1 and fallbacks[0, 0, :64].sum() == 0
+    else:
+        assert (fallbacks == 0).all()
+
+
+def test_the_forward_counter_names_its_body():
+    """``flash_geometry`` says per forward call which body it lowered, the
+    sub-tile and tau; the backward kernels' entries are as they were."""
+    from ddstore_tpu.ops.attention import _TAU
+    from ddstore_tpu.utils import profile
+    for d, tile in ((64, "512x512"), (128, "512x256"), (256, "1024x512")):
+        x = jax.ShapeDtypeStruct((1, 1, 4096, d), jnp.bfloat16)
+        jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), x)
+        calls = profile.counters()["flash_geometry"]
+        (fwd,) = [g for c, g in calls["ddstore_flash_fwd"].items()
+                  if c.startswith(f"causal bh1 q4096+0 k4096+0 d{d} ")]
+        assert (fwd["body"], fwd["tile"], fwd["tau"]) == ("one_pass", tile,
+                                                          _TAU)
+        for kernel in ("ddstore_flash_dq", "ddstore_flash_dkv"):
+            assert all("body" not in g for g in calls[kernel].values())
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("axes", [{"sp": 8}, {"dp": 2, "sp": 4}])
 def test_ring_matches_full(causal, axes):

@@ -295,13 +295,22 @@ def _flash_case(block, half, h, h_kv, d, layout="bhsd", **blocks):
     (4, 512, 1, 1, 128, "bhsd", dict(block_q=256, block_k=512,
                                      bwd_blocks=(512, 256, 256, 512))),
     # more variants than static bodies: whole blocks under a traced band
-    (4, 128, 1, 1, 32, "bhsd", dict(block_q=8, block_k=128))],
+    (4, 128, 1, 1, 32, "bhsd", dict(block_q=8, block_k=128)),
+    # the forward's sub-tiles cut small: a noised row's first sub-tile of a
+    # strip is masked, its reference is a later one
+    (4, 128, 2, 1, 32, "bhsd", dict(block_q=64, block_k=128, tile=(32, 32))),
+    (8, 128, 2, 2, 32, "bhsd", dict(block_q=64, block_k=128, tile=(48, 40)))],
     ids=["defaults", "blocks-of-8", "blocks-of-1", "seq-major-gqa",
-         "strips", "traced-band"])
+         "strips", "traced-band", "first-sub-tile-masked",
+         "sub-tiles-that-do-not-divide-the-strips"])
 def test_flash_under_the_mask_matches_the_reference(block, half, h, h_kv, d,
-                                                    layout, blocks):
+                                                    layout, blocks,
+                                                    small_tiles):
     """Forward, dq, dk and dv through the interpreted kernels, the lse's
     cotangent included."""
+    blocks = dict(blocks)
+    if "tile" in blocks:
+        small_tiles(blocks.pop("tile"))
     operands, plain, flash, scalar = _flash_case(block, half, h, h_kv, d,
                                                  layout, **blocks)
     for got, want in zip(flash(*operands), plain(*operands)):

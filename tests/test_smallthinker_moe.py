@@ -257,13 +257,24 @@ def _flash_case(h, h_kv, d, s, window, layout="bhsd", **blocks):
                                       bwd_blocks=(64, 128, 128, 64))),
     # more block positions than static bodies: whole blocks under traced
     # edges
-    (1, 1, 32, 512, 200, "bhsd", dict(block_q=8, block_k=256))],
+    (1, 1, 32, 512, 200, "bhsd", dict(block_q=8, block_k=256)),
+    # the forward's sub-tiles cut small: past the window's first keys a
+    # row's first sub-tile is masked, and a block the window's lower edge
+    # crosses holds rows that see none of it
+    (2, 1, 32, 256, 40, "bhsd", dict(block_q=64, block_k=64, tile=(32, 16))),
+    (2, 1, 32, 256, 70, "bhsd", dict(block_q=64, block_k=256,
+                                     tile=(48, 40)))],
     ids=["blocks", "seq-major-group-of-7", "both-edges", "defaults",
-         "bwd-blocks", "traced-edges"])
+         "bwd-blocks", "traced-edges", "first-sub-tile-masked",
+         "sub-tiles-that-do-not-divide-the-strips"])
 def test_flash_under_the_window_matches_the_reference(h, h_kv, d, s, window,
-                                                      layout, blocks):
+                                                      layout, blocks,
+                                                      small_tiles):
     """Forward, dq, dk and dv through the interpreted kernels, the lse's
     cotangent included."""
+    blocks = dict(blocks)
+    if "tile" in blocks:
+        small_tiles(blocks.pop("tile"))
     operands, plain, flash, scalar = _flash_case(h, h_kv, d, s, window,
                                                  layout, **blocks)
     for got, want in zip(flash(*operands), plain(*operands)):

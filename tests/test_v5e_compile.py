@@ -48,14 +48,19 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("bh,s", [(160, 2048), (40, 8192)])
+@pytest.mark.parametrize("bh,s,d", [
+    pytest.param(160, 2048, 256, id="160-2048"),
+    pytest.param(40, 8192, 256, id="40-8192"),
+    pytest.param(32, 8192, 128, id="width-128")])
 def test_flash_kernels_at_width_256_fit_the_chip(one_chip, no_compile_cache,
-                                                 bh, s):
+                                                 bh, s, d):
     """The MLA block's calls: 20 heads of 256 over 16,384 tokens, at the
-    block defaults the call's shape selects (1024 x 1024)."""
+    block defaults the call's shape selects (1024 x 1024); and a call at
+    width 128 (Nemotron's and SmallThinker's heads, 512 x 2048), whose
+    forward's one-pass body takes other sub-tiles than width 64's."""
     from ddstore_tpu.ops.attention import flash_attention
 
-    q = jax.ShapeDtypeStruct((1, bh, s, 256), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, bh, s, d), jnp.bfloat16,
                              sharding=one_chip)
 
     def f(q, k, v):
