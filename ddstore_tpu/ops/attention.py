@@ -36,10 +36,17 @@ Design notes (TPU):
   queries on lanes, no block-wide f32 scores written out; a step whose
   sums pass e**``_TAU`` is taken again by the two-pass body, and lane 1 of
   its statistics output counts that (``forward_fallbacks``);
+* the backward is one kernel on the key-major walk (key blocks outer,
+  query blocks inner): each live block pair's probabilities and their
+  cotangent are formed once and feed dv and dk, accumulated for the key
+  block, and dq, accumulated in a float32 buffer that holds the whole
+  head's rows in VMEM from the head's first grid step to its last
+  (``_bwd_kernel``);
 * on CPU (tests) the identical kernel runs in interpreter mode;
-* the three kernels carry stable names (``ddstore_flash_fwd``,
-  ``ddstore_flash_dq``, ``ddstore_flash_dkv``): a device trace names the
-  custom call after them, on one chip and inside the ring's branches.
+* the two kernels carry stable names (``ddstore_flash_fwd``, and
+  ``ddstore_flash_dkv`` for the backward, which writes dq, dk and dv): a
+  device trace names the custom call after them, on one chip and inside
+  the ring's branches.
 """
 
 from __future__ import annotations
@@ -61,18 +68,17 @@ from ..utils import profile
 NEG_INF = float("-inf")
 
 
-# Rows (forward, dq) or columns (dkv) of one compute strip inside a block
+# Rows (forward) or columns (backward) of one compute strip inside a block
 # that straddles the causal diagonal, per kernel, and the grain its other
 # side is cut to (the lane width). The FETCHED block stays large; what is
 # computed of it ends at the diagonal. Chosen on the chip (PERF.md section
-# 6, PR 26): dq gains a tenth from the narrower strip, the forward and dkv
-# nothing that pays for twice the bodies to trace and lower at every start.
-_STRIP = {"ddstore_flash_fwd": 512, "ddstore_flash_dq": 256,
-          "ddstore_flash_dkv": 512}
-# Heads wider than 128 (PR 27, width 256): dkv gains 5 % from the narrower
-# strip at S=2048 (4.54 against 4.78 ms a call of 160 heads) and 1.5 % at
-# S=8192, the forward nothing; its blocks are square, so one diagonal
-# position and two more bodies.
+# 6): neither kernel gains from a narrower strip what twice the bodies
+# cost to trace and lower at every start.
+_STRIP = {"ddstore_flash_fwd": 512, "ddstore_flash_dkv": 512}
+# Heads wider than 128 (width 256): the backward strips at 256, where the
+# dk/dv half of it gained 5 % at S=2048 (4.54 against 4.78 ms a call of
+# 160 heads) and 1.5 % at S=8192, the forward nothing; its blocks are
+# square, so one diagonal position and two more bodies.
 _STRIP_WIDE = dict(_STRIP, ddstore_flash_dkv=256)
 _LANES = 128
 # A diagonal block's position against the diagonal (first row - first
@@ -96,7 +102,7 @@ def _live_cols(row_lo, rows, col_lo, cols, n):
     position ``col_lo`` against the rows ``[row_lo, row_lo + rows)``:
     ``(n_past, n_live)``. Tiles ``[0, n_past)`` lie wholly in the past (no
     mask), ``[n_past, n_live)`` straddle the diagonal, the rest are dead.
-    THE classification of the forward and dq kernels (k/v stream) at both
+    THE classification of the forward kernel (k/v stream) at both
     sizes, blocks over the grid and lane-wide tiles inside a block, and of
     the counter."""
     return (_tiles(row_lo - col_lo + 1, cols, n),
@@ -106,7 +112,7 @@ def _live_cols(row_lo, rows, col_lo, cols, n):
 def _live_rows(col_lo, cols, row_lo, rows, n):
     """The same classification seen from the columns ``[col_lo, col_lo +
     cols)`` over ``n`` row tiles of height ``rows`` from ``row_lo`` (the
-    dkv kernel's q stream): ``(first_live, first_past)``. Tiles ``[0,
+    backward kernel's q stream): ``(first_live, first_past)``. Tiles ``[0,
     first_live)`` are dead, ``[first_live, first_past)`` straddle the
     diagonal, ``[first_past, n)`` lie wholly in the past."""
     return (_tiles(col_lo - row_lo, rows, n),
@@ -172,7 +178,7 @@ class FlashGeometry(NamedTuple):
     sub_k: int                 # and its columns
     q_offset: int
     kv_offset: int
-    stream: str                # innermost grid axis: "k" (fwd, dq), "q" (dkv)
+    stream: str                # innermost grid axis: "k" (fwd), "q" (bwd)
     pairs_needed: int          # (query, key) pairs with key <= query
     pairs_computed: int        # pairs in the tiles a body runs over
     grid_steps: int
@@ -347,7 +353,7 @@ def _band(kind, shift, log2b):
 
 def _runs(geo, classify, row0, col0):
     """The static strips of a block an edge of a mask crosses: each
-    ``sub_q`` rows (forward, dq) or ``sub_k`` columns (dkv) over the
+    ``sub_q`` rows (forward) or ``sub_k`` columns (backward) over the
     contiguous run of lane-wide tiles on its other side that hold a live
     pair, ``(rows, cols, full)`` (``full``: every pair of the run is live).
     ``classify(row_lo, rows, col_lo, cols)`` is the mask's ``(live, full)``
@@ -385,8 +391,8 @@ def _masked_strips(geo, variant):
 def _strips(geo, shift):
     """The compute strips of a diagonal block whose first row lies
     ``shift`` past its first column: ``(rows, cols, strip_shift)`` as
-    static slices of the block. A forward or dq strip is ``sub_q`` rows by
-    every ``sub_k``-wide tile up to the last live one; a dkv strip is
+    static slices of the block. A forward strip is ``sub_q`` rows by
+    every ``sub_k``-wide tile up to the last live one; a backward strip is
     ``sub_k`` columns by every ``sub_q``-high tile from the first live one.
     One matmul chain each, masked by its own shift. Under a mask ``shift``
     is a variant of ``_enumerate_masked`` (``_masked_strips``); under a
@@ -588,13 +594,17 @@ def _traced_band(mask, q_lo, k_lo):
 
 class _Step(NamedTuple):
     """One grid step as a kernel body sees it: whether it opens / closes
-    its row of the accumulation; ``run(update, done=None)``, which calls
-    ``update(rows, cols, shift)`` over what is live of the step's block
-    (``shift`` None: no mask) and then ``done()``, once, inside the same
-    body; and ``whole()``, the mask of the whole block (traced: the same
-    for every step's body)."""
+    its row of the accumulation, and whether it is its head's first / last
+    step; ``inner``, the index of the block it streams; ``run(update,
+    done=None)``, which calls ``update(rows, cols, shift)`` over what is
+    live of the step's block (``shift`` None: no mask) and then
+    ``done()``, once, inside the same body; and ``whole()``, the mask of
+    the whole block (traced: the same for every step's body)."""
     first: jax.Array
     last: jax.Array
+    head_first: jax.Array
+    head_last: jax.Array
+    inner: jax.Array
     run: Callable
     whole: Callable
 
@@ -612,14 +622,17 @@ def _grid_kernel(kernel, causal, geo):
     the static strips of its position (``_strips``)."""
     if not causal:
         def dense(*refs):
+            outer, n_outer = pl.program_id(1), pl.num_programs(1)
             inner, n = pl.program_id(2), pl.num_programs(2)
 
             def run(update, done=None):
                 update(_WHOLE, _WHOLE, None)
                 if done:
                     done()
-            kernel(_Step(inner == 0, inner == n - 1, run, lambda: None),
-                   *refs)
+            first, last = inner == 0, inner == n - 1
+            kernel(_Step(first, last, first & (outer == 0),
+                         last & (outer == n_outer - 1), inner, run,
+                         lambda: None), *refs)
         return dense
 
     _, _, codes, shifts = _steps(geo)
@@ -663,7 +676,8 @@ def _grid_kernel(kernel, causal, geo):
                 pl.when(what >= _DIAGONAL)(
                     lambda: body([(_WHOLE, _WHOLE, whole())]))
 
-        kernel(_Step((code & _FIRST) != 0, (code & _LAST) != 0, run, whole),
+        kernel(_Step((code & _FIRST) != 0, (code & _LAST) != 0, t == 0,
+                     t == pl.num_programs(1) - 1, inner_ref[t], run, whole),
                *refs)
     return enumerated
 
@@ -880,10 +894,14 @@ def _flash_kernel(step, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
-          interpret, groups=None, heads=None):
+          interpret, groups=None, heads=None, whole=None, vmem=None):
     """One flash ``pallas_call``. ``resident`` says per operand whether its
     block rides the outer grid axis (it stays in VMEM across a row; the
-    outputs all do) or the inner one (it is streamed). ``groups`` says per
+    outputs do) or the inner one (it is streamed); ``whole`` says per
+    output whether its block is rather all of a head's rows, in VMEM from
+    the head's first grid step to its last (the outer axis then carries an
+    accumulation too, and is ``"arbitrary"``). ``vmem``: the call's VMEM
+    limit in bytes (None: the compiler's default). ``groups`` says per
     operand how many of the grid's ``bh`` share one of its heads
     (grouped-query attention: query head ``bh`` reads K/V head ``bh //
     group``, so no repeated K or V is ever in HBM); default 1 throughout.
@@ -911,20 +929,22 @@ def _call(kernel, name, causal, geo, operands, resident, out_shape, scratch,
                    name, causal, geo,
                    tuple(x.shape for x in operands[3 if causal else 0:]),
                    tuple(resident), tuple(out_shape), tuple(scratch),
-                   interpret, groups, heads)(*operands)
+                   interpret, groups, heads,
+                   tuple(whole or (False,) * len(out_shape)), vmem)(*operands)
 
 
 @functools.lru_cache(maxsize=256)
 def _pallas(fn, keywords, name, causal, geo, shapes, resident, out_shape,
-            scratch, interpret, groups, heads):
+            scratch, interpret, groups, heads, whole, vmem):
     """``_call``'s ``pallas_call`` of ``functools.partial(fn,
     **keywords)`` over operands of ``shapes`` (the step tables first,
     causal)."""
     bhs = shapes[0][0] * (heads or 1)     # the query side's b h
     q_side = geo.stream == "k"     # which side the outer axis walks
 
-    def block(shape, on_outer, group=1):
-        rows = geo.block_q if on_outer == q_side else geo.block_k
+    def block(shape, on_outer, group=1, head=False):
+        rows = shape[1] if head else \
+            geo.block_q if on_outer == q_side else geo.block_k
         if heads is not None and shape[0] != bhs:
             # (b, S, h d): told from the statistics by the leading
             # dimension; where they agree (one head) so do the addresses
@@ -939,7 +959,10 @@ def _pallas(fn, keywords, name, causal, geo, shapes, resident, out_shape,
             width = shape[-1]
             lead = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
             col = lambda bh: 0
-        if causal:
+        if head:
+            def index(bh, *_):
+                return lead(bh), 0, col(bh)
+        elif causal:
             def index(bh, t, outer, inner, code):
                 return lead(bh), (outer if on_outer else inner)[t], col(bh)
         else:
@@ -950,7 +973,8 @@ def _pallas(fn, keywords, name, causal, geo, shapes, resident, out_shape,
     in_specs = [block(shape, on_outer, group)
                 for shape, on_outer, group in zip(
                     shapes, resident, groups or (1,) * len(shapes))]
-    out_specs = [block(o.shape, True) for o in out_shape]
+    out_specs = [block(o.shape, True, head=h) for o, h in zip(out_shape,
+                                                                whole)]
     if causal:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(bhs, len(_steps(geo)[0])),
@@ -961,9 +985,11 @@ def _pallas(fn, keywords, name, causal, geo, shapes, resident, out_shape,
         grid_spec = pl.GridSpec(
             grid=(bhs,) + ((n_q, n_k) if q_side else (n_k, n_q)),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch)
-        sems = ("parallel", "parallel", "arbitrary")
+        sems = ("parallel", "arbitrary" if any(whole) else "parallel",
+                "arbitrary")
     params = {} if interpret else {
-        "compiler_params": pltpu.CompilerParams(dimension_semantics=sems)}
+        "compiler_params": pltpu.CompilerParams(dimension_semantics=sems,
+                                                vmem_limit_bytes=vmem)}
     return pl.pallas_call(
         _grid_kernel(functools.partial(fn, **dict(keywords)), causal, geo),
         name=name, grid_spec=grid_spec, out_shape=out_shape,
@@ -1020,71 +1046,55 @@ def _fwd_impl(q, k, v, causal, scale, geo, interpret, seq_major):
             lse_f[..., 1].reshape(b, h, sq))
 
 
-def _recompute_p(q, k, dta_ref, rows, shift, scale):
-    """The backward kernels' recomputed probabilities of one tile."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if shift is not None:
-        s = _causal_mask(s, shift)
-    lse = dta_ref[0, rows, 1:2]                              # (tq, 1)
-    # Fully-masked rows have lse = -inf; exp(s - safe_lse) is then
-    # exp(-inf - big) = 0 for every column — no full-block select.
-    safe_lse = jnp.where(jnp.isfinite(lse), lse, 1e30)
-    return jnp.exp(s - safe_lse)
+def _bwd_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dq_ref, dk_ref,
+                dv_ref, dq_acc, dk_acc, dv_acc, *, scale):
+    """dq, dk and dv of one head, a k/v block at a time, streaming q blocks
+    (the recompute-p flash backward): per live tile ``s = q k^T``, ``p`` and
+    ``dp = do v^T`` are formed once and feed all three, five products where
+    a dq kernel of its own would take ``q k^T`` and ``do v^T`` again.
 
-
-def _bwd_dq_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dq_ref,
-                   dq_acc, *, scale):
-    """dq for one q block, streaming k/v blocks (recompute-p flash bwd).
-
-    ``dta`` packs the per-row residual scalars into one 128-lane tensor
-    (lane 0 = c = delta - dlse with delta = rowsum(do*o); lane 1 = lse):
-    one streamed side input instead of two."""
-    @pl.when(step.first)
+    dk and dv accumulate in the k/v block's scratch over its row of steps.
+    dq accumulates in ``dq_acc``, float32 and the head's every row, zeroed
+    at the head's first grid step and written out at its last (``dq_ref``
+    is the whole head's block, which stays in VMEM as long). The q-side
+    streams (q, do, dta) re-fetch every grid step (their block index rides
+    the innermost loop), so the per-row residual scalars come packed into
+    one 128-lane ``dta`` (lane 0 c = delta - dlse with delta = rowsum(do *
+    o); lane 1 lse): one streamed side input instead of two."""
+    @pl.when(step.head_first)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def update(rows, cols, shift):
-        k = k_ref[0, cols, :]
-        p = _recompute_p(q_ref[0, rows, :], k, dta_ref, rows, shift, scale)
-        dp = jnp.dot(do_ref[0, rows, :], v_ref[0, cols, :].T,
-                     preferred_element_type=jnp.float32)
-        # ds = p * (dp - c) with c = delta - dlse packed in lane 0.
-        t = p * (dp - dta_ref[0, rows, :1])
-        dq_acc[rows, :] = dq_acc[rows, :] + jnp.dot(
-            t.astype(k.dtype), k, preferred_element_type=jnp.float32) * scale
-
-    step.run(update)
-
-    @pl.when(step.last)
-    def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, scale):
-    """dk/dv for one k/v block, streaming q blocks.
-
-    The q-side streams (q, do, dta) re-fetch every grid step here (their
-    block index rides the innermost loop), so the packed single ``dta``
-    side input (c = delta - dlse in lane 0, lse in lane 1) halves the
-    f32 side-stream HBM traffic vs separate lse + dta tensors."""
     @pl.when(step.first)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    bq = q_ref.shape[1]
+    q_lo = step.inner * bq              # the streamed block's first row
+
     def update(rows, cols, shift):
-        q = q_ref[0, rows, :]
-        p = _recompute_p(q, k_ref[0, cols, :], dta_ref, rows, shift, scale)
+        q, k = q_ref[0, rows, :], k_ref[0, cols, :]
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if shift is not None:
+            s = _causal_mask(s, shift)
+        lse = dta_ref[0, rows, 1:2]                          # (tq, 1)
+        # Fully-masked rows have lse = -inf; exp(s - safe_lse) is then
+        # exp(-inf - big) = 0 for every column: no full-block select.
+        p = jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 1e30))
         do = do_ref[0, rows, :]
         dv_acc[cols, :] = dv_acc[cols, :] + jnp.dot(
             p.astype(do.dtype).T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v_ref[0, cols, :].T,
                      preferred_element_type=jnp.float32)
-        t = p * (dp - dta_ref[0, rows, :1])
+        # ds = p * (dp - c) with c = delta - dlse packed in lane 0.
+        ds = (p * (dp - dta_ref[0, rows, :1])).astype(q.dtype)
         dk_acc[cols, :] = dk_acc[cols, :] + jnp.dot(
-            t.astype(q.dtype).T, q, preferred_element_type=jnp.float32) \
-            * scale
+            ds.T, q, preferred_element_type=jnp.float32) * scale
+        r0, r1, _ = rows.indices(bq)
+        at = pl.ds(pl.multiple_of(q_lo + r0, math.gcd(bq, r0)), r1 - r0)
+        dq_acc[at, :] = dq_acc[at, :] + jnp.dot(
+            ds, k, preferred_element_type=jnp.float32) * scale
 
     step.run(update)
 
@@ -1092,6 +1102,10 @@ def _bwd_dkv_kernel(step, q_ref, k_ref, v_ref, do_ref, dta_ref, dk_ref,
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(step.head_last)
+    def _():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -1118,10 +1132,9 @@ def _flash_fwd(q, k, v, causal, scale, geos, interpret, seq_major):
 def _flash_bwd(causal, scale, geos, interpret, seq_major, res, g):
     q, k, v, out, lse = res
     do, dlse = g
-    # The backward kernels stream different data patterns than the
-    # forward (dq: k/v innermost; dkv: the whole q side innermost), so
-    # they take their own block shapes.
-    _, g_dq, g_dkv = geos
+    # The backward streams the whole q side innermost, the forward k and
+    # v: each takes its own block shapes.
+    _, g_bwd = geos
     b, h, h_kv, sq, sk, d = _dims(q, k, seq_major)
     group = h // h_kv
     bhs = b * h
@@ -1148,23 +1161,25 @@ def _flash_bwd(causal, scale, geos, interpret, seq_major, res, g):
     groups = (1, group, group, 1, 1)
     heads = h if seq_major else None
 
-    (dq,) = _call(
-        functools.partial(_bwd_dq_kernel, scale=scale), "ddstore_flash_dq",
-        causal, g_dq, operands, (True, False, False, True, True),
-        [jax.ShapeDtypeStruct(qf.shape, q.dtype)],
-        [pltpu.VMEM((g_dq.block_q, d), jnp.float32)], interpret, groups,
-        heads)
     # dk and dv come out a QUERY head (the grid's bh): a K/V head's are the
     # sum over its group's, taken outside the kernel in float32.
     at_q_heads = qf.shape[:1] + (sk, qf.shape[2])
-    dk, dv = _call(
-        functools.partial(_bwd_dkv_kernel, scale=scale), "ddstore_flash_dkv",
-        causal, g_dkv, operands, (False, True, True, False, False),
-        [jax.ShapeDtypeStruct(at_q_heads, k.dtype),
+    _, vmem = _bwd_vmem(sq, d, q.dtype)
+    if vmem > _VMEM_CAP:
+        raise ValueError(
+            f"the flash backward holds a head's dq in VMEM: {sq} rows of "
+            f"width {d} ask {vmem} bytes, more than {_VMEM_CAP}; split the "
+            f"sequence over chips (``ring_attention``)")
+    dq, dk, dv = _call(
+        functools.partial(_bwd_kernel, scale=scale), "ddstore_flash_dkv",
+        causal, g_bwd, operands, (False, True, True, False, False),
+        [jax.ShapeDtypeStruct(qf.shape, q.dtype),
+         jax.ShapeDtypeStruct(at_q_heads, k.dtype),
          jax.ShapeDtypeStruct(at_q_heads, v.dtype)],
-        [pltpu.VMEM((g_dkv.block_k, d), jnp.float32),
-         pltpu.VMEM((g_dkv.block_k, d), jnp.float32)], interpret, groups,
-        heads)
+        [pltpu.VMEM((sq, d), jnp.float32),          # the head's dq
+         pltpu.VMEM((g_bwd.block_k, d), jnp.float32),
+         pltpu.VMEM((g_bwd.block_k, d), jnp.float32)], interpret, groups,
+        heads, whole=(True, False, False), vmem=vmem)
     if group > 1:
         grouped, axis = ((b, sk, h_kv, group, d), 3) if seq_major \
             else ((b, h_kv, group, sk, d), 2)
@@ -1174,6 +1189,25 @@ def _flash_bwd(causal, scale, geos, interpret, seq_major, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# VMEM: what the backward's blocks, k/v side and streamed, and the values
+# of its body take at the most (twice the v5e compiler's default scoped
+# limit; at 1024 x 1024 blocks they pass the default by up to 2.1 MB on the
+# described chip), and the most a backward call asks for, of the v5e's 128
+# MiB (``ops/moe_gmm.py`` and ``moe_combine.py`` ask as much).
+_VMEM_BLOCKS = 32 * 1024 * 1024
+_VMEM_CAP = 100 * 1024 * 1024
+
+
+def _bwd_vmem(sq: int, d: int, dtype) -> Tuple[int, int]:
+    """``(dq_vmem_bytes, vmem_limit)`` of the backward at ``sq`` query rows
+    of head width ``d`` in ``dtype``: the head's float32 dq buffer, and the
+    call's limit, the blocks' beside it and the whole-head dq block's two
+    pipeline buffers."""
+    resident = sq * d * 4
+    return resident, (_VMEM_BLOCKS + resident
+                      + 2 * sq * d * jnp.dtype(dtype).itemsize)
 
 
 def _fit_block(block: int, s: int, grain: int = 8) -> int:
@@ -1188,27 +1222,32 @@ def _fit_block(block: int, s: int, grain: int = 8) -> int:
 
 
 def _default_blocks(causal, sq, sk, d, q_offset, kv_offset, masked=False):
-    """``((block_q, block_k) forward, (block_q, block_k) dq and dkv)``
-    from what a call can see; PERF.md section 6 (PR 26, and PR 27 for
-    heads wider than 128) has the v5e times they were chosen from. Heads
-    wider than 128 take 1024 x 1024 throughout. Otherwise a causal call of
-    at most 2048 x 2048, none of whose blocks would lie wholly in the past, is
-    fetched whole by the backward kernels and in up to 1024 rows by the
+    """``((block_q, block_k) forward, (block_q, block_k) backward)`` from
+    what a call can see; PERF.md section 6 has the v5e times they were
+    chosen from. Heads wider than 128 take 1024 x 1024 throughout (the
+    backward 3 to 58 % longer at 512 x 1024, 1024 x 512, 2048 x 1024 and
+    1024 x 2048). Otherwise a causal call of at most 2048 x 2048, none of
+    whose blocks would lie wholly in the past, is fetched whole by the
+    backward and in up to 1024 rows by the
     forward (the whole 2048 rows put the forward within 1 % of the 16 MiB
     of VMEM a kernel may take): nothing runs as an interior body, whose
     block-wide scores would not fit VMEM at these sizes, and almost every
     grid step is work. Otherwise 512 x 2048 below S=8192; at it and beyond
-    the backward kernels and the non-causal forward take 1024 x 1024
-    (2048-wide q blocks exceed VMEM there), the causal forward stays at
-    512 x 2048: its diagonal strips want the width. A call under a
-    block-diffusion mask or a sliding window (``masked``) takes the causal
-    sizes of its ``2 half`` (or S) positions, the short whole-call form
-    apart."""
+    the non-causal forward takes 1024 x 1024 (2048-wide q blocks exceed
+    VMEM there), the causal forward stays at 512 x 2048: its diagonal
+    strips want the width; the backward takes 1024 x 2048 (2.0-2.4 % less
+    time than 1024 x 1024 at widths 64 and 128, causal or not; 2048 x 2048
+    exceeds VMEM). A call under a block-diffusion mask or a sliding window
+    (``masked``) takes the causal sizes of its ``2 half`` (or S)
+    positions, the short whole-call form apart, but for the backward's,
+    which stay 1024 x 1024 there: 2048-wide key blocks meet the mask's
+    edges at more positions than the kernel has static bodies for, and
+    take 2.4 to 2.7 times as long."""
     if d > 128:
         # Chosen on the chip at width 256 (PERF.md section 6, PR 27): every
         # q/k/v/do/acc tile is twice as deep, 512 x 2048 and wider do not
         # fit VMEM at S=8192, and of what fits 1024 x 1024 is the fastest
-        # for all three kernels at S=2048 and S=8192 alike.
+        # for every kernel at S=2048 and S=8192 alike.
         return (1024, 1024), (1024, 1024)
     if causal and not masked and max(sq, sk) <= 2048:
         short = (min(sq, 1024), sk), (sq, sk)
@@ -1218,7 +1257,8 @@ def _default_blocks(causal, sq, sk, d, q_offset, kv_offset, masked=False):
             return short
     if sq < 8192:
         return (512, 2048), (512, 2048)
-    return ((512, 2048) if causal else (1024, 1024)), (1024, 1024)
+    return ((512, 2048) if causal else (1024, 1024)), (
+        (1024, 1024) if masked else (1024, 2048))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1226,7 +1266,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     kv_offset: int = 0, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    bwd_blocks: Optional[Tuple[int, int, int, int]] = None,
+                    bwd_blocks: Optional[Tuple[int, int]] = None,
                     interpret: Optional[bool] = None, layout: str = "bhsd",
                     mask: Optional[BlockDiffusion] = None,
                     window: Optional[int] = None
@@ -1235,7 +1275,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     ``window`` (with ``causal``): a sliding window, query position i sees
     key positions ``i - window < j <= i`` (its own and the ``window - 1``
-    before it). The three kernels step over the blocks that hold such a
+    before it). The kernels step over the blocks that hold such a
     pair and no others: a block wholly before the window is no grid step
     and fetches nothing, as one wholly after the diagonal, and only the
     strips the window's two edges cross are masked. A window no shorter
@@ -1244,7 +1284,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``mask``: the block-diffusion training mask as a description
     (:class:`BlockDiffusion`; q, k and v are then the ``2 half`` positions
     ``[noised ; clean]``, and ``causal`` and the offsets stay unset). The
-    three kernels step over the blocks that hold a live pair and no others,
+    kernels step over the blocks that hold a live pair and no others,
     and mask the strips the mask's edges cross; blocks are fitted to the
     half, so none lies in two quadrants. The block length is a power of two
     of at most a lane tile, so every strip starts on a block's edge.
@@ -1259,27 +1299,30 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Grouped-query attention: ``k`` and ``v`` may have ``H_kv`` heads with
     ``H_kv`` dividing H; query head h reads K/V head ``h // (H / H_kv)``
-    through the kernels' index maps (the forward and dq never see a
-    repeated K or V; dkv writes a query head's dk, dv, summed over each
-    group outside the kernel).
+    through the kernels' index maps (neither kernel sees a repeated K or
+    V; the backward writes a query head's dk, dv, summed over each group
+    outside the kernel).
 
-    Differentiable: the backward pass is the standard recompute-p flash
-    backward as two Pallas kernels (dq streaming K/V blocks; dk/dv
-    streaming Q blocks), so training never materializes S×S. Sequence
+    Differentiable: the backward pass is the recompute-p flash backward as
+    one Pallas kernel (``ddstore_flash_dkv``) that streams Q blocks past
+    each K/V block and writes dq, dk and dv, a head's dq accumulated in
+    VMEM over all of its steps, so training never materializes S×S.
+    Sequence
     lengths must be multiples of 8 (callers pad; the data layer's budgets
     already guarantee static shapes). On non-TPU backends the same
     kernels run in interpreter mode.
 
-    block_q/block_k (forward) and ``bwd_blocks`` = (block_q_dq,
-    block_k_dq, block_q_dkv, block_k_dkv) are upper bounds, fitted per
-    call to the largest divisor of the sequence length that is a multiple
-    of 8. The defaults come from the call's own shape
-    (``_default_blocks``); the backward's follow an explicit
-    block_q/block_k unless overridden. What a causal call then computes
-    and fetches is ``causal_geometry``'s to say, and is recorded per
-    kernel under ``utils.profile.counters()["flash_geometry"]``, with the
-    forward's body (``one_pass`` or ``two_pass``), its sub-tile and
-    ``_TAU``.
+    block_q/block_k (forward) and ``bwd_blocks`` = (block_q, block_k) of
+    the backward are upper bounds, fitted per call to the largest divisor
+    of the sequence length that is a multiple of 8. The defaults come from
+    the call's own shape (``_default_blocks``); the backward's follow an
+    explicit block_q/block_k unless overridden. What a causal call then
+    computes and fetches is ``causal_geometry``'s to say, and is recorded
+    per kernel under ``utils.profile.counters()["flash_geometry"]``, with
+    the forward's body (``one_pass`` or ``two_pass``), its sub-tile and
+    ``_TAU``, and the backward's ``dq`` (``"resident"``), the bytes of its
+    dq buffer (``dq_vmem_bytes``) and the VMEM limit it sets
+    (``vmem_limit``).
     """
     return _flash(q, k, v, *_plan(
         q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
@@ -1338,13 +1381,13 @@ def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
     fwd, bwd = _default_blocks(causal or mask is not None, sq, sk, d,
                                q_offset, kv_offset,
                                mask is not None or window is not None)
-    # An explicit block_q / block_k bounds all three kernels, as ever.
+    # An explicit block_q / block_k bounds both kernels, as ever.
     fwd = (block_q or fwd[0], block_k or fwd[1])
     if bwd_blocks is None:
-        bwd_blocks = (block_q or bwd[0], block_k or bwd[1]) * 2
-    elif any(bl < 8 for bl in bwd_blocks):
-        raise ValueError(f"bwd_blocks entries must be >= 8 (TPU sublane "
-                         f"tile), got {bwd_blocks}")
+        bwd_blocks = (block_q or bwd[0], block_k or bwd[1])
+    elif len(bwd_blocks) != 2 or any(bl < 8 for bl in bwd_blocks):
+        raise ValueError(f"bwd_blocks: (block_q, block_k), each >= 8 (TPU "
+                         f"sublane tile), got {bwd_blocks}")
     # Block sizes are upper bounds: fit each to the largest multiple of 8
     # (Mosaic sublane tile) that divides the sequence. Any seq length
     # divisible by 8 therefore works with the big TPU-tuned defaults
@@ -1353,7 +1396,7 @@ def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
     block_q, block_k = (_fit_block(bl, s_, grain)
                         for bl, s_ in zip(fwd, fit_to))
     bwd_blocks = tuple(_fit_block(bl, s_, grain) for bl, s_
-                       in zip(bwd_blocks, fit_to * 2))
+                       in zip(bwd_blocks, fit_to))
     if not (block_q and block_k and all(bwd_blocks)):
         raise ValueError(f"seq lens {fit_to} must be multiples of {grain} "
                          f"(TPU tile alignment)")
@@ -1366,8 +1409,7 @@ def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
     geos = []
     for name, stream, bq, bk in (
             ("ddstore_flash_fwd", "k", block_q, block_k),
-            ("ddstore_flash_dq", "k") + bwd_blocks[:2],
-            ("ddstore_flash_dkv", "q") + bwd_blocks[2:]):
+            ("ddstore_flash_dkv", "q") + bwd_blocks):
         kind, counted = "causal" if causal else "full", (
             "pairs_needed", "pairs_computed", "grid_steps",
             "steps_fetching_dead")
@@ -1397,6 +1439,10 @@ def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
             tile = _one_pass_tile(d, bq, bk)
             counts.update(body="one_pass", tau=_TAU,
                           tile=f"{tile[0]}x{tile[1]}")
+        else:
+            dq_bytes, vmem = _bwd_vmem(sq, d, q.dtype)
+            counts.update(dq="resident", dq_vmem_bytes=dq_bytes,
+                          vmem_limit=vmem)
         profile.count_geometry(
             name, f"{kind} bh{b * h} "
             f"q{sq}+{geo.q_offset} k{sk}+{geo.kv_offset} d{d} "

@@ -148,10 +148,10 @@ STEP_SCOPES = {
     "ddstore_flash_fwd": "``ops/attention.py``, ``pallas_call(name=)``: "
                          "the flash forward, alike on one chip and in the "
                          "ring's steps.",
-    "ddstore_flash_dq": "``ops/attention.py``: the flash backward for q "
-                        "(recomputes the scores inside its body).",
-    "ddstore_flash_dkv": "``ops/attention.py``: the flash backward for k "
-                         "and v.",
+    "ddstore_flash_dkv": "``ops/attention.py``: the flash backward, one "
+                         "kernel that writes dq, dk and dv (recomputes "
+                         "the scores inside its body); the trace readers "
+                         "know the backward by this name.",
     "ddstore_short_conv_fwd": "``ops/short_conv.py``: the gated "
                               "convolution, forward.",
     "ddstore_short_conv_bwd": "``ops/short_conv.py``: its backward.",

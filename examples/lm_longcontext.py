@@ -328,10 +328,11 @@ def main():
                           f"{geo['pairs_needed']}, largest over mean "
                           f"{geo['max_over_mean']:.2f}", flush=True)
                 # Which body each forward call lowered (one_pass: its
-                # sub-tile and tau), and what a sliding window's kernels
-                # step over: 1.00 visited over live is a grid of no block
-                # wholly outside the window (the kernels run on the chip;
-                # the CPU takes the reference)
+                # sub-tile and tau), that the backward held each head's dq
+                # in VMEM (its bytes, the limit the call set), and what a
+                # sliding window's kernels step over: 1.00 visited over
+                # live is a grid of no block wholly outside the window (the
+                # kernels run on the chip; the CPU takes the reference)
                 for kernel, calls in profile.counters()[
                         "flash_geometry"].items():
                     for call, geo in calls.items():
@@ -340,6 +341,11 @@ def main():
                                   f"{geo['body']}" + "".join(
                                       f" {k} {geo[k]}" for k in ("tile", "tau")
                                       if k in geo), flush=True)
+                        if "dq" in geo:
+                            print(f"flash {call} {kernel}: dq {geo['dq']} "
+                                  f"dq_vmem_bytes {geo['dq_vmem_bytes']} "
+                                  f"vmem_limit {geo['vmem_limit']}",
+                                  flush=True)
                         if call.startswith("window"):
                             seen = geo["grid_steps"] / geo["blocks_live"]
                             print(f"flash {call} {kernel}: pairs needed "

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ddstore_tpu.ops.attention import flash_attention, mha_reference
+from ddstore_tpu.ops.attention import (BlockDiffusion, flash_attention,
+                                       mha_reference)
 from ddstore_tpu.parallel import balanced_order, make_mesh, ring_attention
 
 
@@ -123,7 +124,8 @@ def test_the_one_pass_forward_and_its_fallback(small_tiles, kw, tile, jump,
 
 def test_the_forward_counter_names_its_body():
     """``flash_geometry`` says per forward call which body it lowered, the
-    sub-tile and tau; the backward kernels' entries are as they were."""
+    sub-tile and tau; the backward kernel's entries name no body, and no
+    dq kernel of its own has any."""
     from ddstore_tpu.ops.attention import _TAU
     from ddstore_tpu.utils import profile
     for d, tile in ((64, "512x512"), (128, "512x256"), (256, "1024x512")):
@@ -134,8 +136,9 @@ def test_the_forward_counter_names_its_body():
                   if c.startswith(f"causal bh1 q4096+0 k4096+0 d{d} ")]
         assert (fwd["body"], fwd["tile"], fwd["tau"]) == ("one_pass", tile,
                                                           _TAU)
-        for kernel in ("ddstore_flash_dq", "ddstore_flash_dkv"):
-            assert all("body" not in g for g in calls[kernel].values())
+        assert all("body" not in g
+                   for g in calls["ddstore_flash_dkv"].values())
+        assert "ddstore_flash_dq" not in calls
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -192,12 +195,14 @@ def test_flash_gradients_match_reference(causal):
                                    rtol=2e-3)
 
 
+@pytest.mark.parametrize("bwd", [(32, 256), (128, 32)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_blocks_match_reference(causal):
-    """Per-kernel backward block shapes (bwd_blocks) are numerics-neutral:
-    rectangular dq/dkv blocks different from the forward's — exercising
-    both the interior (mask-free) and diagonal-straddling kernel bodies —
-    must give the same gradients."""
+def test_flash_bwd_blocks_match_reference(causal, bwd):
+    """The backward's own block shapes (bwd_blocks) are numerics-neutral:
+    rectangular blocks different from the forward's — exercising both the
+    interior (mask-free) and diagonal-straddling kernel bodies, and query
+    blocks taller or shorter than the key blocks the head's dq is summed
+    over — must give the same gradients."""
     q, k, v = _qkv(6, b=1, h=2, s=256, d=64)
     tgt = jax.random.normal(jax.random.key(10), q.shape)
 
@@ -211,10 +216,127 @@ def test_flash_bwd_blocks_match_reference(causal):
         q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
     gf = jax.grad(loss(lambda q, k, v: flash_attention(
         q, k, v, causal=causal, block_q=128, block_k=64,
-        bwd_blocks=(64, 128, 32, 256))), argnums=(0, 1, 2))(q, k, v)
+        bwd_blocks=bwd)), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3,
                                    rtol=2e-3)
+
+
+# The one backward kernel against jax.grad of the reference: (b, h, h_kv, s,
+# d, layout, flash_attention's keywords). Grouped K/V heads of 1, 4 and 7,
+# both layouts, widths 64 and 128, each kind of call the kernel is built
+# for; the last cases hold several heads and several key blocks a head, so
+# a dq buffer not zeroed at a head's first grid step, or written out at
+# any step but its last, reads another head's or part of its own rows.
+_FUSED_CASES = [
+    pytest.param(1, 2, 2, 256, 64, "bhsd",
+                 dict(causal=True, block_q=64, block_k=64,
+                      bwd_blocks=(64, 128)), id="causal"),
+    pytest.param(1, 2, 2, 256, 64, "bhsd",
+                 dict(causal=True, q_offset=64, kv_offset=0, block_q=64,
+                      block_k=64), id="causal-q_offset"),
+    pytest.param(1, 2, 2, 256, 64, "bhsd",
+                 dict(causal=True, q_offset=0, kv_offset=96, block_q=64,
+                      block_k=64), id="causal-kv_offset-masked-rows"),
+    pytest.param(1, 2, 2, 256, 64, "bhsd", dict(causal=True),
+                 id="short-whole-call"),
+    pytest.param(1, 2, 2, 256, 64, "bhsd",
+                 dict(causal=True, window=40, block_q=32, block_k=64),
+                 id="window"),
+    pytest.param(1, 2, 1, 256, 64, "bhsd",
+                 dict(mask=BlockDiffusion(4, 128), block_q=64, block_k=64),
+                 id="block-diffusion"),
+    pytest.param(1, 2, 2, 256, 64, "bhsd",
+                 dict(causal=False, block_q=64, block_k=64), id="non-causal"),
+    pytest.param(1, 4, 1, 256, 64, "bhsd",
+                 dict(causal=True, block_q=64, block_k=64), id="gqa-4"),
+    pytest.param(1, 7, 1, 128, 64, "bhsd",
+                 dict(causal=True, block_q=32, block_k=64), id="gqa-7"),
+    pytest.param(1, 7, 1, 128, 128, "bshd",
+                 dict(causal=True, block_q=32, block_k=64),
+                 id="gqa-7-seq-major-d128"),
+    pytest.param(1, 4, 2, 256, 128, "bshd",
+                 dict(causal=True, window=100, block_q=64, block_k=128),
+                 id="window-seq-major-d128"),
+    pytest.param(1, 2, 1, 256, 128, "bshd",
+                 dict(mask=BlockDiffusion(8, 128), block_q=64, block_k=64),
+                 id="block-diffusion-seq-major-d128"),
+    pytest.param(1, 2, 2, 256, 128, "bhsd",
+                 dict(causal=False, block_q=64, block_k=128),
+                 id="non-causal-d128"),
+    pytest.param(2, 3, 3, 512, 64, "bhsd",
+                 dict(causal=True, block_q=64, block_k=64,
+                      bwd_blocks=(64, 128)), id="heads-and-key-blocks"),
+    pytest.param(2, 3, 3, 512, 64, "bhsd",
+                 dict(causal=False, block_q=64, block_k=64,
+                      bwd_blocks=(128, 64)),
+                 id="heads-and-key-blocks-non-causal"),
+]
+
+
+@pytest.mark.parametrize("b,h,h_kv,s,d,layout,kw", _FUSED_CASES)
+def test_fused_backward_matches_reference(b, h, h_kv, s, d, layout, kw):
+    """dq, dk and dv of the one backward kernel, float32, against jax.grad
+    of ``mha_reference``, the lse's cotangent included."""
+    ks = jax.random.split(jax.random.key(s + d + h * 8 + h_kv), 4)
+    q, w = (jax.random.normal(kk, (b, h, s, d)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, h_kv, s, d)) for kk in ks[2:])
+    lay = (lambda t: t.transpose(0, 2, 1, 3)) if layout == "bshd" \
+        else (lambda t: t)
+    ref_kw = {key: kw[key] for key in ("causal", "q_offset", "kv_offset",
+                                       "window", "mask") if key in kw}
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            # a wholly masked row's lse is -inf: keep it out of the sum
+            return (out * w).sum() + jnp.sin(
+                jnp.where(jnp.isfinite(lse), lse, 0.0)).sum()
+        return f
+
+    def flash(q, k, v):
+        out, lse = flash_attention(lay(q), lay(k), lay(v), layout=layout,
+                                   **kw)
+        return lay(out), lse
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: mha_reference(q, k, v, **ref_kw)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, g, w_ in zip(("dq", "dk", "dv"), got, want):
+        assert g.shape == w_.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_), atol=1e-4,
+                                   rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("s,d,dtype", [(4096, 64, jnp.bfloat16),
+                                       (1024, 128, jnp.float32)])
+def test_the_backward_counter_says_dq_is_resident(s, d, dtype):
+    """A traced call's backward entry in ``flash_geometry`` records that dq
+    is held in VMEM for the head, the bytes of that float32 buffer (S_q d
+    4) and the VMEM limit the call sets: the blocks' 32 MiB, the buffer and
+    the whole-head dq block's two pipeline buffers."""
+    from ddstore_tpu.utils import profile
+    x = jax.ShapeDtypeStruct((1, 2, s, d), dtype)
+    jax.eval_shape(jax.grad(lambda q: flash_attention(
+        q, q, q, causal=True)[0].astype(jnp.float32).sum()), x)
+    calls = profile.counters()["flash_geometry"]["ddstore_flash_dkv"]
+    (geo,) = [g for c, g in calls.items()
+              if c.startswith(f"causal bh2 q{s}+0 k{s}+0 d{d} ")]
+    assert geo["dq"] == "resident"
+    assert geo["dq_vmem_bytes"] == s * d * 4
+    assert geo["vmem_limit"] == 32 * 2 ** 20 + s * d * 4 \
+        + 2 * s * d * jnp.dtype(dtype).itemsize
+
+
+def test_a_head_too_long_for_the_resident_dq_is_refused_by_name():
+    """The backward holds a head's dq in VMEM: a head whose buffer would
+    take the limit past the cap is refused when differentiated, with the
+    ring named; its forward still runs."""
+    x = jax.ShapeDtypeStruct((1, 1, 131072, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), x)
+    with pytest.raises(ValueError, match="dq in VMEM.*ring_attention"):
+        jax.eval_shape(jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True)[0].astype(jnp.float32).sum()), x)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -317,7 +439,9 @@ def test_ring_single_axis_mesh_fallback():
 
 def test_the_three_kernels_carry_their_names():
     """A device trace names a Mosaic call after ``pallas_call(name=)``: the
-    gradient of flash attention holds exactly the three named kernels."""
+    gradient of flash attention holds exactly the two named kernels, the
+    forward and the one backward (``ddstore_flash_dkv``, which writes dq
+    too); no dq kernel of its own."""
     q, k, v = _qkv(7, s=64)
 
     def loss(q, k, v):
@@ -335,8 +459,7 @@ def test_the_three_kernels_carry_their_names():
                 walk(sub)
 
     walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr)
-    assert sorted(names) == ["ddstore_flash_dkv", "ddstore_flash_dq",
-                             "ddstore_flash_fwd"]
+    assert sorted(names) == ["ddstore_flash_dkv", "ddstore_flash_fwd"]
 
 
 # ---------------------------------------------------------------------------
@@ -373,9 +496,10 @@ def _pallas_calls(fn, *args):
     return found
 
 
-# sq, sk, q_offset, kv_offset, (block_q, block_k), bwd_blocks. dq cuts a
-# 384-row block into three 128-row strips; the forward and dkv strip at 512,
-# which the 1536-row blocks of the last case give three of.
+# sq, sk, q_offset, kv_offset, (block_q, block_k), bwd_blocks. The backward
+# cuts a 384-row query block into three lane-high tiles under each strip;
+# the forward and the backward strip at 512, which the 1536-row blocks of
+# the last case give three of.
 _GEOMETRY_CASES = [
     pytest.param(768, 768, 0, 0, (384, 384), None, id="square"),
     pytest.param(768, 1536, 0, 0, (384, 768), None, id="sq<sk"),
@@ -383,9 +507,9 @@ _GEOMETRY_CASES = [
     pytest.param(768, 768, 128, 0, (384, 384), None, id="q_offset"),
     pytest.param(768, 768, 0, 384, (384, 384), None, id="partly-masked-q"),
     pytest.param(768, 768, 0, 1024, (384, 384), None, id="fully-masked-q"),
-    pytest.param(768, 768, 64, 200, (384, 768), (384, 384, 768, 384),
+    pytest.param(768, 768, 64, 200, (384, 768), (768, 384),
                  id="unaligned-offsets"),
-    pytest.param(768, 768, 0, 0, (384, 384), (64, 128, 32, 256),
+    pytest.param(768, 768, 0, 0, (384, 384), (32, 256),
                  id="bwd_blocks"),
     pytest.param(3072, 3072, 0, 0, (1536, 1536), None, id="three-strips"),
 ]
@@ -398,14 +522,15 @@ def test_causal_geometry_matches_reference(sq, sk, q_off, kv_off, blocks,
     geometry is entered: at least two blocks of at least three sub-tiles
     each, rectangular calls, offsets that mask a q range wholly or partly
     (out = 0, lse = -inf, no NaN, forward and backward)."""
-    from ddstore_tpu.ops.attention import _STRIP, _sub_tile
+    from ddstore_tpu.ops.attention import _LANES, _STRIP, _sub_tile
     bq, bk = blocks
-    # Two q blocks at least, of three strips at least in dq (in all three
-    # kernels in the case with 1536-row blocks).
+    # Two q blocks at least, of three lane-high tiles at least under the
+    # backward's strips (three strips in both kernels in the case with
+    # 1536-row blocks).
     strips = {name[14:]: (bk if name.endswith("dkv") else bq) // _sub_tile(
         bk if name.endswith("dkv") else bq, want)
         for name, want in _STRIP.items()}
-    assert sq // bq >= 2 and strips["dq"] >= 3, strips
+    assert sq // bq >= 2 and bq // _sub_tile(bq, _LANES) >= 3, strips
     assert bq < 1536 or min(strips.values()) >= 3, strips
     kq, kk, kv, kt = jax.random.split(jax.random.key(sq + sk + q_off), 4)
     q = jax.random.normal(kq, (1, 2, sq, 32))
@@ -481,7 +606,7 @@ def test_noncausal_kernels_keep_their_structure():
             return out.sum()
         return jax.grad(f, argnums=(0, 1, 2))
 
-    names = ["ddstore_flash_dkv", "ddstore_flash_dq", "ddstore_flash_fwd"]
+    names = ["ddstore_flash_dkv", "ddstore_flash_fwd"]
     full = _pallas_calls(grad_of(False), q, q, q)
     assert sorted(c[0] for c in full) == names
     for name, grid, body, maps in full:
@@ -498,7 +623,7 @@ def test_noncausal_kernels_keep_their_structure():
 
 @pytest.mark.parametrize("s,bound", [(2048, 1.35), (8192, 1.10),
                                      (16384, 1.10)])
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "dkv"])
 def test_causal_geometry_counts(s, bound, kernel):
     """The counter as arithmetic, at the geometry ``flash_attention``
     derives for a (1, 1, s, 64) causal call with default blocks: what is

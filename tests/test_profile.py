@@ -501,8 +501,11 @@ def test_the_benchmarks_partition_reads_a_recorded_slice_by_pass(
             passes.NO_SCOPE, passes.NO_NAME}
         by_kind[kind].add(which)
     assert by_kind["ddstore_flash_fwd"] == {"forward"}
-    assert by_kind["ddstore_flash_dq"] == by_kind["ddstore_flash_dkv"] \
-        == {"backward"}
+    # the slice was recorded when dq had a kernel of its own: that name is
+    # no step scope now, so its time is its enclosing block's, backward
+    assert by_kind["ddstore_flash_dkv"] == {"backward"}
+    assert "ddstore_flash_dq" not in by_kind
+    assert "backward" in by_kind["attn"]
     assert by_kind["optimizer"] == {"update"}          # the Adam fusions
     assert by_kind[passes.NO_NAME] == {passes.UNKNOWN}
     assert "recompute" not in set().union(*by_kind.values())

@@ -293,7 +293,7 @@ def _flash_case(block, half, h, h_kv, d, layout="bhsd", **blocks):
     (1, 32, 2, 1, 32, "bhsd", dict(block_q=8, block_k=32)),
     (4, 256, 2, 1, 128, "bshd", dict(block_q=128, block_k=256)),
     (4, 512, 1, 1, 128, "bhsd", dict(block_q=256, block_k=512,
-                                     bwd_blocks=(512, 256, 256, 512))),
+                                     bwd_blocks=(256, 512))),
     # more variants than static bodies: whole blocks under a traced band
     (4, 128, 1, 1, 32, "bhsd", dict(block_q=8, block_k=128)),
     # the forward's sub-tiles cut small: a noised row's first sub-tile of a

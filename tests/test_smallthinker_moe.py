@@ -254,7 +254,7 @@ def _flash_case(h, h_kv, d, s, window, layout="bhsd", **blocks):
     (2, 2, 32, 128, 24, "bhsd", dict(block_q=8, block_k=128)),
     (2, 1, 32, 256, 70, "bhsd", {}),
     (1, 1, 32, 256, 100, "bhsd", dict(block_q=128, block_k=64,
-                                      bwd_blocks=(64, 128, 128, 64))),
+                                      bwd_blocks=(128, 64))),
     # more block positions than static bodies: whole blocks under traced
     # edges
     (1, 1, 32, 512, 200, "bhsd", dict(block_q=8, block_k=256)),
@@ -326,27 +326,29 @@ def test_flash_refuses_a_window_it_does_not_build(kw):
 
 
 # Every causal flash call the other cells compile, (b h, S, head width),
-# and the sha256 of its three kernels' grid tables and strips as the parent
-# of the window's PR built them (``_enumerate`` and ``_strips``, which that
-# PR left as they were; a window of None takes them as they are).
+# and the sha256 of its two kernels' grid tables and strips as they are
+# built since the backward became one kernel on the key-major walk, its key
+# blocks 2048 wide at S >= 8192 and widths up to 128 (PERF.md section 6;
+# the forward's as the window's change found them: ``_enumerate`` and
+# ``_strips``, which it left as they were; a window of None takes them as
+# they are).
 _CELL_CALLS = {
-    "dense-lm-d1024.s8192": ((2 * 16, 8192, 64), "581b84a8"),
-    "dense-lm-d1024.s2048": ((8 * 16, 2048, 64), "568fc891"),
-    "dense-lm-d1024.s32k.dp2sp2": ((16, 16384, 64), "c2336b85"),
-    "glm47-flash-ep8.s2048": ((8 * 20, 2048, 256), "cf6c8298"),
-    "glm47-flash-ep8.s8192": ((2 * 20, 8192, 256), "d4d091f3"),
-    "lfm2-8b-a1b-ep4.s8192.b4": ((4 * 32, 8192, 64), "581b84a8"),
-    "nemotron3-nano-ep16.s8192": ((2 * 32, 8192, 128), "581b84a8"),
+    "dense-lm-d1024.s8192": ((2 * 16, 8192, 64), "24f3a7ed"),
+    "dense-lm-d1024.s2048": ((8 * 16, 2048, 64), "8bfac5b0"),
+    "dense-lm-d1024.s32k.dp2sp2": ((16, 16384, 64), "c24a8b58"),
+    "glm47-flash-ep8.s2048": ((8 * 20, 2048, 256), "af598a08"),
+    "glm47-flash-ep8.s8192": ((2 * 20, 8192, 256), "ad422419"),
+    "lfm2-8b-a1b-ep4.s8192.b4": ((4 * 32, 8192, 64), "24f3a7ed"),
+    "nemotron3-nano-ep16.s8192": ((2 * 32, 8192, 128), "24f3a7ed"),
 }
 
 
 def _grids_digest(s, d):
-    """What ``flash_attention(causal=True)`` builds its three grids from at
+    """What ``flash_attention(causal=True)`` builds its two grids from at
     ``s`` x ``s`` and head width ``d``, hashed."""
     fwd, bwd = A._default_blocks(True, s, s, d, 0, 0)
     h = hashlib.sha256()
     for name, stream, (bq, bk) in (("ddstore_flash_fwd", "k", fwd),
-                                   ("ddstore_flash_dq", "k", bwd),
                                    ("ddstore_flash_dkv", "q", bwd)):
         bq, bk = A._fit_block(bq, s), A._fit_block(bk, s)
         strip = (A._STRIP_WIDE if d > 128 else A._STRIP)[name]

@@ -230,7 +230,8 @@ def assert_the_layout_names_the_products(entry, d, hidden):
 def test_the_architectures_emit_the_vocabulary_between_them():
     kernels = {n for n in profile.STEP_SCOPES if n.startswith("ddstore_")}
     assert set().union(*EMITS.values()) | kernels == set(profile.STEP_SCOPES)
-    assert len(kernels) == 12
+    # the flash backward is one kernel, ``ddstore_flash_dkv``: no dq kernel
+    assert len(kernels) == 11 and "ddstore_flash_dq" not in kernels
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])

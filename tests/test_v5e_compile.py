@@ -1,7 +1,9 @@
 """Compiles, for a described TPU v5e and at the sizes the benchmark runs,
-what no interpret-mode test can refuse: the three flash kernels at head
-width 256 (VMEM), at the ring's call shapes on four chips and with
-grouped K/V heads, the latent-attention mixer with the copies XLA puts
+what no interpret-mode test can refuse: the two flash kernels (the
+forward, and the backward that holds a head's dq in VMEM) at head width
+256 (VMEM), at the ring's call shapes on four chips, with grouped K/V
+heads and at the largest call shapes the cells make, within the VMEM
+limit the backward sets, the latent-attention mixer with the copies XLA puts
 around its kernels, the gated short convolution's two kernels, the expert
 layer's grouped products (``ops/moe_gmm.py``'s kernels at the three
 configurations' widths) and its way back (``ops/moe_combine.py``'s kernel
@@ -69,9 +71,9 @@ def test_flash_kernels_at_width_256_fit_the_chip(one_chip, no_compile_cache,
 
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q) \
         .compile().as_text()
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv"):
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv"):
         assert kernel in text
+    assert "ddstore_flash_dq" not in text
 
 
 @pytest.mark.parametrize("causal,bh,s", [(True, 16, 16384),
@@ -93,9 +95,61 @@ def test_the_ring_calls_of_the_four_chip_cell_fit_the_chip(
 
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q) \
         .compile().as_text()
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv"):
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv"):
         assert kernel in text
+    assert "ddstore_flash_dq" not in text
+
+
+@pytest.mark.parametrize("shape,h_kv,layout,how", [
+    ((1, 16, 16384, 64), 16, "bhsd", dict(causal=True)),
+    ((1, 16384, 28, 128), 4, "bshd", dict(causal=True)),
+    ((1, 16384, 28, 128), 4, "bshd", dict(causal=True, window=4096)),
+    ((2, 8192, 20, 256), 20, "bshd", dict(causal=True)),
+    ((1, 16384, 32, 128), 4, "bshd", dict(mask=(4, 8192)))],
+    ids=["four-chip-causal-16384-d64", "smallthinker-full-16384-d128",
+         "smallthinker-window-16384-d128", "glm-8192-d256",
+         "sdar-masked-16384-d128"])
+def test_the_fused_backward_lowers_within_its_vmem_limit(
+        one_chip, no_compile_cache, shape, h_kv, layout, how):
+    """The largest flash calls the cells make, differentiated: the
+    backward is one kernel, ``ddstore_flash_dkv``, which holds the head's
+    float32 dq (S_q d 4 bytes: 4 MB at 16,384 x 64, 8 MB at 16,384 x 128
+    and at 8,192 x 256) and lowers within the VMEM limit it sets and the
+    counter records; no ``ddstore_flash_dq`` kernel is left."""
+    import re
+
+    from ddstore_tpu.ops.attention import BlockDiffusion, flash_attention
+    from ddstore_tpu.utils import profile
+
+    how = dict(how)
+    if "mask" in how:
+        how["mask"] = BlockDiffusion(*how["mask"])
+    heads = 2 if layout == "bshd" else 1
+    kv_shape = shape[:heads] + (h_kv,) + shape[heads + 1:]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        out, _ = flash_attention(q, k, v, layout=layout, interpret=False,
+                                 **how)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, kv, kv) \
+        .compile().as_text()
+    assert "ddstore_flash_dq" not in text
+    (call,) = _mosaic_calls(text, "ddstore_flash_dkv")
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          call).group(1))
+    (b, s, h), d = ((shape[0], shape[1], shape[2]) if layout == "bshd"
+                    else (shape[0], shape[2], shape[1])), shape[-1]
+    key = ("window4096" if "window" in how else "blockdiff4" if "mask" in how
+           else "causal") + f" bh{b * h} q{s}+0 k{s}+0 d{d} "
+    (geo,) = [g for c, g in profile.counters()["flash_geometry"][
+        "ddstore_flash_dkv"].items() if c.startswith(key)
+        and c.endswith(f"{layout} kv{b * h_kv}")]
+    assert geo["dq"] == "resident" and geo["dq_vmem_bytes"] == s * d * 4
+    assert limit == geo["vmem_limit"] \
+        == 32 * 2 ** 20 + geo["dq_vmem_bytes"] + 2 * s * d * 2
 
 
 def test_expert_layer_lowers_to_grouped_products(one_chip, no_compile_cache,
@@ -192,8 +246,8 @@ def test_the_way_back_kernel_fits_the_chip_at_the_cells_shapes(
 def test_gqa_kernels_lower_with_kv_at_their_own_heads(one_chip,
                                                       no_compile_cache):
     """The LFM2 cell's one attention call a step: 32 query heads on 8 K/V
-    heads of 64 at S=8192, b=4. K and V enter the three kernels as (b x 8,
-    S, 64): no operand repeated to 32 heads reaches them."""
+    heads of 64 at S=8192, b=4. K and V enter both kernels as (b x 8, S,
+    64): no operand repeated to 32 heads reaches them."""
     from ddstore_tpu.ops.attention import flash_attention
 
     q = jax.ShapeDtypeStruct((4, 32, 8192, 64), jnp.bfloat16,
@@ -211,7 +265,7 @@ def test_gqa_kernels_lower_with_kv_at_their_own_heads(one_chip,
              and any(k in ln.split(" = ")[0] for k in (
                  "ddstore_flash_fwd", "ddstore_flash_dq",
                  "ddstore_flash_dkv"))]
-    assert len(calls) == 3
+    assert len(calls) == 2
     for ln in calls:
         operands = ln.split("custom-call(")[1]
         assert operands.count("bf16[32,8192,64]") >= 2, ln   # k and v
@@ -274,16 +328,15 @@ def test_the_lfm2_cell_step_fits_the_chip(one_chip, no_compile_cache,
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 4.3e9 < total < 15e9, total
     text = compiled.as_text()
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv", "ddstore_short_conv_fwd",
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv",
+                   "ddstore_short_conv_fwd",
                    "ddstore_short_conv_bwd"):
         assert kernel in text, kernel
     assert "ragged-dot" not in text
     # by pass: the flash forward's output is saved by name, so only the
     # convolution's forward kernel runs again under nn.remat
     assert _kernel_passes(text) == {
-        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
-        "ddstore_flash_dkv": {"backward"},
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dkv": {"backward"},
         "ddstore_short_conv_fwd": {"forward", "recompute"},
         "ddstore_short_conv_bwd": {"backward"}, **_PRODUCTS_PASSES}
 
@@ -431,14 +484,12 @@ def test_the_nemotron_cell_step_fits_the_chip(one_chip, no_compile_cache,
     assert len(_mosaic_calls(text, "ddstore_ssd_fwd")) == 2 * 4
     assert len(_mosaic_calls(text, "ddstore_ssd_bwd")) == 4
     assert _kernel_passes(text) == {
-        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
-        "ddstore_flash_dkv": {"backward"},
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dkv": {"backward"},
         "ddstore_ssd_fwd": {"forward", "recompute"},
         "ddstore_ssd_bwd": {"backward"},
         "ddstore_conv_silu_fwd": {"forward", "recompute"},
         "ddstore_conv_silu_bwd": {"backward"}, **_PRODUCTS_PASSES}
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv"):
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv"):
         calls = _mosaic_calls(text, kernel)
         assert calls, kernel
         for ln in calls:
@@ -492,10 +543,8 @@ def test_the_sdar_cell_step_fits_the_chip(one_chip, no_compile_cache,
     assert "ragged-dot" not in text
     assert "bf16[16,2048,768]" in text and "bf16[16,768,2048]" in text
     assert _kernel_passes(text) == {
-        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
-        "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv"):
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv"):
         calls = _mosaic_calls(text, kernel)
         assert len(calls) == 6, kernel
         for ln in calls:
@@ -559,10 +608,8 @@ def test_the_smallthinker_cell_step_fits_the_chip(one_chip, no_compile_cache,
     assert "ragged-dot" not in text
     assert "bf16[16,2560,768]" in text and "bf16[16,768,2560]" in text
     assert _kernel_passes(text) == {
-        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dq": {"backward"},
-        "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
-    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dq",
-                   "ddstore_flash_dkv"):
+        "ddstore_flash_fwd": {"forward"}, "ddstore_flash_dkv": {"backward"}, **_PRODUCTS_PASSES}
+    for kernel in ("ddstore_flash_fwd", "ddstore_flash_dkv"):
         calls = _mosaic_calls(text, kernel)
         assert len(calls) == 4, kernel
         windowed = 0
@@ -586,7 +633,7 @@ def test_mla_mixer_hands_the_kernels_what_its_products_write(
         one_chip, no_compile_cache, monkeypatch, b, s, most):
     """One latent-attention mixer of ``glm47-flash-ep8`` at its published
     widths (20 heads of 192 | 64 and 256), forward and backward under the
-    cell's ``remat_policy``, at the two cells' shapes. The three kernels
+    cell's ``remat_policy``, at the two cells' shapes. Both kernels
     take q, k, v (and ``do``) as ``bf16[b,S,5120]``, sequence-major, and fit
     VMEM; and the module holds at most ``most`` transposing ``copy``
     instructions of a 20 x 256-wide bfloat16 tensor: q and k after their
@@ -637,7 +684,7 @@ def test_mla_mixer_hands_the_kernels_what_its_products_write(
     text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(
         *on_chip((params, x, pos))).compile().as_text()
     wide = f"bf16[{b},{s},5120]"
-    for kernel, operands in (("ddstore_flash_fwd", 3), ("ddstore_flash_dq", 4),
+    for kernel, operands in (("ddstore_flash_fwd", 3),
                              ("ddstore_flash_dkv", 4)):
         (call,) = [ln for ln in text.splitlines() if "custom-call(" in ln
                    and kernel in ln.split(" = ")[0]]
