@@ -25,7 +25,7 @@ natural order.
 from __future__ import annotations
 
 import math
-from typing import Any, Mapping, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -34,9 +34,8 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.attention import BlockDiffusion, flash_attention, mha_reference
-from ..ops.short_conv import gated_short_conv, short_conv
-from ..ops.ssd import SCAN as SSD_SCAN, ssd
+from ..ops import on_chip
+from ..ops.attention import flash_attention, mha_reference
 from ..parallel.pipeline import (interleave_order, pipeline_1f1b,
                                  pipeline_apply,
                                  pipeline_interleaved,
@@ -47,6 +46,10 @@ from ..parallel.tp import (expert_rules, megatron_rules, shard_pytree,
                            shardings_of)
 from ..utils import profile
 from ..utils.profile import phase
+# the described architectures' public names, importable from here too
+from .arch import (Arch, Lfm2MoeArch, MlaMoeArch, NemotronHArch,  # noqa: F401
+                   SdarMoeArch, SmallThinkerArch, lm_from_description)
+from .mixers import MIXERS, RMSNorm
 
 
 def ring_order(mesh: Optional[Mesh], sp_axis: str, *arrays, axis: int = 1,
@@ -108,7 +111,7 @@ class Block(nn.Module):
             if use_sp:
                 out, _ = ring_attention(q, k, v, mesh=self.mesh,
                                         axis=self.sp_axis, causal=True)
-            elif jax.default_backend() == "tpu":
+            elif on_chip():
                 # On the chip the kernel is the only path: a length it cannot
                 # tile raises in flash_attention (callers pad) rather than
                 # sliding to the S×S reference.
@@ -147,788 +150,20 @@ class Block(nn.Module):
         return x
 
 
-class MlaMoeArch(NamedTuple):
-    """A latent-attention (MLA), shared + routed expert, multi-token-
-    prediction decoder, by the keys of its published ``config.json`` (the
-    ``glm4_moe_lite`` / DeepSeek-V3 family). ``TransformerLM(arch=...)``
-    builds :class:`DecoderBlock` layers from it; ``vocab``, ``dim``,
-    ``heads`` and ``layers`` stay the model's own fields.
-    ``n_routed_experts`` is the router's width (the published count);
-    ``expert_share = (which, of)`` is this chip's share of them
-    (:class:`~.moe.SharedRoutedMoe`).
-
-    What the model's one path for described architectures reads of an
-    ``arch`` (this one, :class:`Lfm2MoeArch` or :class:`NemotronHArch`):
-    ``mixer(layer)``, the layer's mixer (a key of ``_MIXERS``) or None
-    where it has none; ``mlp(layer)``, its MLP (``"dense"``, or
-    ``"experts"``: such a layer returns a load vector) or None; and the
-    fields the three name alike."""
-
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
-    intermediate_size: int
-    moe_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    n_shared_experts: int = 1
-    routed_scaling_factor: float = 1.0
-    first_k_dense_replace: int = 1
-    rope_theta: float = 10000.0
-    rms_norm_eps: float = 1e-5
-    num_nextn_predict_layers: int = 0
-    mtp_loss_weight: float = 0.3
-    expert_share: Tuple[int, int] = (0, 1)
-    bias_update_speed: float = 0.0   # noaux_tc: what a step moves each
-    #                               expert's correction bias toward balance
-    #                               (update_router_bias); 0 leaves it alone
-    tie_word_embeddings: bool = False   # the head is the embedding's matrix
-    route_eps: float = 0.0           # route_noaux_tc's normaliser epsilon
-    expert_activation: str = "swiglu"   # SharedRoutedMoe's ``activation``
-    moe_shared_expert_intermediate_size: Optional[int] = None   # the shared
-    #                               expert's width where it is a key of its
-    #                               own; None: n_shared x the routed width
-
-    def mixer(self, layer: int) -> Optional[str]:
-        """The kind of layer ``layer``'s mixer: a key of ``_MIXERS``."""
-        return "mla"
-
-    def mlp(self, layer: int) -> Optional[str]:
-        """The kind of layer ``layer``'s MLP: ``dense`` or ``experts``."""
-        return "dense" if layer < self.first_k_dense_replace else "experts"
-
-
-class Lfm2MoeArch(NamedTuple):
-    """A gated-short-convolution / grouped-query-attention decoder with
-    bias-routed experts and no shared expert, by the keys of its published
-    ``config.json`` (``model_type`` ``lfm2_moe``): ``layer_types`` says a
-    layer which mixer it has (``conv`` or ``full_attention``), the first
-    ``first_k_dense_replace`` layers (the published ``num_dense_layers``)
-    have a dense SwiGLU MLP and the others ``num_experts_per_tok`` of
-    ``n_routed_experts`` routed experts (the router's width: the published
-    ``num_experts``), of which this chip holds ``expert_share``'s.
-    ``rms_norm_eps`` is the published ``norm_eps``. Heads are ``head_dim``
-    wide (None: ``dim / heads``), q and k RMS-normed a head (``qk_norm``)
-    and rotated (``rotary``). The fields :class:`MlaMoeArch` has too mean
-    what they mean there, ``mixer`` and ``mlp`` included."""
-
-    layer_types: Tuple[str, ...]
-    num_key_value_heads: int
-    conv_L_cache: int                # taps of the short convolution
-    intermediate_size: int
-    moe_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    first_k_dense_replace: int
-    routed_scaling_factor: float = 1.0
-    rope_theta: float = 1000000.0
-    rms_norm_eps: float = 1e-5
-    n_shared_experts: int = 0
-    route_eps: float = 1e-6          # Lfm2MoeSparseMoeBlock's normaliser
-    tie_word_embeddings: bool = True
-    num_nextn_predict_layers: int = 0
-    mtp_loss_weight: float = 0.0
-    expert_share: Tuple[int, int] = (0, 1)
-    bias_update_speed: float = 0.0
-    head_dim: Optional[int] = None
-    qk_norm: bool = True
-    rotary: bool = True
-    expert_activation: str = "swiglu"
-    moe_shared_expert_intermediate_size: Optional[int] = None
-
-    def mixer(self, layer: int) -> Optional[str]:
-        return self.layer_types[layer]
-
-    def mlp(self, layer: int) -> Optional[str]:
-        return "dense" if layer < self.first_k_dense_replace else "experts"
-
-
-class NemotronHArch(NamedTuple):
-    """A Mamba-2 / attention / expert hybrid every layer of which is one
-    branch alone, by the keys of its published ``config.json``
-    (``model_type`` ``nemotron_h``): ``pattern`` (the published
-    ``hybrid_override_pattern``) says a layer what it is: ``M`` a Mamba-2
-    mixer (``mamba_num_heads`` heads of ``mamba_head_dim``, state
-    ``ssm_state_size``, ``n_groups`` groups of B and C, ``conv_kernel``
-    biased taps, scanned in chunks of ``chunk_size``), ``*`` grouped-query
-    attention at ``head_dim`` with no rotary step and no q/k norm, ``E``
-    ``num_experts_per_tok`` of ``n_routed_experts`` ungated relu² experts
-    (the router's width; this chip holds ``expert_share``'s) beside a
-    shared expert ``moe_shared_expert_intermediate_size`` wide. No layer
-    has a second branch. ``rms_norm_eps`` is the published
-    ``layer_norm_epsilon``; ``time_step_*`` bound the step the Mamba
-    layers' ``dt_bias`` is drawn for. The fields :class:`MlaMoeArch` has too
-    mean what they mean there."""
-
-    pattern: str
-    num_key_value_heads: int
-    head_dim: int
-    mamba_num_heads: int
-    mamba_head_dim: int
-    ssm_state_size: int
-    n_groups: int
-    conv_kernel: int
-    chunk_size: int
-    moe_intermediate_size: int
-    moe_shared_expert_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    n_shared_experts: int = 1
-    routed_scaling_factor: float = 1.0
-    time_step_min: float = 0.001
-    time_step_max: float = 0.1
-    time_step_floor: float = 1e-4
-    rms_norm_eps: float = 1e-5
-    route_eps: float = 1e-20         # NemotronHTopkRouter's normaliser
-    tie_word_embeddings: bool = False
-    num_nextn_predict_layers: int = 0
-    mtp_loss_weight: float = 0.0
-    expert_share: Tuple[int, int] = (0, 1)
-    bias_update_speed: float = 0.0
-    qk_norm: bool = False
-    rotary: bool = False
-    rope_theta: float = 10000.0      # published, read by nothing
-    expert_activation: str = "relu2"
-
-    def mixer(self, layer: int) -> Optional[str]:
-        return {"M": "mamba2", "*": "full_attention"}.get(self.pattern[layer])
-
-    def mlp(self, layer: int) -> Optional[str]:
-        return "experts" if self.pattern[layer] == "E" else None
-
-
-class SdarMoeArch(NamedTuple):
-    """A grouped-query-attention decoder of softmax-routed experts trained
-    by block diffusion, by the keys of its published ``config.json``
-    (``model_type`` ``sdar_moe``; the layer is Qwen3-MoE's): every layer
-    ``heads`` query heads on ``num_key_value_heads`` K/V heads of
-    ``head_dim``, q and k RMS-normed a head and rotated, then
-    ``num_experts_per_tok`` of ``n_routed_experts`` SwiGLU experts (the
-    router's width: the published ``num_experts``; this chip holds
-    ``expert_share``'s) chosen by a softmax over all of them and
-    renormalised, no shared expert, no dense layer, an untied head.
-
-    The objective is the arch's (:func:`lm_loss`, :func:`diffusion_noise`):
-    a window is cut into blocks of ``block_length``, each block draws a
-    masking probability t uniform on ``[noise_low, 1]`` and every position
-    of it is replaced by ``mask_token`` with that probability (``None``:
-    the vocabulary's last id); the trunk runs on ``[noised ; clean]``, both
-    halves at the window's positions, under the block-diffusion mask
-    (:class:`~ddstore_tpu.ops.attention.BlockDiffusion`), and the loss is
-    the cross-entropy of the masked positions against the window's own
-    tokens, weighted 1 / t, over all the window's positions. The draw of
-    step n is keyed by ``(noise_seed, n)``. ``block_length = 0`` is the
-    same trunk as a causal next-token model.
-
-    The fields are what a description sets. What the shared layers read of
-    an arch besides (:class:`Lfm2MoeArch`'s fields of those names) is the
-    same in every model of this type: constants of the class, which a
-    description that gives another value is refused for."""
-
-    num_key_value_heads: int
-    head_dim: int
-    moe_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    block_length: int
-    noise_low: float
-    mask_token: Optional[int] = None
-    noise_seed: int = 0
-    rope_theta: float = 1000000.0
-    rms_norm_eps: float = 1e-6
-    expert_share: Tuple[int, int] = (0, 1)
-
-    router_scoring = "softmax"
-    n_shared_experts = 0
-    moe_shared_expert_intermediate_size = None
-    expert_activation = "swiglu"
-    routed_scaling_factor = 1.0      # unread by softmax routing
-    route_eps = 0.0                  # unread by softmax routing
-    bias_update_speed = 0.0          # the router has no bias
-    tie_word_embeddings = False
-    num_nextn_predict_layers = 0
-    mtp_loss_weight = 0.0
-    qk_norm = True
-    rotary = True
-
-    def mixer(self, layer: int) -> Optional[str]:
-        return "full_attention"
-
-    def mlp(self, layer: int) -> Optional[str]:
-        return "experts"
-
-
-class SmallThinkerArch(NamedTuple):
-    """A decoder of grouped-query attention, window and full mixed, over
-    softmax-routed ReGLU experts whose router reads the layer's input before
-    attention, by the keys of its published ``config.json`` (PowerInfer
-    SmallThinker; the catalog's row gives no ``model_type``): every layer
-    ``heads`` query heads on ``num_key_value_heads`` K/V heads of
-    ``head_dim``, no q/k norm; layer i rotates q and k on the whole head
-    width where ``rope_layout[i]`` is 1 (no position step at all where it is
-    0: NoPE) and sees only the ``sliding_window`` keys up to its own where
-    ``sliding_window_layout[i]`` is 1 (causal where it is 0). Then
-    ``num_experts_per_tok`` of ``n_routed_experts`` experts ``down(relu(gate
-    h) * up h)`` (the router's width: the published
-    ``moe_num_primary_experts``; this chip holds ``expert_share``'s) chosen
-    by a softmax over the router's logits and renormalised, the router
-    reading ``ln1(x)``, the normed input of attention, and the experts
-    ``ln2(x + attn)``. No shared expert, no dense layer, an untied head;
-    next-token cross-entropy.
-
-    The fields are what a description sets; the class's constants are the
-    same in every model of this type, and a description that gives another
-    value is refused, as :class:`SdarMoeArch`'s."""
-
-    num_key_value_heads: int
-    head_dim: int
-    moe_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    rope_layout: Tuple[int, ...]
-    sliding_window_layout: Tuple[int, ...]
-    sliding_window: int
-    rope_theta: float = 1500000.0
-    rms_norm_eps: float = 1e-6
-    expert_share: Tuple[int, int] = (0, 1)
-
-    router_scoring = "softmax"
-    router_input = "ln1"             # the router reads attention's input
-    n_shared_experts = 0
-    moe_shared_expert_intermediate_size = None
-    expert_activation = "reglu"
-    routed_scaling_factor = 1.0      # unread by softmax routing
-    route_eps = 0.0                  # unread by softmax routing
-    bias_update_speed = 0.0          # the router has no bias
-    tie_word_embeddings = False
-    num_nextn_predict_layers = 0
-    mtp_loss_weight = 0.0
-    qk_norm = False
-
-    def mixer(self, layer: int) -> Optional[str]:
-        return "sliding_attention" if self.sliding_window_layout[layer] \
-            else "full_attention"
-
-    def mlp(self, layer: int) -> Optional[str]:
-        return "experts"
-
-    def attention(self, layer: int) -> Tuple[bool, Optional[int]]:
-        """``(rotary, window)`` of layer ``layer``'s attention: whether it
-        rotates q and k, and its window (None: causal)."""
-        return (bool(self.rope_layout[layer]),
-                self.sliding_window if self.sliding_window_layout[layer]
-                else None)
-
-
-# What a ``smallthinker`` description's published keys set of
-# :class:`SmallThinkerArch`, by field, and the class's constants.
-_SMALLTHINKER_KEYS = {
-    "num_key_value_heads": "num_key_value_heads", "head_dim": "head_dim",
-    "moe_intermediate_size": "moe_ffn_hidden_size",
-    "num_experts_per_tok": "moe_num_active_primary_experts",
-    "sliding_window": "sliding_window_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_norm_eps"}
-_SMALLTHINKER_FIXED = ("router_scoring", "router_input", "n_shared_experts",
-                       "moe_shared_expert_intermediate_size",
-                       "expert_activation", "routed_scaling_factor",
-                       "route_eps", "bias_update_speed",
-                       "tie_word_embeddings", "num_nextn_predict_layers",
-                       "mtp_loss_weight", "qk_norm")
-
-
-# What a ``sdar_moe`` description sets of :class:`SdarMoeArch` under the
-# field's own name (the published keys, then the objective's), and the
-# class's constants, which it may repeat and not change.
-_SDAR_KEYS = ("num_key_value_heads", "head_dim", "moe_intermediate_size",
-              "num_experts_per_tok", "rope_theta", "rms_norm_eps",
-              "block_length", "noise_low", "mask_token", "noise_seed")
-_SDAR_FIXED = ("router_scoring", "n_shared_experts",
-               "moe_shared_expert_intermediate_size", "expert_activation",
-               "routed_scaling_factor", "route_eps", "bias_update_speed",
-               "tie_word_embeddings", "num_nextn_predict_layers",
-               "mtp_loss_weight", "qk_norm", "rotary")
-
-
-def _refuse_unless(desc: Mapping[str, Any], built) -> None:
-    """Raises for a key of ``desc`` whose value is not the one built here
-    (``built``: pairs of key and that value; an absent key passes)."""
-    for key, want in built:
-        if desc.get(key, want) != want:
-            raise ValueError(f"{key}={desc[key]!r} is not built here "
-                             f"(only {key}={want!r})")
-
-
-def _mla_arch(desc: Mapping[str, Any]) -> MlaMoeArch:
-    _refuse_unless(desc, (
-        ("hidden_act", "silu"), ("topk_method", "noaux_tc"), ("n_group", 1),
-        ("topk_group", 1), ("norm_topk_prob", True),
-        ("attention_bias", False), ("rope_scaling", None),
-        ("partial_rotary_factor", 1),
-        ("num_key_value_heads", desc["num_attention_heads"])))
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    fields = {k: desc[k] for k in MlaMoeArch._fields if k in desc}
-    fields["n_routed_experts"] = int(desc["n_routed_experts"]) \
-        * int(ep["chips"])
-    fields["expert_share"] = (int(ep["chip"]), int(ep["chips"]))
-    return MlaMoeArch(**fields)
-
-
-def _lfm2_arch(desc: Mapping[str, Any]) -> Lfm2MoeArch:
-    _refuse_unless(desc, (
-        ("conv_bias", False), ("norm_topk_prob", True),
-        ("use_expert_bias", True), ("rope_scaling", None),
-        ("tie_embedding", True)))
-    kinds = tuple(desc["layer_types"])
-    if len(kinds) != int(desc["num_hidden_layers"]):
-        raise ValueError(f"layer_types has {len(kinds)} entries for "
-                         f"num_hidden_layers={desc['num_hidden_layers']}")
-    for kind in kinds:
-        if kind not in ("conv", "full_attention"):
-            raise ValueError(f"layer_types entry {kind!r} is not built "
-                             f"here (only 'conv' and 'full_attention')")
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    rope = desc.get("rope_parameters", desc)
-    fields = {k: desc[k] for k in Lfm2MoeArch._fields if k in desc}
-    fields.update(
-        layer_types=kinds,
-        n_routed_experts=int(desc["num_experts"]) * int(ep["chips"]),
-        first_k_dense_replace=int(desc["num_dense_layers"]),
-        rope_theta=float(rope["rope_theta"]),
-        rms_norm_eps=float(desc["norm_eps"]), tie_word_embeddings=True,
-        expert_share=(int(ep["chip"]), int(ep["chips"])))
-    return Lfm2MoeArch(**fields)
-
-
-def _nemotron_arch(desc: Mapping[str, Any]) -> NemotronHArch:
-    _refuse_unless(desc, (
-        ("mamba_proj_bias", False), ("use_bias", False), ("mlp_bias", False),
-        ("attention_bias", False), ("use_conv_bias", True), ("n_group", 1),
-        ("topk_group", 1), ("norm_topk_prob", True),
-        ("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")))
-    pattern = str(desc["hybrid_override_pattern"])
-    if len(pattern) != int(desc["num_hidden_layers"]):
-        raise ValueError(f"hybrid_override_pattern has {len(pattern)} "
-                         f"layers for num_hidden_layers="
-                         f"{desc['num_hidden_layers']}")
-    for kind in pattern:
-        if kind not in "ME*":
-            raise ValueError(
-                f"hybrid_override_pattern layer {kind!r} is not built here "
-                f"(only 'M', 'E' and '*'; '-' is a dense MLP layer)")
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    fields = {k: desc[k] for k in NemotronHArch._fields if k in desc}
-    fields.update(
-        pattern=pattern,
-        n_routed_experts=int(desc["n_routed_experts"]) * int(ep["chips"]),
-        rms_norm_eps=float(desc["layer_norm_epsilon"]),
-        expert_share=(int(ep["chip"]), int(ep["chips"])))
-    return NemotronHArch(**fields)
-
-
-def _sdar_arch(desc: Mapping[str, Any]) -> SdarMoeArch:
-    _refuse_unless(desc, (
-        ("use_sliding_window", False), ("rope_scaling", None),
-        ("attention_bias", False), ("norm_topk_prob", True),
-        ("mlp_only_layers", []), ("decoder_sparse_step", 1),
-        ("hidden_act", "silu"),
-        *((k, getattr(SdarMoeArch, k)) for k in _SDAR_FIXED)))
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    return SdarMoeArch(
-        **{k: desc[k] for k in _SDAR_KEYS if k in desc},
-        n_routed_experts=int(desc["num_experts"]) * int(ep["chips"]),
-        expert_share=(int(ep["chip"]), int(ep["chips"])))
-
-
-def _smallthinker_arch(desc: Mapping[str, Any]) -> SmallThinkerArch:
-    _refuse_unless(desc, (
-        ("rope_scaling", None), ("attention_bias", False),
-        ("norm_topk_prob", True), ("moe_primary_router_apply_softmax", True),
-        ("moe_enable_early_router", True),
-        ("moe_enable_secondary_experts", False),
-        *((k, getattr(SmallThinkerArch, k)) for k in _SMALLTHINKER_FIXED)))
-    layers = int(desc["num_hidden_layers"])
-    layouts = {}
-    for key in ("rope_layout", "sliding_window_layout"):
-        layouts[key] = tuple(int(x) for x in desc[key])
-        if len(layouts[key]) != layers or not set(layouts[key]) <= {0, 1}:
-            raise ValueError(f"{key} {list(desc[key])}: one 0 or 1 for each "
-                             f"of num_hidden_layers={layers}")
-    ep = desc.get("expert_parallel", {"chips": 1, "chip": 0})
-    return SmallThinkerArch(
-        **{field: desc[key] for field, key in _SMALLTHINKER_KEYS.items()
-           if key in desc}, **layouts,
-        n_routed_experts=int(desc["moe_num_primary_experts"])
-        * int(ep["chips"]),
-        expert_share=(int(ep["chip"]), int(ep["chips"])))
-
-
-def lm_from_description(desc: Mapping[str, Any], **kw) -> "TransformerLM":
-    """A :class:`TransformerLM` from one description of the architecture:
-    the dense block's own keys (``vocab``, ``dim``, ``heads``, ``layers``,
-    ``mlp_ratio``; ``experts`` / ``moe_top_k`` for the capacity-bound
-    ``MoeMlp``), or the keys of a published model's ``config.json``: a
-    latent-attention expert model (``q_lora_rank`` present;
-    :class:`MlaMoeArch`), a short-convolution / grouped-query expert
-    model (``model_type`` ``lfm2_moe``, or ``layer_types`` beside
-    ``conv_L_cache``; :class:`Lfm2MoeArch`) or a Mamba-2 / attention /
-    expert hybrid of one-branch layers (``model_type`` ``nemotron_h``;
-    :class:`NemotronHArch`, its layers by ``hybrid_override_pattern``) or a
-    block-diffusion model of softmax-routed experts (``model_type``
-    ``sdar_moe``; :class:`SdarMoeArch`: the published keys, and the
-    objective's ``block_length``, ``noise_low``, ``mask_token``,
-    ``noise_seed``, and no other field of the arch; refused:
-    ``use_sliding_window`` true, a ``rope_scaling``, ``attention_bias``
-    true, ``norm_topk_prob`` false, a non-empty ``mlp_only_layers``, a
-    ``decoder_sparse_step`` other than 1, ``tie_word_embeddings`` true, and
-    any other value than the class's for what every model of the type has
-    alike, ``router_scoring`` or ``n_shared_experts`` say) or a decoder of
-    window and full attention over early-routed ReGLU experts
-    (``model_type`` ``smallthinker``, or ``moe_num_primary_experts`` beside
-    ``sliding_window_layout``; :class:`SmallThinkerArch`: its layers by
-    ``rope_layout`` and ``sliding_window_layout``; refused: a
-    ``rope_scaling``, ``attention_bias`` true, ``norm_topk_prob`` or
-    ``moe_primary_router_apply_softmax`` false, an early router off,
-    secondary experts on, and another value for a constant of the class).
-    In all five, the key that counts the routed experts (``n_routed_experts``
-    / ``num_experts`` / ``moe_num_primary_experts``) counts the experts held
-    here, of ``expert_parallel =
-    {"chips": n, "chip": i}`` chips that share each layer, and the router
-    is ``chips`` times as wide. What a description asks for and is not
-    built raises, naming the key and the value that is. ``kw`` are further
-    ``TransformerLM`` fields (``compute_dtype``, ``mesh``, ``remat``...)."""
-    if desc.get("model_type") == "smallthinker" or (
-            "moe_num_primary_experts" in desc
-            and "sliding_window_layout" in desc):
-        arch = _smallthinker_arch(desc)
-    elif desc.get("model_type") == "sdar_moe":
-        arch = _sdar_arch(desc)
-    elif desc.get("model_type") == "nemotron_h":
-        arch = _nemotron_arch(desc)
-    elif desc.get("model_type") == "lfm2_moe" or (
-            "layer_types" in desc and "conv_L_cache" in desc):
-        arch = _lfm2_arch(desc)
-    elif "q_lora_rank" in desc:
-        arch = _mla_arch(desc)
-    else:
-        return TransformerLM(
-            vocab=int(desc["vocab"]), dim=int(desc["dim"]),
-            heads=int(desc["heads"]), layers=int(desc["layers"]),
-            mlp_ratio=int(desc.get("mlp_ratio", 4)),
-            n_experts=int(desc.get("experts", 0)),
-            moe_top_k=int(desc.get("moe_top_k", 1)), **kw)
-    if "remat_policy" in desc and "remat_policy" not in kw:
-        kw = dict(kw, remat=True, remat_policy=desc["remat_policy"])
-    return TransformerLM(
-        vocab=int(desc["vocab_size"]), dim=int(desc["hidden_size"]),
-        heads=int(desc["num_attention_heads"]),
-        layers=int(desc["num_hidden_layers"]), arch=arch, **kw)
-
-
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * scale`` in float32."""
-
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        x = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * scale
-
-
-def rope(x, positions, theta: float):
-    """Rotary positions on all of ``x``'s last dimension, ``x`` (B, S, H,
-    D), ``positions`` (B, S) global indices. Pairs are the two halves
-    (dimension i with i + D/2, the Hugging Face ``rotate_half`` layout); a
-    checkpoint that pairs neighbours differs by a fixed permutation of the
-    projection's columns."""
-    half = x.shape[-1] // 2
-    inv = jnp.exp(-math.log(theta) * jnp.arange(half) / half)
-    ang = positions[..., None, None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _attend(q, k, v, mask=None, window=None):
-    """Causal attention (under ``mask``, a
-    :class:`~ddstore_tpu.ops.attention.BlockDiffusion`: that mask's; with
-    ``window``, a sliding window of that many keys) over
-    (B, S, H, D) heads as the projections write them, K and V perhaps fewer heads than Q (grouped-query): ``(out,
-    layout)``, ``out`` (B, S, H, D) and the layout the kernels took their
-    operands in. Heads of whole lanes go as they lie (``bshd``: nothing is
-    transposed on the way in, out or back); a narrower head is no block of
-    (B, S, H D), so those are transposed and go head-major (``bhsd``). On
-    the chip the kernel is the only path, as in :class:`Block`: a length it
-    cannot tile raises in ``flash_attention`` rather than sliding to the S
-    x S reference, which is what runs elsewhere (``reference``)."""
-    on_chip = jax.default_backend() == "tpu"
-    how = {"causal": True} if mask is None else {"mask": mask}
-    if window is not None:
-        how["window"] = window
-    if on_chip and q.shape[-1] % 128 == 0:
-        return flash_attention(q, k, v, layout="bshd", **how)[0], "bshd"
-    attend = flash_attention if on_chip else mha_reference
-    out = attend(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), **how)[0]
-    return out.transpose(0, 2, 1, 3), "bhsd" if on_chip else "reference"
-
-
-class _LatentKV(nn.Module):
-    """``kv_b`` of latent attention: one ``(kv_lora_rank, H (nope + v))``
-    kernel in the checkpoint's column order (a head's key columns, then its
-    value columns), applied as two products: ``k_nope`` from the key
-    columns and V from its own. The columns are cut on the weight's side,
-    so V is written where attention reads it and is never a slice of a
-    448-wide activation (nor its gradient half of a concatenation). The
-    leaf, its initialisation and its name are ``nn.Dense``'s."""
-
-    heads: int
-    nope: int
-    v_dim: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, ckv):
-        nh, nope, vd = self.heads, self.nope, self.v_dim
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (ckv.shape[-1], nh * (nope + vd)))
-        w = kernel.astype(self.dtype).reshape(-1, nh, nope + vd)
-        return (ckv @ w[..., :nope].reshape(-1, nh * nope),
-                ckv @ w[..., nope:].reshape(-1, nh * vd))
-
-
-def _mla_mixer(blk: "DecoderBlock", x, positions):
-    """Multi-head latent attention computed uncompressed (training: per
-    head q = [q_nope | q_rope], k = [k_nope | k_rope], the rotary key one
-    vector a position shared by all heads), norm to output projection.
-    Heads stay where the projections write them, (B, S, H, D), from the
-    products through attention to ``proj``."""
-    b, s, _ = x.shape
-    a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
-    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
-    norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
-    nope, rot, vd = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
-    if vd != nope + rot:
-        raise NotImplementedError(
-            f"v_head_dim={vd} beside a query/key width of {nope + rot}: "
-            f"the flash kernels give K and V one width")
-    h = norm("ln1")(x).astype(dt)
-    with jax.named_scope("mix_in"):
-        cq = lin(a.q_lora_rank, "q_a")(h)
-        with jax.named_scope("mix_norm"):
-            cq = norm("q_norm")(cq).astype(dt)
-        q = lin(nh * (nope + rot), "q_b")(cq).reshape(b, s, nh, nope + rot)
-        kva = lin(a.kv_lora_rank + rot, "kv_a")(h)
-        with jax.named_scope("mix_norm"):
-            ckv = norm("kv_norm")(kva[..., :a.kv_lora_rank]).astype(dt)
-        k_nope, v = _LatentKV(nh, nope, vd, dt, name="kv_b")(ckv)
-    k_rope = rope(kva[..., None, a.kv_lora_rank:], positions, a.rope_theta)
-    q = jnp.concatenate(
-        [q[..., :nope], rope(q[..., nope:], positions, a.rope_theta)],
-        axis=-1)
-    k = jnp.concatenate(
-        [k_nope.reshape(b, s, nh, nope),
-         jnp.broadcast_to(k_rope, (b, s, nh, rot))], axis=-1)
-    out, layout = _attend(q, k, v.reshape(b, s, nh, vd))
-    profile.count_mixer_layout("/".join(blk.path), kind="mla", heads=nh,
-                               kv_heads=nh, tokens=b * s, layout=layout)
-    with jax.named_scope("mix_out"):
-        return lin(blk.dim, "proj")(out.reshape(b, s, nh * vd).astype(dt))
-
-
-def _layer_attention(a, blk):
-    """``(rotary, window)`` of block ``blk``'s attention: the arch's own
-    for the block's layer where it has a pattern of attention kinds
-    (``attention``), else its one ``rotary`` and no window."""
-    per_layer = getattr(a, "attention", None)
-    return per_layer(blk.layer) if per_layer else (a.rotary, None)
-
-
-def _gqa_mixer(blk: "DecoderBlock", x, positions, normed=None):
-    """Grouped-query attention (``Lfm2MoeAttention``,
-    ``NemotronHAttention``): ``heads`` query heads over
-    ``num_key_value_heads`` K/V heads of the arch's ``head_dim`` (``dim /
-    heads`` where it gives none); where the arch asks, q and k RMS-normed a
-    head (``qk_norm``: one learned scale of the head's width each) and
-    rotated on the whole width (``rotary``, or a layer's own where the arch
-    has a pattern of attention kinds, which also gives the layer's sliding
-    window: such a layer's kernels run under the scope ``window``); K and V
-    go to the kernels as they are. ``normed``: ``ln1(x)`` where the block
-    has it already."""
-    b, s, _ = x.shape
-    a, dt, nh = blk.arch, blk.compute_dtype, blk.heads
-    nkv, hd = a.num_key_value_heads, a.head_dim or blk.dim // blk.heads
-    norm = lambda name: RMSNorm(a.rms_norm_eps, name=name)
-    h = norm("ln1")(x).astype(dt) if normed is None else normed
-    rotary, window = _layer_attention(a, blk)
-    # [W_q | W_k | W_v] as one product
-    with jax.named_scope("mix_in"):
-        qkv = nn.Dense((nh + 2 * nkv) * hd, use_bias=False, dtype=dt,
-                       name="qkv")(h).reshape(b, s, nh + 2 * nkv, hd)
-    q, k, v = jnp.split(qkv, (nh, nh + nkv), axis=2)
-
-    def prepared(t, name):
-        if a.qk_norm:
-            with jax.named_scope("mix_norm"):
-                t = norm(name)(t)
-        if rotary:
-            t = rope(t, positions, a.rope_theta)
-        return t.astype(dt)
-
-    q, k = prepared(q, "q_norm"), prepared(k, "k_norm")
-    # a block-diffusion arch's sequence is [noised ; clean]
-    blocks = getattr(a, "block_length", 0)
-    mask = BlockDiffusion(blocks, s // 2) if blocks else None
-    extra = {"mask": f"block_diffusion {blocks}"} if blocks else {}
-    if window is None:
-        out, layout = _attend(q, k, v, mask)
-    else:
-        # what the kernels are handed, for whoever asks with
-        # mutable=["intermediates"] (the benchmark's check of the window's
-        # statistics); nothing otherwise
-        blk.sow("intermediates", "window_qk", (q, k, v))
-        with jax.named_scope("window"):
-            out, layout = _attend(q, k, v, window=window)
-    if hasattr(a, "attention"):
-        extra.update(window=window, rotary=rotary)
-    profile.count_mixer_layout(
-        "/".join(blk.path), kind="full_attention", heads=nh, kv_heads=nkv,
-        head_dim=hd, tokens=b * s, layout=layout, **extra)
-    out = out.reshape(b, s, nh * hd).astype(dt)
-    with jax.named_scope("mix_out"):
-        return nn.Dense(blk.dim, use_bias=False, dtype=dt,
-                        name="proj")(out)
-
-
-def _conv_mixer(blk: "DecoderBlock", x, positions):
-    """Gated short convolution (``Lfm2ShortConv``): ``[Bg | Cg | u] = W_in
-    h``, a causal depthwise convolution of ``conv_L_cache`` taps over ``Bg
-    * u``, gated by ``Cg``, then ``W_out``
-    (:func:`~ddstore_tpu.ops.short_conv.gated_short_conv`). ``conv_taps``
-    is (taps, dim), the last row the current position's: the checkpoint's
-    (dim, 1, taps) weight transposed."""
-    a, dt = blk.arch, blk.compute_dtype
-    b, s, _ = x.shape
-    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
-    profile.count_mixer_layout("/".join(blk.path), kind="conv",
-                               taps=a.conv_L_cache, tokens=b * s)
-    with jax.named_scope("conv_mixer"):
-        h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
-        taps = blk.param(
-            "conv_taps", nn.initializers.variance_scaling(
-                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
-            (a.conv_L_cache, blk.dim))
-        with jax.named_scope("mix_in"):
-            bcu = lin(3 * blk.dim, "in_proj")(h)
-        y = gated_short_conv(bcu, taps)
-        with jax.named_scope("mix_out"):
-            return lin(blk.dim, "out_proj")(y)
-
-
-class _GatedGroupNorm(nn.Module):
-    """``MambaRMSNormGated``, gate before norm: ``y * silu(z)``, RMS-normed
-    over each of ``groups`` equal groups of channels, times one learned
-    scale of all the channels; float32."""
-
-    groups: int
-    eps: float
-
-    @nn.compact
-    def __call__(self, y, z):
-        f32 = jnp.float32
-        y = y.astype(f32) * nn.silu(z.astype(f32))
-        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
-        grouped = y.reshape(y.shape[:-1] + (self.groups, -1))
-        grouped = grouped * jax.lax.rsqrt(
-            jnp.mean(grouped * grouped, axis=-1, keepdims=True) + self.eps)
-        return grouped.reshape(y.shape) * scale
-
-
-def _dt_bias_init(a: NemotronHArch):
-    """``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform between
-    the arch's ``time_step_min`` and ``time_step_max``, floored at
-    ``time_step_floor`` (Mamba-2's initialisation)."""
-    def init(key, shape, dtype=jnp.float32):
-        lo, hi = math.log(a.time_step_min), math.log(a.time_step_max)
-        step = jnp.maximum(jnp.exp(jax.random.uniform(
-            key, shape, dtype, lo, hi)), a.time_step_floor)
-        return step + jnp.log(-jnp.expm1(-step))   # softplus's inverse
-    return init
-
-
-def _mamba2_mixer(blk: "DecoderBlock", x, positions):
-    """Mamba-2 (``NemotronHMamba2Mixer``): ``[z | xBC | dt] = W_in h``;
-    ``xBC`` through a causal depthwise convolution of ``conv_kernel``
-    biased taps and silu (:func:`~ddstore_tpu.ops.short_conv.short_conv`;
-    ``conv_taps`` (taps, channels), the last row the current position's),
-    then ``[x | B | C]``; the state-space scan over heads of
-    ``mamba_head_dim`` with ``dt = softplus(dt + dt_bias)`` and ``A =
-    -exp(A_log)`` (:func:`~ddstore_tpu.ops.ssd.ssd`); the gated group norm
-    and ``W_out``. No position enters: the scan gives order."""
-    a, dt = blk.arch, blk.compute_dtype
-    b, s, _ = x.shape
-    nh, hp, g, n = (a.mamba_num_heads, a.mamba_head_dim, a.n_groups,
-                    a.ssm_state_size)
-    inner, conv = nh * hp, nh * hp + 2 * g * n
-    lin = lambda n, name: nn.Dense(n, use_bias=False, dtype=dt, name=name)
-    profile.count_mixer_layout(
-        "/".join(blk.path), kind="mamba2", heads=nh, head_dim=hp, state=n,
-        groups=g, chunk=a.chunk_size, taps=a.conv_kernel, tokens=b * s,
-        scan=SSD_SCAN)
-    with jax.named_scope("mamba_mixer"):
-        h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
-        with jax.named_scope("mix_in"):
-            z, xbc, step = jnp.split(lin(inner + conv + nh, "in_proj")(h),
-                                     (inner, inner + conv), axis=-1)
-        taps = blk.param(
-            "conv_taps", nn.initializers.variance_scaling(
-                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
-            (a.conv_kernel, conv))
-        bias = blk.param("conv_bias", nn.initializers.zeros, (conv,))
-        with jax.named_scope("mamba_conv"):
-            xbc = short_conv(xbc, taps, bias)
-        xs, B, C = jnp.split(xbc, (inner, inner + g * n), axis=-1)
-        step = nn.softplus(step.astype(jnp.float32) + blk.param(
-            "dt_bias", _dt_bias_init(a), (nh,)))
-        A = -jnp.exp(blk.param(
-            "A_log", lambda key, shape: jnp.log(jax.random.uniform(
-                key, shape, jnp.float32, 1.0, 16.0)), (nh,)))
-        D = blk.param("D", nn.initializers.ones, (nh,))
-        y = ssd(xs.reshape(b, s, nh, hp), step, A, B.reshape(b, s, g, n),
-                C.reshape(b, s, g, n), D, a.chunk_size)
-        with jax.named_scope("mix_norm"):
-            y = _GatedGroupNorm(g, a.rms_norm_eps, name="norm")(
-                y.reshape(b, s, inner), z)
-        with jax.named_scope("mix_out"):
-            return lin(blk.dim, "out_proj")(y.astype(dt))
-
-
-# A described layer's mixer, by the name ``arch.mixer(layer)`` gives it:
-# (block, x, positions) -> what the mixer adds to x, under the block's own
-# scope (its submodules are the block's).
-_MIXERS = {"mla": _mla_mixer, "full_attention": _gqa_mixer,
-           "sliding_attention": _gqa_mixer, "conv": _conv_mixer,
-           "mamba2": _mamba2_mixer}
-
-
 class DecoderBlock(nn.Module):
-    """Pre-RMSNorm decoder layer of a described architecture
-    (:class:`MlaMoeArch`, :class:`Lfm2MoeArch`, :class:`NemotronHArch`,
-    :class:`SdarMoeArch`, :class:`SmallThinkerArch`), built of what the arch says the layer has: ``x + mixer(norm(x))`` with
-    the mixer ``mixer`` names (``_MIXERS``: latent attention, grouped-query
-    attention, gated short convolution, Mamba-2; None: no mixer and no
-    ``ln1``), then ``x + mlp(norm(x))`` with a SwiGLU MLP (``mlp`` =
-    ``"dense"``) or the shared + routed experts (``"experts"``; None: no
-    MLP and no ``ln2``). No biases. Returns ``x``, and an expert layer's
-    load vector beside it. Where the arch's router reads the layer's input
-    (``router_input`` ``ln1``), the layer's one ``router`` leaf is the
-    block's own: its logits are taken from ``ln1(x)`` before attention runs
-    and handed to the experts, which run on ``ln2(x + attn)``. ``layer``:
-    the layer's index, for an arch whose attention differs a layer."""
+    """Pre-RMSNorm decoder layer of a described architecture (an
+    :class:`~.arch.Arch`), built of what the arch says the layer has: ``x +
+    mixer(norm(x))`` with the mixer ``mixer`` names (``mixers.MIXERS``:
+    latent attention, grouped-query attention, gated short convolution,
+    Mamba-2; None: no mixer and no ``ln1``), then ``x + mlp(norm(x))``
+    with a SwiGLU MLP (``mlp`` = ``"dense"``) or the shared + routed
+    experts (``"experts"``; None: no MLP and no ``ln2``). No biases.
+    Returns ``x``, and an expert layer's load vector beside it. Where the
+    arch's router reads the layer's input (``router_input`` ``ln1``), the
+    layer's one ``router`` leaf is the block's own: its logits are taken
+    from ``ln1(x)`` before attention runs and handed to the experts, which
+    run on ``ln2(x + attn)``. ``layer``: the layer's index, for an arch
+    whose attention differs a layer."""
 
     dim: int
     heads: int
@@ -946,7 +181,7 @@ class DecoderBlock(nn.Module):
                                        name=name)
 
         logits = None
-        if getattr(a, "router_input", "ln2") == "ln1":
+        if a.router_input == "ln1":
             with jax.named_scope("attn"):
                 h = RMSNorm(a.rms_norm_eps, name="ln1")(x).astype(dt)
             with jax.named_scope("mlp"), jax.named_scope("moe_dispatch"):
@@ -954,10 +189,10 @@ class DecoderBlock(nn.Module):
                                   dtype=jnp.float32, name="router")(
                     h.reshape(b * s, self.dim).astype(jnp.float32))
             with jax.named_scope("attn"):
-                x = x + _MIXERS[self.mixer](self, x, positions, h)
+                x = x + MIXERS[self.mixer](self, x, positions, h)
         elif self.mixer is not None:
             with jax.named_scope("attn"):
-                x = x + _MIXERS[self.mixer](self, x, positions)
+                x = x + MIXERS[self.mixer](self, x, positions)
         if self.mlp is None:
             return x
 
@@ -977,7 +212,7 @@ class DecoderBlock(nn.Module):
                 n_shared=a.n_shared_experts, route_eps=a.route_eps,
                 compute_dtype=dt, activation=a.expert_activation,
                 shared_hidden=a.moe_shared_expert_intermediate_size,
-                scoring=getattr(a, "router_scoring", "sigmoid"),
+                scoring=a.router_scoring,
                 name="moe")(h.reshape(b * s, self.dim), logits)
             return x + y.reshape(b, s, self.dim), load
 
@@ -1127,9 +362,7 @@ class TransformerLM(nn.Module):
     #                               most of full remat's memory win at a
     #                               fraction of its recompute cost), or
     #                               "names:flash_out,flash_lse" (_remat_policy)
-    arch: Optional[Any] = None    # a described architecture (MlaMoeArch,
-    #                               Lfm2MoeArch, NemotronHArch, SdarMoeArch,
-    #                               SmallThinkerArch): DecoderBlock
+    arch: Optional[Arch] = None   # a described architecture: DecoderBlock
     #                               layers (RMSNorm, the mixer arch.mixer(i)
     #                               and the MLP arch.mlp(i) name, MTP)
     #                               instead of Block
@@ -1324,10 +557,10 @@ def lm_loss(model: "TransformerLM", params, tokens, targets, positions, *,
                     "accumulation); pass fused_xent=False for the f32 "
                     "Dense head", model.vocab, 2 * xent_block,
                     jnp.dtype(model.compute_dtype).name)
-    if getattr(model.arch, "block_length", 0):
-        return _diffusion_loss(model, params, tokens, positions, noise_key,
-                               fused_xent, xent_block)
     if model.arch is not None:
+        if model.arch.block_length:
+            return _diffusion_loss(model, params, tokens, positions,
+                                   noise_key, fused_xent, xent_block)
         return _described_loss(model, params, tokens, targets, positions,
                                fused_xent, xent_block)
     mutable = ("intermediates",) if model.n_experts > 0 else False
@@ -1386,6 +619,17 @@ def _head_kernel(model, params):
     return p["lmhead"]["head"]["kernel"]
 
 
+def _fused_nll(model, w, feats, tgt, xent_block):
+    """Each position's cross-entropy of ``feats`` against ``tgt`` through
+    the head ``w`` (:func:`_head_kernel`), fused: one row a position."""
+    from ..ops.xent import fused_linear_xent
+
+    dt = model.compute_dtype
+    return fused_linear_xent(
+        feats.reshape(-1, feats.shape[-1]).astype(dt), w, tgt.reshape(-1),
+        _balanced_block(model.vocab, xent_block), dt)
+
+
 def _described_loss(model, params, tokens, targets, positions, fused_xent,
                     xent_block):
     """``(CE_main + mtp_loss_weight * CE_mtp, loads)`` of an ``arch``
@@ -1393,14 +637,7 @@ def _described_loss(model, params, tokens, targets, positions, fused_xent,
     out, mtp, loads = model.apply(params, tokens, positions, fused_xent,
                                   next_tokens=targets)
     w = _head_kernel(model, params)
-    dt = model.compute_dtype
-
-    def nll(feats, tgt):
-        from ..ops.xent import fused_linear_xent
-
-        return fused_linear_xent(
-            feats.reshape(-1, feats.shape[-1]).astype(dt), w,
-            tgt.reshape(-1), _balanced_block(model.vocab, xent_block), dt)
+    nll = lambda feats, tgt: _fused_nll(model, w, feats, tgt, xent_block)
 
     with jax.named_scope("head"):
         loss = nll(out, targets).mean() if fused_xent \
@@ -1475,13 +712,8 @@ def _diffusion_loss(model, params, tokens, positions, key, fused_xent,
     out = out[:, :s]
     with jax.named_scope("head"):
         if fused_xent:
-            from ..ops.xent import fused_linear_xent
-
-            dt = model.compute_dtype
-            per = fused_linear_xent(
-                out.reshape(b * s, -1).astype(dt), _head_kernel(model, params),
-                tokens.reshape(-1), _balanced_block(model.vocab, xent_block),
-                dt).reshape(b, s)
+            per = _fused_nll(model, _head_kernel(model, params), out, tokens,
+                             xent_block).reshape(b, s)
         else:
             per = -jnp.take_along_axis(jax.nn.log_softmax(out, axis=-1),
                                        tokens[..., None], axis=-1)[..., 0]
@@ -1614,7 +846,7 @@ def place_experts(model: "TransformerLM", state: "TrainState", tokens,
             f"{int(state.opt_state[0].count)}")
     a = model.arch
     which, of = a.expert_share
-    noisy = bool(getattr(a, "block_length", 0))
+    noisy = bool(a.block_length)
 
     @jax.jit
     def measure(params, tokens):
@@ -1631,7 +863,7 @@ def place_experts(model: "TransformerLM", state: "TrainState", tokens,
     held = a.n_routed_experts // of
     params = state.params
     # an early router is the block's leaf, not the expert layer's
-    early = getattr(a, "router_input", "ln2") == "ln1"
+    early = a.router_input == "ln1"
 
     def relabelled(node, order):
         new = dict(node, router=dict(
@@ -1678,7 +910,7 @@ def create_train_state(rng: jax.Array, model: TransformerLM,
     # mesh axes.
     # (a block-diffusion arch's sequence is two halves of whole blocks, each
     # a length the kernels tile)
-    blocks = getattr(model.arch, "block_length", 0)
+    blocks = 0 if model.arch is None else model.arch.block_length
     n = 2 * max(8, blocks) if blocks else 8
     tok = jnp.zeros((1, n), jnp.int32)
     init_model = model.clone(mesh=None)
@@ -1755,7 +987,7 @@ def make_train_step(model: TransformerLM, tx: optax.GradientTransformation,
 
     # A block-diffusion arch's step draws its noise from (noise_seed, step),
     # a micro-step's from that split; every other model has no key.
-    noisy = bool(getattr(model.arch, "block_length", 0))
+    noisy = model.arch is not None and bool(model.arch.block_length)
 
     # An ``arch`` model's loss comes with its expert layers' load vectors;
     # the step then returns ``(loss, loads)`` where the others return the

@@ -63,6 +63,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_chip
 from ..utils import profile
 
 NEG_INF = float("-inf")
@@ -1403,7 +1404,7 @@ def _plan(q, k, v, *, causal=False, q_offset=0, kv_offset=0, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_chip()
     # One geometry a kernel, from what this call can see; the kernels'
     # set-up runs on it and the process's counters keep it per shape.
     geos = []

@@ -46,6 +46,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_chip
+
 # What ``counters()["moe_layout"]`` reports of an expert layer's way back.
 COMBINE = "pallas"
 
@@ -245,6 +247,6 @@ def moe_combine(rows: jax.Array, rank: jax.Array, sizes: jax.Array,
     the pairs with ``0 <= rank < sizes.sum()``, summed in float32 and given
     in ``dtype``, (T, d), reading those rows alone."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_chip()
     return _combine(rows, rank.astype(jnp.int32), sizes, weights,
                     jnp.dtype(dtype), interpret)

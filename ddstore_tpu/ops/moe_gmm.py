@@ -56,6 +56,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_chip
+
 # What ``counters()["moe_layout"]`` reports of an expert layer's products.
 PRODUCTS = "pallas"
 
@@ -288,7 +290,7 @@ def _params(interpret):
 
 
 def _interpret(interpret):
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+    return not on_chip() if interpret is None else interpret
 
 
 def _check(lhs, contracted, g, steps, tm):

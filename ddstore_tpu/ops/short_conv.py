@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_chip
+
 # Rows of a block (all 3 C channels wide), the lanes a strip of the body
 # works on at a time (its float32 temporaries are rows x strip), and the
 # rows of the carried / fetched halo (a sublane tile; at least L - 1).
@@ -201,7 +203,7 @@ def gated_short_conv(bcu: jax.Array, taps: jax.Array, *,
         raise ValueError(f"{taps.shape[0]} taps: the carried halo holds "
                          f"{_HALO} rows, one a tap")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_chip()
     return _conv(bcu, taps, _fit_rows(bcu.shape[1], _ROWS), interpret)
 
 
@@ -352,5 +354,5 @@ def short_conv(x: jax.Array, taps: jax.Array, bias: jax.Array, *,
         raise ValueError(f"{taps.shape[0]} taps: the block of taps and "
                          f"bias holds {_HALO - 1} taps")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_chip()
     return _conv_silu(x, taps, bias, _fit_rows(x.shape[1], _ROWS), interpret)

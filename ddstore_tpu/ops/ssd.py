@@ -91,6 +91,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_chip
+
 # What ``counters()["mixer_layout"]`` reports of a Mamba-2 layer's scan.
 SCAN = "pallas"
 
@@ -423,7 +425,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     if h % g:
         raise ValueError(f"{g} groups do not divide {h} heads")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_chip()
     f32, cd = jnp.float32, x.dtype
     with jax.named_scope("ssd"):
         dt = dt.astype(f32)

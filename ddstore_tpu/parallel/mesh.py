@@ -69,17 +69,3 @@ def batch_sharding(mesh: Mesh, axis: str = "dp") -> NamedSharding:
 
 def replicate(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
-
-
-def shard_batch(mesh: Mesh, batch, axis: str = "dp"):
-    """Assemble a globally-sharded device array from this process's local
-    batch — the device-staging step of the pipeline (reference analogue:
-    ``data.to(device)`` in the DDP loop, vae-ddp.py:244; here it is a
-    sharded transfer so each DP group gets its slice with no host gather).
-
-    Works single-process (slices the local batch over local devices) and
-    multi-process (each process contributes its slice of the global batch).
-    """
-    sharding = batch_sharding(mesh, axis)
-    return jax.tree_util.tree_map(
-        lambda x: jax.make_array_from_process_local_data(sharding, x), batch)

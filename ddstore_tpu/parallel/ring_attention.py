@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops import on_chip
 from ..ops.attention import flash_attention, mha_reference
 from ..utils import profile
 
@@ -256,8 +257,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # CPU test path). On the chip a chunk shape the kernel cannot take
     # raises like an explicit "flash" does — it never slides to the
     # (S/n)×(S/n) reference.
-    use_flash = impl == "flash" or (impl == "auto"
-                                    and jax.default_backend() == "tpu")
+    use_flash = impl == "flash" or (impl == "auto" and on_chip())
     # What the kernel tiles: a chunk, or under the mask a stripe, half of
     # one.
     tile = sq // 2 if causal and n > 1 else sq

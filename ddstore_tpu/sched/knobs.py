@@ -244,16 +244,6 @@ REGISTRY: Dict[str, Knob] = {k.env: k for k in [
 ]}
 
 
-def _int_env(name: str) -> Optional[int]:
-    v = os.environ.get(name, "").strip()
-    if not v:
-        return None
-    try:
-        return int(v)
-    except ValueError:
-        return None
-
-
 def pinned_knobs(env: Optional[dict] = None) -> Dict[str, object]:
     """The planned knobs the USER froze via env vars, with their pinned
     values — the planner plans everything NOT in this dict.

@@ -441,15 +441,15 @@ def count_moe_layout(layer: str, **counts) -> None:
 
 
 def count_mixer_layout(layer: str, **counts) -> None:
-    """``models/transformer.py``, while a described layer is traced: its
+    """``models/mixers.py``, while a described layer is traced: its
     mixer's ``kind`` (``mla``, ``full_attention``, ``conv``, ``mamba2``),
     its ``heads`` and ``kv_heads`` (attention: with ``head_dim`` and the
     ``layout`` its kernels ran in) or its ``taps`` (a Mamba-2 layer's
     ``heads``, ``head_dim``, ``state``, ``groups``, ``chunk`` too, and what
     its ``scan`` ran as: ``pallas``, the kernels of ``ops/ssd.py``), the
     ``tokens`` of the call and, where attention ran under another mask than
-    the causal, that ``mask``; for an arch whose attention differs a layer,
-    the layer's ``window`` (None: causal) and whether it is ``rotary``."""
+    the causal, that ``mask``; and an attention layer's ``window`` (None:
+    causal) and whether it is ``rotary``."""
     with _lock:
         _mixer_layout[layer] = dict(counts)
 

@@ -252,8 +252,7 @@ def main():
                                            state=state,
                                            accum_steps=args.accum_steps)
         batch = 2 * dp
-        if model.arch is not None and getattr(
-                model.arch, "router_scoring", "sigmoid") == "sigmoid":
+        if model.arch is not None and model.arch.router_scoring == "sigmoid":
             # A seeded router is far from the balance a model in training
             # keeps: bring the correction biases there on the first windows
             # (a softmax router has no such bias and no such rule).

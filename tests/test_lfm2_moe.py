@@ -478,18 +478,51 @@ MLA_DESC = dict(
 DENSE_DESC = dict(vocab=128, dim=32, heads=4, layers=2)
 
 
+def configured(name):
+    """The benchmark's description ``name`` at its dry-run sizes."""
+    import json
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    return {**cfg, **cfg.get("dry_run", {})}
+
+
+def untyped(desc):
+    return {k: v for k, v in desc.items() if k != "model_type"}
+
+
+GLM = configured("glm47-flash-ep8")
+SMALLTHINKER = configured("smallthinker-21b-a3b-ep4")
+
+
 @pytest.mark.parametrize("desc,arch,mixers", [
     (DENSE_DESC, type(None), None),
     (MLA_DESC, T.MlaMoeArch, ["mla", "mla"]),
     (DESC, T.Lfm2MoeArch, DESC["layer_types"]),
     # recognised without model_type, by layer_types beside conv_L_cache
-    ({k: v for k, v in DESC.items() if k != "model_type"}, T.Lfm2MoeArch,
-     DESC["layer_types"])],
-    ids=["dense", "mla", "lfm2", "lfm2-by-keys"])
+    (untyped(DESC), T.Lfm2MoeArch, DESC["layer_types"]),
+    (configured("dense-lm-d1024"), type(None), None),
+    (GLM, T.MlaMoeArch, ["mla", "mla"]),
+    # without model_type, by q_lora_rank
+    (untyped(GLM), T.MlaMoeArch, ["mla", "mla"]),
+    (configured("nemotron3-nano-ep16"), T.NemotronHArch,
+     ["mamba2", None, "mamba2", "full_attention", None]),
+    (configured("sdar-30b-a3b-ep8"), T.SdarMoeArch, ["full_attention"] * 2),
+    # the catalog's description gives no model_type: recognised by
+    # moe_num_primary_experts beside sliding_window_layout
+    (SMALLTHINKER, T.SmallThinkerArch,
+     ["full_attention"] + ["sliding_attention"] * 3),
+    (dict(SMALLTHINKER, model_type="smallthinker"), T.SmallThinkerArch,
+     ["full_attention"] + ["sliding_attention"] * 3)],
+    ids=["dense", "mla", "lfm2", "lfm2-by-keys", "dense-config", "glm47",
+         "mla-by-keys", "nemotron", "sdar", "smallthinker-by-keys",
+         "smallthinker"])
 def test_lm_from_description_builds_each_description(desc, arch, mixers):
     model = T.lm_from_description(desc, compute_dtype=jnp.float32)
     assert isinstance(model.arch, arch)
-    assert model.vocab == 128 and model.dim == 32
+    assert model.vocab == desc.get("vocab_size", desc.get("vocab"))
+    assert model.dim == desc.get("hidden_size", desc.get("dim"))
     if mixers is not None:
         assert [model.arch.mixer(i) for i in range(model.layers)] == mixers
 

@@ -14,7 +14,7 @@ import numpy as np
 import optax
 import pytest
 
-from ddstore_tpu.models import moe, transformer as T
+from ddstore_tpu.models import mixers, moe, transformer as T
 from ddstore_tpu.utils import profile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -195,7 +195,7 @@ def test_kv_b_is_the_leaf_a_dense_layer_would_draw():
     class Split(nn.Module):
         @nn.compact
         def __call__(self, c):
-            return T._LatentKV(4, 12, 16, jnp.float32, name="kv_b")(c)
+            return mixers._LatentKV(4, 12, 16, jnp.float32, name="kv_b")(c)
 
     class Whole(nn.Module):
         @nn.compact

@@ -234,7 +234,7 @@ def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
     backend; the test answers, and only traces)."""
     import flax.linen as nn
 
-    from ddstore_tpu.models import transformer as T
+    from ddstore_tpu.models import mixers, transformer as T
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     arch = T.Lfm2MoeArch(
@@ -248,10 +248,11 @@ def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
         heads: int
         arch: object
         compute_dtype: object
+        layer: int = 0
 
         @nn.compact
         def __call__(self, x, positions):
-            return T._gqa_mixer(self, x, positions)
+            return mixers._gqa_mixer(self, x, positions)
 
     mixer = Mixer(nh * head_dim, nh, arch, jnp.float32)
     x = jax.ShapeDtypeStruct((b, s, nh * head_dim), jnp.float32)
@@ -260,7 +261,8 @@ def test_counters_name_the_layout_attention_ran_in(monkeypatch, backend,
     # the layer's key is its module path: empty, for a module on its own
     mixed = profile.counters()["mixer_layout"][""]
     assert mixed == dict(kind="full_attention", heads=nh, kv_heads=1,
-                         head_dim=head_dim, tokens=b * s, layout=layout)
+                         head_dim=head_dim, tokens=b * s, layout=layout,
+                         window=None, rotary=True)
     calls = [call for call in
              profile.counters()["flash_geometry"].get("ddstore_flash_fwd", {})
              if call.startswith(f"causal bh{b * nh} q{s}+0 k{s}+0 "

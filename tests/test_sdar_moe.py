@@ -474,9 +474,13 @@ def test_a_description_sets_the_published_and_the_objectives_keys_alone():
         DESC, router_scoring="softmax", tie_word_embeddings=False,
         expert_share=(3, 4))).arch
     assert a == T.lm_from_description(DESC).arch
-    assert set(a._fields) == set(T._SDAR_KEYS) | {"n_routed_experts",
-                                                  "expert_share"}
-    assert not set(T._SDAR_FIXED) & set(a._fields)
+    # the published keys and the objective's, then the two derived fields
+    assert set(a._fields) == {
+        "num_key_value_heads", "head_dim", "moe_intermediate_size",
+        "num_experts_per_tok", "rope_theta", "rms_norm_eps", "block_length",
+        "noise_low", "mask_token", "noise_seed"} | {"n_routed_experts",
+                                                   "expert_share"}
+    assert not set(T.SdarMoeArch.fixed()) & set(a._fields)
 
 
 def test_the_benchmarks_file_is_the_published_description():

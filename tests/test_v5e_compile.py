@@ -649,7 +649,7 @@ def test_mla_mixer_hands_the_kernels_what_its_products_write(
 
     import flax.linen as nn
 
-    from ddstore_tpu.models import transformer as T
+    from ddstore_tpu.models import mixers, transformer as T
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -666,7 +666,7 @@ def test_mla_mixer_hands_the_kernels_what_its_products_write(
 
         @nn.compact
         def __call__(self, x, positions):
-            return x + T._mla_mixer(self, x, positions)
+            return x + mixers._mla_mixer(self, x, positions)
 
     mixer = Mixer()
     on_chip = lambda t: jax.tree_util.tree_map(
